@@ -50,6 +50,9 @@ def main() -> int:
                     help="small model/images for smoke runs")
     ap.add_argument("--batch", type=int, default=8)
     args = ap.parse_args()
+    from tpurpc.utils import jaxenv
+
+    jaxenv.enable_compile_cache()  # before first use of jax
     srv, port, _, size = build_server(args.port, args.thin, args.batch)
     print(f"ResNet server on :{port} (image size {size})", flush=True)
     srv.wait_for_termination()

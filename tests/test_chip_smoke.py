@@ -1,0 +1,133 @@
+"""chip_smoke.py from the CPU side: it must FAIL where there is no TPU, its
+CPU rehearsal must pass end to end without ever reading as a chip result, the
+compile cache must go where it is told and stop growing, and the kernels
+must still compile for a v5e (ahead of time, no chip needed)."""
+
+import json
+import os
+import subprocess
+import sys
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+SMOKE = os.path.join(ROOT, "chip_smoke.py")
+
+
+def _run(args, env_extra, timeout):
+    env = dict(os.environ, JAX_PLATFORMS="cpu", **env_extra)
+    env.pop("XLA_FLAGS", None)  # one CPU device, as a user's shell has
+    return subprocess.run([sys.executable, SMOKE, *args], env=env, cwd=ROOT,
+                          capture_output=True, text=True, timeout=timeout)
+
+
+def test_no_tpu_exits_nonzero_and_names_the_device(tmp_path):
+    """No argument, no TPU: non-zero exit, the missing device named, no
+    result line. With g++ off PATH as well, the Python data plane is
+    announced loudly (and nothing is compiled, which keeps this fast)."""
+    p = _run([], {"PATH": str(tmp_path)}, timeout=120)
+    assert p.returncode != 0
+    out = p.stdout + p.stderr
+    assert "no tpu device" in out and "does not fall back" in out
+    assert "DATA PLANE: PYTHON" in p.stdout and "g++" in p.stdout
+    assert not any(line.startswith("{") for line in p.stdout.splitlines())
+
+
+def test_peak_flops_table_rejects_unknown_kind():
+    sys.path.insert(0, ROOT)
+    try:
+        import bench
+    finally:
+        sys.path.remove(ROOT)
+    peak, source = bench._peak_flops("TPU v5 lite")
+    assert peak == 197e12 and "v5e" in source
+    with pytest.raises(KeyError, match="no published peak"):
+        bench._peak_flops("TPU v9 imaginary")
+    with pytest.raises(KeyError):
+        bench._peak_flops("cpu")  # a host has no published peak either
+
+
+@pytest.mark.slow
+def test_rehearsal_passes_twice_and_the_cache_stops_growing(tmp_path):
+    """The CPU dress rehearsal end to end, twice from one checkout, with
+    the compile cache placed from outside: entries appear THERE, the second
+    run adds none, and no line can be mistaken for a chip result."""
+    cache = tmp_path / "cache"
+    counts = []
+    for _ in range(2):
+        p = _run(["--rehearsal-cpu"],
+                 {"JAX_COMPILATION_CACHE_DIR": str(cache)}, timeout=600)
+        assert p.returncode == 0, p.stdout[-3000:] + p.stderr[-3000:]
+        lines = p.stdout.strip().splitlines()
+        assert all(ln.startswith("[REHEARSAL on cpu") for ln in lines[:-1])
+        result = json.loads(lines[-1])
+        assert result["ok"] is False and result["rehearsal_passed"] is True
+        assert result["device"]["platform"] == "cpu"
+        assert result["claim"] is None
+        assert result["setup"]["cache_dir"] == str(cache)
+        tensor = result["legs"]["tensor"]
+        assert tensor["measured"]["compiles"] == 0
+        assert (tensor["measured"]["paths"]["hbm_place_scatter"]
+                == tensor["wrapped_spans_per_pass"] >= 2)
+        assert result["legs"]["serving"]["batches"] < \
+            result["legs"]["serving"]["rows"]
+        assert result["data_plane"]["plane"] in ("native", "python")
+        counts.append(len(os.listdir(cache)))
+    assert counts[0] > 0 and counts[1] == counts[0], counts
+
+
+_AOT = r"""
+import sys
+try:
+    import libtpu  # noqa: F401
+    import jax, jax.numpy as jnp
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+except Exception as exc:  # no libtpu, or another process holds its lock
+    print("SKIP", type(exc).__name__, str(exc)[:200])
+    sys.exit(0)
+dev = topo.devices[0]
+assert dev.device_kind == "TPU v5 lite", dev.device_kind
+sh = SingleDeviceSharding(dev)
+S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=sh)
+from tpurpc.ops.ring_scatter import _ring_scatter_impl
+from tpurpc.ops.ring_window import _ring_window_impl
+from tpurpc.tpu.hbm_ring import _ring_jits
+update, slice_, shaped = _ring_jits()
+cap = 1 << 20
+ring, word = S((cap,), jnp.uint8), S((1,), jnp.int32)
+for n in (1200, 4096, 1 << 16, 262148):  # 262148: odd, over the 4 KiB block
+    _ring_scatter_impl.lower(ring, S((n,), jnp.uint8), word, n_words=n // 4,
+                             interpret=False).compile()
+    _ring_window_impl.lower(ring, word, n_words=n // 4,
+                            interpret=False).compile()
+    update.lower(ring, S((n,), jnp.uint8), S((), jnp.int32)).compile()
+    slice_.lower(ring, S((), jnp.int32), n).compile()
+    shaped.lower(S((n,), jnp.uint8), jnp.dtype(jnp.float32),
+                 (n // 4,)).compile()
+print("COMPILED")
+"""
+
+
+@pytest.mark.slow
+def test_ring_programs_compile_for_v5e_ahead_of_time():
+    """From the CPU sandbox: lower and compile ring_scatter, ring_window and
+    HbmRing's update/slice/view programs for a ``TPU v5 lite`` device with
+    ``interpret=False`` — so a kernel PR learns that Mosaic (or the TPU
+    compiler's patience: see tpurpc.ops.layout) refuses it before it spends
+    a call to the chip. In a process of its own: libtpu takes a machine-wide
+    lock. Skips cleanly where libtpu cannot be had."""
+    import time
+
+    t0 = time.monotonic()
+    p = subprocess.run([sys.executable, "-c", _AOT], cwd=ROOT,
+                       env=dict(os.environ, PYTHONPATH=ROOT),
+                       capture_output=True, text=True, timeout=600)
+    assert p.returncode == 0, p.stderr[-3000:]
+    if p.stdout.startswith("SKIP"):
+        pytest.skip(p.stdout.strip())
+    assert "COMPILED" in p.stdout
+    # the whole-array flatten this guards against took 66 s per MiB
+    assert time.monotonic() - t0 < 120, "a ring program compiles slowly again"

@@ -121,32 +121,36 @@ def test_shape_guards():
 
 
 def test_hbm_ring_place_uses_kernel():
-    """HbmRing.place routes through ring_scatter (no fallback tripped) and
-    wrapped placements round-trip through view."""
-    import warnings
-
+    """HbmRing.place routes a wrapped span through ring_scatter and its view
+    through ring_window — the path counters say so — and the bytes
+    round-trip."""
+    from tpurpc.obs import metrics
     from tpurpc.tpu.hbm_ring import HbmRing
 
     ring = HbmRing(16384)
     r = _rng(10)
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")  # a kernel failure warning = test fail
-        # advance near the end so the next placement wraps
-        spans = []
-        for n in (8192, 4096):
-            payload = r.integers(0, 256, n, dtype=np.uint8).tobytes()
-            spans.append((ring.place(payload), payload))
-        for (off, n), payload in spans:
-            lease = ring.view(off, n)
-            got = np.asarray(lease.array)
-            np.testing.assert_array_equal(got, np.frombuffer(payload, np.uint8))
-            lease.release()
-        # wrap case: head advanced, place 8KB crossing the 16KB boundary
-        payload = r.integers(0, 256, 8192, dtype=np.uint8).tobytes()
-        off, n = ring.place(payload)
-        assert (off & (16384 - 1)) + n > 16384  # really wraps
-        with ring.view(off, n) as arr:
-            np.testing.assert_array_equal(
-                np.asarray(arr), np.frombuffer(payload, np.uint8))
-    assert not getattr(ring, "_pallas_place_broken", False)
-    assert not getattr(ring, "_pallas_broken", False)
+    before = metrics.registry().counters_snapshot()
+    # advance near the end so the next placement wraps
+    spans = []
+    for n in (8192, 4096):
+        payload = r.integers(0, 256, n, dtype=np.uint8).tobytes()
+        spans.append((ring.place(payload), payload))
+    for (off, n), payload in spans:
+        lease = ring.view(off, n)
+        got = np.asarray(lease.array)
+        np.testing.assert_array_equal(got, np.frombuffer(payload, np.uint8))
+        lease.release()
+    # wrap case: head advanced, place 8KB crossing the 16KB boundary
+    payload = r.integers(0, 256, 8192, dtype=np.uint8).tobytes()
+    off, n = ring.place(payload)
+    assert (off & (16384 - 1)) + n > 16384  # really wraps
+    with ring.view(off, n) as arr:
+        np.testing.assert_array_equal(
+            np.asarray(arr), np.frombuffer(payload, np.uint8))
+    after = metrics.registry().counters_snapshot()
+    moved = {k: after[k] - before.get(k, 0) for k in after
+             if k.startswith(("hbm_place_", "hbm_view_"))
+             and after[k] != before.get(k, 0)}
+    assert moved["hbm_place_update"] == 2 and moved["hbm_place_scatter"] == 1
+    assert moved["hbm_view_window"] == 1
+    assert "hbm_place_split" not in moved and "hbm_view_concat" not in moved

@@ -3,6 +3,7 @@
 import numpy as np
 import pytest
 
+from tpurpc.jaxshim.codec import to_jax
 from tpurpc.tpu import HbmRing, ledger
 from tpurpc.tpu.serialize import deserialize_to_device, serialize_from_device
 
@@ -56,16 +57,25 @@ def test_serialize_from_device_roundtrip():
     np.testing.assert_array_equal(np.asarray(y), np.asarray(x))
 
 
-def test_deserialize_counts_alias_on_host_backend():
+def test_deserialize_bills_its_one_movement_once():
+    """One wire record in, one ledger entry out: an alias when the payload
+    sits 64-byte aligned in a writable buffer, one h2d copy when it does
+    not — never both, never twice, and no host memcpy either way."""
     from tpurpc.jaxshim import codec
 
     x = np.arange(1024, dtype=np.float32)
-    buf = bytearray(codec.encode_tensor_bytes(x))  # writable → dlpack alias
-    with ledger.track() as w:
-        y, _ = deserialize_to_device(buf)
-    assert w["zero_copy"] >= x.nbytes
-    assert w["host_copy"] == 0
-    np.testing.assert_array_equal(np.asarray(y), x)
+    wire = codec.encode_tensor_bytes(x)
+    raw = np.zeros(len(wire) + 128, np.uint8)
+    start = -raw.ctypes.data % 64          # record (and payload) 64B-aligned
+    for shift, kind in ((0, "zero_copy"), (4, "dma_h2d")):
+        buf = raw[start + shift:start + shift + len(wire)]
+        buf[:] = np.frombuffer(wire, np.uint8)
+        with ledger.track() as w:
+            y, _ = deserialize_to_device(buf)
+        assert w[kind] == x.nbytes and w[kind + "_ops"] == 1, w.delta
+        assert w["zero_copy"] + w["dma_h2d"] == x.nbytes
+        assert w["host_copy"] == 0
+        np.testing.assert_array_equal(np.asarray(y), x)
 
 
 # -- HBM ring ----------------------------------------------------------------
@@ -139,7 +149,7 @@ def test_view_unwrapped_is_dlpack_alias_zero_copy():
     assert w["zero_copy"] == x.nbytes and w["zero_copy_ops"] == 1
     assert w["dma_d2d"] == 0 and w["dma_d2d_ops"] == 0
     np.testing.assert_array_equal(np.asarray(lease.array), x)
-    # independent pointer proof (same introspection chipcheck uses)
+    # independent pointer proof
     ring_ptr = ring._ptr_of(ring.buf)
     view_ptr = ring._ptr_of(lease.array)
     if ring_ptr is not None and view_ptr is not None:
@@ -228,7 +238,7 @@ def test_end_to_end_rx_into_hbm_ring_zero_host_copy_after_assembly():
 
 
 def test_place_is_single_landing_write_all_spans():
-    """VERDICT r3 next#6: every placement must be exactly ONE in-ring
+    """Every placement must be exactly ONE in-ring
     landing write (dma_d2d op), wrapped or not — the reference's placement
     is always one RDMA WRITE (pair.cc:587-622). The op-count ledger makes
     it assertable; on kernel-ineligible configs the fallback chain pays
@@ -252,13 +262,116 @@ def test_place_is_single_landing_write_all_spans():
         off3, n3 = ring.place(payload)
     assert (off3 & (32768 - 1)) + n3 > 32768, "span did not wrap"
     # kernel-eligible configs land the wrap in ONE aliased write; on
-    # fallback configs (TPURPC_PALLAS=0, non-cpu/tpu backends, or a
-    # latched kernel failure) the chain pays two and the ledger says so
-    kernel = (not getattr(ring, "_pallas_place_broken", False)
-              and ring._pallas_ok(off3 & (32768 - 1), n3, 2 * 9 * 512,
-                                  "_pallas_place_broken"))
+    # ineligible ones (TPURPC_PALLAS=0, a backend that is neither cpu nor
+    # tpu) the chain pays two and the ledger says so
+    kernel = ring._pallas_ok(off3 & (32768 - 1), n3, 2 * 9 * 512)
     expect = 1 if kernel else 2
     assert (w["dma_h2d_ops"], w["dma_d2d_ops"]) == (1, expect), w.delta
     lease3 = ring.view(off3, n3)
     assert bytes(np.asarray(lease3.array)) == payload
     lease3.release()
+
+
+# -- placement: JAX's default device, not device 0 ----------------------------
+
+def test_to_jax_and_default_ring_follow_default_device():
+    """``to_jax`` (writable AND read-only input) and a default ``HbmRing()``
+    land on JAX's default device. On the 8-device CPU mesh this reproduces
+    without a chip what happened on one: the dlpack import ignored the
+    default device and put every writable view on CPU device 0."""
+    import jax
+
+    d3 = jax.devices()[3]
+    x = np.arange(4096, dtype=np.float32)
+    frozen = x.view()
+    frozen.setflags(write=False)
+    with jax.default_device(d3):
+        for arr in (x, frozen):
+            out = to_jax(arr)
+            assert out.devices() == {d3}
+            np.testing.assert_array_equal(np.asarray(out), x)
+        ring = HbmRing(1 << 16)
+        assert ring.device == d3 and ring.buf.devices() == {d3}
+        off, n = ring.place(x)
+        with ring.view(off, n, np.float32, x.shape) as arr:
+            assert arr.devices() == {d3}
+            np.testing.assert_array_equal(np.asarray(arr), x)
+
+
+def test_to_jax_bills_what_happened():
+    """An aligned writable view on a CPU device is an alias (zero_copy,
+    pointer-proven); a read-only one, and a dtype dlpack cannot carry, is
+    one copy (dma_h2d) — decided up front, never by catching an import
+    error."""
+    import ml_dtypes
+
+    raw = np.zeros(4096 + 64, np.uint8)
+    start = -raw.ctypes.data % 64
+    aligned = raw[start:start + 4096].view(np.float32)
+    aligned[:] = np.arange(1024)
+    with ledger.track() as w:
+        out = to_jax(aligned)
+    assert (w["zero_copy"], w["dma_h2d"]) == (4096, 0)
+    assert out.unsafe_buffer_pointer() == aligned.ctypes.data
+    frozen = aligned.view()
+    frozen.setflags(write=False)
+    with ledger.track() as w:
+        to_jax(frozen)
+    assert (w["zero_copy"], w["dma_h2d"]) == (0, 4096)
+    bf16 = np.ones(64, ml_dtypes.bfloat16)
+    with ledger.track() as w:
+        out = to_jax(bf16)
+    assert (w["zero_copy"], w["dma_h2d"]) == (0, bf16.nbytes)
+    assert out.dtype == bf16.dtype
+
+
+# -- a failing kernel is an error, not a detour -------------------------------
+
+class _FakeTpu:
+    """Stands in for ``ring.device`` where only ``.platform`` is read."""
+    platform = "tpu"
+
+
+def test_kernel_failure_propagates_on_tpu_platform(monkeypatch):
+    """On a ring whose device says ``tpu`` the kernels are asked for
+    compiled (interpret=False), and an exception out of either one reaches
+    the caller: no latch, no warning, no slice chain taking over."""
+    import jax
+
+    import tpurpc.ops as ops_pkg
+    import tpurpc.ops.ring_scatter as scatter_mod
+    from tpurpc.obs import metrics
+
+    cap = 32768
+    ring = HbmRing(cap)
+    off, n = ring.place(b"\0" * (cap - 2048))
+    ring.view(off, n).release()
+    payload = bytes(range(256)) * 16          # 4 KiB over the 2 KiB left
+    off, n = ring.place(payload)              # lands wrapped, via the
+    assert (off & (cap - 1)) + n > cap        # interpreted kernel (CPU)
+
+    asked = []
+
+    def boom(*_a, interpret, **_kw):
+        asked.append(interpret)
+        raise RuntimeError("kernel boom")
+
+    monkeypatch.setattr(ops_pkg, "ring_window", boom)
+    monkeypatch.setattr(scatter_mod, "ring_scatter", boom)
+    before = metrics.registry().counters_snapshot()
+    ring.device = _FakeTpu()
+    with pytest.raises(RuntimeError, match="kernel boom"):
+        ring.view(off, n)
+    dev_payload = jax.device_put(np.frombuffer(payload, np.uint8))
+    with pytest.raises(RuntimeError, match="kernel boom"):
+        with ring._lock:
+            ring._land(dev_payload, off & (cap - 1), n)
+    assert asked == [False, False]
+    after = metrics.registry().counters_snapshot()
+    assert after["hbm_view_concat"] == before["hbm_view_concat"]
+    assert after["hbm_place_split"] == before["hbm_place_split"]
+    # the failed view took no lease: with the kernel back, the span reads
+    monkeypatch.undo()
+    ring.device = jax.devices()[0]
+    with ring.view(off, n) as arr:
+        assert bytes(np.asarray(arr)) == payload

@@ -310,10 +310,18 @@ def test_e2e_streaming_rolling_credit(monkeypatch):
 
 
 def test_device_method_falls_back_off_platform(monkeypatch):
-    """device=True on a TCP transport degrades to the host decode."""
+    """device=True on a TCP transport degrades to the host decode — and
+    says so: the handler sees numpy, the degrade counter moves."""
+    from tpurpc.obs import metrics
+
+    seen = []
+
     def fn(tree):
+        seen.append(type(tree["x"]))
         return {"y": np.asarray(tree["x"]) * 3}
 
+    degraded = metrics.counter("tensor_device_degraded")
+    before = degraded.snapshot()
     srv, port = _tpu_server(monkeypatch, fn, platform="TCP")
     try:
         x = np.arange(64, dtype=np.float32)
@@ -321,6 +329,8 @@ def test_device_method_falls_back_off_platform(monkeypatch):
             out = TensorClient(ch).call("Call", {"x": x}, timeout=30)
             np.testing.assert_array_equal(np.asarray(out["y"]), x * 3)
             assert ch.device_ring() is None
+        assert seen == [np.ndarray]
+        assert degraded.snapshot() == before + 1
     finally:
         srv.stop(grace=0)
 

@@ -1,8 +1,9 @@
 #!/usr/bin/env python3
-"""tpurpc-oracle bench diff (ISSUE 20): compare two ``BENCH_r*.json``
-snapshots and flag regressions, with waterfall-hop attribution.
+"""tpurpc-oracle bench diff (ISSUE 20): compare two ``bench.py`` result
+snapshots (``{"parsed": {...}}``) and flag regressions, with waterfall-hop
+attribution.
 
-    python tools/bench_diff.py BENCH_r04.json BENCH_r05.json
+    python tools/bench_diff.py old.json new.json
     python tools/bench_diff.py old.json new.json --threshold 5 --json
 
 Every numeric series in ``parsed`` is compared direction-aware:
@@ -18,8 +19,7 @@ whose relative drop is worst — the same attribution the live lens
 waterfall gives, applied to the delta ("the regression lives in the
 scatter hop"), instead of a bare "0.68 → 0.55 GB/s".
 
-Old snapshots whose ``parsed`` is null (a crashed run, e.g. the r01
-seed) still diff: every series in the other file reports as
+Snapshots whose ``parsed`` is null (a crashed run) still diff: every series in the other file reports as
 added/removed rather than crashing the tool.
 """
 
@@ -39,8 +39,8 @@ _HEADLINE = frozenset({
 
 _SKIP_SUFFIXES = ("_gate_pct", "_pass", "_error")
 _SKIP_KEYS = frozenset({
-    "n", "rc", "metric", "unit", "calibration", "fallback",
-    "fallback_reason", "device_kind", "jax_platform", "serving_model",
+    "n", "rc", "metric", "unit", "calibration",
+    "device_kind", "jax_platform", "serving_model",
     "peak_flops", "peak_flops_assumed", "peak_flops_source",
     "model_flops_per_inference", "serving_requests",
     "serving_client_depth", "serving_client_mode", "host_load",

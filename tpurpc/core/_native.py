@@ -18,8 +18,66 @@ import numpy as np
 _LIB: "Optional[ctypes.CDLL]" = None
 _SPIN: "Optional[ctypes.CDLL]" = None
 _TRIED = False
+#: why load() returned None, for status(): the Python data plane must never
+#: be what ran without anyone being able to say so
+_WHY = "load() not called yet"
 
 ABI_VERSION = 7
+
+
+def _src_dir() -> str:
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    return os.path.join(root, "native", "src")
+
+
+def sources_digest() -> str:
+    """sha256 over ``native/src`` (names and contents of every .cc/.h): what
+    an artifact must have been built from to be the checkout's data plane.
+    mtimes say nothing after a checkout or a copy; contents do."""
+    import hashlib
+
+    h = hashlib.sha256()
+    src = _src_dir()
+    for name in sorted(os.listdir(src)):
+        if name.endswith((".cc", ".h")):
+            h.update(name.encode() + b"\0")
+            with open(os.path.join(src, name), "rb") as f:
+                h.update(f.read())
+    return h.hexdigest()
+
+
+def build_from_sources(out_path: str) -> None:
+    """Compile ``native/src/*.cc`` into ``out_path`` — the one g++ invocation
+    the first-use build also makes. Raises: ``FileNotFoundError`` when there
+    is no ``g++``, ``subprocess.CalledProcessError`` (stderr attached) when
+    the compile fails."""
+    import glob
+    import shutil
+    import subprocess
+
+    gxx = shutil.which("g++")
+    if gxx is None:
+        raise FileNotFoundError("g++ not found on PATH")
+    srcs = sorted(glob.glob(os.path.join(_src_dir(), "*.cc")))
+    os.makedirs(os.path.dirname(out_path), exist_ok=True)
+    tmp = f"{out_path}.{os.getpid()}.tmp"
+    # -lrt: shm_open/shm_unlink live in librt on older glibc (< 2.34);
+    # without it the link "succeeds" but dlopen fails with an undefined
+    # symbol. Harmless where libc already provides them.
+    subprocess.run(
+        [gxx, "-std=c++17", "-O3", "-DNDEBUG", "-shared", "-fPIC", *srcs,
+         "-o", tmp, "-lpthread", "-lrt"],
+        check=True, timeout=300, capture_output=True)
+    os.replace(tmp, out_path)  # atomic: no partially-linked .so visible
+
+
+def status() -> dict:
+    """Which data plane this process runs, and why: ``{"plane": "native",
+    "path": ...}`` or ``{"plane": "python", "why": ...}``."""
+    if load() is not None:
+        return {"plane": "native", "path": _lib_path()}
+    return {"plane": "python", "why": _WHY}
 
 
 def _lib_path() -> str:
@@ -42,21 +100,17 @@ def _try_build(path: str) -> None:
     by an exclusive lockfile so concurrent processes don't race the link;
     losers wait for the winner. Failure is fine: callers fall back to the
     pure-Python data plane. ``TPURPC_NATIVE_BUILD=0`` disables."""
+    import glob
     import shutil
-    import subprocess
 
     if os.environ.get("TPURPC_NATIVE_BUILD", "1") == "0":
         return
     if os.environ.get("TPURPC_NATIVE_LIB"):
         return  # an explicitly pointed-at artifact is never auto-built
-    gxx = shutil.which("g++")
-    if gxx is None:
+    if shutil.which("g++") is None:
         return
-    import glob
-
     build_dir = os.path.dirname(path)
-    srcs = sorted(glob.glob(
-        os.path.join(os.path.dirname(build_dir), "src", "*.cc")))
+    srcs = sorted(glob.glob(os.path.join(_src_dir(), "*.cc")))
     if not srcs:
         return
     os.makedirs(build_dir, exist_ok=True)
@@ -83,85 +137,73 @@ def _try_build(path: str) -> None:
                     return
                 os.unlink(fail_stamp)
             if not os.path.exists(path):
-                tmp = path + ".tmp"
                 try:
-                    # -lrt: shm_open/shm_unlink live in librt on older glibc
-                    # (< 2.34); without it the link "succeeds" but dlopen
-                    # fails with an undefined-symbol error and the whole
-                    # native data plane silently falls back to Python — the
-                    # exact failure observed on this host. Harmless where
-                    # libc already provides them.
-                    subprocess.run(
-                        [gxx, "-std=c++17", "-O3", "-DNDEBUG", "-shared",
-                         "-fPIC", *srcs, "-o", tmp, "-lpthread", "-lrt"],
-                        check=True, timeout=120, capture_output=True)
+                    build_from_sources(path)
                 except Exception as exc:
                     # Stamp the failure so future processes skip the broken
-                    # 120s compile until the sources change.
+                    # compile until the sources change.
                     with open(fail_stamp, "w") as f:
                         f.write(f"{src_mtime}\n{type(exc).__name__}: {exc}\n")
                     return
-                os.replace(tmp, path)  # atomic: no partially-linked .so visible
     except Exception:
         pass
 
 
+def _open() -> "tuple[Optional[ctypes.CDLL], str]":
+    """``(lib, "")`` or ``(None, why)``: find, if need be build, and dlopen
+    the artifact, rebuilding a stale one from the sources once."""
+    if os.environ.get("TPURPC_NATIVE", "1") == "0":
+        return None, "TPURPC_NATIVE=0"
+    path = _lib_path()
+    explicit = bool(os.environ.get("TPURPC_NATIVE_LIB"))
+    if not os.path.exists(path):
+        _try_build(path)
+    if not os.path.exists(path):
+        return None, (f"{path} is absent and was not built (g++ missing, "
+                      "build failed or disabled)")
+    for attempt in (0, 1):
+        try:
+            # PyDLL: calls run WITH the GIL held. The ring ops take raw
+            # pointers into shm segments whose lifetime is managed by Python
+            # memoryview release + munmap on other threads; holding the GIL
+            # makes each [liveness-check → native call] pair atomic against
+            # teardown, the exact safety the pure-Python slicing path gets
+            # implicitly.
+            lib = ctypes.PyDLL(path)
+            if lib.tpr_abi_version() == ABI_VERSION:
+                return lib, ""
+            why = (f"{path} has ABI {lib.tpr_abi_version()}, "
+                   f"want {ABI_VERSION}")
+        except OSError as exc:
+            # observed: a build without -lrt leaves shm_open undefined on
+            # older glibc and dlopen fails
+            why = f"dlopen {path}: {exc}"
+        # A stale or mis-linked artifact: rebuild from the sources on disk
+        # once instead of dropping the whole native data plane to Python
+        # for the life of the process. An explicitly pointed-at
+        # TPURPC_NATIVE_LIB is never deleted or rebuilt.
+        if attempt or explicit:
+            return None, why
+        try:
+            os.unlink(path)
+        except OSError:
+            return None, why
+        _try_build(path)
+        if not os.path.exists(path):
+            return None, why + "; rebuild failed"
+    return None, why
+
+
 def load() -> "Optional[ctypes.CDLL]":
-    """The native library, or None (absent, disabled, or ABI-mismatched)."""
-    global _LIB, _TRIED
+    """The native library, or None (absent, disabled, or ABI-mismatched);
+    :func:`status` says which and why."""
+    global _LIB, _TRIED, _WHY
     if _TRIED:
         return _LIB
     _TRIED = True
-    if os.environ.get("TPURPC_NATIVE", "1") == "0":
+    lib, _WHY = _open()
+    if lib is None:
         return None
-    path = _lib_path()
-    if not os.path.exists(path):
-        _try_build(path)
-    if not os.path.exists(path):
-        return None
-    try:
-        # PyDLL: calls run WITH the GIL held. The ring ops take raw pointers
-        # into shm segments whose lifetime is managed by Python memoryview
-        # release + munmap on other threads; holding the GIL makes each
-        # [liveness-check → native call] pair atomic against teardown, the
-        # exact safety the pure-Python slicing path gets implicitly.
-        lib = ctypes.PyDLL(path)
-    except OSError:
-        # A stale or mis-linked artifact fails dlopen (observed: a build
-        # without -lrt leaves shm_open undefined on older glibc). Rebuild
-        # from sources once instead of silently dropping the whole native
-        # data plane to Python for the life of the process.
-        try:
-            os.unlink(path)
-        except OSError:
-            return None
-        _try_build(path)
-        if not os.path.exists(path):
-            return None
-        try:
-            lib = ctypes.PyDLL(path)
-        except OSError:
-            return None
-    if lib.tpr_abi_version() != ABI_VERSION:
-        # A stale artifact from an older checkout: rebuild from the sources
-        # on disk instead of silently dropping the native data plane (the
-        # same recovery the dlopen-failure path gets). An explicitly
-        # pointed-at TPURPC_NATIVE_LIB is never deleted or rebuilt.
-        if os.environ.get("TPURPC_NATIVE_LIB"):
-            return None
-        try:
-            os.unlink(path)
-        except OSError:
-            return None
-        _try_build(path)
-        if not os.path.exists(path):
-            return None
-        try:
-            lib = ctypes.PyDLL(path)
-        except OSError:
-            return None
-        if lib.tpr_abi_version() != ABI_VERSION:
-            return None
     u64 = ctypes.c_uint64
     pu64 = ctypes.POINTER(u64)
     pu8 = ctypes.c_void_p
@@ -195,7 +237,7 @@ def load() -> "Optional[ctypes.CDLL]":
     # must not starve the very threads that produce what it waits for.
     # Callers pin the watched memory (an exported buffer view) across the
     # call; Region.close retries on BufferError until waiters unpin.
-    spin = ctypes.CDLL(path)
+    spin = ctypes.CDLL(_lib_path())
     spin.tpr_ring_wait_message.restype = ctypes.c_int
     spin.tpr_ring_wait_message.argtypes = [pu8, u64, u64, u64, u64]
     spin.tpr_spin_u64_change.restype = ctypes.c_int
@@ -238,7 +280,8 @@ def pin(buf, writable: bool):
 
 
 def reset_for_tests() -> None:
-    global _LIB, _SPIN, _TRIED
+    global _LIB, _SPIN, _TRIED, _WHY
     _LIB = None
     _SPIN = None
     _TRIED = False
+    _WHY = "load() not called yet"

@@ -229,37 +229,43 @@ def decode_tensor(buf, offset: int = 0, copy: bool = False) -> Tuple[np.ndarray,
 
 
 def to_jax(arr: np.ndarray):
-    """Host view → jax.Array.
+    """Host view → jax.Array on JAX's default device (``jax.default_device``
+    respected), always.
 
-    On the CPU backend dlpack import aliases the numpy buffer (zero copy); on
-    TPU this is the one host→HBM DMA of the receive path. The HBM-resident
-    ring (tpurpc/tpu/hbm_ring.py) removes even that for the north-star path.
+    When that device is a CPU device, a writable view of a dtype dlpack can
+    carry is imported in place: XLA adopts a 64-byte-aligned buffer without
+    moving it (ledger ``zero_copy``, proven by pointer, never assumed).
+    Everything else — an accelerator, a read-only view, bfloat16/fp8, a
+    strided view — is ONE ``device_put``, billed ``dma_h2d``: on a TPU the one
+    host→HBM DMA of the receive path.
     """
     import jax
 
     from tpurpc.tpu import ledger
+    from tpurpc.utils.jaxenv import default_device
 
     t0 = time.monotonic_ns()
     nbytes = arr.nbytes
-    try:
-        if not arr.flags.writeable:
-            # jax dlpack import refuses read-only buffers; device_put
-            # instead (still a single copy onto device / into the arena).
-            ledger.dma_h2d(nbytes)
-            _LENS_JAX_COPY.inc(nbytes)
-            return jax.device_put(arr)
-        try:
-            out = jax.dlpack.from_dlpack(arr)
-            ledger.zero_copy(nbytes)
-            return out
-        except (TypeError, RuntimeError, ValueError):
-            ledger.dma_h2d(nbytes)
-            _LENS_JAX_COPY.inc(nbytes)
-            return jax.device_put(arr)
-    finally:
-        dt = time.monotonic_ns() - t0
-        _LENS_JAX_NS.inc(dt)
-        _LENS_JAX_BYTES.inc(nbytes)
+    dev = default_device()
+    aliased = False
+    # what numpy's __dlpack__ exports and jax's import accepts: anything
+    # else would raise, and an exception must not be what picks the device
+    if (dev.platform == "cpu" and arr.flags.writeable
+            and arr.flags.c_contiguous and arr.dtype.kind in "biufc"):
+        out = jax.dlpack.from_dlpack(arr, device=dev)
+        aliased = (out.unsafe_buffer_pointer()
+                   == arr.__array_interface__["data"][0])
+    else:
+        out = jax.device_put(arr, dev)
+    if aliased:
+        ledger.zero_copy(nbytes)
+    else:
+        ledger.dma_h2d(nbytes)
+        _LENS_JAX_COPY.inc(nbytes)
+    dt = time.monotonic_ns() - t0
+    _LENS_JAX_NS.inc(dt)
+    _LENS_JAX_BYTES.inc(nbytes)
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -272,10 +278,14 @@ TREE_MAGIC = b"TPTR"
 
 def encode_tree(tree: Any) -> List[bytes]:
     """Encode an arbitrary pytree of arrays as a gather list."""
-    import jax
+    leaves: list = []
+    skeleton = _plain_flatten(tree, leaves)
+    if skeleton is None:  # a container only jax's pytree registry can judge
+        import jax
 
-    leaves, treedef = jax.tree_util.tree_flatten(tree)
-    trailer = json.dumps(_treedef_to_json(treedef)).encode()
+        leaves, treedef = jax.tree_util.tree_flatten(tree)
+        skeleton = _treedef_to_json(treedef)
+    trailer = json.dumps(skeleton).encode()
     segs: List[bytes] = [_TREE.pack(TREE_MAGIC, len(leaves), len(trailer))]
     pad = (-_TREE.size) % _ALIGN
     if pad:
@@ -314,8 +324,6 @@ def decode_tree_at(buf, offset: int = 0, copy: bool = False,
     buffer (memoryview offsets all the way down, no intermediate ``bytes``
     slices of the payload).
     """
-    import jax
-
     t0 = time.monotonic_ns()
     view = memoryview(buf)
     if len(view) - offset < _TREE.size:
@@ -336,8 +344,7 @@ def decode_tree_at(buf, offset: int = 0, copy: bool = False,
     if len(view) - pos < trailer_len:
         raise CodecError("short tree trailer")
     trailer = view[pos:pos + trailer_len].tobytes()
-    treedef = _treedef_from_json(json.loads(trailer.decode()))
-    out = jax.tree_util.tree_unflatten(treedef, leaves), pos + trailer_len
+    out = unflatten(json.loads(trailer.decode()), leaves), pos + trailer_len
     # tpurpc-lens `decode` hop: one bump set per tree record (to_jax's
     # share is also visible on its own jax_array row — hops may nest)
     dt = time.monotonic_ns() - t0
@@ -425,25 +432,75 @@ def _skel_to_json(s) -> Any:
     raise CodecError(f"unsupported pytree node {type(s)!r}")
 
 
-def _json_to_skel(j) -> Any:
+def _plain_flatten(tree, leaves: list) -> Any:
+    """JSON skeleton of a tree built from exactly dict/list/tuple/None, with
+    its leaves appended to ``leaves`` in jax's flatten order (dict keys
+    sorted) — computed without importing jax, so a process that only ships
+    numpy tensors never loads it. Returns None for a tree holding anything
+    else (namedtuple, OrderedDict, a registered node class): only jax's
+    registry knows how those flatten, and the caller asks it."""
+    if tree is None:
+        return _NONE
+    t = type(tree)
+    if t is list or t is tuple:
+        items = []
+        for v in tree:
+            j = _plain_flatten(v, leaves)
+            if j is None:
+                return None
+            items.append(j)
+        return {"__seq__": "list" if t is list else "tuple", "items": items}
+    if t is dict:
+        try:
+            keys = sorted(tree)
+        except TypeError:
+            return None
+        pairs = []
+        for k in keys:
+            j = _plain_flatten(tree[k], leaves)
+            if j is None:
+                return None
+            pairs.append([_key_to_json(k), j])
+        return {"__dict__": pairs}
+    if (isinstance(tree, (np.ndarray, np.generic, bool, int, float, complex))
+            or hasattr(tree, "__array__")):
+        leaves.append(tree)
+        return _LEAF
+    return None
+
+
+def unflatten(skeleton, leaves: list) -> Any:
+    """Rebuild the tree a decoded JSON ``skeleton`` describes around
+    ``leaves`` (flatten order). The wire format only carries
+    dict/list/tuple/None nodes, so this needs no jax; dict entries are
+    visited in sorted-key order whatever order the sender wrote them in,
+    which is the order jax flattens — and a jax-side sender encodes — in."""
+    it = iter(leaves)
+    try:
+        tree = _fill(skeleton, it)
+    except StopIteration:
+        raise CodecError("treedef has more leaves than the message") from None
+    for _ in it:
+        raise CodecError("message has more leaves than its treedef")
+    return tree
+
+
+def _fill(j, it) -> Any:
     if j == _LEAF:
-        return _SENTINEL
+        return next(it)
     if j == _NONE:
         return None
-    if "__seq__" in j:
-        items = [_json_to_skel(v) for v in j["items"]]
+    if isinstance(j, dict) and "__seq__" in j:
+        items = [_fill(v, it) for v in j["items"]]
         return items if j["__seq__"] == "list" else tuple(items)
-    if "__dict__" in j:
-        return {_key_from_json(k): _json_to_skel(v) for k, v in j["__dict__"]}
+    if isinstance(j, dict) and "__dict__" in j:
+        pairs = [(_key_from_json(k), v) for k, v in j["__dict__"]]
+        try:
+            pairs.sort(key=lambda kv: kv[0])
+        except TypeError:
+            pass  # unsortable mixed keys: the sender's order stands
+        return {k: _fill(v, it) for k, v in pairs}
     raise CodecError(f"bad treedef json {j!r}")
-
-
-def _treedef_from_json(j) -> Any:
-    import jax
-
-    skeleton = _json_to_skel(j)
-    return jax.tree_util.tree_structure(
-        skeleton, is_leaf=lambda x: x is _SENTINEL)
 
 
 # Serializer/Deserializer adapters for the rpc layer.

@@ -87,8 +87,9 @@ def resnet18_thin(num_classes: int = 1000, dtype=jnp.float32) -> ResNet:
 def init_resnet(key, model: ResNet, image_size: int = 224,
                 batch: int = 1):
     x = jnp.zeros((batch, image_size, image_size, 3), jnp.float32)
-    variables = model.init(key, x, train=False)
-    return variables
+    # one jitted program, not one eager dispatch (and one compile) per
+    # initializer: ResNet-50 has 161 parameter arrays
+    return jax.jit(functools.partial(model.init, train=False))(key, x)
 
 
 def make_infer_fn(model: ResNet) -> Callable:

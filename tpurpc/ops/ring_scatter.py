@@ -46,6 +46,7 @@ import numpy as np
 
 import jax
 
+from tpurpc.ops.layout import bytes_to_words, words_to_bytes
 from tpurpc.ops.ring_window import (_C, _R, _SCRATCH_ROWS, _flat_roll_neg,
                                     _flat_roll_pos)
 
@@ -119,16 +120,13 @@ def _ring_scatter_impl(buf_u8, payload_u8, start_word, *, n_words: int,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    buf_words = jax.lax.bitcast_convert_type(
-        buf_u8.reshape(-1, 4), jnp.uint32).reshape(-1, _C)
+    buf_words = bytes_to_words(buf_u8)
     rows = buf_words.shape[0]
     block = _R * _C
     padded = ((n_words + block - 1) // block) * block
-    pay_words = jax.lax.bitcast_convert_type(
-        payload_u8.reshape(-1, 4), jnp.uint32).reshape(-1)
-    pay_words = jnp.concatenate(
-        [pay_words, jnp.zeros((padded - n_words,), jnp.uint32)]
-    ).reshape(-1, _C)
+    # zero-pad to whole (8,128) blocks while still bytes (see ops.layout)
+    pay_words = bytes_to_words(
+        jnp.pad(payload_u8, (0, 4 * (padded - n_words))))
     grid = (padded // block,)
     out = pl.pallas_call(
         functools.partial(_kernel, rows=rows, n_words=n_words),
@@ -146,8 +144,7 @@ def _ring_scatter_impl(buf_u8, payload_u8, start_word, *, n_words: int,
         input_output_aliases={2: 0},  # the ring updates in place
         interpret=interpret,
     )(start_word, pay_words, buf_words)
-    return jax.lax.bitcast_convert_type(
-        out.reshape(-1, 1), jnp.uint8).reshape(-1)
+    return words_to_bytes(out)
 
 
 def ring_scatter(buf, payload, start: int, *, interpret: bool = False):

@@ -35,6 +35,8 @@ import numpy as np
 
 import jax
 
+from tpurpc.ops.layout import bytes_to_words, words_to_bytes
+
 #: lanes per row (TPU vector lane width)
 _C = 128
 #: output rows per program: (8, 128) is the minimal uint32 tile
@@ -124,13 +126,13 @@ def _kernel(head_ref, buf_ref, out_ref, scr_a, scr_b, sem_a, sem_b,
 @functools.partial(jax.jit, static_argnames=("n_words", "interpret"))
 def _ring_window_impl(buf_u8, head_word, *, n_words: int, interpret: bool):
     """One compiled dispatch: uint8→uint32 bitcast, the pallas gather, and
-    the uint32→uint8 bitcast all fuse under this jit."""
+    the uint32→uint8 bitcast all under this jit (tpurpc.ops.layout says why
+    the bitcasts are spelt the way they are)."""
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    buf_words = jax.lax.bitcast_convert_type(
-        buf_u8.reshape(-1, 4), jnp.uint32).reshape(-1, _C)
+    buf_words = bytes_to_words(buf_u8)
     rows = buf_words.shape[0]
     block = _R * _C
     padded = ((n_words + block - 1) // block) * block
@@ -150,8 +152,7 @@ def _ring_window_impl(buf_u8, head_word, *, n_words: int, interpret: bool):
                         pltpu.SemaphoreType.DMA],
         interpret=interpret,
     )(head_word, buf_words)
-    return jax.lax.bitcast_convert_type(
-        out.reshape(-1)[:n_words].reshape(-1, 1), jnp.uint8).reshape(-1)
+    return words_to_bytes(out)[:4 * n_words]
 
 
 def ring_window(buf, head: int, n: int, *, interpret: bool = False):

@@ -67,8 +67,6 @@ def initialize_cluster(coordinator: Optional[str] = None,
     impl = os.environ.get("TPURPC_CPU_COLLECTIVES", "gloo")
     try:
         jax.config.update("jax_cpu_collectives_implementation", impl)
-    except AttributeError:
-        pass  # older jax without the knob
     except ValueError:
         if "TPURPC_CPU_COLLECTIVES" in os.environ:
             raise  # an explicitly-set bad value must fail loudly

@@ -28,18 +28,12 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # jax >= 0.8
-    from jax import shard_map as _shard_map
-    _CHECK_KW = "check_vma"
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _CHECK_KW = "check_rep"
-
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_rep: bool = False):
-    """Version-stable shard_map (jax renamed check_rep → check_vma in 0.8)."""
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **{_CHECK_KW: check_rep})
+    """``jax.shard_map`` with replication checking off unless asked for
+    (``check_rep`` is what jax now calls ``check_vma``)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_rep)
 
 AXES = ("dp", "pp", "sp", "tp", "ep")
 

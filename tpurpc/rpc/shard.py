@@ -454,6 +454,12 @@ class ShardedServer:
             if w.alive:
                 w.alive = False
                 died = not w.stopping and not self._stopping
+        if died and w.scrape_port is None and self._fatal is None:
+            # never reported ready: start() must fail now, with the reason,
+            # not after its ready timeout (an abort inside a native runtime
+            # sends no "fatal" message)
+            self._fatal = (f"shard {w.shard_id} (pid {w.pid}) died during "
+                           f"start-up, wait status {status}")
         if died:
             # tpurpc-manycore death contract: the shard's connections are
             # gone (clients got UNAVAILABLE and re-dial onto live shards —

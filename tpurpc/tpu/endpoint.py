@@ -169,8 +169,6 @@ def decode_tree_to_ring(ring: HbmRing, buf,
     """
     import json
 
-    import jax
-
     t0 = time.monotonic_ns()
     view = memoryview(buf)
     magic, n_leaves, trailer_len = codec._TREE.unpack_from(view, 0)
@@ -208,8 +206,7 @@ def decode_tree_to_ring(ring: HbmRing, buf,
             leases.append(lease)
             leaves.append(lease.array)
         trailer = bytes(view[pos:pos + trailer_len])
-        treedef = codec._treedef_from_json(json.loads(trailer.decode()))
-        tree = jax.tree_util.tree_unflatten(treedef, leaves)
+        tree = codec.unflatten(json.loads(trailer.decode()), leaves)
     except Exception:
         # Corrupt leaf, trailer, or treedef: every already-taken lease must
         # go back, or a poison message permanently pins ring credit — and

@@ -8,22 +8,27 @@ XLA-visible pieces so the protocol, lease discipline, and copy ledger are
 real even though the placement is a ``device_put``:
 
 * ``place`` — one h2d movement per payload (ledger: dma_h2d) followed by the
-  donated-buffer ``dynamic_update_slice`` that lands it in the ring. The
-  in-ring landing write moves the payload a second time ON DEVICE, and the
-  ledger records it as ``dma_d2d`` — two honest entries for two real
-  movements (on NIC hardware the DMA writes the ring directly and both
-  entries collapse into the NIC's single placement write).
-* ``view`` — for aligned, unwrapped spans on the emulated (CPU-backed)
-  platform: a **dlpack alias** of the ring bytes themselves — a
-  ``jax.Array`` whose buffer pointer is ``ring_base + offset``, zero bytes
-  moved, ledger ``zero_copy`` (round-4 chipcheck proved the seam:
-  ``dlpack_ptr_same: true``; round 5 makes the receive path use it).
+  landing write that puts it in the ring: a donated ``dynamic_update_slice``,
+  or the ring_scatter kernel when the span wraps. The landing write moves
+  the payload a second time ON DEVICE, and the ledger records it as
+  ``dma_d2d`` — two honest entries for two real movements (on NIC hardware
+  the DMA writes the ring directly and both entries collapse into the NIC's
+  single placement write).
+* ``view`` — for aligned, unwrapped spans on a CPU device: a **dlpack
+  alias** of the ring bytes themselves — a ``jax.Array`` whose buffer
+  pointer is ``ring_base + offset``, zero bytes moved, ledger ``zero_copy``.
   Aliasing is **verified per view** by pointer comparison — an import the
   backend chose to copy (misaligned span, exotic dtype) is recorded as
-  ``dma_d2d``, honestly. Wrapped spans and real-TPU backends use
-  ``dynamic_slice`` (+ bitcast): a device copy, recorded as ``dma_d2d``
-  (on real hardware the aliasing seam is the dmabuf export, out of this
-  environment's reach). Payload bytes never touch the host either way.
+  ``dma_d2d``, honestly. On a TPU every view is a device copy, recorded as
+  ``dma_d2d``: ``dynamic_slice`` (+ bitcast) for an unwrapped span, the
+  ring_window kernel for a wrapped one (on real hardware the aliasing seam
+  is the dmabuf export, out of this environment's reach). Payload bytes
+  never touch the host either way.
+
+  Which path each placement and each view took is counted
+  (``hbm_place_{update,scatter,split}``, ``hbm_view_{alias,slice,window,
+  concat}`` in the metrics registry), and a kernel that fails raises: no
+  path gives way to another behind the caller's back.
 
   The alias relies on one invariant the real hardware has by construction
   (a pinned ring is never reallocated): XLA's donation must keep the ring
@@ -53,6 +58,7 @@ here the drain's landing target is device memory.
 
 from __future__ import annotations
 
+import functools
 import threading
 import time
 from typing import Dict, Optional, Tuple
@@ -71,6 +77,15 @@ _HBM_PLACE_MSGS = _metrics.counter("hbm_place_msgs")
 _HBM_PLACE_BYTES = _metrics.counter("hbm_place_bytes")
 _HBM_RINGS = _metrics.fleet("hbm_ring_occupancy_bytes",
                             lambda r: r.tail - r.head)
+#: which code path each landing write and each view took — read these, do
+#: not guess: `update` one donated dynamic_update_slice, `scatter` the
+#: ring_scatter kernel, `split` two updates across the wrap (kernel
+#: ineligible); `alias` dlpack view, `slice` one dynamic_slice, `window` the
+#: ring_window kernel, `concat` slice+slice+concatenate (kernel ineligible)
+_PLACE_PATH = {k: _metrics.counter(f"hbm_place_{k}")
+               for k in ("update", "scatter", "split")}
+_VIEW_PATH = {k: _metrics.counter(f"hbm_view_{k}")
+              for k in ("alias", "slice", "window", "concat")}
 
 # tpurpc-lens (ISSUE 8): the `hbm` waterfall hop — bytes landed in the
 # device ring and the nanoseconds the placement dispatch took, one bump
@@ -81,10 +96,38 @@ _LENS_HBM_BYTES, _LENS_HBM_NS, _LENS_HBM_COPY = _lens.hop_counters("hbm")
 _LENS_STAGES = {
     "place": "hbm-place",
     "place_many": "hbm-place",
-    "_pallas_place": "hbm-place",
+    "_land": "hbm-place",
     "view": "device-dispatch",
 }
 _profiler.register_stages(__file__, _LENS_STAGES)
+
+
+@functools.lru_cache(maxsize=None)
+def _ring_jits():
+    """``(update, slice, shaped)``, jitted once per PROCESS, not per ring:
+    jit's in-memory cache is keyed by function identity, so per-ring closures
+    made every new connection retrace and reload each payload size it had
+    already seen on another. ``update`` donates the ring; ``slice``'s length
+    is static (one program per payload size); ``shaped`` reinterprets a
+    span's bytes as ``dtype[shape]`` in one dispatch."""
+    import jax
+    from jax import lax
+
+    def update(buf, payload, start):
+        return lax.dynamic_update_slice(buf, payload, (start,))
+
+    def slice_(buf, start, n):
+        return lax.dynamic_slice(buf, (start,), (n,))
+
+    def shaped(seg, dtype, shape):
+        from tpurpc.ops.layout import bytes_as
+
+        out = bytes_as(seg, dtype)
+        return out if shape is None else out.reshape(shape)
+
+    return (jax.jit(update, donate_argnums=0),
+            jax.jit(slice_, static_argnums=2),
+            jax.jit(shaped, static_argnums=(1, 2)))
 
 
 class HbmRing:
@@ -99,7 +142,9 @@ class HbmRing:
         self.capacity = capacity
         self._mask = capacity - 1
         if device is None:
-            device = jax.devices()[0]
+            from tpurpc.utils.jaxenv import default_device
+
+            device = default_device()
         self.device = device
         self.buf = jax.device_put(jnp.zeros((capacity,), jnp.uint8), device)
         self.tail = 0   # absolute bytes ever placed
@@ -120,18 +165,7 @@ class HbmRing:
         #: to build the alias and to verify stability across donations
         self._base_ptr = self._ptr_of(self.buf)
 
-        def _update(buf, payload, start):
-            import jax.lax as lax
-            return lax.dynamic_update_slice(buf, payload, (start,))
-
-        self._update = jax.jit(_update, donate_argnums=0)
-
-        def _slice(buf, start, n):
-            import jax.lax as lax
-            return lax.dynamic_slice(buf, (start,), (n,))
-
-        # n is static per shape; jit caches per payload size
-        self._slice = jax.jit(_slice, static_argnums=2)
+        self._update, self._slice, self._shaped = _ring_jits()
 
     @staticmethod
     def _ptr_of(arr) -> Optional[int]:
@@ -199,70 +233,47 @@ class HbmRing:
             return None
         return arr, self._ptr_of(arr) == self._base_ptr + p
 
-    def _pallas_ok(self, p: int, n: int, min_capacity: int,
-                   broken_attr: str) -> bool:
-        """Shared eligibility guard for the place/view kernels: first-failure
-        latch, 4-byte alignment, capacity floor, validated platforms, env
-        opt-out (``TPURPC_PALLAS=0``)."""
+    def _pallas_ok(self, p: int, n: int, min_capacity: int) -> bool:
+        """Shared eligibility guard for the place/view kernels: 4-byte
+        alignment, capacity floor, validated platforms, env opt-out
+        (``TPURPC_PALLAS=0``). A kernel that is eligible and fails RAISES —
+        nothing here remembers a failure and quietly takes the jax-op chain,
+        on any platform: the path counters must mean what they say."""
         import os
 
-        return not (getattr(self, broken_attr, False)
-                    or p % 4 or n % 4 or self.capacity < min_capacity
+        return not (p % 4 or n % 4 or self.capacity < min_capacity
                     or self.device.platform not in ("cpu", "tpu")
                     or os.environ.get("TPURPC_PALLAS", "1") == "0")
 
-    def _pallas_place(self, dev_payload, p: int, n: int) -> bool:
-        """Land ``dev_payload`` at physical offset ``p`` via the aliased
-        ring_scatter kernel (tpurpc.ops.ring_scatter) — ONE landing write
-        per placement, wrapped or not (the kernel's wrap window is
-        conditional, so the unwrapped span is the same single aliased
-        dispatch; the reference's placement is always one RDMA WRITE,
-        ``pair.cc:587-622``). Returns False to use the jax-op chain."""
-        if not self._pallas_ok(p, n, 2 * 9 * 512, "_pallas_place_broken"):
-            return False
-        on_cpu = self.device.platform == "cpu"
-        try:
+    def _land(self, dev_payload, p: int, n: int) -> None:
+        """The in-ring landing write of ``n`` device-resident bytes at
+        physical offset ``p`` (caller holds ``self._lock``): ONE write per
+        placement — a donated ``dynamic_update_slice`` when the span fits
+        before the edge, the aliased ring_scatter kernel
+        (tpurpc.ops.ring_scatter; compiled on TPU, interpreted on CPU) when
+        it wraps — and two donated updates only where the kernel is
+        ineligible, which the ledger and the path counter both say.
+        Rebinding ``self.buf`` under the lock: view() must never slice a
+        just-donated (deleted) binding."""
+        first = min(n, self.capacity - p)
+        if first >= n:
+            self.buf = self._update(self.buf, dev_payload, p)
+            ledger.dma_d2d(n)
+            _PLACE_PATH["update"].inc()
+        elif self._pallas_ok(p, n, 2 * 9 * 512):
             from tpurpc.ops.ring_scatter import ring_scatter
 
             self.buf = ring_scatter(self.buf, dev_payload, p,
-                                    interpret=on_cpu)
-            return True
-        except Exception as exc:
-            # ring_scatter DONATES the ring. A compile-time failure (the
-            # usual Mosaic/tunnel mode) raises before launch, so the buffer
-            # is intact and falling back is safe. A post-launch runtime
-            # failure consumed the donation — the old contents are gone and
-            # "fallback" would update a deleted array after tail/_live were
-            # advanced: surface the corruption honestly instead.
-            if getattr(self.buf, "is_deleted", lambda: False)():
-                raise
-            self._pallas_place_broken = True
-            import warnings
-
-            warnings.warn(f"pallas ring_scatter disabled after failure: {exc}")
-            return False
-
-    def _pallas_window(self, p: int, n: int):
-        """Fused wrapped-window gather (tpurpc.ops.ring_window), or None to
-        use the jax-op chain. The kernel is validated on real TPU hardware
-        (v5e) and in interpret mode (CPU, where the suite runs it on every
-        wrapped view) — on by default, ``TPURPC_PALLAS=0`` opts out."""
-        if not self._pallas_ok(p, n, 9 * 512, "_pallas_broken"):
-            return None  # ineligible, or failed once (don't re-pay per view)
-        on_cpu = self.device.platform == "cpu"
-        try:
-            from tpurpc.ops import ring_window
-
-            return ring_window(self.buf, p, n, interpret=on_cpu)
-        except Exception as exc:
-            # kernel trouble: the slice+concat chain is law. Remember and
-            # warn ONCE — retracing a failing kernel on every wrapped view
-            # (under self._lock, on the consume hot path) is not acceptable.
-            self._pallas_broken = True
-            import warnings
-
-            warnings.warn(f"pallas ring_window disabled after failure: {exc}")
-            return None
+                                    interpret=self.device.platform == "cpu")
+            ledger.dma_d2d(n)
+            _PLACE_PATH["scatter"].inc()
+        else:
+            self.buf = self._update(self.buf, dev_payload[:first], p)
+            ledger.dma_d2d(first)
+            self.buf = self._update(self.buf, dev_payload[first:], 0)
+            ledger.dma_d2d(n - first)
+            _PLACE_PATH["split"].inc()
+        self._assert_stable()
 
     # -- producer ------------------------------------------------------------
 
@@ -308,33 +319,12 @@ class HbmRing:
             self.tail += n
             self._live[(off, n)] = [0, False]
             p = off & self._mask
-            dev = jax.device_put(jax.numpy.asarray(src), self.device)
+            # The h2d transfer and the landing write stay separate
+            # movements: XLA cannot land a host transfer at an offset of an
+            # existing device buffer (a NIC-DMA'd ring would fuse them).
+            dev = jax.device_put(src, self.device)
             ledger.dma_h2d(n)
-            first = min(n, self.capacity - p)
-            # Single-landing-write invariant (VERDICT r3 next#6, assertable
-            # via the ledger's op counts): every placement is exactly ONE
-            # in-ring write — the unwrapped case as one donated
-            # dynamic_update_slice, the wrapped case through the aliased
-            # ring_scatter kernel (two donated updates only when the kernel
-            # is ineligible, and then the ledger says so honestly). The
-            # h2d transfer stays a separate movement: XLA cannot land a
-            # host transfer at an offset of an existing device buffer
-            # (chipcheck's aliasing verdict) — a real NIC-DMA'd ring would
-            # fuse them, which is exactly what the dlpack import seam is
-            # reserved for.
-            if first >= n:  # unwrapped: already a single landing write
-                # Donating update: rebinding self.buf under the lock —
-                # view() must never slice a just-donated (deleted) binding.
-                self.buf = self._update(self.buf, dev, p)
-                ledger.dma_d2d(n)
-            elif self._pallas_place(dev, p, n):
-                ledger.dma_d2d(n)  # one aliased kernel write across the wrap
-            else:
-                self.buf = self._update(self.buf, dev[:first], p)
-                ledger.dma_d2d(first)
-                self.buf = self._update(self.buf, dev[first:], 0)
-                ledger.dma_d2d(n - first)
-            self._assert_stable()
+            self._land(dev, p, n)
         dt = time.monotonic_ns() - t0
         _HBM_PLACE_MSGS.inc()
         _HBM_PLACE_BYTES.inc(n)
@@ -391,20 +381,9 @@ class HbmRing:
                 off += n
             packed = np.concatenate(srcs) if len(srcs) > 1 else srcs[0]
             p = spans[0][0] & self._mask
-            dev = jax.device_put(jax.numpy.asarray(packed), self.device)
+            dev = jax.device_put(packed, self.device)
             ledger.dma_h2d(total)
-            first = min(total, self.capacity - p)
-            if first >= total:  # unwrapped: one donated landing write
-                self.buf = self._update(self.buf, dev, p)
-                ledger.dma_d2d(total)
-            elif self._pallas_place(dev, p, total):
-                ledger.dma_d2d(total)  # one aliased kernel write at the wrap
-            else:
-                self.buf = self._update(self.buf, dev[:first], p)
-                ledger.dma_d2d(first)
-                self.buf = self._update(self.buf, dev[first:], 0)
-                ledger.dma_d2d(total - first)
-            self._assert_stable()
+            self._land(dev, p, total)
         dt = time.monotonic_ns() - t0
         _HBM_PLACE_MSGS.inc(len(spans))
         _HBM_PLACE_BYTES.inc(total)
@@ -445,7 +424,6 @@ class HbmRing:
         which of the two actually happened for every message.
         """
         import jax.numpy as jnp
-        from jax import lax
 
         if n == 0:
             dt = jnp.dtype(dtype)
@@ -473,27 +451,33 @@ class HbmRing:
                             ledger.zero_copy(n)
                         else:  # backend copied on import: correct + billed
                             ledger.dma_d2d(n)
+                        _VIEW_PATH["alias"].inc()
                         return HbmLease(self, off, n, seg, aliased=is_alias)
-                seg = None
-                if first < n:  # wrapped span: try the fused Pallas gather —
-                    # ONE kernel/d2d pass instead of slice+slice+concatenate
-                    seg = self._pallas_window(p, n)
-                if seg is None:
-                    seg = self._slice(self.buf, p, first)
-                    if first < n:
-                        seg = jnp.concatenate(
-                            [seg, self._slice(self.buf, 0, n - first)])
+                    seg = self._slice(self.buf, p, n)
+                    _VIEW_PATH["slice"].inc()
+                elif self._pallas_ok(p, n, 9 * 512):
+                    # wrapped span: the fused gather (tpurpc.ops.ring_window;
+                    # compiled on TPU, interpreted on CPU) — ONE kernel/d2d
+                    # pass instead of slice+slice+concatenate
+                    from tpurpc.ops import ring_window
+
+                    seg = ring_window(self.buf, p, n,
+                                      interpret=self.device.platform == "cpu")
+                    _VIEW_PATH["window"].inc()
+                else:
+                    seg = jnp.concatenate(
+                        [self._slice(self.buf, p, first),
+                         self._slice(self.buf, 0, n - first)])
+                    _VIEW_PATH["concat"].inc()
             except BaseException:
                 self._live[(off, n)][0] -= 1
                 self._advance_locked()  # cnt may now be 0 on a consumed span
                 raise
         try:
             dt = jnp.dtype(dtype)
-            if dt != jnp.uint8:
-                seg = lax.bitcast_convert_type(
-                    seg.reshape(-1, dt.itemsize), dt).reshape(-1)
-            if shape is not None:
-                seg = seg.reshape(shape)
+            if dt != jnp.uint8 or shape is not None:
+                seg = self._shaped(seg, dt, None if shape is None
+                                   else tuple(shape))
         except BaseException:
             # failed shaping does NOT consume the span (another consumer may
             # still take a correct view of it)
@@ -592,20 +576,9 @@ class HbmRing:
             if (off, nbytes) not in self._live:
                 raise KeyError(f"span ({off}, {nbytes}) not live")
             p = off & self._mask
-            dev = jax.device_put(jax.numpy.asarray(src), self.device)
+            dev = jax.device_put(src, self.device)
             ledger.dma_h2d(nbytes)
-            first = min(nbytes, self.capacity - p)
-            if first >= nbytes:
-                self.buf = self._update(self.buf, dev, p)
-                ledger.dma_d2d(nbytes)
-            elif self._pallas_place(dev, p, nbytes):
-                ledger.dma_d2d(nbytes)
-            else:
-                self.buf = self._update(self.buf, dev[:first], p)
-                ledger.dma_d2d(first)
-                self.buf = self._update(self.buf, dev[first:], 0)
-                ledger.dma_d2d(nbytes - first)
-            self._assert_stable()
+            self._land(dev, p, nbytes)
         dt = time.monotonic_ns() - t0
         _HBM_PLACE_MSGS.inc()
         _HBM_PLACE_BYTES.inc(nbytes)

@@ -8,8 +8,8 @@ The BASELINE north star names two helpers:
   segments alias it), then the ring/endpoint gather-write places the same
   buffer. No intermediate host buffer is ever allocated.
 * ``DeserializeToDevice`` — received wire bytes become a ``jax.Array`` with
-  exactly one h2d movement (none on a host backend: dlpack import aliases the
-  assembly buffer).
+  exactly one h2d movement (none on a CPU device when the payload is
+  64-byte aligned: dlpack import aliases the assembly buffer).
 
 Both report to :mod:`tpurpc.tpu.ledger`; tests assert the copy counts, which
 is the honesty mechanism SURVEY.md §7 stage 6 demands of the emulated path.
@@ -52,16 +52,11 @@ def serialize_from_device(x) -> List[bytes]:
 
 
 def deserialize_to_device(buf, offset: int = 0):
-    """Wire record → jax.Array with ledger accounting; returns (array, end)."""
-    import jax
-
+    """Wire record → jax.Array on JAX's default device; returns (array,
+    end). The one movement (or the alias) is billed by ``codec.to_jax``,
+    once."""
     arr, end = codec.decode_tensor(buf, offset)  # zero-copy view of buf
-    out = codec.to_jax(arr)
-    if _on_host_backend(out):
-        ledger.zero_copy(arr.nbytes)   # dlpack alias, no movement
-    else:
-        ledger.dma_h2d(arr.nbytes)     # one host→HBM DMA, no host memcpy
-    return out, end
+    return codec.to_jax(arr), end
 
 
 def tree_from_device(tree: Any) -> List[bytes]:
