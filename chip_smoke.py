@@ -27,8 +27,11 @@ One process per chip: this parent is the client and never imports jax (it
 says so at exit); each server is a child that owns the chip for its life and
 is stopped cleanly. Nothing here catches a failure and carries on: any check
 that fails or phase that raises ends the run with a non-zero exit and no
-result line. The last line of a passing run is one JSON object,
-``{"ok": true, "device": {"platform": "tpu", ...}, ..., "claim": null}``.
+result line. The last line of a passing run is one JSON object with exactly
+these keys, ``{"ok": true, "device": {"platform": "tpu", "kind": "...",
+"count": 1}}``; the line before it, ``summary {...}``, carries the counts of
+every leg and ends with ``"claim": null`` (also written to
+``chiprun_out/chip_smoke/summary.json``).
 """
 
 from __future__ import annotations
@@ -743,11 +746,8 @@ def main() -> int:
     say("parent never imported jax")
     wall = time.monotonic() - t_run
     say(f"all legs passed in {wall:.1f}s wall")
-    print(json.dumps({
-        # a rehearsal is never "ok": it ran on a CPU
-        "ok": not rehearsal,
+    summary = {
         **({"rehearsal_passed": True} if rehearsal else {}),
-        "device": device,
         "versions": ready["versions"],
         "data_plane": native,
         "setup": {"startup_s": ready["startup_s"],
@@ -761,7 +761,15 @@ def main() -> int:
         **result,
         "wall_s": round(wall, 1),
         "claim": None,
-    }), flush=True)
+    }
+    say(f"summary {json.dumps(summary)}")
+    with open(os.path.join(OUT_DIR, "summary.json"), "w") as f:
+        json.dump(summary, f, indent=1)
+    # the result line: exactly these keys, the device as JAX reported it in
+    # the server. A rehearsal is never "ok": it ran on a CPU.
+    print(json.dumps({"ok": not rehearsal, "device": {
+        "platform": device["platform"], "kind": device["kind"],
+        "count": device["count"]}}), flush=True)
     return 0
 
 

@@ -60,10 +60,19 @@ def test_rehearsal_passes_twice_and_the_cache_stops_growing(tmp_path):
         assert p.returncode == 0, p.stdout[-3000:] + p.stderr[-3000:]
         lines = p.stdout.strip().splitlines()
         assert all(ln.startswith("[REHEARSAL on cpu") for ln in lines[:-1])
-        result = json.loads(lines[-1])
-        assert result["ok"] is False and result["rehearsal_passed"] is True
-        assert result["device"]["platform"] == "cpu"
-        assert result["claim"] is None
+        # the last line: exactly the keys the driver's check reads
+        last = json.loads(lines[-1])
+        assert set(last) == {"ok", "device"} and last["ok"] is False
+        assert set(last["device"]) == {"platform", "kind", "count"}
+        assert last["device"]["platform"] == "cpu"
+        assert isinstance(last["device"]["kind"], str)
+        assert type(last["device"]["count"]) is int
+        # the line before it: the counts of every leg
+        head, _, body = lines[-2].partition(" summary ")
+        assert head.startswith("[REHEARSAL on cpu") and body.endswith(
+            '"claim": null}')
+        result = json.loads(body)
+        assert result["rehearsal_passed"] is True and result["claim"] is None
         assert result["setup"]["cache_dir"] == str(cache)
         tensor = result["legs"]["tensor"]
         assert tensor["measured"]["compiles"] == 0
