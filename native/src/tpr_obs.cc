@@ -4,8 +4,10 @@
 
 #include <pthread.h>
 #include <sched.h>
+#include <stdlib.h>
 #include <string.h>
 #include <time.h>
+#include <unistd.h>
 
 #include <mutex>
 
@@ -47,6 +49,20 @@ std::mutex g_init_mu;   // init / intern / reset only — never on emit
 State *g_state = nullptr;  // set once under g_init_mu, read lock-free
 bool g_init_done = false;
 
+// Clean exit of the process that made the region: take its NAME out of
+// /dev/shm (every server used to leave 176,368 bytes there). The mapping
+// stays, since threads still running at exit may emit, and goes with the
+// process. A forked child that exits without tpr_obs_postfork still holds
+// the parent's region: owner_pid keeps it from unlinking that.
+pid_t g_owner_pid = 0;
+
+void unlink_at_exit() {
+  State *st = __atomic_load_n(&g_state, __ATOMIC_RELAXED);
+  if (st && st->shm.owner && !st->shm.name.empty() &&
+      getpid() == g_owner_pid)
+    ::shm_unlink(("/" + st->shm.name).c_str());
+}
+
 State *build_state() {
   uint32_t cap = ring_capacity();
   uint32_t metrics_off = kHdrBytes;
@@ -80,6 +96,12 @@ State *build_state() {
   st->tags = b + tags_off;
   st->seq = reinterpret_cast<uint64_t *>(b + seq_off);
   st->recs = reinterpret_cast<uint64_t *>(b + rec_off);
+  static bool registered = false;  // callers hold g_init_mu
+  if (!registered) {
+    registered = true;
+    atexit(unlink_at_exit);
+  }
+  g_owner_pid = getpid();
   return st;
 }
 

@@ -128,6 +128,9 @@ enum MetricIdx {
   kMetConnDown,           // connections died
   kMetEmitted,            // flight records emitted (wraps overwrite)
   kMetTagOverflow,        // tag interns refused (table full -> tag 0)
+  kMetSrvQueueNs,         // ns delivered messages sat on call->pending
+  kMetSrvQueueMsgs,       // messages popped off call->pending by a handler
+  kMetRdvRefused,         // offers the receiver refused (landing pool empty)
   kNumMetrics,
 };
 

@@ -972,6 +972,7 @@ void Link::on_offer(uint32_t sid, const uint8_t *p, size_t len) {
   }
   if (!lease) {
     count(kCtrRdvRefused);
+    tpr_obs::metric_add(tpr_obs::kMetRdvRefused);
     ctrl_send(kOpClaim, sid, pack_claim_refused(req));
     return;
   }

@@ -71,6 +71,9 @@ struct OwnedBuf {
   // never free(). tpr_srv_buf_free consults the same registry, so the
   // handler-facing contract is unchanged either way.
   bool ext = false;
+  // tpr_obs::now_ns() when the message was pushed on call->pending;
+  // tpr_srv_recv's pop turns it into srv_queue_ns
+  uint64_t queued_ns = 0;
 
   // move-only: a raw-owning struct that the compiler lets you copy is a
   // double free waiting for a maintainer (the container moves below are
@@ -79,7 +82,7 @@ struct OwnedBuf {
   OwnedBuf(const OwnedBuf &) = delete;
   OwnedBuf &operator=(const OwnedBuf &) = delete;
   OwnedBuf(OwnedBuf &&o) noexcept
-      : p(o.p), len(o.len), cap(o.cap), ext(o.ext) {
+      : p(o.p), len(o.len), cap(o.cap), ext(o.ext), queued_ns(o.queued_ns) {
     o.p = nullptr;
     o.len = o.cap = 0;
     o.ext = false;
@@ -91,6 +94,7 @@ struct OwnedBuf {
       len = o.len;
       cap = o.cap;
       ext = o.ext;
+      queued_ns = o.queued_ns;
       o.p = nullptr;
       o.len = o.cap = 0;
       o.ext = false;
@@ -503,6 +507,7 @@ struct tpr_server {
     if (data != nullptr) {
       OwnedBuf b;
       b.adopt(data, len, rdv);
+      b.queued_ns = tpr_obs::now_ns();
       call->pending.push_back(std::move(b));
     }
     if (flags & kFlagEndStream) call->half_closed = true;
@@ -790,8 +795,10 @@ struct tpr_server {
     } else if (type == kMessage) {
       if (!(flags & kFlagNoMessage))
         call->partial.append(payload.data(), payload.size());
-      if (!(flags & kFlagMore) && !(flags & kFlagNoMessage))
+      if (!(flags & kFlagMore) && !(flags & kFlagNoMessage)) {
+        call->partial.queued_ns = tpr_obs::now_ns();
         call->pending.push_back(std::move(call->partial));
+      }
       if (flags & kFlagEndStream) call->half_closed = true;
     }
     lk.unlock();
@@ -1269,6 +1276,9 @@ int tpr_srv_recv(tpr_server_call *c, uint8_t **data, size_t *len) {
       // zero-copy handoff: the accumulator is malloc-backed from the
       // start, so the handler takes the buffer itself (frees with
       // tpr_srv_buf_free == free(), the unchanged contract)
+      tpr_obs::metric_add(tpr_obs::kMetSrvQueueNs,
+                          tpr_obs::now_ns() - c->pending.front().queued_ns);
+      tpr_obs::metric_add(tpr_obs::kMetSrvQueueMsgs);
       *data = c->pending.front().release(len);
       c->pending.pop_front();
       return 1;

@@ -863,6 +863,32 @@ def bad_site2():
     assert _rules(lint_source(src, "fixture.py")) == ["stage"]
 
 
+@pytest.mark.parametrize("site,why", [
+    ('with _lens.stage("hbm", n) as st:\n        st.copy = n', None),
+    ('with lens.stage("srv_recv", call=self.call, seq=self.seq):\n'
+     '        pass', None),
+    ('with _lens.stage(hop, n):\n        pass', "declared hop"),
+    ('with _lens.stage("warp-drive", n):\n        pass', "declared hop"),
+    ('with _lens.stage("hbm", len(views)):\n        pass',
+     "precompute the int"),
+    ('with other.stage("anything", len(views)):\n        pass', None),
+    ('with _lens.stage("hbm", len(v)):  # tpr: allow(stage)\n'
+     '        pass', None),
+])
+def test_stage_rule_holds_lens_stage_sites_to_the_same_contract(site, why):
+    """ISSUE 26's form of a site: a literal declared hop, pure-int
+    arguments; other objects' `.stage(...)` are none of its business."""
+    src = f"""
+def site(self, hop, n, v, views):
+    {site}
+"""
+    vs = lint_source(src, "fixture.py")
+    if why is None:
+        assert vs == []
+    else:
+        assert _rules(vs) == ["stage"] and why in vs[0].message
+
+
 def test_stage_rule_ignores_non_lens_counters():
     src = '''
 def site(c, n):

@@ -484,3 +484,294 @@ def test_trace_on_non_answering_shard_appears_in_merged_view():
     finally:
         tracing.force(None)
         sup.stop()
+
+
+# ---------------------------------------------------------------------------
+# lens.stage (ISSUE 26): counters always, profiler spans when jax is here
+# ---------------------------------------------------------------------------
+
+def _hop(name):
+    snap = metrics.registry().counters_snapshot()
+    return {k: snap[f"lens_{name}_{k}"]
+            for k in ("bytes", "busy_ns", "ops", "copy_bytes")}
+
+
+@pytest.mark.parametrize("raises", [False, True])
+def test_stage_bumps_bytes_busy_ops_once_per_exit(raises):
+    before = _hop("jax_array")
+    try:
+        with lens.stage("jax_array", 100) as st:
+            st.copy = 7
+            time.sleep(0.002)
+            if raises:
+                raise RuntimeError("the body failed")
+    except RuntimeError:
+        assert raises
+    after = _hop("jax_array")
+    assert after["ops"] == before["ops"] + 1
+    assert after["bytes"] == before["bytes"] + 100
+    assert after["copy_bytes"] == before["copy_bytes"] + 7
+    assert after["busy_ns"] - before["busy_ns"] >= 2_000_000
+
+
+def test_stage_begin_end_pair_and_exclude():
+    before = _hop("srv_handler")
+    st = lens.stage("srv_handler", 5).begin()
+    time.sleep(0.02)
+    st.exclude(15_000_000)  # a sibling's 15 ms ran inside this interval
+    dt = st.end()
+    after = _hop("srv_handler")
+    assert after["ops"] == before["ops"] + 1
+    assert after["busy_ns"] - before["busy_ns"] == dt
+    assert 4_000_000 <= dt < 20_000_000
+
+
+def test_every_hop_has_an_ops_counter_and_the_list_only_grows():
+    snap = metrics.registry().counters_snapshot()
+    for hop in lens.HOP_NAMES:
+        assert f"lens_{hop}_ops" in snap
+    assert lens.HOP_NAMES[:12] == (
+        "device", "send_ring", "wire", "rendezvous", "ctrl", "native_send",
+        "native_recv", "native_rdv", "peer_ring", "decode", "hbm",
+        "jax_array")
+    with pytest.raises(KeyError):
+        lens.stage("warp-drive").begin().end()
+
+
+def test_nested_stages_keep_parent_over_the_sum_of_its_children():
+    """`decode` over `hbm_credit`, `hbm` and `hbm_view`, on one thread, with
+    a placement that has to wait for credit."""
+    import numpy as np
+
+    from tpurpc.jaxshim import codec
+    from tpurpc.tpu import HbmRing
+    from tpurpc.tpu.endpoint import decode_tree_to_ring
+
+    hops = ("decode", "hbm_credit", "hbm", "hbm_view")
+    before = {h: _hop(h) for h in hops}
+    ring = HbmRing(1 << 16)
+    wire = codec.encode_tree_bytes({"x": np.arange(10240, dtype=np.float32)})
+    _, first = decode_tree_to_ring(ring, bytearray(wire))  # 40 KiB of 64
+
+    def release_soon():
+        time.sleep(0.05)
+        for lease in first:
+            lease.release()
+
+    t = threading.Thread(target=release_soon)
+    t.start()
+    _, second = decode_tree_to_ring(ring, bytearray(wire), timeout=10)
+    t.join(timeout=10)
+    assert not t.is_alive()
+    for lease in second:
+        lease.release()
+    d = {h: {k: _hop(h)[k] - before[h][k] for k in before[h]} for h in hops}
+    assert d["decode"]["ops"] == 2 and d["hbm"]["ops"] == 2
+    assert d["hbm_view"]["ops"] == 2
+    assert d["hbm_credit"]["ops"] == 1  # only the placement that blocked
+    assert d["hbm_credit"]["busy_ns"] >= 30_000_000
+    assert d["decode"]["busy_ns"] >= (d["hbm_credit"]["busy_ns"]
+                                      + d["hbm"]["busy_ns"]
+                                      + d["hbm_view"]["busy_ns"])
+    assert d["hbm"]["bytes"] == d["hbm"]["copy_bytes"] == 2 * 40960
+    assert d["decode"]["bytes"] == d["hbm_view"]["bytes"] == 2 * 40960
+
+
+def test_stage_in_a_process_without_jax_leaves_it_unimported():
+    import os
+    import subprocess
+    import sys
+
+    script = (
+        "import sys\n"
+        "from tpurpc.obs import lens, metrics\n"
+        "with lens.stage('srv_recv', 3, call=1, seq=0):\n"
+        "    with lens.stage('decode', 3):\n"
+        "        pass\n"
+        "snap = metrics.registry().counters_snapshot()\n"
+        "assert snap['lens_srv_recv_ops'] == 1, snap\n"
+        "assert snap['lens_decode_bytes'] == 3, snap\n"
+        "assert 'jax' not in sys.modules\n"
+        "print('NOJAX-OK')\n")
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, PYTHONPATH=here + os.pathsep
+               + os.environ.get("PYTHONPATH", ""))
+    res = subprocess.run([sys.executable, "-c", script], env=env,
+                         capture_output=True, text=True, timeout=120)
+    assert res.returncode == 0 and "NOJAX-OK" in res.stdout, res.stderr
+
+
+def _device_stream_server(monkeypatch, fn):
+    """A `device=True` stream method on the plane that has a device ring
+    (GRPC_PLATFORM_TYPE=RDMA_TPU is served by rpc/server.py: the native
+    server plane does not adopt it)."""
+    from tpurpc.jaxshim import add_tensor_method
+    from tpurpc.rpc.server import Server
+    from tpurpc.utils import config as config_mod
+
+    monkeypatch.setenv("GRPC_PLATFORM_TYPE", "RDMA_TPU")
+    config_mod.set_config(None)
+    srv = Server(max_workers=4)
+    add_tensor_method(srv, "Sink", fn, kind="stream_stream", device=True)
+    srv.start()
+    return srv, srv.add_insecure_port("127.0.0.1:0")
+
+
+def test_profiler_trace_holds_the_stage_spans_of_a_device_stream(
+        monkeypatch, tmp_path):
+    """One device stream under a CPU `jax.profiler` trace: the handler's
+    thread line holds `tpurpc.srv_recv`, `tpurpc.decode`, `tpurpc.hbm`,
+    `tpurpc.hbm_view` and `tpurpc.srv_handler` inside the test's own window
+    span, each carrying `call` and a `seq` that rises with the messages."""
+    import glob
+    import re
+
+    import jax
+    import numpy as np
+
+    from tpurpc.jaxshim import TensorClient
+    from tpurpc.rpc.channel import Channel
+
+    def consume(trees):
+        n = 0
+        for t in trees:
+            t["x"].block_until_ready()
+            n += 1
+        yield {"n": np.int64(n)}
+
+    srv, port = _device_stream_server(monkeypatch, consume)
+    x = np.ones(4096, np.float32)
+    try:
+        with Channel(f"127.0.0.1:{port}") as ch:
+            client = TensorClient(ch)
+            list(client.duplex("Sink", iter([{"x": x}] * 2), timeout=60))
+            jax.profiler.start_trace(str(tmp_path))
+            with jax.profiler.TraceAnnotation("test.window"):
+                replies = list(client.duplex(
+                    "Sink", iter([{"x": x}] * 6), timeout=60))
+            jax.profiler.stop_trace()
+        assert int(np.asarray(replies[0]["n"]).ravel()[0]) == 6
+    finally:
+        srv.stop(grace=0)
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    data = jax.profiler.ProfileData.from_file(path)
+    window = None
+    lines = {}
+    for plane in data.planes:
+        # thread lines by position: two threads may share a line name
+        for nth, line in enumerate(plane.lines):
+            for ev in line.events:
+                name = ev.name.split("#")[0]
+                if name == "test.window":
+                    window = (ev.start_ns, ev.start_ns + ev.duration_ns)
+                elif name.startswith("tpurpc."):
+                    stats = dict(ev.stats)
+                    text = ev.name + " " + " ".join(
+                        f"{k}={v}" for k, v in stats.items())
+                    seq = int(re.search(r"seq=(\d+)", text).group(1))
+                    call = int(re.search(r"call=(\d+)", text).group(1))
+                    lines.setdefault((plane.name, nth), []).append(
+                        (ev.start_ns, ev.start_ns + ev.duration_ns, name,
+                         call, seq))
+    assert window is not None
+    want = {"tpurpc.srv_recv", "tpurpc.decode", "tpurpc.hbm",
+            "tpurpc.hbm_view", "tpurpc.srv_handler"}
+    handler_lines = [evs for evs in lines.values()
+                     if want <= {e[2] for e in evs}]
+    assert len(handler_lines) == 1, {k: {e[2] for e in v}
+                                     for k, v in lines.items()}
+    evs = sorted(handler_lines[0])
+    assert all(window[0] <= e[0] and e[1] <= window[1] for e in evs)
+    assert len({e[3] for e in evs}) == 1 and evs[0][3] > 0  # one call
+    by_name = {n: [e for e in evs if e[2] == n] for n in want}
+    assert [e[4] for e in by_name["tpurpc.srv_handler"]] == list(range(6))
+    assert [e[4] for e in by_name["tpurpc.srv_recv"]] == list(range(7))
+    for h, d in zip(by_name["tpurpc.srv_handler"],
+                    by_name["tpurpc.decode"]):
+        assert h[0] <= d[0] and d[1] <= h[1] and h[4] == d[4]  # nests
+    for d, child in zip(by_name["tpurpc.decode"] * 2,
+                        by_name["tpurpc.hbm"] + by_name["tpurpc.hbm_view"]):
+        assert d[0] <= child[0] and child[1] <= d[1] and d[4] == child[4]
+
+
+def test_python_plane_counts_what_a_message_waits_for_its_handler(
+        monkeypatch):
+    """`srv_queue` ops = messages taken; its time grows when the handler
+    sleeps between messages, and the top-level stages cover the call."""
+    import numpy as np
+
+    from tpurpc.jaxshim import TensorClient
+    from tpurpc.rpc.channel import Channel
+
+    def slow(trees):
+        n = 0
+        for _ in trees:
+            time.sleep(0.02)
+            n += 1
+        yield {"n": np.int64(n)}
+
+    hops = ("srv_queue", "srv_recv", "srv_handler", "srv_send", "srv_call")
+    srv, port = _device_stream_server(monkeypatch, slow)
+    before = {h: _hop(h) for h in hops}
+    x = np.ones(1024, np.float32)
+    try:
+        with Channel(f"127.0.0.1:{port}") as ch:
+            list(TensorClient(ch).duplex("Sink", iter([{"x": x}] * 10),
+                                         timeout=60))
+    finally:
+        srv.stop(grace=0)
+    d = {h: {k: _hop(h)[k] - before[h][k] for k in before[h]} for h in hops}
+    assert d["srv_queue"]["ops"] == d["srv_handler"]["ops"] == 10
+    assert d["srv_recv"]["ops"] == 11  # ten messages and the stream's end
+    assert d["srv_send"]["ops"] == 1 and d["srv_call"]["ops"] == 1
+    # ten messages sent at once, taken one per 20 ms: they wait their turn
+    assert d["srv_queue"]["busy_ns"] >= 5 * 20_000_000
+    assert d["srv_handler"]["busy_ns"] >= 10 * 20_000_000
+    staged = sum(d[h]["busy_ns"] for h in ("srv_recv", "srv_handler",
+                                           "srv_send"))
+    assert 0.9 * d["srv_call"]["busy_ns"] <= staged <= d["srv_call"]["busy_ns"]
+
+
+def test_ring_programs_and_kernels_have_names_of_their_own():
+    """What a device trace's reduction finds them by: the three jitted ring
+    programs by their lowered module's name, the two Pallas kernels by the
+    `name` of their `pallas_call` (interpret mode keeps no custom call)."""
+    import jax
+    import jax.numpy as jnp
+
+    from tpurpc.ops import ring_window
+    from tpurpc.ops.ring_scatter import ring_scatter
+    from tpurpc.tpu.hbm_ring import _ring_jits
+
+    update, slice_, shaped = _ring_jits()
+    buf = jnp.zeros((1 << 15,), jnp.uint8)
+    seg = jnp.zeros((4096,), jnp.uint8)
+    assert "tpurpc_ring_update" in update.lower(buf, seg, 0).as_text()
+    assert "tpurpc_ring_slice" in slice_.lower(buf, 0, 4096).as_text()
+    assert "tpurpc_ring_shaped" in shaped.lower(
+        seg, jnp.dtype("float32"), (32, 32)).as_text()
+
+    def kernel_names(fn, *args):
+        names = []
+
+        def walk(jaxpr):
+            for eqn in jaxpr.eqns:
+                if eqn.primitive.name == "pallas_call":
+                    names.append(str(eqn.params.get("name")
+                                     or eqn.params.get("name_and_src_info")))
+                for v in eqn.params.values():
+                    for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                        inner = getattr(sub, "jaxpr", sub)
+                        if hasattr(inner, "eqns"):
+                            walk(inner)
+
+        walk(jax.make_jaxpr(fn)(*args).jaxpr)
+        return names
+
+    scatter = kernel_names(
+        lambda b, p: ring_scatter(b, p, (1 << 15) - 2048, interpret=True),
+        buf, seg)
+    window = kernel_names(
+        lambda b: ring_window(b, (1 << 15) - 2048, 4096, interpret=True), buf)
+    assert scatter and all("tpurpc_ring_scatter" in n for n in scatter)
+    assert window and all("tpurpc_ring_window" in n for n in window)

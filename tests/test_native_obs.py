@@ -51,6 +51,17 @@ def obs_plane(ring_platform):
     flight.RECORDER.reset()
 
 
+def _run_child(script, **env_extra):
+    """``script`` in a fresh interpreter on the ring platform: for what the C
+    side reads once, at first use."""
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, GRPC_PLATFORM_TYPE="RDMA_BPEV",
+               JAX_PLATFORMS="cpu", **env_extra)
+    env["PYTHONPATH"] = here + os.pathsep + env.get("PYTHONPATH", "")
+    return subprocess.run([sys.executable, "-c", script], env=env,
+                          capture_output=True, text=True, timeout=180)
+
+
 def _totaling_server():
     srv = rpc.Server(max_workers=4)
 
@@ -252,13 +263,197 @@ assert set(led) == set(native_client.RDV_COUNTER_NAMES)
 assert native_client.rdv_counters_reset() is True
 print("OFFSWITCH-OK")
 """
-    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ)
-    env["TPURPC_NATIVE_OBS"] = "0"
-    env["GRPC_PLATFORM_TYPE"] = "RDMA_BPEV"
-    env["JAX_PLATFORMS"] = "cpu"
-    env["PYTHONPATH"] = here + os.pathsep + env.get("PYTHONPATH", "")
-    res = subprocess.run([sys.executable, "-c", script], env=env,
-                         capture_output=True, text=True, timeout=180)
+    res = _run_child(script, TPURPC_NATIVE_OBS="0")
     assert res.returncode == 0, (res.stdout, res.stderr)
     assert "OFFSWITCH-OK" in res.stdout
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 26: the queue counted in C, refusals in the table, one instant for
+# all counters, the region unlinked on a clean exit, the watchdog on progress
+# ---------------------------------------------------------------------------
+
+def _sleepy_server(nap_s):
+    srv = rpc.Server(max_workers=4)
+
+    def total(req_iter, ctx):
+        n = 0
+        for m in req_iter:
+            n += len(m)
+            if nap_s:
+                import time
+
+                time.sleep(nap_s)
+        yield str(n).encode()
+
+    srv.add_method("/nobs.S/Total",
+                   rpc.stream_stream_rpc_method_handler(total))
+    port = srv.add_insecure_port("127.0.0.1:0")
+    srv.start()
+    return srv, port
+
+
+def _native_counters():
+    from tpurpc.obs import metrics as metrics_mod
+
+    snap = metrics_mod.registry().counters_snapshot()  # no explicit sync
+    return {k[len("native_"):]: v for k, v in snap.items()
+            if k.startswith("native_")}
+
+
+def _stream(port, messages):
+    with Channel(f"127.0.0.1:{port}") as ch:
+        out = list(ch.stream_stream("/nobs.S/Total")(iter(messages),
+                                                     timeout=60))
+    return int(out[-1])
+
+
+def test_counters_snapshot_is_current_with_no_explicit_sync(obs_plane):
+    _cross_plane_exchange()
+    tab = obs_plane.counters()
+    got = _native_counters()
+    assert tab["rdv_send_bytes"] >= len(NATIVE_PAYLOAD)
+    for name in ("rdv_send_bytes", "rdv_recv_bytes", "conn_up",
+                 "srv_queue_msgs"):
+        assert got[name] == tab[name] > 0, (name, got, tab)
+
+
+def test_srv_queue_counts_every_message_and_its_wait(obs_plane):
+    """`srv_queue_msgs` = messages a handler took off `call->pending`;
+    `srv_queue_ns` grows when the handler sleeps between messages while the
+    client has already sent the next ones."""
+    from tpurpc.obs import lens, metrics as metrics_mod
+
+    waits = {}
+    for nap_s in (0.0, 0.03):
+        srv, port = _sleepy_server(nap_s)
+        try:
+            assert srv._native_dp is not None
+            before = _native_counters()
+            handled = metrics_mod.registry().counters_snapshot()[
+                "lens_srv_handler_ops"]
+            assert _stream(port, [b"x" * 1000] * 8) == 8000
+            after = _native_counters()
+        finally:
+            srv.stop(grace=1)
+        assert after["srv_queue_msgs"] - before["srv_queue_msgs"] == 8
+        assert metrics_mod.registry().counters_snapshot()[
+            "lens_srv_handler_ops"] - handled == 8  # the same plane's stages
+        waits[nap_s] = after["srv_queue_ns"] - before["srv_queue_ns"]
+    # eight sent at once, one taken per 30 ms: they wait their turn
+    assert waits[0.03] >= 4 * 30_000_000 > waits[0.0]
+    assert "srv_recv" in lens.HOP_NAMES
+
+
+def test_rdv_refused_moves_when_the_landing_pool_is_exhausted(ring_platform):
+    """A 1 MiB landing pool cannot lease the region a 1 MiB payload needs:
+    the receiver refuses the offer, the payload falls back to the framed
+    path and still arrives, and the metric table says so (the budget is read
+    once by the C side, hence the subprocess)."""
+    script = r"""
+import tpurpc.rpc as rpc
+from tpurpc.obs import metrics
+from tpurpc.rpc.channel import Channel
+
+srv = rpc.Server(max_workers=2)
+
+def total(req_iter, ctx):
+    yield str(sum(len(m) for m in req_iter)).encode()
+
+srv.add_method("/ref.S/Total", rpc.stream_stream_rpc_method_handler(total))
+port = srv.add_insecure_port("127.0.0.1:0")
+srv.start()
+assert srv._native_dp is not None
+payload = bytes(range(256)) * 4096
+try:
+    with Channel(f"127.0.0.1:{port}") as ch:
+        mc = ch.stream_stream("/ref.S/Total")
+        list(mc(iter([b"warm"]), timeout=30))  # the links negotiate
+        out = list(mc(iter([payload] * 3), timeout=60))
+    assert out[-1] == str(3 * len(payload)).encode(), out
+finally:
+    srv.stop(grace=1)
+snap = metrics.registry().counters_snapshot()
+assert snap["native_rdv_refused"] >= 1, snap
+assert snap["native_rdv_recv_bytes"] == 0, snap
+print("REFUSED-OK", snap["native_rdv_refused"])
+"""
+    res = _run_child(script, TPURPC_RENDEZVOUS_POOL_MB="1")
+    assert res.returncode == 0 and "REFUSED-OK" in res.stdout, \
+        res.stdout + res.stderr[-2000:]
+
+
+def test_obs_region_is_unlinked_on_a_clean_exit(ring_platform):
+    """A server process that starts, serves and stops leaves no
+    ``/dev/shm/tpr_*`` of its own behind: the C side unlinks the region's
+    name at exit (every server used to leave 176,368 bytes there)."""
+    script = r"""
+import os
+import tpurpc.rpc as rpc
+from tpurpc.obs import native_obs
+from tpurpc.rpc.channel import Channel
+
+srv = rpc.Server(max_workers=2)
+srv.add_method("/bye.S/Echo", rpc.unary_unary_rpc_method_handler(
+    lambda req, ctx: req))
+port = srv.add_insecure_port("127.0.0.1:0")
+srv.start()
+with Channel(f"127.0.0.1:{port}") as ch:
+    assert ch.unary_unary("/bye.S/Echo")(b"hi", timeout=30) == b"hi"
+assert native_obs.available()
+name = native_obs._lib().tpr_obs_shm_name().decode()
+assert os.path.exists("/dev/shm/" + name)
+srv.stop(grace=1)
+print("REGION", name)
+"""
+    res = _run_child(script)
+    assert res.returncode == 0, res.stderr[-2000:]
+    name = res.stdout.split("REGION", 1)[1].split()[0]
+    assert name.startswith("tpr_")
+    assert not os.path.exists("/dev/shm/" + name)
+
+
+@pytest.mark.parametrize("platform,native", [("TCP", False),
+                                             ("RDMA_BPEV", True)])
+def test_watchdog_bars_a_stream_on_progress_not_age(monkeypatch, platform,
+                                                    native):
+    """On both server planes: a healthy 30-message stream that outlives the
+    stall floor several times over does not trip; one whose sender goes quiet
+    mid-way does."""
+    import threading
+    import time
+
+    from tpurpc.obs import metrics as metrics_mod
+    from tpurpc.obs import watchdog
+    from tpurpc.utils import config as config_mod
+
+    monkeypatch.setenv("GRPC_PLATFORM_TYPE", platform)
+    config_mod.set_config(None)
+    wd = watchdog.get()
+    saved = (wd.enabled, wd.min_stall_s, wd.sweep_s)
+    wd.enabled, wd.min_stall_s, wd.sweep_s = True, 0.25, 0.05
+    wd.reset()  # no history of the method: the floor is the bar
+    trips = metrics_mod.counter("watchdog_trips")
+    srv, port = _sleepy_server(0.0)
+    try:
+        assert (srv._native_dp is not None) == native
+
+        def paced(n, gap_s, hold=None):
+            for i in range(n):
+                if hold is not None and i == n // 2:
+                    hold.wait(timeout=10)
+                time.sleep(gap_s)
+                yield b"m" * 100
+
+        t0, before = time.monotonic(), trips.snapshot()
+        assert _stream(port, paced(30, 0.03)) == 3000
+        assert time.monotonic() - t0 > 3 * wd.min_stall_s
+        assert trips.snapshot() == before, wd.snapshot()
+        hold = threading.Event()
+        threading.Timer(4 * wd.min_stall_s, hold.set).start()
+        assert _stream(port, paced(6, 0.0, hold)) == 600
+        assert trips.snapshot() > before
+    finally:
+        srv.stop(grace=1)
+        wd.enabled, wd.min_stall_s, wd.sweep_s = saved
+        wd.reset()

@@ -66,7 +66,11 @@ hand (ISSUE 2) and that no general-purpose linter knows about:
   strings. (b) Waterfall hop accounting sites — ``.inc(...)`` on a
   ``_LENS_*``-bound counter — run per batched op on the data plane and
   must use the same pure-int plumbing the ``flight`` rule enforces: names,
-  attributes and arithmetic only, no calls/displays/str constants.
+  attributes and arithmetic only, no calls/displays/str constants. The
+  newer form of a site, ``lens.stage("<hop>", nbytes)`` (ISSUE 26: one
+  call does the timing, the bumps and the profiler span), is held to the
+  same: the hop a string literal naming a declared hop (``lens.HOPS``),
+  every other argument pure-int plumbing.
   Deliberate exceptions carry ``# tpr: allow(stage)``.
 
 * ``kv``       — KV block-alloc pairing (tpurpc-keystone, ISSUE 11): a
@@ -590,6 +594,22 @@ def _static_str_dict(node: Optional[ast.AST],
     return True
 
 
+def _is_lens_stage(node: ast.Call) -> bool:
+    """``lens.stage(...)`` / ``_lens.stage(...)``, or a bare ``stage(...)``
+    (inside ``obs/lens.py`` itself)."""
+    f = node.func
+    if isinstance(f, ast.Name):
+        return True
+    return (isinstance(f, ast.Attribute) and isinstance(f.value, ast.Name)
+            and f.value.id.lstrip("_") == "lens")
+
+
+def _declared_hops() -> Tuple[str, ...]:
+    from tpurpc.obs import lens
+
+    return lens.HOP_NAMES
+
+
 def _check_stage(tree: ast.AST, path: str,
                  lines: Sequence[str]) -> List[LintViolation]:
     """tpurpc-lens (ISSUE 8): static stage/hop registrations + pure-int
@@ -640,6 +660,31 @@ def _check_stage(tree: ast.AST, path: str,
                     "with a string-literal hop name (the cached-counter "
                     "contract: sites pay only the bump); a deliberate "
                     "exception carries '# tpr: allow(stage)'"))
+        elif name == "stage" and _is_lens_stage(node):
+            if "stage" in _allowed_rules(lines, node.lineno):
+                continue
+            hop = node.args[0] if node.args else None
+            if not (isinstance(hop, ast.Constant)
+                    and hop.value in _declared_hops()):
+                out.append(LintViolation(
+                    path, node.lineno, node.col_offset, "stage",
+                    "lens.stage must name a declared hop (lens.HOPS) with "
+                    "a string literal: a dynamic or misspelt hop is a "
+                    "KeyError on the data plane and a counter nobody "
+                    "reads; a deliberate exception carries "
+                    "'# tpr: allow(stage)'"))
+                continue
+            for arg in node.args[1:] + [k.value for k in node.keywords]:
+                why = _flight_arg_violation(arg)
+                if why is None:
+                    continue
+                out.append(LintViolation(
+                    path, node.lineno, node.col_offset, "stage",
+                    f"lens.stage argument {why}: a stage runs per batched "
+                    "op on the data plane — precompute the int (the flight "
+                    "rule's contract); a deliberate exception carries "
+                    "'# tpr: allow(stage)'"))
+                break
         elif name == "inc":
             f = node.func
             if not (isinstance(f, ast.Attribute)

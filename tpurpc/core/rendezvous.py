@@ -104,6 +104,10 @@ _profiler.register_stages(__file__, _LENS_STAGES)
 #: whether the bulk plane is actually carrying traffic
 _RDV_SENT = _metrics.counter("rdv_transfers_sent")
 _RDV_RECV = _metrics.counter("rdv_transfers_received")
+#: payload bytes those received transfers delivered (the Python plane's
+#: twin of the C table's native_rdv_recv_bytes): over the payload a
+#: receiver took in all, the share of traffic that stayed on this path
+_RDV_RECV_BYTES = _metrics.counter("rdv_bytes_received")
 _RDV_FALLBACK = _metrics.counter("rdv_fallbacks")
 _RDV_REFUSED = _metrics.counter("rdv_claims_refused")
 #: control ops that rode the FRAMED path (tpurpc-pulse: a descriptor-ring
@@ -1273,6 +1277,7 @@ class RdvLink:
             lease.release(discard=True)  # a confused sender may write again
             return
         _RDV_RECV.inc()
+        _RDV_RECV_BYTES.inc(nbytes)
         cls, kind = lease.cls, lease.kind
         self._deliver(stream_id, flags, wrapper)
         self._maybe_pregrant(cls, kind)

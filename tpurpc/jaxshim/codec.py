@@ -22,7 +22,6 @@ from __future__ import annotations
 
 import json
 import struct
-import time
 from typing import Any, List, Optional, Tuple
 
 import numpy as np
@@ -32,12 +31,8 @@ from tpurpc.obs import profiler as _profiler
 
 # tpurpc-lens (ISSUE 8) waterfall hops on the codec boundary: `device` is
 # the serialize leg (device/host tensor bytes gathered into wire form),
-# `decode` the parse back, `jax_array` the final materialization. One bump
-# set per tensor record / tree record — never per byte.
-_LENS_DEV_BYTES, _LENS_DEV_NS, _LENS_DEV_COPY = _lens.hop_counters("device")
-_LENS_DEC_BYTES, _LENS_DEC_NS, _LENS_DEC_COPY = _lens.hop_counters("decode")
-_LENS_JAX_BYTES, _LENS_JAX_NS, _LENS_JAX_COPY = _lens.hop_counters(
-    "jax_array")
+# `decode` the parse back, `jax_array` the final materialization. One
+# `lens.stage` per tensor record / tree record — never per byte.
 
 _LENS_STAGES = {
     "encode_tensor": "codec",
@@ -119,24 +114,21 @@ def encode_tensor(x) -> List[bytes]:
     (reference: ``PairPollable::Send`` builds one doorbell from a grpc_slice*
     gather list, ``ibverbs/pair.cc:645-734``).
     """
-    t0 = time.monotonic_ns()
-    arr = _as_numpy(x)
-    # contiguity copies are provable for ndarray inputs (ascontiguousarray
-    # returns the same object when it aliased); a jax input's d2h gather is
-    # the ledger's jurisdiction, not double-counted here
-    materialized = isinstance(x, np.ndarray) and arr is not x
-    code = dtype_code(arr.dtype)
-    dims = struct.pack(f"<{arr.ndim}q", *arr.shape) if arr.ndim else b""
-    head = _HDR.pack(MAGIC, code, arr.ndim, 0, arr.nbytes) + dims
-    pad = (-len(head)) % _ALIGN
-    head += b"\x00" * pad
-    payload = arr.reshape(-1).view(np.uint8).data  # memoryview, no copy
-    dt = time.monotonic_ns() - t0
-    nbytes = arr.nbytes
-    _LENS_DEV_NS.inc(dt)
-    _LENS_DEV_BYTES.inc(nbytes)
-    if materialized:
-        _LENS_DEV_COPY.inc(nbytes)
+    with _lens.stage("device") as st:
+        arr = _as_numpy(x)
+        st.nbytes = arr.nbytes
+        # contiguity copies are provable for ndarray inputs
+        # (ascontiguousarray returns the same object when it aliased); a jax
+        # input's d2h gather is the ledger's jurisdiction, not double-counted
+        # here
+        if isinstance(x, np.ndarray) and arr is not x:
+            st.copy = arr.nbytes
+        code = dtype_code(arr.dtype)
+        dims = struct.pack(f"<{arr.ndim}q", *arr.shape) if arr.ndim else b""
+        head = _HDR.pack(MAGIC, code, arr.ndim, 0, arr.nbytes) + dims
+        pad = (-len(head)) % _ALIGN
+        head += b"\x00" * pad
+        payload = arr.reshape(-1).view(np.uint8).data  # memoryview, no copy
     return [head, payload]
 
 
@@ -244,27 +236,25 @@ def to_jax(arr: np.ndarray):
     from tpurpc.tpu import ledger
     from tpurpc.utils.jaxenv import default_device
 
-    t0 = time.monotonic_ns()
     nbytes = arr.nbytes
-    dev = default_device()
-    aliased = False
-    # what numpy's __dlpack__ exports and jax's import accepts: anything
-    # else would raise, and an exception must not be what picks the device
-    if (dev.platform == "cpu" and arr.flags.writeable
-            and arr.flags.c_contiguous and arr.dtype.kind in "biufc"):
-        out = jax.dlpack.from_dlpack(arr, device=dev)
-        aliased = (out.unsafe_buffer_pointer()
-                   == arr.__array_interface__["data"][0])
-    else:
-        out = jax.device_put(arr, dev)
-    if aliased:
-        ledger.zero_copy(nbytes)
-    else:
-        ledger.dma_h2d(nbytes)
-        _LENS_JAX_COPY.inc(nbytes)
-    dt = time.monotonic_ns() - t0
-    _LENS_JAX_NS.inc(dt)
-    _LENS_JAX_BYTES.inc(nbytes)
+    with _lens.stage("jax_array", nbytes) as st:
+        dev = default_device()
+        aliased = False
+        # what numpy's __dlpack__ exports and jax's import accepts: anything
+        # else would raise, and an exception must not be what picks the
+        # device
+        if (dev.platform == "cpu" and arr.flags.writeable
+                and arr.flags.c_contiguous and arr.dtype.kind in "biufc"):
+            out = jax.dlpack.from_dlpack(arr, device=dev)
+            aliased = (out.unsafe_buffer_pointer()
+                       == arr.__array_interface__["data"][0])
+        else:
+            out = jax.device_put(arr, dev)
+        if aliased:
+            ledger.zero_copy(nbytes)
+        else:
+            ledger.dma_h2d(nbytes)
+            st.copy = nbytes
     return out
 
 
@@ -324,34 +314,34 @@ def decode_tree_at(buf, offset: int = 0, copy: bool = False,
     buffer (memoryview offsets all the way down, no intermediate ``bytes``
     slices of the payload).
     """
-    t0 = time.monotonic_ns()
-    view = memoryview(buf)
-    if len(view) - offset < _TREE.size:
-        raise CodecError("short tree header")
-    magic, n, trailer_len = _TREE.unpack_from(view, offset)
-    if magic != TREE_MAGIC:
-        raise CodecError(f"bad tree magic {magic!r}")
-    pos = offset + _TREE.size + ((-_TREE.size) % _ALIGN)
-    leaves = []
-    payload = 0
-    for _ in range(n):
-        arr, pos = decode_tensor(view, pos, copy=copy)
-        pos += (-(pos - offset)) % _ALIGN
-        payload += arr.nbytes
-        leaves.append(to_jax(arr) if as_jax else arr)
-    # Trailer sits at the decode cursor — never measure from the buffer end;
-    # zero-copy receive windows may carry ring-alignment slack behind it.
-    if len(view) - pos < trailer_len:
-        raise CodecError("short tree trailer")
-    trailer = view[pos:pos + trailer_len].tobytes()
-    out = unflatten(json.loads(trailer.decode()), leaves), pos + trailer_len
-    # tpurpc-lens `decode` hop: one bump set per tree record (to_jax's
-    # share is also visible on its own jax_array row — hops may nest)
-    dt = time.monotonic_ns() - t0
-    _LENS_DEC_NS.inc(dt)
-    _LENS_DEC_BYTES.inc(payload)
-    if copy:
-        _LENS_DEC_COPY.inc(payload)
+    # tpurpc-lens `decode` hop: one stage per tree record (to_jax's share is
+    # also visible on its own jax_array row — hops may nest)
+    with _lens.stage("decode") as st:
+        view = memoryview(buf)
+        if len(view) - offset < _TREE.size:
+            raise CodecError("short tree header")
+        magic, n, trailer_len = _TREE.unpack_from(view, offset)
+        if magic != TREE_MAGIC:
+            raise CodecError(f"bad tree magic {magic!r}")
+        pos = offset + _TREE.size + ((-_TREE.size) % _ALIGN)
+        leaves = []
+        payload = 0
+        for _ in range(n):
+            arr, pos = decode_tensor(view, pos, copy=copy)
+            pos += (-(pos - offset)) % _ALIGN
+            payload += arr.nbytes
+            leaves.append(to_jax(arr) if as_jax else arr)
+        # Trailer sits at the decode cursor — never measure from the buffer
+        # end; zero-copy receive windows may carry ring-alignment slack
+        # behind it.
+        if len(view) - pos < trailer_len:
+            raise CodecError("short tree trailer")
+        trailer = view[pos:pos + trailer_len].tobytes()
+        out = (unflatten(json.loads(trailer.decode()), leaves),
+               pos + trailer_len)
+        st.nbytes = payload
+        if copy:
+            st.copy = payload
     return out
 
 
@@ -513,12 +503,9 @@ def tensor_serializer(x) -> List[bytes]:
 
 
 def tensor_deserializer(buf) -> np.ndarray:
-    t0 = time.monotonic_ns()
-    arr, _ = decode_tensor(buf)
-    dt = time.monotonic_ns() - t0
-    nbytes = arr.nbytes
-    _LENS_DEC_NS.inc(dt)
-    _LENS_DEC_BYTES.inc(nbytes)
+    with _lens.stage("decode") as st:
+        arr, _ = decode_tensor(buf)
+        st.nbytes = arr.nbytes
     return arr
 
 

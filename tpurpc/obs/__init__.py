@@ -78,5 +78,6 @@ answers over time and across members — then writes the postmortem itself.
 """
 
 from tpurpc.obs import flight, lens, metrics, profiler, tracing  # noqa: F401
+from tpurpc.obs import native_obs  # noqa: F401  (registers its collector)
 
 __all__ = ["flight", "lens", "metrics", "profiler", "tracing"]

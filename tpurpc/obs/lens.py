@@ -11,7 +11,9 @@ scrape-time division ``bytes / busy_ns`` is that hop's effective GB/s
 (B/ns ≡ GB/s, no unit conversion). The hop with the lowest effective rate
 under load is, by construction, the one to attack.
 
-The hop chain, in data-flow order (the ISSUE 8 vocabulary)::
+The hop chain, in data-flow order (the ISSUE 8 vocabulary; the server's
+per-message hops ``srv_*``, ``hbm_credit`` and ``hbm_view`` of ISSUE 26 are
+listed with their sites in :data:`HOPS`)::
 
     device     serialize: tensor bytes gathered host-side into wire form
                (jaxshim/codec.py encode — the device→host leg)
@@ -25,8 +27,9 @@ The hop chain, in data-flow order (the ISSUE 8 vocabulary)::
     decode     codec parse of wire bytes back into tensors
                (jaxshim/codec.py decode_tree_at, tpu/endpoint.py
                decode_tree_to_ring)
-    hbm        placement into the device-resident landing ring
-               (tpu/hbm_ring.py place/place_many)
+    hbm        host time to ENQUEUE the h2d transfer and the landing write
+               (tpu/hbm_ring.py place/place_many; dispatch is
+               asynchronous, so this is not device time)
     jax_array  materialization as jax.Array — dlpack alias or the
                device_put staging copy (jaxshim/codec.py to_jax)
 
@@ -34,11 +37,16 @@ Cost model — why this is ALWAYS on, like the rest of the obs stack:
 
 * accounting sites run once per **batched operation** (a drain, a gathered
   writev, a tree decode), never per byte: two ``time.monotonic_ns`` reads
-  and two/three GIL-atomic Counter bumps per op;
-* the counters are plain registry Counters, cached as module globals at
-  import by every instrumented module (the ``stage`` lint rule enforces
-  the pure-int plumbing contract at each site, exactly as the ``flight``
-  rule does for the recorder);
+  and three or four GIL-atomic Counter bumps per op;
+* a site is one :class:`stage` (``with lens.stage("hbm", n): ...``): it
+  bumps the hop's ``bytes``, ``busy_ns``, ``ops`` (and ``copy_bytes``) on
+  exit, and in a process that has imported jax it is also a
+  ``jax.profiler.TraceAnnotation`` named ``tpurpc.<hop>``, so the stages
+  lie on the device trace's own clock whenever a profiler session runs
+  (no session, or no jax: no annotation is made). Older
+  sites in ``core/`` still bump counters bound by :func:`hop_counters` by
+  hand. The ``stage`` lint rule enforces a literal declared hop and
+  pure-int arguments at both kinds of site;
 * hops may NEST (``wire`` wraps ``send_ring`` on the pair plane;
   ``decode`` wraps ``jax_array``): the table is a waterfall of per-hop
   effective rates, not a disjoint partition of wall time. The invariant
@@ -64,13 +72,17 @@ always-on accounting as ``ring_bytes_read``.
 
 from __future__ import annotations
 
+import itertools
+import sys
+import threading
+import time
 from typing import Dict, List, Optional, Tuple
 
 from tpurpc.obs import metrics as _metrics
 
 __all__ = [
-    "HOPS", "HOP_NAMES", "hop_counters", "enabled", "waterfall",
-    "render_text", "slowest_hop",
+    "HOPS", "HOP_NAMES", "hop_counters", "stage", "account", "CallStages",
+    "enabled", "waterfall", "render_text", "slowest_hop",
 ]
 
 #: the declared hop registry, in data-flow order: (name, accounting site /
@@ -93,9 +105,27 @@ HOPS: Tuple[Tuple[str, str], ...] = (
                    "grant round trip (tpr_rdv.cc rdv_claim)"),
     ("peer_ring", "RingReader drain out of the local receive ring"),
     ("decode", "codec parse of wire bytes back into tensors"),
-    ("hbm", "placement into the device-resident HBM landing ring"),
+    ("hbm", "host time to enqueue the h2d transfer and the landing "
+            "write, not device time (HbmRing.place/place_many/fill)"),
     ("jax_array", "materialization as jax.Array (dlpack alias or "
                   "device_put staging)"),
+    # ISSUE 26: the server's per-message path, one stage per message on
+    # the call's handler thread (both planes: rpc/server.py and
+    # rpc/native_server.py); srv_queue and srv_call are counters only
+    ("srv_recv", "handler thread waiting for the call's next message "
+                 "(the wire, and getting the interpreter back)"),
+    ("srv_queue", "a delivered message waiting on the call's queue for "
+                  "the handler thread (Python plane; the native plane "
+                  "counts native_srv_queue_*)"),
+    ("srv_handler", "the registered behavior with one message: from its "
+                    "hand-over until the behavior asks for the next"),
+    ("srv_send", "serialize and write one response message"),
+    ("srv_call", "whole server calls, start of the handler to its end "
+                 "(the denominator of the stages' coverage)"),
+    ("hbm_credit", "a placement blocked waiting for ring credit "
+                   "(HbmRing._space; no op where it never blocked)"),
+    ("hbm_view", "host time to enqueue the view of a placed span "
+                 "(slice / window / concat + shaped)"),
 )
 
 HOP_NAMES: Tuple[str, ...] = tuple(name for name, _ in HOPS)
@@ -103,10 +133,14 @@ HOP_NAMES: Tuple[str, ...] = tuple(name for name, _ in HOPS)
 _BYTES: Dict[str, _metrics.Counter] = {}
 _NS: Dict[str, _metrics.Counter] = {}
 _COPY: Dict[str, _metrics.Counter] = {}
+_OPS: Dict[str, _metrics.Counter] = {}
+_SPAN: Dict[str, str] = {}
 for _name, _desc in HOPS:
     _BYTES[_name] = _metrics.counter(f"lens_{_name}_bytes")
     _NS[_name] = _metrics.counter(f"lens_{_name}_busy_ns")
     _COPY[_name] = _metrics.counter(f"lens_{_name}_copy_bytes")
+    _OPS[_name] = _metrics.counter(f"lens_{_name}_ops")
+    _SPAN[_name] = f"tpurpc.{_name}"
 
 
 def hop_counters(name: str) -> Tuple[_metrics.Counter, _metrics.Counter,
@@ -120,6 +154,159 @@ def hop_counters(name: str) -> Tuple[_metrics.Counter, _metrics.Counter,
         raise ValueError(f"unknown waterfall hop {name!r}; "
                          f"declared hops: {HOP_NAMES}")
     return _BYTES[name], _NS[name], _COPY[name]
+
+
+# -- the stage primitive (ISSUE 26) ---------------------------------------------
+
+#: the thread's current ``(call, seq)``: set by the call path's top-level
+#: stage of each message, read by the stages nested under it so that every
+#: span of one message carries the same pair
+_tls = threading.local()
+_CALL_IDS = itertools.count(1)
+_annotation = None  # jax.profiler.TraceAnnotation, once this process has it
+
+
+def _annotation_cls():
+    """``jax.profiler.TraceAnnotation`` where this process has ALREADY
+    imported jax, else None. Never imports it: clients and parents stay off
+    jax (one process per chip), and ``obs/`` must not be what loads it."""
+    global _annotation
+    prof = sys.modules.get("jax.profiler")
+    _annotation = getattr(prof, "TraceAnnotation", None)
+    return _annotation
+
+
+class stage:
+    """One accounted operation of a declared hop, as a context manager
+    (or ``begin()`` / ``end()`` where a generator's ``yield`` stands
+    between them).
+
+    On exit, also when the body raised: ``lens_<hop>_busy_ns`` += elapsed,
+    ``lens_<hop>_bytes`` += ``nbytes``, ``lens_<hop>_ops`` += 1,
+    ``lens_<hop>_copy_bytes`` += ``copy``. ``nbytes`` / ``copy`` may be
+    set on the object inside the body, for sites that only know the size
+    once the work is done. In a process that has imported jax the stage is
+    also a ``jax.profiler.TraceAnnotation`` ``tpurpc.<hop>`` on the calling
+    thread, carrying the thread's current ``call`` and ``seq``, while a
+    profiler session is on (``TraceAnnotation.is_enabled()``): the session
+    is the only switch. ``call=`` sets that pair for
+    the thread (the call path does, once per message); nested stages
+    inherit it. ``begin`` and ``end`` run on one thread."""
+
+    __slots__ = ("hop", "nbytes", "copy", "_t0", "_span")
+
+    def __init__(self, hop: str, nbytes: int = 0, *,
+                 call: Optional[int] = None, seq: int = 0):
+        if call is not None:
+            _tls.ids = (call, seq)
+        self.hop = hop
+        self.nbytes = nbytes
+        self.copy = 0
+
+    def begin(self) -> "stage":
+        ann = _annotation or _annotation_cls()
+        # no session: a 50 ns look, not a 0.5 us annotation that records
+        # nothing
+        if ann is not None and ann.is_enabled():
+            call, seq = getattr(_tls, "ids", (0, 0))
+            self._span = ann(_SPAN[self.hop], call=call, seq=seq)
+            self._span.__enter__()
+        else:
+            self._span = None
+        self._t0 = time.monotonic_ns()
+        return self
+
+    def end(self) -> int:
+        dt = time.monotonic_ns() - self._t0
+        if self._span is not None:
+            self._span.__exit__(None, None, None)
+        hop = self.hop
+        _NS[hop].inc(dt)
+        _BYTES[hop].inc(self.nbytes)
+        _OPS[hop].inc()
+        if self.copy:
+            _COPY[hop].inc(self.copy)
+        return dt
+
+    def exclude(self, ns: int) -> None:
+        """Take ``ns`` of a sibling stage that ran inside this one's
+        interval out of this one's busy time (a response sent while the
+        handler's stage is open), so that the call path's top-level stages
+        stay additive."""
+        self._t0 += ns
+
+    __enter__ = begin
+
+    def __exit__(self, *exc) -> bool:
+        self.end()
+        return False
+
+
+def account(hop: str, busy_ns: int, nbytes: int = 0) -> None:
+    """One operation of ``hop`` timed by the caller: counters only, no span
+    (``srv_call``: the duration the call path already computes;
+    ``srv_queue``: a wait that is no thread's time)."""
+    _NS[hop].inc(busy_ns)
+    _BYTES[hop].inc(nbytes)
+    _OPS[hop].inc()
+
+
+class CallStages:
+    """The call path's stages of ONE server call, driven from its handler
+    thread by either plane (``rpc/server.py``, ``rpc/native_server.py``)::
+
+        with stages.recv() as rx:     # srv_recv: wait for message `seq`
+            ...; rx.nbytes = n
+        stages.handle(n)              # srv_handler opens: the behavior has it
+        ...                           #   yield / behavior(...)
+        stages.handled()              # ...until it asks for the next one
+        tx = stages.send_begin()      # srv_send: one response out, and its
+        ...; stages.send_end(tx)      #   time out of an open srv_handler
+
+    so that ``srv_recv`` + ``srv_handler`` + ``srv_send`` add up to the call
+    (``srv_call``, :meth:`finish`) less what no stage covers. ``touch`` is
+    called for every message in or out: the stall watchdog's progress."""
+
+    __slots__ = ("call", "seq", "handling", "_touch", "_t0")
+
+    def __init__(self, touch):
+        self.call = next(_CALL_IDS)  # process-wide ordinal: the spans' `call`
+        self.seq = 0
+        self.handling: Optional[stage] = None
+        self._touch = touch
+        self._t0 = time.monotonic_ns()
+
+    def recv(self) -> stage:
+        return stage("srv_recv", call=self.call, seq=self.seq)
+
+    def handle(self, nbytes: int) -> None:
+        self._touch()
+        self.handling = stage("srv_handler", nbytes).begin()
+
+    def handled(self) -> None:
+        if self.handling is not None:
+            self.handling.end()
+            self.handling = None
+            self.seq += 1
+
+    def send_begin(self) -> stage:
+        return stage("srv_send").begin()
+
+    def send_end(self, tx: stage) -> None:
+        dt = tx.end()
+        if self.handling is not None:
+            self.handling.exclude(dt)
+        self._touch()
+
+    def finish(self) -> None:
+        """The call is over: one op of ``srv_call``, once. A plane whose
+        caller sees the end of a stream before the handler thread is done
+        with it calls this BEFORE it writes the trailers, so that a
+        snapshot taken when the caller has its reply holds the call."""
+        if self._t0:
+            self.handled()
+            account("srv_call", time.monotonic_ns() - self._t0)
+            self._t0 = 0
 
 
 def enabled() -> bool:
@@ -138,14 +325,10 @@ def waterfall() -> dict:
     call time. ``gbps`` is ``bytes / busy_ns`` (identical units); a hop
     that has seen no traffic reports zeros and is excluded from the
     bottleneck argmin."""
-    # tpurpc-xray: pull the C core's byte/busy_ns table into the native
-    # hops first, so slowest_hop judges the PRODUCTION plane too
-    try:
-        from tpurpc.obs import native_obs as _nobs
-
-        _nobs.sync_registry()
-    except Exception:
-        pass
+    # the registry's collectors first (tpurpc-xray's pulls the C core's
+    # byte/busy_ns table into the native hops), so slowest_hop judges the
+    # PRODUCTION plane too
+    _metrics.registry().collect()
     rows: List[dict] = []
     for name, desc in HOPS:
         b = _BYTES[name].snapshot()
@@ -157,6 +340,7 @@ def waterfall() -> dict:
             "busy_ms": round(ns / 1e6, 3),
             "gbps": round(b / ns, 3) if ns else 0.0,
             "copy_bytes": cp,
+            "ops": _OPS[name].snapshot(),
             "what": desc,
         })
     out = {"hops": rows, "slowest_hop": slowest_hop(rows)}

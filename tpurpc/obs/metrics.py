@@ -299,6 +299,29 @@ class Registry:
     def __init__(self):
         self._lock = threading.Lock()
         self._metrics: Dict[str, object] = {}
+        self._collectors: list = []
+
+    def add_collector(self, fn: Callable[[], object]) -> None:
+        """Register ``fn`` to run before every export: a source that owns
+        its totals elsewhere (the C core's shm table) writes them into
+        this registry there, so one snapshot holds every series as of one
+        instant."""
+        with self._lock:
+            if fn not in self._collectors:
+                self._collectors.append(fn)
+
+    def collect(self) -> None:
+        """Run the collectors (``snapshot`` and its kin do, first; an
+        export that walks :meth:`metrics` itself calls this before). A
+        collector that raises is skipped: one broken source must not take
+        the export down."""
+        with self._lock:
+            fns = list(self._collectors)
+        for fn in fns:
+            try:
+                fn()
+            except Exception:
+                continue
 
     def _get(self, name: str, factory, want_cls):
         with self._lock:
@@ -339,6 +362,7 @@ class Registry:
 
     def snapshot(self) -> Dict[str, Dict]:
         """All metrics as plain dicts (tests / JSON export)."""
+        self.collect()
         out: Dict[str, Dict] = {"counters": {}, "gauges": {},
                                 "histograms": {}, "fleet": {},
                                 "labeled": {}}
@@ -358,6 +382,7 @@ class Registry:
         return out
 
     def counters_snapshot(self) -> Dict[str, int]:
+        self.collect()
         return {n: m.snapshot() for n, m in self.metrics().items()
                 if isinstance(m, Counter)}
 

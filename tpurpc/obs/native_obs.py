@@ -10,8 +10,11 @@ record. Three consumers sit on top:
 * :func:`counters` — the metrics table as a name → value dict (names
   mirror ``MetricIdx`` in tpr_obs.h IN ORDER — the index is the ABI);
 * :func:`sync_registry` — pushes the table into the PR 4 registry as
-  ``native_*`` series and feeds the lens waterfall's native hops, called
-  at scrape/sample time (/metrics, tsdb ticks, /debug/waterfall).
+  ``native_*`` series and feeds the lens waterfall's native hops. It is
+  one of the registry's collectors (registered at this module's import,
+  which ``tpurpc.obs`` does): every export of the registry (``snapshot``,
+  ``counters_snapshot``, /metrics, tsdb ticks, /debug/waterfall) runs it
+  first, so ``native_*`` is as of the same instant as everything else.
 
 The decoder honors the writer's seqlock: per slot it reads the seq word,
 copies the record, and re-reads the seq word — a wrap during the copy
@@ -26,9 +29,11 @@ plane off: every entry point here degrades to empty/no-op and the PR 18
 
 from __future__ import annotations
 
+import atexit
 import mmap
 import os
 import struct
+import sys
 import threading
 from typing import Dict, List, Optional, Tuple
 
@@ -66,6 +71,9 @@ METRIC_NAMES: Tuple[str, ...] = (
     "conn_down",           # connections died
     "emitted",             # flight records emitted (wraps overwrite)
     "tag_overflow",        # tag interns refused (table full -> tag 0)
+    "srv_queue_ns",        # ns delivered messages sat on call->pending
+    "srv_queue_msgs",      # messages popped off call->pending by a handler
+    "rdv_refused",         # offers the receiver refused (landing pool empty)
 )
 
 #: table slots that are instantaneous values, not monotonic totals
@@ -255,6 +263,7 @@ def counters() -> Dict[str, int]:
 # `stage` lint rule's cached-counter contract); the table keys each hop
 # mirrors ride alongside
 from tpurpc.obs import lens as _lens  # noqa: E402  (after the ABI tables)
+from tpurpc.obs import metrics as _metrics  # noqa: E402
 
 _HOP_SYNC: Tuple[Tuple[Tuple, str, str], ...] = (
     (_lens.hop_counters("native_send"), "rdv_send_bytes",
@@ -265,17 +274,27 @@ _HOP_SYNC: Tuple[Tuple[Tuple, str, str], ...] = (
 )
 
 
+def _library_loaded() -> bool:
+    """Whether either loader (the ring ops' ``core/_native``, the native
+    client and server's ``rpc/native_client``) has libtpurpc open in this
+    process. Looks; never imports, loads or builds."""
+    return any(getattr(sys.modules.get(mod), "_LIB", None) is not None
+               for mod in ("tpurpc.core._native", "tpurpc.rpc.native_client"))
+
+
 def sync_registry() -> bool:
     """Mirror the native table into the PR 4 registry (``native_<name>``
     series: counters get their externally-owned running total, gauges the
     instantaneous value) and feed the lens waterfall's native hops.
-    Scrape-time only — /metrics, tsdb sampling, and /debug/waterfall call
-    this; the C hot path never sees Python. Returns False when off."""
+    Export-time only — the registry runs this as a collector; the C hot
+    path never sees Python. Returns False when off, and in a process that
+    has not loaded the native library (an export must not be what builds
+    or loads it)."""
+    if not _library_loaded():
+        return False
     vals = counters()
     if not vals:
         return False
-    from tpurpc.obs import metrics as _metrics
-
     reg = _metrics.registry()
     for name, v in vals.items():
         if name in GAUGE_METRICS:
@@ -289,7 +308,21 @@ def sync_registry() -> bool:
     return True
 
 
+_metrics.registry().add_collector(sync_registry)
+
+
 # -- test / lifecycle hooks ---------------------------------------------------
+
+@atexit.register
+def _close_map() -> None:
+    """Unmap the region at interpreter exit: the C side unlinks its name
+    there (tpr_obs.cc), and a mapping left open would keep the pages."""
+    global _state
+    with _lock:
+        if isinstance(_state, _Map):
+            _state.close()
+        _state = False
+
 
 def reset() -> None:
     """Zero the ring + table (test isolation; callers quiesce emitters

@@ -95,16 +95,10 @@ def render_prometheus() -> str:
     the copy ledger, and channelz — scrape-time reads only."""
     lines: List[str] = []
 
-    # tpurpc-xray: fold the C core's shm metrics table into the registry
-    # as native_* series before the pass (scrape-time read, hot path
+    # collectors first: tpurpc-xray's folds the C core's shm metrics table
+    # into the registry as native_* series (scrape-time read, hot path
     # untouched; a no-op when the native plane is off)
-    try:
-        from tpurpc.obs import native_obs as _nobs
-
-        _nobs.sync_registry()
-    except Exception:
-        pass
-
+    _metrics.registry().collect()
     snap = _metrics.registry().metrics()
     for name in sorted(snap):
         m = snap[name]

@@ -354,7 +354,8 @@ def aggregate_waterfall() -> dict:
             hop = row.get("hop")
             if hop not in merged:
                 merged[hop] = {"hop": hop, "bytes": 0, "busy_ms": 0.0,
-                               "copy_bytes": 0, "what": row.get("what", "")}
+                               "copy_bytes": 0, "ops": 0,
+                               "what": row.get("what", "")}
                 order.append(hop)
             # tpurpc-argus: these SUM raw per-shard counters — exactly the
             # merge a worker restart would step backwards; clamp each
@@ -365,6 +366,8 @@ def aggregate_waterfall() -> dict:
                 (k, hop, "busy_ms"), float(row.get("busy_ms") or 0.0))
             merged[hop]["copy_bytes"] += int(clamp.clamp(
                 (k, hop, "copy_bytes"), int(row.get("copy_bytes") or 0)))
+            merged[hop]["ops"] += int(clamp.clamp(
+                (k, hop, "ops"), int(row.get("ops") or 0)))
     rows = []
     for hop in order:
         r = merged[hop]

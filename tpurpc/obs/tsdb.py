@@ -277,18 +277,12 @@ class Tsdb:
         """One sampler tick (tests drive this directly with synthetic
         stamps; the daemon loop calls it on the fine grain)."""
         now = now_ns if now_ns is not None else time.monotonic_ns()
-        # tpurpc-xray: refresh the native_* mirror series from the C
-        # core's shm table before the registry pass, so history picks up
-        # native-plane counters at the same grain as everything else.
-        # (Process-wide registry only — a test's private registry stays
-        # free of ambient native state.)
-        if self._registry is _metrics.registry():
-            try:
-                from tpurpc.obs import native_obs as _nobs
-
-                _nobs.sync_registry()
-            except Exception:
-                pass
+        # collectors first (tpurpc-xray's refreshes the native_* mirror
+        # series from the C core's shm table), so history picks up
+        # native-plane counters at the same grain as everything else. A
+        # test's private registry has no collectors and stays free of
+        # ambient native state.
+        self._registry.collect()
         with self._lock:
             readings = self._read_registry()
             self._fine.record(now, readings)

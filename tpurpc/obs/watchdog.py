@@ -3,10 +3,15 @@
 A serving fleet's worst page is "one call is stuck and nothing says where".
 The watchdog is a background sweeper over an in-process registry of
 in-flight RPCs (both server handlers and the pipelined client's windows
-register): any call in flight past a multiple of its method's ROLLING p99
-— or past a static floor when the method has no history yet — produces a
-structured diagnosis naming the blocked *stage*, derived from the flight
-recorder's tail plus the scrape plane's fleet gauges:
+register): any call that has made no PROGRESS for a multiple of its
+method's ROLLING p99 — or for a static floor when the method has no
+history yet — produces a structured diagnosis. Progress is the call's
+start and, for a streaming call, every message it receives or sends
+(:func:`call_progress`, one touch from the server's ``srv_recv`` and
+``srv_send`` sites): a stream is one call however long it lives, and a
+healthy one must not be barred on its age. The diagnosis names the blocked
+*stage*, derived from the flight recorder's tail plus the scrape plane's
+fleet gauges:
 
 * ``credit-starvation`` — an open (unmatched) send-lease reserve, an
   unresolved ring credit-starvation edge, or a freshly write-stalled pair;
@@ -46,8 +51,8 @@ from tpurpc.obs import flight as _flight
 from tpurpc.obs import metrics as _metrics
 from tpurpc.obs import profiler as _obs_profiler
 
-__all__ = ["StallWatchdog", "get", "call_started", "call_finished",
-           "STAGES"]
+__all__ = ["StallWatchdog", "get", "call_started", "call_progress",
+           "call_finished", "STAGES"]
 
 #: tpurpc-lens: the sweeper thread parked between sweeps is infrastructure
 #: idle time, not unattributed serving work
@@ -146,7 +151,7 @@ class StallWatchdog:
             os.environ.get("TPURPC_WATCHDOG_MULT", "8"))
         self.min_stall_s = min_stall_s if min_stall_s is not None else float(
             os.environ.get("TPURPC_WATCHDOG_MIN_S", "1.0"))
-        #: token -> [method, t0_ns, trace_id, kind, tripped]
+        #: token -> [method, t0_ns, trace_id, kind, tripped, progress_ns]
         self._inflight: Dict[int, list] = {}
         self._tokens = itertools.count(1)
         self._rolls: Dict[str, _Roll] = {}
@@ -168,11 +173,17 @@ class StallWatchdog:
         # a diagnosis that sharpens, e.g. rendezvous -> native-ctrl-frozen
         # once the C evidence lands, re-trips under the sharper stage so
         # the trip hooks capture the better story; each stage at most once)
-        self._inflight[tok] = [method, time.monotonic_ns(), trace_id, kind,
-                               set()]
+        t0 = time.monotonic_ns()
+        self._inflight[tok] = [method, t0, trace_id, kind, set(), t0]
         if self._thread is None:
             self._ensure_thread()
         return tok
+
+    def call_progress(self, token: Optional[int]) -> None:
+        """The call moved a message: the stall bar counts from here."""
+        entry = self._inflight.get(token)
+        if entry is not None:
+            entry[5] = time.monotonic_ns()
 
     def call_finished(self, token: Optional[int],
                       error: bool = False) -> None:
@@ -260,10 +271,10 @@ class StallWatchdog:
         to_trip: List[tuple] = []
         evidence = None
         for tok, entry in list(self._inflight.items()):
-            method, t0, trace_id, kind, tripped_stages = entry
-            age = now - t0
-            if age < self._stall_bar_ns(method):
+            method, t0, trace_id, kind, tripped_stages, progress = entry
+            if now - progress < self._stall_bar_ns(method):
                 continue
+            age = now - t0
             if evidence is None:
                 evidence = self._gather_evidence(now)
             stage, detail = self._attribute(evidence, kind, age)
@@ -794,6 +805,11 @@ def get() -> StallWatchdog:
 def call_started(method: str, trace_id: int = 0,
                  kind: str = "server") -> Optional[int]:
     return get().call_started(method, trace_id, kind)
+
+
+def call_progress(token: Optional[int]) -> None:
+    if token is not None:
+        get().call_progress(token)
 
 
 def call_finished(token: Optional[int], error: bool = False) -> None:

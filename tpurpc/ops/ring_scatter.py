@@ -143,6 +143,7 @@ def _ring_scatter_impl(buf_u8, payload_u8, start_word, *, n_words: int,
                         pltpu.SemaphoreType.DMA],
         input_output_aliases={2: 0},  # the ring updates in place
         interpret=interpret,
+        name="tpurpc_ring_scatter",  # what a device trace calls the kernel
     )(start_word, pay_words, buf_words)
     return words_to_bytes(out)
 

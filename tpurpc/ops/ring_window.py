@@ -151,6 +151,7 @@ def _ring_window_impl(buf_u8, head_word, *, n_words: int, interpret: bool):
                         pltpu.SemaphoreType.DMA,
                         pltpu.SemaphoreType.DMA],
         interpret=interpret,
+        name="tpurpc_ring_window",  # what a device trace calls the kernel
     )(head_word, buf_words)
     return words_to_bytes(out)[:4 * n_words]
 

@@ -42,6 +42,7 @@ import threading
 import weakref
 from typing import Optional
 
+from tpurpc.obs import lens as _lens
 from tpurpc.obs import tracing as _tracing
 from tpurpc.rpc.native_client import _u8_zc
 from tpurpc.rpc.status import AbortError, StatusCode, deserialize
@@ -304,24 +305,6 @@ class NativeDataplane:
                         call, f"unknown method {path}".encode())
                     return 12  # UNIMPLEMENTED
 
-                def requests():
-                    pptr = ctypes.POINTER(ctypes.c_uint8)()
-                    plen = ctypes.c_size_t()
-                    while True:
-                        r = lib.tpr_srv_recv(call, ctypes.byref(pptr),
-                                             ctypes.byref(plen))
-                        if r != 1:
-                            return
-                        yield deserialize(_h.request_deserializer,
-                                          _take(lib, pptr, plen))
-
-                def send(resp) -> int:
-                    raw = _h.response_serializer(resp)
-                    # zero-copy for bytes: tpr_srv_send consumes the
-                    # buffer (rdv memcpy or framed write) before returning
-                    buf, blen = _u8_zc(raw)
-                    return lib.tpr_srv_send(call, buf, blen)
-
                 # tpurpc-scope (ISSUE 4): the trace context a sampled
                 # caller shipped through tpr_call_start's metadata — same
                 # wire key as the Python plane, installed as this handler
@@ -345,14 +328,56 @@ class NativeDataplane:
 
                 wd_tok = _watchdog.call_started(
                     path, tctx.trace_id if tctx is not None else 0)
+                # tpurpc-lens (ISSUE 26): srv_recv / srv_handler / srv_send
+                # per message, as on the Python plane (rpc/server.py)
+                stages = _lens.CallStages(
+                    lambda: _watchdog.call_progress(wd_tok))
+
+                def requests():
+                    pptr = ctypes.POINTER(ctypes.c_uint8)()
+                    plen = ctypes.c_size_t()
+                    while True:
+                        with stages.recv() as rx:
+                            r = lib.tpr_srv_recv(call, ctypes.byref(pptr),
+                                                 ctypes.byref(plen))
+                            if r != 1:
+                                return
+                            n = rx.nbytes = plen.value
+                        message = deserialize(_h.request_deserializer,
+                                              _take(lib, pptr, plen))
+                        # from the hand-over until the behavior asks for
+                        # the next one (a unary call: until it ends)
+                        stages.handle(n)
+                        try:
+                            yield message
+                        finally:
+                            stages.handled()
+
+                def send(resp) -> int:
+                    tx = stages.send_begin()
+                    try:
+                        raw = _h.response_serializer(resp)
+                        # zero-copy for bytes: tpr_srv_send consumes the
+                        # buffer (rdv memcpy or framed write) before
+                        # returning
+                        buf, blen = _u8_zc(raw)
+                        tx.nbytes = blen
+                        return lib.tpr_srv_send(call, buf, blen)
+                    finally:
+                        stages.send_end(tx)
+
                 t0 = _time.monotonic_ns()
                 rc = 13
+                # one generator for the call: a unary call's only message
+                # stays the behavior's (its srv_handler stage open) until
+                # the generator is closed below
+                reqs = requests()
                 try:
                     try:
                         with _tracing.use(tctx) if tctx is not None \
                                 else _tracing.NULL_CM:
                             if _h.kind == "unary_unary":
-                                req = next(requests(), None)
+                                req = next(reqs, None)
                                 if req is None:
                                     return 13  # half-close with no message
                                 with _tracing.span("handler", tctx):
@@ -360,17 +385,17 @@ class NativeDataplane:
                                 if send(resp) != 0:
                                     return 14  # UNAVAILABLE: conn died
                             elif _h.kind == "unary_stream":
-                                req = next(requests(), None)
+                                req = next(reqs, None)
                                 if req is None:
                                     return 13
                                 for resp in _h.behavior(req, ctx):
                                     if send(resp) != 0:
                                         return 14
                             elif _h.kind == "stream_unary":
-                                if send(_h.behavior(requests(), ctx)) != 0:
+                                if send(_h.behavior(reqs, ctx)) != 0:
                                     return 14
                             else:  # stream_stream
-                                for resp in _h.behavior(requests(), ctx):
+                                for resp in _h.behavior(reqs, ctx):
                                     if send(resp) != 0:
                                         return 14
                     except AbortError as exc:
@@ -380,9 +405,11 @@ class NativeDataplane:
                     rc = ctx._finish_code()
                     return rc
                 finally:
+                    reqs.close()
                     _watchdog.call_finished(wd_tok, error=rc != 0)
                     _tracing.tail_decide(tctx, _time.monotonic_ns() - t0,
                                          error=rc != 0, method=path)
+                    stages.finish()
             except Exception as exc:  # handler raised: INTERNAL
                 try:
                     lib.tpr_srv_set_details(call, repr(exc).encode())

@@ -17,6 +17,7 @@ Reference mapping:
 
 from __future__ import annotations
 
+import collections
 import logging
 import queue
 import threading
@@ -30,9 +31,11 @@ from tpurpc.core import rendezvous as _rdv
 from tpurpc.core.endpoint import (Endpoint, EndpointError, EndpointListener,
                                   passthru_endpoint_pair)
 from tpurpc.obs import flight as _flight
+from tpurpc.obs import lens as _lens
 from tpurpc.obs import metrics as _obs_metrics
 from tpurpc.obs import profiler as _obs_profiler
 from tpurpc.obs import tracing as _tracing
+from tpurpc.obs import watchdog as _watchdog
 from tpurpc.rpc import frame as fr
 from tpurpc.rpc.status import (AbortError, Deserializer, Metadata, Serializer,
                                StatusCode, deserialize as _deserialize,
@@ -459,6 +462,12 @@ class _ServerStream:
         #: commit when the request completes (runs on the reader thread)
         self.inline_call = None
         self.inline_timer = None  # deadline watchdog for the parked call
+        #: tpurpc-lens (ISSUE 26): the call's stage bookkeeping, made by
+        #: _run_handler on the handler thread before it takes a message
+        self.stages: Optional[_lens.CallStages] = None
+        #: monotonic_ns of each queued message's put, in queue order: the
+        #: pop turns it into the `srv_queue` hop (sentinels carry none)
+        self._queued: "collections.deque[int]" = collections.deque()
         #: Backpressure: at most queue_depth completed-but-unconsumed
         #: messages per stream. The connection READER blocks acquiring a
         #: credit, which stops draining the transport, which dries the
@@ -508,6 +517,7 @@ class _ServerStream:
                         self.requests.put(self._BAD_COMPRESSION)
                         body = None
                 if body is not None:
+                    self._queued.append(time.monotonic_ns())
                     self.requests.put(body)
             else:
                 self.assembly.take()  # stream dead: drop, free the bytes
@@ -520,6 +530,7 @@ class _ServerStream:
         already in its final landing buffer (decode aliases it in place).
         Same per-stream credit backpressure as framed commits."""
         if self._acquire_credit():
+            self._queued.append(time.monotonic_ns())
             self.requests.put(body)
         if end_stream:
             self.half_closed = True
@@ -531,10 +542,17 @@ class _ServerStream:
         self.requests.put(self._END)
 
     def next_request(self, timeout: Optional[float] = None):
-        """One queue item with its credit returned; queue.Empty on timeout."""
-        item = self.requests.get(timeout=timeout)
-        if item not in (self._END, self._OVERSIZED, self._BAD_COMPRESSION):
-            self._release_credit()
+        """One queue item with its credit returned; queue.Empty on timeout.
+        The wait is one `srv_recv` stage; what the item waited on the queue
+        before this thread came for it is the `srv_queue` hop."""
+        with self.stages.recv() as rx:
+            item = self.requests.get(timeout=timeout)
+            if item not in (self._END, self._OVERSIZED,
+                            self._BAD_COMPRESSION):
+                self._release_credit()
+                rx.nbytes = len(item)
+                _lens.account("srv_queue", time.monotonic_ns()
+                              - self._queued.popleft(), rx.nbytes)
         return item
 
     def request_iterator(self, deserializer: Deserializer,
@@ -553,7 +571,14 @@ class _ServerStream:
                                  "compressed message failed to decompress")
             if not context.is_active():
                 return
-            yield _deserialize(deserializer, item)
+            message = _deserialize(deserializer, item)
+            # from the hand-over until the behavior asks for the next one
+            # (or drops the iterator): what it does with this message
+            self.stages.handle(len(item))
+            try:
+                yield message
+            finally:
+                self.stages.handled()
 
 
 class _ServerSink(fr.MessageSink):
@@ -1039,7 +1064,6 @@ class _ServerConnection:
 
     def _run_handler(self, handler: RpcMethodHandler, st: _ServerStream,
                      ctx: ServerContext, path: str) -> None:
-        from tpurpc.obs import watchdog as _watchdog
         from tpurpc.utils import stats as _stats
 
         counters = self.server.call_counters
@@ -1055,6 +1079,8 @@ class _ServerConnection:
         # method's rolling-p99 multiple
         wd_tok = _watchdog.call_started(
             path, tctx.trace_id if tctx is not None else 0)
+        st.stages = _lens.CallStages(
+            lambda: _watchdog.call_progress(wd_tok))
         t0 = time.perf_counter_ns()
         t0_mono = time.monotonic_ns()
         try:
@@ -1076,6 +1102,7 @@ class _ServerConnection:
             # turned out pathological (slow for its method, or failed)
             _tracing.tail_decide(tctx, time.monotonic_ns() - t0_mono,
                                  error=not ok, method=path)
+            st.stages.finish()
 
     def _run_handler_inner(self, handler: RpcMethodHandler, st: _ServerStream,
                            ctx: ServerContext, path: str) -> bool:
@@ -1110,6 +1137,9 @@ class _ServerConnection:
                             "client half-closed before sending a request")
                     return
                 request_in = _deserialize(handler.request_deserializer, item)
+                # one request: the behavior has it until the call ends (a
+                # request stream's iterator opens one stage per message)
+                st.stages.handle(len(item))
 
             result = handler.behavior(request_in, ctx)
 
@@ -1126,11 +1156,15 @@ class _ServerConnection:
                     # once the lazy iterator has consumed a compressed
                     # frame — a value frozen before the generator ran
                     # would lose the mirror race.
-                    self.writer.send(
-                        fr.MESSAGE,
-                        fr.FLAG_COMPRESSED if st.peer_compressed else 0,
-                        st.stream_id,
-                        handler.response_serializer(response))
+                    tx = st.stages.send_begin()
+                    try:
+                        self.writer.send(
+                            fr.MESSAGE,
+                            fr.FLAG_COMPRESSED if st.peer_compressed else 0,
+                            st.stream_id,
+                            handler.response_serializer(response))
+                    finally:
+                        st.stages.send_end(tx)
                 if ctx.is_active():
                     code = (ctx._code if ctx._code is not None
                             else StatusCode.OK)
@@ -1142,6 +1176,7 @@ class _ServerConnection:
                 # + the gathered write are the trace timeline's "respond".
                 code = ctx._code if ctx._code is not None else StatusCode.OK
                 st.final_code = code
+                tx = st.stages.send_begin()
                 try:
                     with (_tracing.span("respond", st.trace_ctx)
                           if st.trace_ctx is not None else _tracing.NULL_CM):
@@ -1162,6 +1197,8 @@ class _ServerConnection:
                     self._send_trailers(st, StatusCode.INTERNAL,
                                         "trailing metadata too large")
                     return False
+                finally:
+                    st.stages.send_end(tx)
                 return code is StatusCode.OK
         except AbortError as exc:
             self._send_trailers(st, exc.code, exc.details, ctx._trailing)
@@ -1178,6 +1215,10 @@ class _ServerConnection:
     def _send_trailers(self, st: _ServerStream, code: StatusCode, details: str,
                        metadata: Metadata = ()) -> None:
         st.final_code = code
+        if st.stages is not None:
+            # the caller has the end of its stream once these trailers are
+            # out: count the call before, not in _run_handler's finally
+            st.stages.finish()
         # tpurpc-fleet: every terminal response piggybacks the (cached)
         # load report — the least_loaded policy's per-response feed
         md = list(metadata) + self.server._load_md()

@@ -34,7 +34,6 @@ from __future__ import annotations
 
 import socket
 import struct
-import time
 from typing import Any, List, Optional, Tuple
 
 import numpy as np
@@ -47,10 +46,11 @@ from tpurpc.tpu.hbm_ring import HbmLease, HbmRing
 from tpurpc.utils.config import Platform, get_config
 from tpurpc.utils.trace import trace_endpoint
 
-# tpurpc-lens (ISSUE 8): the device-plane decode (wire record → placed
-# device view) is the `decode` waterfall hop here; its HBM placement share
-# is visible on the `hbm` row (hops may nest — see obs/lens.py).
-_LENS_DEC_BYTES, _LENS_DEC_NS, _LENS_DEC_COPY = _lens.hop_counters("decode")
+# tpurpc-lens (ISSUE 8, 26): the device-plane decode (wire record → placed
+# device view) is one `decode` stage per message here, the parent of the
+# ring's `hbm_credit`, `hbm` and `hbm_view` stages (hops nest — see
+# obs/lens.py): decode less those three is parse, unflatten and lease
+# bookkeeping.
 
 _LENS_STAGES = {
     "decode_tensor_to_ring": "codec",
@@ -147,13 +147,12 @@ def decode_tensor_to_ring(ring: HbmRing, buf, offset: int = 0,
     ``(lease, next_offset)``. ``lease.array`` is the shaped/dtyped device
     view; releasing the lease returns the span's credit.
     """
-    t0 = time.monotonic_ns()
-    dt, shape, payload, next_pos = _parse_tensor_record(memoryview(buf), offset)
-    off, n = ring.place(payload, timeout=timeout)
-    lease = ring.view(off, n, dtype=dt, shape=shape)
-    elapsed = time.monotonic_ns() - t0
-    _LENS_DEC_NS.inc(elapsed)
-    _LENS_DEC_BYTES.inc(n)
+    with _lens.stage("decode") as st:
+        dt, shape, payload, next_pos = _parse_tensor_record(memoryview(buf),
+                                                            offset)
+        off, n = ring.place(payload, timeout=timeout)
+        st.nbytes = n
+        lease = ring.view(off, n, dtype=dt, shape=shape)
     return lease, next_pos
 
 
@@ -169,60 +168,59 @@ def decode_tree_to_ring(ring: HbmRing, buf,
     """
     import json
 
-    t0 = time.monotonic_ns()
-    view = memoryview(buf)
-    magic, n_leaves, trailer_len = codec._TREE.unpack_from(view, 0)
-    if magic != codec.TREE_MAGIC:
-        raise codec.CodecError(f"bad tree magic {magic!r}")
-    # A tree whose payloads can never fit the ring must fail fast: waiting on
-    # lease releases is futile when the blocking leases are this same
-    # message's earlier leaves (reviewer finding: every such request would
-    # stall a worker the full place timeout before the inevitable error).
-    total = _tree_payload_bytes(view, n_leaves)
-    if total > ring.capacity:
-        raise BufferError(
-            f"tree payloads total {total} bytes > ring capacity "
-            f"{ring.capacity}; raise TPURPC_HBM_RING_SIZE_KB")
-    pos = codec._TREE.size + ((-codec._TREE.size) % codec._ALIGN)
-    # Batched placement: parse EVERY leaf header first (host control words),
-    # then land all payloads with ONE ring.place_many dispatch — one h2d +
-    # one donated update per tree instead of per leaf (ISSUE 1 tentpole;
-    # a transformer pytree has hundreds of leaves and paid a dispatch each).
-    metas = []  # (dtype, shape)
-    payloads = []
-    for _ in range(n_leaves):
-        dt, shape, payload, pos = _parse_tensor_record(view, pos)
-        pos += (-pos) % codec._ALIGN
-        metas.append((dt, shape))
-        payloads.append(payload)
-    if len(view) - pos < trailer_len:
-        raise codec.CodecError("short tree trailer")
-    spans = ring.place_many(payloads, timeout=timeout)
-    leases: List[HbmLease] = []
-    leaves = []
-    try:
-        for (dt, shape), (off, n) in zip(metas, spans):
-            lease = ring.view(off, n, dtype=dt, shape=shape)
-            leases.append(lease)
-            leaves.append(lease.array)
-        trailer = bytes(view[pos:pos + trailer_len])
-        tree = codec.unflatten(json.loads(trailer.decode()), leaves)
-    except Exception:
-        # Corrupt leaf, trailer, or treedef: every already-taken lease must
-        # go back, or a poison message permanently pins ring credit — and
-        # spans placed but never viewed must be consumed-and-released too,
-        # or the batch's tail spans block the head forever.
-        for lease in leases:
-            lease.release()
-        for off, n in spans[len(leases):]:
-            try:
-                ring.view(off, n).release()
-            except Exception:
-                pass  # span already torn down; nothing more to free
-        raise
-    elapsed = time.monotonic_ns() - t0
-    _LENS_DEC_NS.inc(elapsed)
-    _LENS_DEC_BYTES.inc(total)
+    with _lens.stage("decode") as st:
+        view = memoryview(buf)
+        magic, n_leaves, trailer_len = codec._TREE.unpack_from(view, 0)
+        if magic != codec.TREE_MAGIC:
+            raise codec.CodecError(f"bad tree magic {magic!r}")
+        # A tree whose payloads can never fit the ring must fail fast:
+        # waiting on lease releases is futile when the blocking leases are
+        # this same message's earlier leaves (reviewer finding: every such
+        # request would stall a worker the full place timeout before the
+        # inevitable error).
+        total = st.nbytes = _tree_payload_bytes(view, n_leaves)
+        if total > ring.capacity:
+            raise BufferError(
+                f"tree payloads total {total} bytes > ring capacity "
+                f"{ring.capacity}; raise TPURPC_HBM_RING_SIZE_KB")
+        pos = codec._TREE.size + ((-codec._TREE.size) % codec._ALIGN)
+        # Batched placement: parse EVERY leaf header first (host control
+        # words), then land all payloads with ONE ring.place_many dispatch —
+        # one h2d + one donated update per tree instead of per leaf (ISSUE 1
+        # tentpole; a transformer pytree has hundreds of leaves and paid a
+        # dispatch each).
+        metas = []  # (dtype, shape)
+        payloads = []
+        for _ in range(n_leaves):
+            dt, shape, payload, pos = _parse_tensor_record(view, pos)
+            pos += (-pos) % codec._ALIGN
+            metas.append((dt, shape))
+            payloads.append(payload)
+        if len(view) - pos < trailer_len:
+            raise codec.CodecError("short tree trailer")
+        spans = ring.place_many(payloads, timeout=timeout)
+        leases: List[HbmLease] = []
+        leaves = []
+        try:
+            for (dt, shape), (off, n) in zip(metas, spans):
+                lease = ring.view(off, n, dtype=dt, shape=shape)
+                leases.append(lease)
+                leaves.append(lease.array)
+            trailer = bytes(view[pos:pos + trailer_len])
+            tree = codec.unflatten(json.loads(trailer.decode()), leaves)
+        except Exception:
+            # Corrupt leaf, trailer, or treedef: every already-taken lease
+            # must go back, or a poison message permanently pins ring credit
+            # — and spans placed but never viewed must be consumed-and-
+            # released too, or the batch's tail spans block the head forever.
+            for lease in leases:
+                lease.release()
+            for off, n in spans[len(leases):]:
+                try:
+                    ring.view(off, n).release()
+                except Exception:
+                    pass  # span already torn down; nothing more to free
+            raise
     return tree, leases
 
 

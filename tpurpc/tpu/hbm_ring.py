@@ -87,11 +87,12 @@ _PLACE_PATH = {k: _metrics.counter(f"hbm_place_{k}")
 _VIEW_PATH = {k: _metrics.counter(f"hbm_view_{k}")
               for k in ("alias", "slice", "window", "concat")}
 
-# tpurpc-lens (ISSUE 8): the `hbm` waterfall hop — bytes landed in the
-# device ring and the nanoseconds the placement dispatch took, one bump
-# set per place/place_many call. The emulated placement stages host→device
-# (dma_h2d), so every placed byte is also a copy byte here.
-_LENS_HBM_BYTES, _LENS_HBM_NS, _LENS_HBM_COPY = _lens.hop_counters("hbm")
+# tpurpc-lens (ISSUE 8, 26): three hops, one `lens.stage` each per call.
+# `hbm_credit` the wait for ring credit (no op where a placement never
+# blocked), `hbm` the host time to ENQUEUE the h2d transfer and the landing
+# write (dispatch is asynchronous: not device time), `hbm_view` the host
+# time to enqueue the view. The emulated placement stages host→device
+# (dma_h2d), so every placed byte is also a copy byte of `hbm`.
 
 _LENS_STAGES = {
     "place": "hbm-place",
@@ -109,25 +110,28 @@ def _ring_jits():
     made every new connection retrace and reload each payload size it had
     already seen on another. ``update`` donates the ring; ``slice``'s length
     is static (one program per payload size); ``shaped`` reinterprets a
-    span's bytes as ``dtype[shape]`` in one dispatch."""
+    span's bytes as ``dtype[shape]`` in one dispatch. A jitted program is
+    named after its function, and a device trace's reduction finds it by
+    that name (``jit_tpurpc_ring_update`` ...): the names are the ring's
+    own, and stable."""
     import jax
     from jax import lax
 
-    def update(buf, payload, start):
+    def tpurpc_ring_update(buf, payload, start):
         return lax.dynamic_update_slice(buf, payload, (start,))
 
-    def slice_(buf, start, n):
+    def tpurpc_ring_slice(buf, start, n):
         return lax.dynamic_slice(buf, (start,), (n,))
 
-    def shaped(seg, dtype, shape):
+    def tpurpc_ring_shaped(seg, dtype, shape):
         from tpurpc.ops.layout import bytes_as
 
         out = bytes_as(seg, dtype)
         return out if shape is None else out.reshape(shape)
 
-    return (jax.jit(update, donate_argnums=0),
-            jax.jit(slice_, static_argnums=2),
-            jax.jit(shaped, static_argnums=(1, 2)))
+    return (jax.jit(tpurpc_ring_update, donate_argnums=0),
+            jax.jit(tpurpc_ring_slice, static_argnums=2),
+            jax.jit(tpurpc_ring_shaped, static_argnums=(1, 2)))
 
 
 class HbmRing:
@@ -280,6 +284,21 @@ class HbmRing:
     def writable(self) -> int:
         return self.capacity - (self.tail - self.head)
 
+    def _wait_credit(self, n: int, timeout: Optional[float]) -> None:
+        """Block (caller holds ``self._lock``) until ``n`` bytes are
+        writable or ``timeout`` seconds pass; with ``timeout=None`` never
+        waits. Raises :class:`BufferError` where the ring is still full.
+        The wait, where there is one, is one op of the ``hbm_credit`` hop."""
+        if n > self.writable() and timeout is not None:
+            with _lens.stage("hbm_credit", n):
+                deadline = time.monotonic() + timeout
+                while n > self.writable():
+                    remain = deadline - time.monotonic()
+                    if remain <= 0 or not self._space.wait(timeout=remain):
+                        break
+        if n > self.writable():
+            raise BufferError(f"HBM ring full: {n} > {self.writable()}")
+
     def place(self, payload, timeout: Optional[float] = None) -> Tuple[int, int]:
         """DMA one payload into the ring; returns its (offset, nbytes) span.
 
@@ -304,33 +323,23 @@ class HbmRing:
             return self.tail, 0
         if n > self.capacity:
             raise BufferError(f"payload {n} exceeds ring capacity {self.capacity}")
-        t0 = time.monotonic_ns()
         with self._lock:
-            if n > self.writable() and timeout is not None:
-                import time as _time
-                deadline = _time.monotonic() + timeout
-                while n > self.writable():
-                    remain = deadline - _time.monotonic()
-                    if remain <= 0 or not self._space.wait(timeout=remain):
-                        break
-            if n > self.writable():
-                raise BufferError(f"HBM ring full: {n} > {self.writable()}")
-            off = self.tail
-            self.tail += n
-            self._live[(off, n)] = [0, False]
-            p = off & self._mask
-            # The h2d transfer and the landing write stay separate
-            # movements: XLA cannot land a host transfer at an offset of an
-            # existing device buffer (a NIC-DMA'd ring would fuse them).
-            dev = jax.device_put(src, self.device)
-            ledger.dma_h2d(n)
-            self._land(dev, p, n)
-        dt = time.monotonic_ns() - t0
+            self._wait_credit(n, timeout)
+            with _lens.stage("hbm", n) as st:
+                st.copy = n
+                off = self.tail
+                self.tail += n
+                self._live[(off, n)] = [0, False]
+                p = off & self._mask
+                # The h2d transfer and the landing write stay separate
+                # movements: XLA cannot land a host transfer at an offset of
+                # an existing device buffer (a NIC-DMA'd ring would fuse
+                # them).
+                dev = jax.device_put(src, self.device)
+                ledger.dma_h2d(n)
+                self._land(dev, p, n)
         _HBM_PLACE_MSGS.inc()
         _HBM_PLACE_BYTES.inc(n)
-        _LENS_HBM_BYTES.inc(n)
-        _LENS_HBM_NS.inc(dt)
-        _LENS_HBM_COPY.inc(n)
         return off, n
 
     def place_many(self, payloads,
@@ -359,37 +368,25 @@ class HbmRing:
         if total > self.capacity:
             raise BufferError(
                 f"batch of {total} bytes exceeds ring capacity {self.capacity}")
-        t0 = time.monotonic_ns()
         with self._lock:
-            if total > self.writable() and timeout is not None:
-                import time as _time
-                deadline = _time.monotonic() + timeout
-                while total > self.writable():
-                    remain = deadline - _time.monotonic()
-                    if remain <= 0 or not self._space.wait(timeout=remain):
-                        break
-            if total > self.writable():
-                raise BufferError(
-                    f"HBM ring full: {total} > {self.writable()}")
-            off = self.tail
-            self.tail += total
-            spans = []
-            for n in lens:
-                if n:  # zero-size spans hold no credit (see place())
-                    self._live[(off, n)] = [0, False]
-                spans.append((off, n))
-                off += n
-            packed = np.concatenate(srcs) if len(srcs) > 1 else srcs[0]
-            p = spans[0][0] & self._mask
-            dev = jax.device_put(packed, self.device)
-            ledger.dma_h2d(total)
-            self._land(dev, p, total)
-        dt = time.monotonic_ns() - t0
+            self._wait_credit(total, timeout)
+            with _lens.stage("hbm", total) as st:
+                st.copy = total
+                off = self.tail
+                self.tail += total
+                spans = []
+                for n in lens:
+                    if n:  # zero-size spans hold no credit (see place())
+                        self._live[(off, n)] = [0, False]
+                    spans.append((off, n))
+                    off += n
+                packed = np.concatenate(srcs) if len(srcs) > 1 else srcs[0]
+                p = spans[0][0] & self._mask
+                dev = jax.device_put(packed, self.device)
+                ledger.dma_h2d(total)
+                self._land(dev, p, total)
         _HBM_PLACE_MSGS.inc(len(spans))
         _HBM_PLACE_BYTES.inc(total)
-        _LENS_HBM_BYTES.inc(total)
-        _LENS_HBM_NS.inc(dt)
-        _LENS_HBM_COPY.inc(total)
         return spans
 
     def _assert_stable(self) -> None:
@@ -430,6 +427,12 @@ class HbmRing:
             empty = jnp.zeros((0,), dt).reshape(shape if shape is not None
                                                 else (0,))
             return HbmLease(self, off, 0, empty)
+        with _lens.stage("hbm_view", n):
+            return self._view(off, n, dtype, shape)
+
+    def _view(self, off: int, n: int, dtype, shape) -> "HbmLease":
+        import jax.numpy as jnp
+
         with self._lock:
             if (off, n) not in self._live:
                 raise KeyError(f"span ({off}, {n}) not live")
@@ -545,16 +548,7 @@ class HbmRing:
             raise BufferError(
                 f"payload {nbytes} exceeds ring capacity {self.capacity}")
         with self._lock:
-            if nbytes > self.writable() and timeout is not None:
-                import time as _time
-                deadline = _time.monotonic() + timeout
-                while nbytes > self.writable():
-                    remain = deadline - _time.monotonic()
-                    if remain <= 0 or not self._space.wait(timeout=remain):
-                        break
-            if nbytes > self.writable():
-                raise BufferError(
-                    f"HBM ring full: {nbytes} > {self.writable()}")
+            self._wait_credit(nbytes, timeout)
             off = self.tail
             self.tail += nbytes
             self._live[(off, nbytes)] = [0, False]
@@ -571,20 +565,16 @@ class HbmRing:
         if src.nbytes != nbytes:
             raise ValueError(f"fill of {src.nbytes} bytes into a "
                              f"{nbytes}-byte lease")
-        t0 = time.monotonic_ns()
-        with self._lock:
+        with self._lock, _lens.stage("hbm", nbytes) as st:
+            st.copy = nbytes
             if (off, nbytes) not in self._live:
                 raise KeyError(f"span ({off}, {nbytes}) not live")
             p = off & self._mask
             dev = jax.device_put(src, self.device)
             ledger.dma_h2d(nbytes)
             self._land(dev, p, nbytes)
-        dt = time.monotonic_ns() - t0
         _HBM_PLACE_MSGS.inc()
         _HBM_PLACE_BYTES.inc(nbytes)
-        _LENS_HBM_BYTES.inc(nbytes)
-        _LENS_HBM_NS.inc(dt)
-        _LENS_HBM_COPY.inc(nbytes)
 
 
 class HbmLease:
