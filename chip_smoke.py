@@ -10,10 +10,14 @@ Drives the system's main path once, through the entry points a user calls
 
 1. *tensor RPC into HBM* — ``GRPC_PLATFORM_TYPE=RDMA_TPU``, a ``device=True``
    stream: seeded random ``float32[1024,1024]`` (4 MiB) tensors plus sizes that
-   do not divide the 16 MiB device ring, enough to lap it four times a pass, so
-   spans wrap and ``ring_scatter`` / ``ring_window`` run compiled on the
-   request path. The handler insists every leaf is a ``jax.Array`` on the TPU,
-   folds a position-weighted checksum on the device and reads it back once.
+   do not divide the 16 MiB device ring, enough to lap its credit window four
+   times a pass. On a TPU no view can alias the ring, so every message must
+   land directly: one ``device_put`` to its final array, no ring program
+   (``hbm_place_direct`` = messages, ``dma_d2d`` = 0); on the CPU rehearsal
+   the bytes go through the ring, so spans wrap and ``ring_scatter`` /
+   ``ring_window`` run (interpreted) on the request path. The handler insists
+   every leaf is a ``jax.Array`` on the device, folds a position-weighted
+   checksum on the device and reads it back once.
 2. *serving* — ResNet-50, 1000 classes, 224x224x3, bf16, random weights from a
    seed, behind ``FanInBatcher(max_batch=8, fixed_bucket=True,
    transfer_dtype=bf16)``; 8 connections; each reply is compared with the same
@@ -413,7 +417,8 @@ def plan_pass(cfg: dict, capacity: int, floor: int):
     return shapes, wrapped
 
 
-def tensor_leg(say, cfg, srv: ServerChild, port: int, capacity: int) -> dict:
+def tensor_leg(say, cfg, srv: ServerChild, port: int, capacity: int,
+               on_tpu: bool) -> dict:
     import numpy as np
 
     from tpurpc.jaxshim import TensorClient
@@ -484,13 +489,26 @@ def tensor_leg(say, cfg, srv: ServerChild, port: int, capacity: int) -> dict:
         check(led.get("host_copy", 0) < floor,
               f"host_copy {led.get('host_copy')} B: a payload was copied on "
               f"the host (control frames alone stay under {floor})")
-        check(c.get("hbm_place_scatter", 0) == wrapped
-              and c.get("hbm_view_window", 0) == wrapped,
-              f"wrapped spans took {paths}, want {wrapped} through each kernel")
-        check(not c.get("hbm_place_split") and not c.get("hbm_view_concat"),
-              f"a jax-op chain stood in for a kernel: {paths}")
-        check(c.get("hbm_place_update", 0) == len(shapes) - wrapped,
-              f"unwrapped placements {paths}")
+        if on_tpu:
+            # no view of a TPU ring can alias it: every message lands in its
+            # final array by its one transfer, and nothing else is counted
+            check(paths == {"hbm_place_direct": len(shapes),
+                            "hbm_view_direct": len(shapes)},
+                  f"landings took {paths}, want {len(shapes)} direct")
+            check(not led.get("dma_d2d"),
+                  f"dma_d2d {led.get('dma_d2d')} B moved on the device")
+        else:
+            check(c.get("hbm_place_scatter", 0) == wrapped
+                  and c.get("hbm_view_window", 0) == wrapped,
+                  f"wrapped spans took {paths}, want {wrapped} through each "
+                  "kernel")
+            check(not c.get("hbm_place_split")
+                  and not c.get("hbm_view_concat"),
+                  f"a jax-op chain stood in for a kernel: {paths}")
+            check(c.get("hbm_place_update", 0) == len(shapes) - wrapped,
+                  f"unwrapped placements {paths}")
+            check(not c.get("hbm_place_direct"),
+                  f"an aliasing ring landed directly: {paths}")
         check(not c.get("tensor_device_degraded"),
               "device=True degraded to the host decode")
         # and once below the rendezvous bar: the framed path into the ring
@@ -716,7 +734,8 @@ def main() -> int:
         check(rehearsal or ready["ring_capacity"] == 16 << 20,
               f"device ring is {ready['ring_capacity']} B, not the default")
         result["legs"]["tensor"] = tensor_leg(
-            say, cfg, srv, ready["port"], ready["ring_capacity"])
+            say, cfg, srv, ready["port"], ready["ring_capacity"],
+            device["platform"] == "tpu")
         result["legs"]["serving"] = serving_leg(say, cfg, srv, ready["port"])
         bye = srv.stop()
         say(f"server 1 stopped cleanly; compile cache now holds "

@@ -82,6 +82,10 @@ class _Stack:
 # -- the handoff end-to-end ---------------------------------------------------
 
 def test_disagg_stream_exact_tokens_and_ship_accounting():
+    # the flight recorder is the process's: judge this test's events only,
+    # not those an earlier file left in the same worker (tests/test_protocol.py
+    # emits kv-ship events with the same ids)
+    t0 = time.monotonic_ns()
     st = _Stack()
     try:
         prompt = list(range(20))
@@ -93,7 +97,7 @@ def test_disagg_stream_exact_tokens_and_ship_accounting():
         assert [t for _, t in pairs] == reference_decode(prompt, 12)
         # 21 entries of 16 bytes went one-sided into the decode arena
         assert w["rdma_write"] >= 21 * 16, w.delta
-        snap = flight.snapshot()
+        snap = flight.snapshot(since_ns=t0)
         protocol.assert_ordered(snap, ["kv-ship-offer",
                                        "kv-ship-complete"])
         assert protocol.check_events(snap, strict=False) == []

@@ -577,6 +577,34 @@ def test_nested_stages_keep_parent_over_the_sum_of_its_children():
     assert d["decode"]["bytes"] == d["hbm_view"]["bytes"] == 2 * 40960
 
 
+def test_direct_landing_is_one_op_of_each_landing_stage(monkeypatch):
+    """A message landed directly (a ring that cannot alias, as on a TPU) is
+    still one `decode` over one `hbm` and one `hbm_view`, whatever its leaf
+    count: the three readers of the landing keep an additive split."""
+    import numpy as np
+
+    from tpurpc.jaxshim import codec
+    from tpurpc.tpu import HbmRing
+    from tpurpc.tpu.endpoint import decode_tree_to_ring
+
+    monkeypatch.setenv("TPURPC_DLPACK_VIEW", "0")
+    hops = ("decode", "hbm_credit", "hbm", "hbm_view")
+    ring = HbmRing(1 << 16)
+    tree = {"x": np.arange(8192, dtype=np.float32), "y": np.ones(5, np.int32)}
+    wire = bytearray(codec.encode_tree_bytes(tree))
+    before = {h: _hop(h) for h in hops}
+    _, leases = decode_tree_to_ring(ring, wire)
+    d = {h: {k: _hop(h)[k] - before[h][k] for k in before[h]} for h in hops}
+    for lease in leases:
+        lease.release()
+    payload = 8192 * 4 + 5 * 4
+    assert [d[h]["ops"] for h in hops] == [1, 0, 1, 1]
+    assert d["decode"]["busy_ns"] >= (d["hbm"]["busy_ns"]
+                                      + d["hbm_view"]["busy_ns"]) > 0
+    assert d["hbm"]["bytes"] == d["hbm"]["copy_bytes"] == payload
+    assert d["decode"]["bytes"] == d["hbm_view"]["bytes"] == payload
+
+
 def test_stage_in_a_process_without_jax_leaves_it_unimported():
     import os
     import subprocess

@@ -375,3 +375,167 @@ def test_kernel_failure_propagates_on_tpu_platform(monkeypatch):
     ring.device = jax.devices()[0]
     with ring.view(off, n) as arr:
         assert bytes(np.asarray(arr)) == payload
+
+
+# -- direct landing: a ring whose views cannot alias it -----------------------
+
+@pytest.fixture
+def direct_ring(monkeypatch):
+    """A ring factory on the path every TPU ring takes: no view can alias the
+    ring (here by ``TPURPC_DLPACK_VIEW=0``, read once as the ring is made),
+    so ``land_many`` puts each leaf straight into its final array."""
+    monkeypatch.setenv("TPURPC_DLPACK_VIEW", "0")
+
+    def make(capacity=1 << 16):
+        ring = HbmRing(capacity)
+        assert not ring._aliasing
+        return ring
+    return make
+
+
+def _path_counters():
+    from tpurpc.obs import metrics
+
+    snap = metrics.registry().counters_snapshot()
+    return {k: v for k, v in snap.items()
+            if k.startswith(("hbm_place_", "hbm_view_"))}
+
+
+def _moved(before):
+    """The path counters that moved since ``before = _path_counters()``."""
+    return {k: v - before[k] for k, v in _path_counters().items()
+            if v != before[k]}
+
+
+def test_aliasing_is_decided_once_per_ring(monkeypatch):
+    ring = HbmRing(1 << 12)
+    assert ring._aliasing  # CPU device, default settings
+    monkeypatch.setenv("TPURPC_DLPACK_VIEW", "0")
+    assert ring._aliasing and not HbmRing(1 << 12)._aliasing
+
+
+@pytest.mark.parametrize("dtype, shape", [
+    (np.float32, (32, 32)), (np.uint8, (4096,)), (np.int32, (3, 5, 7)),
+    ("bfloat16", (8, 16)), (np.float32, (0, 3)), (np.float16, ())])
+def test_land_direct_one_transfer_no_ring_program(direct_ring, dtype, shape):
+    import jax
+    import ml_dtypes
+
+    dt = np.dtype(ml_dtypes.bfloat16 if dtype == "bfloat16" else dtype)
+    x = (np.arange(int(np.prod(shape)), dtype=np.float64) * 3 - 7).astype(
+        dt).reshape(shape)
+    wire = bytearray(x.tobytes())  # the wire buffer, reused below
+    ring = direct_ring()
+    before = _path_counters()
+    with ledger.track() as w:
+        lease = ring.land(wire, dt, shape)
+    wire[:] = bytes(len(wire))  # the array must not alias the wire buffer
+    arr = lease.array
+    assert isinstance(arr, jax.Array) and arr.devices() == {ring.device}
+    assert arr.dtype == dt and arr.shape == tuple(shape)
+    assert not lease.aliased
+    np.testing.assert_array_equal(np.asarray(arr), x)
+    assert w["dma_h2d"] == x.nbytes and w["dma_h2d_ops"] == bool(x.nbytes)
+    assert w["dma_d2d"] == w["zero_copy"] == w["host_copy"] == 0
+    assert _moved(before) == {
+        "hbm_place_direct": 1, "hbm_view_direct": 1, "hbm_place_msgs": 1,
+        **({"hbm_place_bytes": x.nbytes} if x.nbytes else {})}
+    assert ring.stats()["live_spans"] == bool(x.nbytes)
+    assert ring.stats()["tail"] == x.nbytes
+    lease.release()
+    lease.release()  # idempotent
+    st = ring.stats()
+    assert st["live_spans"] == 0 and st["head"] == st["tail"] == x.nbytes
+
+
+def test_land_direct_credit_window(direct_ring):
+    """16 KiB of credit, 8 KiB messages: the third landing blocks until a
+    release; leases released out of order advance the head in order; over
+    capacity raises at once; a timeout raises and changes nothing."""
+    import threading
+    import time
+
+    ring = direct_ring(1 << 14)
+    msg = np.arange(2048, dtype=np.float32)  # 8 KiB
+    f32 = np.dtype(np.float32)
+    a = ring.land(msg, f32, (2048,))
+    b = ring.land(msg, f32, (2048,))
+    assert ring.writable() == 0
+    t0 = time.monotonic()
+    with pytest.raises(BufferError, match="ring full"):
+        ring.land(msg, f32, (2048,), timeout=0.05)
+    assert 0.04 <= time.monotonic() - t0 < 2
+    with pytest.raises(BufferError):
+        ring.land(msg, f32, (2048,))  # timeout=None never waits
+    assert ring.stats() == {"capacity": 1 << 14, "head": 0, "tail": 1 << 14,
+                            "live_spans": 2, "writable": 0}
+    t0 = time.monotonic()
+    with pytest.raises(BufferError, match="capacity"):
+        ring.land(np.zeros((1 << 14) + 4, np.uint8), np.dtype(np.uint8),
+                  ((1 << 14) + 4,), timeout=30)
+    assert time.monotonic() - t0 < 1
+    # out of order: b's release frees nothing while a is held
+    b.release()
+    assert ring.stats()["head"] == 0 and ring.writable() == 0
+    timer = threading.Timer(0.1, a.release)
+    timer.start()
+    t0 = time.monotonic()
+    c = ring.land(msg, f32, (2048,), timeout=10)  # blocks, then lands
+    assert time.monotonic() - t0 >= 0.05
+    timer.join(timeout=10)
+    assert not timer.is_alive()
+    np.testing.assert_array_equal(np.asarray(c.array), msg)
+    st = ring.stats()
+    assert st["head"] == 1 << 14 and st["live_spans"] == 1
+    c.release()
+    assert ring.writable() == 1 << 14
+    # the arrays handed out are snapshots: they outlive their credit
+    np.testing.assert_array_equal(np.asarray(a.array), msg)
+
+
+@pytest.mark.parametrize("aliasing", [True, False], ids=["alias", "direct"])
+def test_land_misfit_returns_every_byte_of_credit(monkeypatch, aliasing):
+    """A leaf whose dtype or shape does not fit its bytes (wire-reachable:
+    the header is the sender's) raises, and no credit stays behind, whether
+    it is the only leaf or sits between two good ones."""
+    if not aliasing:
+        monkeypatch.setenv("TPURPC_DLPACK_VIEW", "0")
+    ring = HbmRing(1 << 12)
+    assert ring._aliasing == aliasing
+    good = (np.arange(64, dtype=np.float32), np.dtype(np.float32), (64,))
+    for bad in ((np.zeros(10, np.uint8), np.dtype(np.float32), (2,)),
+                (np.zeros(16, np.uint8), np.dtype(np.float32), (5,))):
+        with pytest.raises(Exception):
+            ring.land(*bad)
+        with pytest.raises(Exception):
+            ring.land_many([good, bad, good])
+        st = ring.stats()
+        assert st["live_spans"] == 0 and st["head"] == st["tail"], st
+    (lease,) = ring.land_many([good])
+    np.testing.assert_array_equal(np.asarray(lease.array), good[0])
+    lease.release()
+    assert ring.writable() == ring.capacity
+
+
+def test_place_view_and_lease_region_keep_their_paths_on_a_direct_ring(
+        direct_ring):
+    """``place`` / ``view`` / ``place_many`` / ``lease_region`` called
+    directly still go through the ring's bytes, whatever ``land_many``
+    does: update + slice, counted and billed as before."""
+    ring = direct_ring()
+    x = np.arange(256, dtype=np.float32)
+    before = _path_counters()
+    with ledger.track() as w:
+        off, n = ring.place(x)
+        with ring.view(off, n, np.float32, (256,)) as arr:
+            np.testing.assert_array_equal(np.asarray(arr), x)
+        region = ring.lease_region(x.nbytes)
+        region.fill(x)
+        with region.view(np.float32, (256,)) as arr:
+            np.testing.assert_array_equal(np.asarray(arr), x)
+        region.release()
+    moved = _moved(before)
+    assert moved["hbm_place_update"] == 2 and moved["hbm_view_slice"] == 2
+    assert "hbm_place_direct" not in moved and "hbm_view_direct" not in moved
+    assert w["dma_h2d"] == 2 * x.nbytes and w["dma_d2d"] == 4 * x.nbytes
+    assert ring.writable() == ring.capacity

@@ -19,6 +19,8 @@ from tpurpc.tpu import HbmRing, ledger
 from tpurpc.tpu.endpoint import (DeviceMessage, TpuRingEndpoint,
                                  decode_tensor_to_ring, decode_tree_to_ring)
 
+from tests.test_tpu import _moved, _path_counters as _paths
+
 
 def _tpu_server(monkeypatch, fn, kind="unary_unary", device=True,
                 platform="TPU"):
@@ -35,6 +37,17 @@ def _tpu_server(monkeypatch, fn, kind="unary_unary", device=True,
     srv.start()
     port = srv.add_insecure_port("127.0.0.1:0")
     return srv, port
+
+
+@pytest.fixture(params=["alias", "direct"])
+def landing(request, monkeypatch):
+    """Both landings of the decode path: ``alias`` is a CPU ring by default
+    (bytes into the ring, dlpack views of them); ``direct`` is a ring whose
+    views cannot alias it, as on every TPU, reached here by
+    ``TPURPC_DLPACK_VIEW=0`` (each ring reads it once, as it is made)."""
+    if request.param == "direct":
+        monkeypatch.setenv("TPURPC_DLPACK_VIEW", "0")
+    return request.param
 
 
 # -- decode-to-ring units -----------------------------------------------------
@@ -70,7 +83,80 @@ def test_decode_tree_to_ring_roundtrip_and_release():
     assert st["live_spans"] == 0 and st["writable"] == st["capacity"]
 
 
-def test_ring_credit_blocks_until_lease_release():
+def _trees():
+    import ml_dtypes
+
+    rng = np.random.default_rng(5)
+    return {
+        "one_leaf": {"x": rng.standard_normal((32, 48)).astype(np.float32)},
+        "forty_leaves": {f"l{i:02d}": rng.integers(
+            0, 255, size=(i + 1, 3), dtype=np.uint8) for i in range(40)},
+        "empty_leaf": {"a": np.zeros((0, 4), np.float32),
+                       "b": np.arange(6, dtype=np.int32)},
+        "zero_d_leaf": {"s": np.float32(2.5), "v": np.ones(3, np.float16)},
+        "bfloat16": {"h": rng.standard_normal((16, 8)).astype(
+            ml_dtypes.bfloat16)},
+    }
+
+
+@pytest.mark.parametrize("case", list(_trees()))
+def test_decode_tree_lands_directly_where_no_view_can_alias(monkeypatch,
+                                                            case):
+    """The TPU's landing, on the CPU: one transfer per message, each leaf
+    its final array (values, dtype, shape as the host decode gives them),
+    nothing moved on the device, nothing copied on the host."""
+    import jax
+
+    monkeypatch.setenv("TPURPC_DLPACK_VIEW", "0")
+    tree = _trees()[case]
+    wire = bytearray(codec.encode_tree_bytes(tree))
+    want = codec.decode_tree(bytes(wire))
+    payload = sum(v.nbytes for v in want.values())
+    ring = HbmRing(1 << 16)
+    before = _paths()
+    with ledger.track() as w:
+        out, leases = decode_tree_to_ring(ring, wire)
+    wire[:] = bytes(len(wire))  # the wire buffer is reused; the arrays stay
+    assert len(leases) == len(tree)
+    for key, ref in want.items():
+        leaf = out[key]
+        assert isinstance(leaf, jax.Array) and leaf.devices() == {ring.device}
+        assert leaf.dtype == ref.dtype and leaf.shape == ref.shape, key
+        np.testing.assert_array_equal(np.asarray(leaf), ref)
+    assert w["dma_h2d"] == payload and w["dma_h2d_ops"] == 1
+    assert w["dma_d2d"] == w["host_copy"] == w["zero_copy"] == 0
+    assert _moved(before) == {
+        "hbm_place_direct": len(tree), "hbm_view_direct": len(tree),
+        "hbm_place_msgs": len(tree), "hbm_place_bytes": payload}
+    assert ring.stats()["tail"] == payload
+    for lease in leases:
+        assert not lease.aliased
+        lease.release()
+    st = ring.stats()
+    assert st["live_spans"] == 0 and st["head"] == st["tail"]
+
+
+def test_decode_tensor_lands_directly_where_no_view_can_alias(monkeypatch):
+    monkeypatch.setenv("TPURPC_DLPACK_VIEW", "0")
+    x = np.arange(2048, dtype=np.float32).reshape(2, 1024)
+    wire = bytearray(codec.encode_tensor_bytes(x))
+    ring = HbmRing(1 << 16)
+    before = _paths()
+    with ledger.track() as w:
+        lease, end = decode_tensor_to_ring(ring, wire)
+    assert end == len(wire)
+    assert (w["dma_h2d"], w["dma_d2d"], w["host_copy"]) == (x.nbytes, 0, 0)
+    assert _moved(before) == {
+        "hbm_place_direct": 1, "hbm_view_direct": 1, "hbm_place_msgs": 1,
+        "hbm_place_bytes": x.nbytes}
+    with lease as arr:
+        assert arr.shape == x.shape and arr.dtype == x.dtype
+        assert arr.devices() == {ring.device}
+        np.testing.assert_array_equal(np.asarray(arr), x)
+    assert ring.writable() == ring.capacity
+
+
+def test_ring_credit_blocks_until_lease_release(landing):
     """An unreleased lease back-pressures placement (flow control), and a
     release from another thread unblocks a waiting place()."""
     x = np.zeros(3000, np.uint8)
@@ -108,7 +194,7 @@ def test_empty_tensors_no_span_collision():
     assert st["live_spans"] == 0 and st["writable"] == st["capacity"]
 
 
-def test_corrupt_trailer_releases_leases():
+def test_corrupt_trailer_releases_leases(landing):
     """A poison trailer must return every taken lease (reviewer finding:
     leaked credit = one-peer DoS on the connection's ring)."""
     tree = {"x": np.ones(64, np.float32)}
@@ -119,6 +205,27 @@ def test_corrupt_trailer_releases_leases():
         decode_tree_to_ring(ring, wire)
     st = ring.stats()
     assert st["live_spans"] == 0 and st["writable"] == st["capacity"]
+
+
+def test_misfit_header_returns_every_byte_of_credit(landing):
+    """A header whose dtype does not fit ``nbytes`` (the sender writes it):
+    the decode raises, alone or as the middle leaf of a tree, and the ring
+    has all of its credit back."""
+    ring = HbmRing(1 << 12)
+    rec = bytearray(codec.encode_tensor_bytes(np.arange(10, dtype=np.uint8)))
+    rec[4] = codec._DTYPE_TO_CODE[np.dtype(np.float32)]  # 10 B of float32
+    with pytest.raises(Exception):
+        decode_tensor_to_ring(ring, rec)
+    tree = {"a": np.ones(8, np.float32), "b": np.arange(10, dtype=np.uint8),
+            "c": np.ones(8, np.float32)}
+    wire = bytearray(codec.encode_tree_bytes(tree))
+    at = wire.index(bytes(rec[:4]), wire.index(bytes(rec[:4])) + 1)
+    assert wire[at + 4] == codec._DTYPE_TO_CODE[np.dtype(np.uint8)]
+    wire[at + 4] = rec[4]
+    with pytest.raises(Exception):
+        decode_tree_to_ring(ring, wire)
+    st = ring.stats()
+    assert st["live_spans"] == 0 and st["writable"] == st["capacity"], st
 
 
 def test_tree_larger_than_ring_fails_fast():
@@ -170,12 +277,13 @@ def test_factory_dispatches_tpu_endpoint(monkeypatch, spelling):
 
 # -- end-to-end tensor RPC on the TPU platform --------------------------------
 
-def test_e2e_device_tensor_rpc(monkeypatch):
+def test_e2e_device_tensor_rpc(monkeypatch, landing):
     """GRPC_PLATFORM_TYPE=TPU end to end: handler receives ring-backed device
     arrays, decode adds no host copies beyond frame assembly."""
     import jax
 
     seen = {}
+    before = _paths()
 
     def fn(tree):
         seen["type"] = type(tree["x"])
@@ -188,6 +296,10 @@ def test_e2e_device_tensor_rpc(monkeypatch):
             out = TensorClient(ch).call("Call", {"x": x}, timeout=30)
         np.testing.assert_array_equal(np.asarray(out["y"]), x * 2)
         assert issubclass(seen["type"], jax.Array)
+        took = {"alias": {"hbm_place_update": 1, "hbm_view_alias": 1},
+                "direct": {"hbm_place_direct": 1, "hbm_view_direct": 1}}
+        assert _moved(before) == {**took[landing], "hbm_place_msgs": 1,
+                                  "hbm_place_bytes": x.nbytes}
     finally:
         srv.stop(grace=0)
 
@@ -287,10 +399,11 @@ def test_e2e_client_device_response(monkeypatch):
         srv.stop(grace=0)
 
 
-def test_e2e_streaming_rolling_credit(monkeypatch):
+def test_e2e_streaming_rolling_credit(monkeypatch, landing):
     """A device-mode stream longer than the ring holds only one message's
     leases at a time (rolling release as the handler advances)."""
     monkeypatch.setenv("TPURPC_HBM_RING_SIZE_KB", "64")  # 64 KiB device ring
+    before = _paths()
 
     def consume(trees):
         total = 0
@@ -305,6 +418,7 @@ def test_e2e_streaming_rolling_credit(monkeypatch):
             replies = list(TensorClient(ch).duplex(
                 "Call", iter([{"x": x}] * 8), timeout=60))
         assert int(np.asarray(replies[0]["total"]).ravel()[0]) == 8 * 4096
+        assert _moved(before)["hbm_view_" + landing] == 8
     finally:
         srv.stop(grace=0)
 

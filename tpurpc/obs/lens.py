@@ -27,9 +27,10 @@ listed with their sites in :data:`HOPS`)::
     decode     codec parse of wire bytes back into tensors
                (jaxshim/codec.py decode_tree_at, tpu/endpoint.py
                decode_tree_to_ring)
-    hbm        host time to ENQUEUE the h2d transfer and the landing write
-               (tpu/hbm_ring.py place/place_many; dispatch is
-               asynchronous, so this is not device time)
+    hbm        host time to ENQUEUE the h2d transfer and, where the bytes
+               go through the ring, the landing write (tpu/hbm_ring.py
+               place/place_many/land_many; dispatch is asynchronous, so
+               this is not device time)
     jax_array  materialization as jax.Array — dlpack alias or the
                device_put staging copy (jaxshim/codec.py to_jax)
 
@@ -106,7 +107,8 @@ HOPS: Tuple[Tuple[str, str], ...] = (
     ("peer_ring", "RingReader drain out of the local receive ring"),
     ("decode", "codec parse of wire bytes back into tensors"),
     ("hbm", "host time to enqueue the h2d transfer and the landing "
-            "write, not device time (HbmRing.place/place_many/fill)"),
+            "write, not device time (HbmRing.place/place_many/fill; "
+            "land_many: its one transfer)"),
     ("jax_array", "materialization as jax.Array (dlpack alias or "
                   "device_put staging)"),
     # ISSUE 26: the server's per-message path, one stage per message on
@@ -125,7 +127,8 @@ HOPS: Tuple[Tuple[str, str], ...] = (
     ("hbm_credit", "a placement blocked waiting for ring credit "
                    "(HbmRing._space; no op where it never blocked)"),
     ("hbm_view", "host time to enqueue the view of a placed span "
-                 "(slice / window / concat + shaped)"),
+                 "(slice / window / concat + shaped; a direct landing: "
+                 "the lease hand-off)"),
 )
 
 HOP_NAMES: Tuple[str, ...] = tuple(name for name, _ in HOPS)

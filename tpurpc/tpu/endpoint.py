@@ -19,11 +19,13 @@ device handles, host-memcpy = 0 after frame assembly"):
   RPCs never pay jax initialization.
 * :func:`decode_tensor_to_ring` / :func:`decode_tree_to_ring` are the
   ``DeserializeToDevice`` of this platform (SURVEY §7 stage 6): they parse
-  the codec's host-visible tensor header, place the payload span into the
-  device ring straight from the wire-assembly buffer (zero host memcpy —
-  the ledger proves it), and hand back device views whose leases gate the
-  ring's credit return (hard-part #4: a jax.Array aliasing ring memory
-  must pin its span).
+  the codec's host-visible tensor header, land the payload spans through
+  the device ring (``HbmRing.land_many``) straight from the wire-assembly
+  buffer (zero host memcpy — the ledger proves it), and hand back device
+  arrays whose leases gate the ring's credit return (hard-part #4: a
+  jax.Array aliasing ring memory must pin its span). Where a ring's views
+  cannot alias it (every TPU) the landing is ONE ``device_put`` per message
+  to the final arrays and the ring is the credit window over them.
 
 The RPC layer reaches the device ring through ``ServerContext.device_ring``
 (server) and ``Channel.device_ring()`` (client); the jaxshim tensor service
@@ -49,8 +51,8 @@ from tpurpc.utils.trace import trace_endpoint
 # tpurpc-lens (ISSUE 8, 26): the device-plane decode (wire record → placed
 # device view) is one `decode` stage per message here, the parent of the
 # ring's `hbm_credit`, `hbm` and `hbm_view` stages (hops nest — see
-# obs/lens.py): decode less those three is parse, unflatten and lease
-# bookkeeping.
+# obs/lens.py): decode less those three is parse, unflatten and the ring
+# call's own bookkeeping.
 
 _LENS_STAGES = {
     "decode_tensor_to_ring": "codec",
@@ -139,30 +141,29 @@ def _parse_tensor_record(view: memoryview, offset: int):
 def decode_tensor_to_ring(ring: HbmRing, buf, offset: int = 0,
                           timeout: Optional[float] = PLACE_TIMEOUT_S
                           ) -> Tuple[HbmLease, int]:
-    """One wire tensor record → device-ring placement + lease-backed view.
+    """One wire tensor record → device landing + lease-backed array.
 
-    Parses the codec header host-side (control words), places ONLY the
-    payload span into ``ring`` directly from ``buf`` (no intermediate host
-    buffer — the ledger's host_copy stays 0 for this step), and returns
+    Parses the codec header host-side (control words), lands ONLY the
+    payload span through ``ring`` directly from ``buf`` (no intermediate
+    host buffer — the ledger's host_copy stays 0 for this step), and returns
     ``(lease, next_offset)``. ``lease.array`` is the shaped/dtyped device
-    view; releasing the lease returns the span's credit.
+    array; releasing the lease returns the span's credit.
     """
     with _lens.stage("decode") as st:
         dt, shape, payload, next_pos = _parse_tensor_record(memoryview(buf),
                                                             offset)
-        off, n = ring.place(payload, timeout=timeout)
-        st.nbytes = n
-        lease = ring.view(off, n, dtype=dt, shape=shape)
+        st.nbytes = payload.nbytes
+        lease = ring.land(payload, dt, shape, timeout=timeout)
     return lease, next_pos
 
 
 def decode_tree_to_ring(ring: HbmRing, buf,
                         timeout: Optional[float] = PLACE_TIMEOUT_S
                         ) -> Tuple[Any, List[HbmLease]]:
-    """Pytree wire message → device-ring-backed tree + the leases pinning it.
+    """Pytree wire message → device-resident tree + the leases pinning it.
 
     Mirrors :func:`tpurpc.jaxshim.codec.decode_tree`, but every leaf's
-    payload is placed into the device ring instead of aliased host-side.
+    payload lands on the ring's device instead of being aliased host-side.
     Returns ``(tree, leases)``; release every lease (or use
     :class:`DeviceMessage`) to return the ring credit.
     """
@@ -173,74 +174,32 @@ def decode_tree_to_ring(ring: HbmRing, buf,
         magic, n_leaves, trailer_len = codec._TREE.unpack_from(view, 0)
         if magic != codec.TREE_MAGIC:
             raise codec.CodecError(f"bad tree magic {magic!r}")
-        # A tree whose payloads can never fit the ring must fail fast:
-        # waiting on lease releases is futile when the blocking leases are
-        # this same message's earlier leaves (reviewer finding: every such
-        # request would stall a worker the full place timeout before the
-        # inevitable error).
-        total = st.nbytes = _tree_payload_bytes(view, n_leaves)
-        if total > ring.capacity:
-            raise BufferError(
-                f"tree payloads total {total} bytes > ring capacity "
-                f"{ring.capacity}; raise TPURPC_HBM_RING_SIZE_KB")
         pos = codec._TREE.size + ((-codec._TREE.size) % codec._ALIGN)
-        # Batched placement: parse EVERY leaf header first (host control
-        # words), then land all payloads with ONE ring.place_many dispatch —
-        # one h2d + one donated update per tree instead of per leaf (ISSUE 1
-        # tentpole; a transformer pytree has hundreds of leaves and paid a
-        # dispatch each).
-        metas = []  # (dtype, shape)
-        payloads = []
+        # Batched landing: parse EVERY leaf header first (host control
+        # words), then land all payloads with ONE ring.land_many — one
+        # transfer per tree instead of per leaf (a transformer pytree has
+        # hundreds of leaves), and a tree that can never fit the ring fails
+        # there at once instead of waiting on its own earlier leaves.
+        leaves = []  # (payload, dtype, shape)
         for _ in range(n_leaves):
             dt, shape, payload, pos = _parse_tensor_record(view, pos)
             pos += (-pos) % codec._ALIGN
-            metas.append((dt, shape))
-            payloads.append(payload)
+            leaves.append((payload, dt, shape))
+            st.nbytes += payload.nbytes
         if len(view) - pos < trailer_len:
             raise codec.CodecError("short tree trailer")
-        spans = ring.place_many(payloads, timeout=timeout)
-        leases: List[HbmLease] = []
-        leaves = []
+        leases = ring.land_many(leaves, timeout=timeout)
         try:
-            for (dt, shape), (off, n) in zip(metas, spans):
-                lease = ring.view(off, n, dtype=dt, shape=shape)
-                leases.append(lease)
-                leaves.append(lease.array)
             trailer = bytes(view[pos:pos + trailer_len])
-            tree = codec.unflatten(json.loads(trailer.decode()), leaves)
+            tree = codec.unflatten(json.loads(trailer.decode()),
+                                   [lease.array for lease in leases])
         except Exception:
-            # Corrupt leaf, trailer, or treedef: every already-taken lease
-            # must go back, or a poison message permanently pins ring credit
-            # — and spans placed but never viewed must be consumed-and-
-            # released too, or the batch's tail spans block the head forever.
+            # Corrupt trailer or treedef: every lease must go back, or a
+            # poison message permanently pins ring credit.
             for lease in leases:
                 lease.release()
-            for off, n in spans[len(leases):]:
-                try:
-                    ring.view(off, n).release()
-                except Exception:
-                    pass  # span already torn down; nothing more to free
             raise
     return tree, leases
-
-
-def _tree_payload_bytes(view: memoryview, n_leaves: int) -> int:
-    """Sum the payload sizes of a tree message by walking headers only."""
-    pos = codec._TREE.size + ((-codec._TREE.size) % codec._ALIGN)
-    total = 0
-    for _ in range(n_leaves):
-        if len(view) - pos < codec._HDR.size:
-            raise codec.CodecError("short tensor header")
-        magic, _, ndim, _, nbytes = codec._HDR.unpack_from(view, pos)
-        if magic != codec.MAGIC:
-            raise codec.CodecError(f"bad tensor magic {magic!r}")
-        rec = pos
-        pos += codec._HDR.size + 8 * ndim
-        pos += (-(pos - rec)) % codec._ALIGN
-        pos += nbytes
-        pos += (-pos) % codec._ALIGN
-        total += nbytes
-    return total
 
 
 class DeviceMessage:

@@ -1,11 +1,32 @@
-"""Device-resident receive ring: tensor payloads live in TPU HBM, consumers
-get device views — the emulated form of the BASELINE north star.
+"""Device-resident receive ring: tensor payloads land in TPU HBM, consumers
+get lease-backed device arrays — the emulated form of the BASELINE north star.
 
 Real hardware path (not reachable in this environment): the NIC DMAs into a
 dmabuf-exported HBM ring, head/footer words stay host-visible, and ``Recv``
 returns device buffer handles. This module emulates the *architecture* with
 XLA-visible pieces so the protocol, lease discipline, and copy ledger are
-real even though the placement is a ``device_put``:
+real even though the placement is a ``device_put``.
+
+What the ring IS depends on one thing it observes about its device, once, as
+it is made (``HbmRing._aliasing``): can a view alias the ring's bytes?
+
+* **It can** (a CPU device, unless ``TPURPC_DLPACK_VIEW=0``): the ring is a
+  byte ring. ``land_many``, the decode path's entry, is ``place_many`` +
+  ``view``: the bytes go INTO the ring and the arrays handed out alias them.
+* **It cannot** (every TPU): the ring is a **credit window over directly
+  landed buffers**. ``land_many`` hands the typed host views of a message's
+  leaves to ONE ``jax.device_put`` and leases the arrays it returns: 1 byte
+  moved per payload byte (ledger ``dma_h2d`` alone), no ring program, no host
+  scalar handed to a jit. Writing the bytes into the ring first would be a
+  dead store: nothing could read them back but one more copy. The ring array
+  stays allocated at its configured size, and ``place`` / ``view`` /
+  ``place_many`` / ``lease_region`` below still go through it for callers
+  that use them directly.
+
+The two landings share the credit accounting (``_wait_credit``, ``tail``,
+``_live``, in-order ``_advance_locked``) and no landing logic: an aliasing
+device wants the bytes in the ring, a copying device gains nothing from them
+being there.
 
 * ``place`` — one h2d movement per payload (ledger: dma_h2d) followed by the
   landing write that puts it in the ring: a donated ``dynamic_update_slice``,
@@ -14,21 +35,21 @@ real even though the placement is a ``device_put``:
   ``dma_d2d`` — two honest entries for two real movements (on NIC hardware
   the DMA writes the ring directly and both entries collapse into the NIC's
   single placement write).
-* ``view`` — for aligned, unwrapped spans on a CPU device: a **dlpack
+* ``view`` — for aligned, unwrapped spans on an aliasing ring: a **dlpack
   alias** of the ring bytes themselves — a ``jax.Array`` whose buffer
   pointer is ``ring_base + offset``, zero bytes moved, ledger ``zero_copy``.
   Aliasing is **verified per view** by pointer comparison — an import the
   backend chose to copy (misaligned span, exotic dtype) is recorded as
-  ``dma_d2d``, honestly. On a TPU every view is a device copy, recorded as
-  ``dma_d2d``: ``dynamic_slice`` (+ bitcast) for an unwrapped span, the
+  ``dma_d2d``, honestly. Everywhere else a view is a device copy, recorded
+  as ``dma_d2d``: ``dynamic_slice`` (+ bitcast) for an unwrapped span, the
   ring_window kernel for a wrapped one (on real hardware the aliasing seam
   is the dmabuf export, out of this environment's reach). Payload bytes
   never touch the host either way.
 
   Which path each placement and each view took is counted
-  (``hbm_place_{update,scatter,split}``, ``hbm_view_{alias,slice,window,
-  concat}`` in the metrics registry), and a kernel that fails raises: no
-  path gives way to another behind the caller's back.
+  (``hbm_place_{update,scatter,split,direct}``, ``hbm_view_{alias,slice,
+  window,concat,direct}`` in the metrics registry), and a kernel that fails
+  raises: no path gives way to another behind the caller's back.
 
   The alias relies on one invariant the real hardware has by construction
   (a pinned ring is never reallocated): XLA's donation must keep the ring
@@ -38,14 +59,17 @@ real even though the placement is a ``device_put``:
   aliased leases were outstanding.
 * lease/credit — a message's span stays pinned until every handle is
   released; only then does the head advance (SURVEY.md §7 hard-part #4: a
-  ``jax.Array`` aliasing ring memory must gate credit return).
+  ``jax.Array`` aliasing ring memory must gate credit return). A directly
+  landed array is a snapshot and survives its release; its lease is the
+  back-pressure: at most ``capacity`` bytes of unreleased leases.
 
 Thread model: ``self.buf`` is rebound by donating jits in ``place`` while
 ``view`` slices it — both run under ``self._lock`` for their whole device
 op, because a donated buffer is DELETED the moment the update launches and a
 concurrent slice of the old binding would fault (advisor r1 finding). The
-lock spans an XLA dispatch, which is acceptable for the emulation: one ring
-has one producer (the receive path) and its consumers.
+lock spans an XLA dispatch (a direct landing's one transfer too), which is
+acceptable for the emulation: one ring has one producer (the receive path)
+and its consumers.
 
 Capacity is a power of two; offsets are monotonic 64-bit counters — the same
 invariants as the host ring (tpurpc/core/ring.py), so the flow-control math
@@ -59,6 +83,7 @@ here the drain's landing target is device memory.
 from __future__ import annotations
 
 import functools
+import os
 import threading
 import time
 from typing import Dict, Optional, Tuple
@@ -81,26 +106,38 @@ _HBM_RINGS = _metrics.fleet("hbm_ring_occupancy_bytes",
 #: not guess: `update` one donated dynamic_update_slice, `scatter` the
 #: ring_scatter kernel, `split` two updates across the wrap (kernel
 #: ineligible); `alias` dlpack view, `slice` one dynamic_slice, `window` the
-#: ring_window kernel, `concat` slice+slice+concatenate (kernel ineligible)
+#: ring_window kernel, `concat` slice+slice+concatenate (kernel ineligible);
+#: `direct` (both) a leaf that `land_many` put straight into its final array
 _PLACE_PATH = {k: _metrics.counter(f"hbm_place_{k}")
-               for k in ("update", "scatter", "split")}
+               for k in ("update", "scatter", "split", "direct")}
 _VIEW_PATH = {k: _metrics.counter(f"hbm_view_{k}")
-              for k in ("alias", "slice", "window", "concat")}
+              for k in ("alias", "slice", "window", "concat", "direct")}
 
 # tpurpc-lens (ISSUE 8, 26): three hops, one `lens.stage` each per call.
 # `hbm_credit` the wait for ring credit (no op where a placement never
 # blocked), `hbm` the host time to ENQUEUE the h2d transfer and the landing
 # write (dispatch is asynchronous: not device time), `hbm_view` the host
-# time to enqueue the view. The emulated placement stages host→device
-# (dma_h2d), so every placed byte is also a copy byte of `hbm`.
+# time to enqueue the view. A direct landing (`land_many` on a ring that
+# cannot alias) is one `hbm` round its single transfer and one `hbm_view`
+# round the lease hand-off, per message. The emulated placement stages
+# host→device (dma_h2d), so every placed byte is also a copy byte of `hbm`.
 
 _LENS_STAGES = {
     "place": "hbm-place",
     "place_many": "hbm-place",
+    "land_many": "hbm-place",
     "_land": "hbm-place",
     "view": "device-dispatch",
 }
 _profiler.register_stages(__file__, _LENS_STAGES)
+
+
+def _u8(payload) -> np.ndarray:
+    """``payload`` (a buffer, or an array of any dtype) as flat bytes: a
+    view, never a copy."""
+    if isinstance(payload, np.ndarray):
+        return payload.reshape(-1).view(np.uint8)
+    return np.frombuffer(payload, np.uint8)
 
 
 @functools.lru_cache(maxsize=None)
@@ -168,6 +205,15 @@ class HbmRing:
         #: backend doesn't expose one — the dlpack view path needs it both
         #: to build the alias and to verify stability across donations
         self._base_ptr = self._ptr_of(self.buf)
+        #: whether a view of this ring CAN alias its bytes, decided here
+        #: once: a CPU device, a base address to build the alias on, and no
+        #: ``TPURPC_DLPACK_VIEW=0``; ``_dlpack_view`` latches it off for
+        #: good where the import lands on another device. No TPU ring
+        #: aliases. It picks the landing (see :meth:`land_many`): bytes that
+        #: nothing can alias have no reason to pass through the ring.
+        self._aliasing = (device.platform == "cpu"
+                          and self._base_ptr is not None
+                          and os.environ.get("TPURPC_DLPACK_VIEW", "1") != "0")
 
         self._update, self._slice, self._shaped = _ring_jits()
 
@@ -199,12 +245,7 @@ class HbmRing:
         Consumers must not donate a leased array into a jit — that would
         hand XLA a write alias into ring memory (same contract as the
         reference's borrowed ring slices, ``ring_buffer.cc:122-191``)."""
-        import os
-
-        if (getattr(self, "_dlpack_broken", False)
-                or self.device.platform != "cpu"
-                or self._base_ptr is None
-                or os.environ.get("TPURPC_DLPACK_VIEW", "1") == "0"):
+        if not self._aliasing:
             return None
         import ctypes
 
@@ -233,7 +274,7 @@ class HbmRing:
             # the ring's (virtual multi-device mesh): consumers would trip
             # cross-device errors. Latch off — this is a property of the
             # ring's device, not of one span.
-            self._dlpack_broken = True
+            self._aliasing = False
             return None
         return arr, self._ptr_of(arr) == self._base_ptr + p
 
@@ -243,8 +284,6 @@ class HbmRing:
         (``TPURPC_PALLAS=0``). A kernel that is eligible and fails RAISES —
         nothing here remembers a failure and quietly takes the jax-op chain,
         on any platform: the path counters must mean what they say."""
-        import os
-
         return not (p % 4 or n % 4 or self.capacity < min_capacity
                     or self.device.platform not in ("cpu", "tpu")
                     or os.environ.get("TPURPC_PALLAS", "1") == "0")
@@ -313,8 +352,7 @@ class HbmRing:
         """
         import jax
 
-        src = np.frombuffer(payload, np.uint8) if not isinstance(
-            payload, np.ndarray) else payload.reshape(-1).view(np.uint8)
+        src = _u8(payload)
         n = src.nbytes
         if n == 0:
             # Zero-size spans never enter _live: they'd all share the key
@@ -359,8 +397,7 @@ class HbmRing:
         than the whole ring raises."""
         import jax
 
-        srcs = [np.frombuffer(p, np.uint8) if not isinstance(p, np.ndarray)
-                else p.reshape(-1).view(np.uint8) for p in payloads]
+        srcs = [_u8(p) for p in payloads]
         lens = [s.nbytes for s in srcs]
         total = sum(lens)
         if total == 0:
@@ -388,6 +425,100 @@ class HbmRing:
         _HBM_PLACE_MSGS.inc(len(spans))
         _HBM_PLACE_BYTES.inc(total)
         return spans
+
+    def land(self, payload, dtype, shape,
+             timeout: Optional[float] = None) -> "HbmLease":
+        """:meth:`land_many` of one leaf."""
+        return self.land_many([(payload, dtype, shape)], timeout)[0]
+
+    def land_many(self, leaves,
+                  timeout: Optional[float] = None) -> "list[HbmLease]":
+        """The decode path's landing: one message's ``(payload, dtype,
+        shape)`` leaves in, one lease-backed device array per leaf out.
+
+        Credit is the same on every ring: the batch waits up to ``timeout``
+        for its TOTAL to fit (:class:`BufferError` where it cannot, at once
+        over capacity), each non-empty leaf holds its own span, and the
+        head advances in order as leases are released. Where the bytes land
+        depends on what the ring's device allows (``_aliasing``):
+
+        * views can alias the ring: :meth:`place_many` then :meth:`view`
+          per leaf: the bytes go INTO the ring and the arrays alias them
+          (ledger ``dma_h2d`` + ``dma_d2d`` + ``zero_copy``);
+        * they cannot (every TPU): nothing would ever read the bytes back
+          out of the ring but one more copy, so the typed host views go to
+          the device in ONE ``jax.device_put`` and ARE the arrays handed
+          out (ledger ``dma_h2d`` and nothing else; no ring program, no
+          host scalar handed to a jit). The ring is then a credit window
+          over directly landed buffers: at most ``capacity`` bytes of
+          unreleased leases.
+
+        A leaf whose dtype or shape does not fit its bytes raises, with
+        every byte of the batch's credit returned."""
+        srcs = [_u8(p) for p, _, _ in leaves]
+        total = sum(src.nbytes for src in srcs)
+        if total > self.capacity:
+            raise BufferError(
+                f"message payloads total {total} bytes > ring capacity "
+                f"{self.capacity}; raise TPURPC_HBM_RING_SIZE_KB")
+        if self._aliasing:
+            spans = self.place_many(srcs, timeout)
+            leases: "list[HbmLease]" = []
+            try:
+                for (_, dt, shape), (off, n) in zip(leaves, spans):
+                    leases.append(self.view(off, n, dtype=dt, shape=shape))
+            except BaseException:
+                # the leases taken go back, and the spans placed but never
+                # viewed count as consumed, or they block the head for ever
+                for lease in leases:
+                    lease.release()
+                with self._lock:
+                    for span in spans[len(leases):]:
+                        if span in self._live:
+                            self._live[span][1] = True
+                    self._advance_locked()
+                raise
+            return leases
+        import jax
+
+        # the host decode's own views (codec.decode_tensor): free, and a
+        # leaf that does not fit its bytes raises here, before any credit.
+        # The transfer reads them AFTER device_put returns (chip probe,
+        # PERF.md 6, PR 27); as in place(), what keeps the wire buffer from
+        # being recycled meanwhile is the reference jax holds on the view,
+        # which chains to the buffer's owner, until the transfer completes.
+        host = [src.view(dt).reshape(shape)
+                for src, (_, dt, shape) in zip(srcs, leaves)]
+        if self.device.platform == "cpu":
+            # a CPU device adopts an aligned host buffer in place: the array
+            # would alias the wire buffer, and pin it for life, where a
+            # TPU's is a snapshot. The one movement the ledger bills is
+            # made here
+            host = [h.copy() for h in host]
+        with self._lock:
+            self._wait_credit(total, timeout)
+            with _lens.stage("hbm", total) as st:
+                st.copy = total
+                arrays = jax.device_put(host, self.device)
+                if total:
+                    ledger.dma_h2d(total)
+                # claimed only once the transfer is enqueued: one that
+                # raises leaves nothing to undo
+                spans, off = [], self.tail
+                for h in host:
+                    if h.nbytes:  # zero-size spans hold no credit
+                        self._live[(off, h.nbytes)] = [1, False]
+                    spans.append((off, h.nbytes))
+                    off += h.nbytes
+                self.tail = off
+        with _lens.stage("hbm_view", total):
+            leases = [HbmLease(self, off, n, arr)
+                      for (off, n), arr in zip(spans, arrays)]
+        _HBM_PLACE_MSGS.inc(len(leases))
+        _HBM_PLACE_BYTES.inc(total)
+        _PLACE_PATH["direct"].inc(len(leases))
+        _VIEW_PATH["direct"].inc(len(leases))
+        return leases
 
     def _assert_stable(self) -> None:
         """Donation-stability invariant behind the dlpack aliases (called
@@ -560,8 +691,7 @@ class HbmRing:
         discipline and ledger accounting as :meth:`place`."""
         import jax
 
-        src = np.frombuffer(payload, np.uint8) if not isinstance(
-            payload, np.ndarray) else payload.reshape(-1).view(np.uint8)
+        src = _u8(payload)
         if src.nbytes != nbytes:
             raise ValueError(f"fill of {src.nbytes} bytes into a "
                              f"{nbytes}-byte lease")
