@@ -1,0 +1,12 @@
+"""Host memcpy bytes per payload byte of a round trip (program_counter): the
+ledger's ``host_copy`` over the window, server plus clients, over the payload
+acknowledged. Both directions are in it: a reply that fell back to the framed
+ring is copied on the host on both sides."""
+
+
+def read(run):
+    if not run["payload_bytes"]:
+        return None
+    copied = (run["server_ledger"].get("host_copy", 0)
+              + run["client_ledger"].get("host_copy", 0))
+    return copied / run["payload_bytes"]

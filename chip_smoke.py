@@ -191,6 +191,15 @@ def serve(role: str, rehearsal: bool) -> None:
         add_tensor_method(srv, "Sink", sink, kind="stream_stream",
                           device=True)
 
+        # -- leg 1, outbound: one reply that is a device array
+        bump = jax.jit(lambda x: x + jnp.float32(1))
+
+        def bumped(tree):
+            return {"y": on_chip(bump(on_chip(tree["x"], "request leaf")),
+                                 "reply leaf")}
+
+        add_tensor_method(srv, "Bump", bumped, device=True)
+
         # -- leg 2: the serving stack exactly as bench.py wires it
         from tpurpc.models import resnet
 
@@ -511,6 +520,32 @@ def tensor_leg(say, cfg, srv: ServerChild, port: int, capacity: int,
                   f"an aliasing ring landed directly: {paths}")
         check(not c.get("tensor_device_degraded"),
               "device=True degraded to the host decode")
+        # one reply out of HBM: read back once, billed once, bit-exact
+        m = np.random.default_rng(14).standard_normal(shapes[0],
+                                                      dtype=np.float32)
+        cli.call("Bump", {"x": m}, timeout=600)  # builds the one program
+        s0 = srv.stats()
+        y = np.array(cli.call("Bump", {"x": m}, timeout=600)["y"])
+        s1 = srv.stats()
+        c, led = delta(s1, s0, "counters"), delta(s1, s0, "ledger")
+        check(y.tobytes() == (m + np.float32(1)).tobytes(),
+              "the reply read out of device memory is not x + 1")
+        got = {k: led.get(k, 0) for k in ("dma_d2h", "dma_d2h_ops",
+                                          "zero_copy", "zero_copy_ops")
+               if led.get(k, 0)}
+        # a host backend aliases the reply where it lies (and the aliasing
+        # ring's view of the request bills zero_copy too); a chip reads it
+        # back once and bills nothing else
+        want = ({"dma_d2h": m.nbytes, "dma_d2h_ops": 1} if on_tpu else
+                {"zero_copy": 2 * m.nbytes, "zero_copy_ops": 2})
+        check(got == want and c.get("lens_d2h_ops", 0) == int(on_tpu),
+              f"a reply of {m.nbytes} B billed {got} with "
+              f"{c.get('lens_d2h_ops', 0)} d2h stage(s), want {want}")
+        check(c.get("rdv_bytes_sent", 0) >= m.nbytes,
+              f"the reply left framed: rdv_bytes_sent {c.get('rdv_bytes_sent')}")
+        say(f"  reply out of device memory ({m.nbytes} B): bit-exact, "
+            f"ledger {got}, by rendezvous {c.get('rdv_bytes_sent', 0)} B")
+        out["reply"] = {"bytes": m.nbytes, "ledger": got}
         # and once below the rendezvous bar: the framed path into the ring
         s0 = srv.stats()
         run_pass(cli, 13, [cfg["small"]] * 4)

@@ -395,28 +395,46 @@ def test_hbm_lease_region_death_release_frees_credit():
         lease.fill(np.zeros(1 << 17, np.uint8))  # released: no late landing
 
 
-def test_serialize_into_zero_host_staging():
+def test_device_reply_leaves_by_rendezvous_zero_host_staging(fresh_config):
+    """``SerializeFromDevice`` end to end (the product path that replaced
+    ``serialize_into``): a ``device=True`` reply over the size bar is read
+    back once (``dma_d2h``), its gather list is placed one-sided into the
+    client's landing region (``rdma_write``, ``rdv_bytes_sent``) and no host
+    staging copy of the payload is made on either side."""
     import jax
 
+    from tpurpc.jaxshim import TensorClient, add_tensor_method
+    from tpurpc.obs import metrics as _metrics
+    from tpurpc.rpc.channel import Channel
+    from tpurpc.rpc.server import Server
     from tpurpc.tpu import serialize
 
-    dst = bytearray(1 << 20)
-    view = memoryview(dst)
-
-    def write(off, seg):
-        view[off:off + len(seg)] = seg
-
-    tree = {"a": jax.device_put(np.ones((128, 128), np.float32)),
-            "b": np.arange(64, dtype=np.int64)}
-    with ledger.track() as w:
-        n = serialize.serialize_tree_into(tree, write)
-    assert n > 0
-    assert w["host_copy"] == 0, w.delta       # no staging buffer, ever
-    assert w["rdma_write"] == n               # the placement IS the move
-    from tpurpc.jaxshim import codec
-
-    back = codec.decode_tree(view)
-    assert np.allclose(back["a"], 1.0) and back["b"][63] == 63
+    _reset_platform(fresh_config, "RDMA_TPU")
+    fresh_config.setattr(serialize, "_on_device",
+                         lambda x: isinstance(x, jax.Array))
+    srv = Server(max_workers=2)
+    add_tensor_method(srv, "Call", lambda t: {"y": t["x"] + 1},
+                      device=True)
+    srv.start()
+    port = srv.add_insecure_port("127.0.0.1:0")
+    sent = _metrics.registry().metrics()["rdv_bytes_sent"]
+    x = np.arange(1 << 18, dtype=np.float32)   # 1 MiB, over the 256 KiB bar
+    try:
+        with Channel(f"127.0.0.1:{port}") as ch:
+            cli = TensorClient(ch)
+            cli.call("Call", {"x": x}, timeout=30)   # hello, grants
+            sent0 = sent.snapshot()
+            with ledger.track() as w:
+                y = cli.call("Call", {"x": x}, timeout=30)["y"]
+                assert np.array_equal(y, x + 1)
+                del y
+            moved = sent.snapshot() - sent0
+    finally:
+        srv.stop(grace=0)
+    assert moved >= 2 * x.nbytes            # request and reply, one process
+    assert w["rdma_write"] == moved, w.delta
+    assert w["dma_d2h"] == x.nbytes and w["dma_d2h_ops"] == 1, w.delta
+    assert w["host_copy"] < 4096, w.delta   # control frames, never payload
 
 
 def test_codec_descriptor_only_encode_roundtrip():

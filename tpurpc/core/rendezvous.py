@@ -104,6 +104,11 @@ _profiler.register_stages(__file__, _LENS_STAGES)
 #: whether the bulk plane is actually carrying traffic
 _RDV_SENT = _metrics.counter("rdv_transfers_sent")
 _RDV_RECV = _metrics.counter("rdv_transfers_received")
+#: message bytes those sent transfers placed one-sided (codec header
+#: included): over the payload a sender put out in all, the share that left
+#: on this path; what fell back to the framed path is the rest (a server's
+#: replies are what it sends, so on a server this is the reply side)
+_RDV_SENT_BYTES = _metrics.counter("rdv_bytes_sent")
 #: payload bytes those received transfers delivered (the Python plane's
 #: twin of the C table's native_rdv_recv_bytes): over the payload a
 #: receiver took in all, the share of traffic that stayed on this path
@@ -936,6 +941,7 @@ class RdvLink:
             return False
         self.rdv_complete(claim, stream_id, flags, total)
         _RDV_SENT.inc()
+        _RDV_SENT_BYTES.inc(total)
         return True
 
     def _take_grant(self, cls: int, total: int) -> Optional[_Claim]:

@@ -30,7 +30,8 @@ from tpurpc.obs import lens as _lens
 from tpurpc.obs import profiler as _profiler
 
 # tpurpc-lens (ISSUE 8) waterfall hops on the codec boundary: `device` is
-# the serialize leg (device/host tensor bytes gathered into wire form),
+# the serialize leg (header + gather list over tensor bytes that are on the
+# host; a reply's device leaves were read back under `d2h` before),
 # `decode` the parse back, `jax_array` the final materialization. One
 # `lens.stage` per tensor record / tree record — never per byte.
 
@@ -98,8 +99,11 @@ def _as_numpy(x) -> np.ndarray:
     """Materialize x host-side without gratuitous copies.
 
     jax.Array → np.asarray uses the dlpack/buffer protocol: zero-copy when the
-    array is already in host memory (CPU backend), one device→host DMA when on
-    TPU (unavoidable until the HBM send ring lands, tpurpc/tpu/).
+    array is already in host memory (CPU backend). An array on a TPU is read
+    back here, blocking, only when a caller hands one to the codec directly
+    (a client that sends a device array); a ``device=True`` reply's leaves
+    arrive as the landing buffers of transfers
+    :func:`tpurpc.tpu.serialize.tree_from_device` started and billed.
     """
     if isinstance(x, np.ndarray):
         return np.ascontiguousarray(x)
@@ -266,8 +270,9 @@ _TREE = struct.Struct("<4sIQ")  # magic 'TPTR', n_leaves, trailer nbytes
 TREE_MAGIC = b"TPTR"
 
 
-def encode_tree(tree: Any) -> List[bytes]:
-    """Encode an arbitrary pytree of arrays as a gather list."""
+def flatten_tree(tree: Any) -> Tuple[Any, list]:
+    """``(skeleton, leaves)`` of ``tree``: the JSON treedef the trailer
+    carries and the leaves in jax's flatten order. Walked once per message."""
     leaves: list = []
     skeleton = _plain_flatten(tree, leaves)
     if skeleton is None:  # a container only jax's pytree registry can judge
@@ -275,6 +280,14 @@ def encode_tree(tree: Any) -> List[bytes]:
 
         leaves, treedef = jax.tree_util.tree_flatten(tree)
         skeleton = _treedef_to_json(treedef)
+    return skeleton, leaves
+
+
+def encode_flat(skeleton: Any, leaves: list) -> List[bytes]:
+    """Gather list of a flattened tree (:func:`flatten_tree`): the leaves
+    are host arrays, or arrays whose host copy is already at hand (the
+    device leaves of a reply reach here through
+    :func:`tpurpc.tpu.serialize.tree_from_device`)."""
     trailer = json.dumps(skeleton).encode()
     segs: List[bytes] = [_TREE.pack(TREE_MAGIC, len(leaves), len(trailer))]
     pad = (-_TREE.size) % _ALIGN
@@ -288,6 +301,11 @@ def encode_tree(tree: Any) -> List[bytes]:
             segs.append(b"\x00" * rem)
     segs.append(trailer)
     return segs
+
+
+def encode_tree(tree: Any) -> List[bytes]:
+    """Encode an arbitrary pytree of arrays as a gather list."""
+    return encode_flat(*flatten_tree(tree))
 
 
 def encode_tree_bytes(tree: Any) -> bytes:

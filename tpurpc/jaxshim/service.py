@@ -127,9 +127,14 @@ def add_tensor_method(server: Server, name: str,
     encoded the same way.
 
     With ``device=True`` and the TPU platform
-    (``GRPC_PLATFORM_TYPE=TPU``), request payloads are placed into the
+    (``GRPC_PLATFORM_TYPE=RDMA_TPU``), request payloads are placed into the
     connection's HBM receive ring and ``fn`` gets lease-backed device arrays;
-    the leases (ring credit) are released when ``fn`` returns. A call that
+    the leases (ring credit) are released when ``fn`` returns. Device arrays
+    in what ``fn`` returns (or yields) leave through
+    :func:`tpurpc.tpu.serialize.tree_from_device`: every leaf's transfer to
+    the host is started before any is awaited, each billed ``dma_d2h`` once,
+    the wait its own ``d2h`` stage, and the reply's gather list aliases the
+    transfers' landing buffers all the way to the writer. A call that
     arrives over any other transport has no device ring: ``fn`` then gets the
     host-aliasing decode (numpy views), and the ``tensor_device_degraded``
     counter says how often — a handler that needs device arrays checks
@@ -165,12 +170,17 @@ def add_tensor_method(server: Server, name: str,
     # overwritten in place by a concurrent RPC on the same connection.
     # Serialize-then-release makes the alias's whole read window sit
     # inside the lease window; the handler's serializer is identity.
+    # tree_from_device is the one outbound leg: a reply's device leaves are
+    # read back there (on a TPU into fresh host buffers, so the bytes the
+    # writer places are the reply's own whatever lands in the ring next).
+    from tpurpc.tpu.serialize import tree_from_device
+
     _ident = lambda b: b  # noqa: E731 — already-encoded bytes pass through
     if kind == "unary_unary":
         def behavior(raw, ctx):
             decode, finish = _device_decoder(ctx)
             try:
-                return codec.tree_serializer(fn(decode(raw)))
+                return tree_from_device(fn(decode(raw)))
             finally:
                 finish()
         handler = unary_unary_rpc_method_handler(
@@ -180,7 +190,7 @@ def add_tensor_method(server: Server, name: str,
             decode, finish = _device_decoder(ctx)
             try:
                 for item in fn(decode(raw)):
-                    yield codec.tree_serializer(item)
+                    yield tree_from_device(item)
             finally:
                 finish()
         handler = unary_stream_rpc_method_handler(
@@ -190,7 +200,7 @@ def add_tensor_method(server: Server, name: str,
             decode, finish = _device_decoder(ctx)
             try:
                 for item in fn(decode(raw) for raw in raw_iter):
-                    yield codec.tree_serializer(item)
+                    yield tree_from_device(item)
             finally:
                 finish()
         handler = stream_stream_rpc_method_handler(

@@ -13,10 +13,13 @@ under load is, by construction, the one to attack.
 
 The hop chain, in data-flow order (the ISSUE 8 vocabulary; the server's
 per-message hops ``srv_*``, ``hbm_credit`` and ``hbm_view`` of ISSUE 26 are
-listed with their sites in :data:`HOPS`)::
+listed with their sites in :data:`HOPS`, where ``d2h`` of ISSUE 28 is
+appended: the registry is append-only)::
 
-    device     serialize: tensor bytes gathered host-side into wire form
-               (jaxshim/codec.py encode — the device→host leg)
+    d2h        a reply's device leaves read back into host landing buffers
+               (tpu/serialize.py: start every transfer, await each)
+    device     serialize: header + gather list over tensor bytes the host
+               can address (jaxshim/codec.py encode)
     send_ring  RingWriter placement into the peer's receive ring
                (core/ring.py writev/write_many + the fused native send)
     wire       bytes crossing the transport boundary: the pair-plane
@@ -90,8 +93,9 @@ __all__ = [
 #: what the hop means). Append-only — names land in scrape output and
 #: bench artifacts.
 HOPS: Tuple[Tuple[str, str], ...] = (
-    ("device", "serialize: tensor bytes gathered into wire form "
-               "(codec encode, the device→host leg)"),
+    ("device", "serialize: header and gather list over tensor bytes the "
+               "host can address (codec encode; a reply's device leaves "
+               "were read back under d2h first)"),
     ("send_ring", "RingWriter placement into the peer's receive ring"),
     ("wire", "transport boundary: pair one-sided send / TCP socket write"),
     ("rendezvous", "one-sided bulk payload write into the peer-advertised "
@@ -129,6 +133,11 @@ HOPS: Tuple[Tuple[str, str], ...] = (
     ("hbm_view", "host time to enqueue the view of a placed span "
                  "(slice / window / concat + shaped; a direct landing: "
                  "the lease hand-off)"),
+    # ISSUE 28: the outbound leg, one stage per response that had a leaf
+    # on a device (tpu/serialize.py _read_back, inside srv_handler)
+    ("d2h", "a reply's device leaves read back: every leaf's "
+            "device-to-host transfer started, then each awaited (the wait "
+            "covers what the device still had to finish for them)"),
 )
 
 HOP_NAMES: Tuple[str, ...] = tuple(name for name, _ in HOPS)

@@ -7,6 +7,12 @@ The BASELINE north star names two helpers:
   backend, where the array memory is already host-addressable and the wire
   segments alias it), then the ring/endpoint gather-write places the same
   buffer. No intermediate host buffer is ever allocated.
+  :func:`tree_from_device` is that leg and the ONLY place a reply's device
+  leaves are read back: every ``add_tensor_method(device=True)`` behavior
+  serializes its responses through it (``jaxshim/service.py``), and the
+  frame writer places the gather list it returns, one-sided into the peer's
+  landing region above the rendezvous bar (``core/rendezvous.py``
+  ``_rdv_write``, the ledger's ``rdma_write``).
 * ``DeserializeToDevice`` — received wire bytes become a ``jax.Array`` with
   exactly one h2d movement (none on a CPU device when the payload is
   64-byte aligned: dlpack import aliases the assembly buffer).
@@ -22,33 +28,69 @@ from typing import Any, List
 import numpy as np
 
 from tpurpc.jaxshim import codec
+from tpurpc.obs import lens as _lens
 from tpurpc.tpu import ledger
 
 
-def _on_host_backend(arr) -> bool:
+def _on_device(x) -> bool:
+    """A ``jax.Array`` whose bytes the host cannot address: reading it is a
+    device-to-host transfer. An array of a host backend (and anything that
+    is no ``jax.Array``) is aliased where it lies."""
+    devices = getattr(x, "devices", None)
+    if devices is None or not hasattr(x, "copy_to_host_async"):
+        return False
     try:
-        return all(d.platform == "cpu" for d in arr.devices())
+        return any(d.platform != "cpu" for d in devices())
     except Exception:
         return False
 
 
+def _read_back(leaves: list) -> None:
+    """THE device-to-host site: replace, in place, every leaf of ``leaves``
+    that lives on a device with the host landing buffer of its transfer.
+
+    Every transfer is STARTED (``copy_to_host_async``) before any is
+    awaited, so they overlap each other and whatever the device still has
+    to finish; each is billed ``dma_d2h`` once. Starting and awaiting them
+    is one ``d2h`` stage (span ``tpurpc.d2h``), an op only where there was a
+    device leaf. jax keeps the landing buffer on the array, so a leaf sent
+    twice is read back once. Leaves of a host backend and numpy leaves stay
+    as they are, aliased where they lie and billed ``zero_copy``."""
+    away = []
+    for i, leaf in enumerate(leaves):
+        if _on_device(leaf):
+            away.append(i)
+        else:
+            ledger.zero_copy(getattr(leaf, "nbytes", 0))
+    if not away:
+        return
+    total = sum(leaves[i].nbytes for i in away)
+    with _lens.stage("d2h", total):
+        for i in away:
+            leaves[i].copy_to_host_async()
+        for i in away:
+            ledger.dma_d2h(leaves[i].nbytes)
+            leaves[i] = np.asarray(leaves[i])
+
+
+def tree_from_device(tree: Any) -> List[bytes]:
+    """Wire segments of a pytree whose leaves may live on a device: the
+    outbound leg of every ``device=True`` reply. The tree is flattened
+    once, its device leaves are read back together (:func:`_read_back`),
+    and the gather list the codec's host-leaf path (``codec.encode_flat``)
+    returns aliases the transfers' landing buffers: no ``tobytes``, no
+    join."""
+    skeleton, leaves = codec.flatten_tree(tree)
+    _read_back(leaves)
+    return codec.encode_flat(skeleton, leaves)
+
+
 def serialize_from_device(x) -> List[bytes]:
-    """Wire segments for a jax.Array/numpy without host staging.
-
-    Returns the codec's gather list; the payload segment aliases the d2h
-    landing buffer (or the array itself on host backends) — downstream gather
-    writes (ring slice-send / sendmsg) consume it in place.
-    """
-    import jax
-
-    if isinstance(x, jax.Array) and not _on_host_backend(x):
-        ledger.dma_d2h(x.nbytes)       # the one unavoidable device→host DMA
-        host = np.asarray(x)
-        ledger.zero_copy(host.nbytes)  # segments alias the DMA landing buffer
-        return codec.encode_tensor(host)
-    host = np.asarray(x)
-    ledger.zero_copy(host.nbytes)
-    return codec.encode_tensor(host)
+    """:func:`tree_from_device` for ONE array as a bare tensor record (no
+    tree framing)."""
+    leaf = [x]
+    _read_back(leaf)
+    return codec.encode_tensor(leaf[0])
 
 
 def deserialize_to_device(buf, offset: int = 0):
@@ -57,54 +99,3 @@ def deserialize_to_device(buf, offset: int = 0):
     once."""
     arr, end = codec.decode_tensor(buf, offset)  # zero-copy view of buf
     return codec.to_jax(arr), end
-
-
-def tree_from_device(tree: Any) -> List[bytes]:
-    """Pytree variant of :func:`serialize_from_device` (gather segments)."""
-    import jax
-
-    for leaf in jax.tree_util.tree_leaves(tree):
-        if isinstance(leaf, jax.Array) and not _on_host_backend(leaf):
-            ledger.dma_d2h(leaf.nbytes)
-        else:
-            ledger.zero_copy(getattr(leaf, "nbytes", 0))
-    return codec.encode_tree(tree)
-
-
-# ---------------------------------------------------------------------------
-# SerializeFromDevice → rendezvous region / send ring (tpurpc-express, ISSUE 9)
-# ---------------------------------------------------------------------------
-
-def serialize_into(x, write, offset: int = 0) -> int:
-    """``SerializeFromDevice`` finished end-to-end: gather-serialize one
-    array STRAIGHT into a rendezvous landing window (or any one-sided
-    write target) with zero host staging — each codec segment (header,
-    payload view aliasing the d2h landing buffer or the array itself)
-    lands via ``write(offset, segment)``; no intermediate host buffer is
-    ever allocated or joined. ``write`` must be a one-sided placement
-    (a :class:`~tpurpc.core.pair.Window` write / rendezvous region); the
-    movement is billed as ``rdma_write``, and the copy ledger proves the
-    zero-staging claim: exactly one ``dma_d2h`` on device backends (zero on
-    host backends, where the segments alias the array) and zero
-    ``host_copy``. Returns bytes written past ``offset``."""
-    segs = serialize_from_device(x)
-    return _write_segments(segs, write, offset)
-
-
-def serialize_tree_into(tree: Any, write, offset: int = 0) -> int:
-    """Pytree variant of :func:`serialize_into` — the outbound half the
-    multi-host activation transport (ROADMAP item 5) consumes: device
-    activations leave HBM and land in the peer's advertised region with
-    no host staging buffer in between."""
-    segs = tree_from_device(tree)
-    return _write_segments(segs, write, offset)
-
-
-def _write_segments(segs: List[bytes], write, offset: int) -> int:
-    total = 0
-    for seg in segs:
-        view = memoryview(seg).cast("B")
-        write(offset + total, view)
-        total += len(view)
-    ledger.rdma_write(total)
-    return total
