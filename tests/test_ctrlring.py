@@ -198,6 +198,338 @@ def test_plane_negotiation_ladder():
 
 
 # ---------------------------------------------------------------------------
+# the consumer's wait: watching the next stamp, or parked (ISSUE 29)
+# ---------------------------------------------------------------------------
+
+_WINDOW_S = 0.002       # how long a stream's reader watches behind a record
+_SLICE_S = 0.00025      # what the fake clock moves by in a spin that finds nothing
+_KICK_FRAME = object()  # what the fake framed read hands back when kicked
+
+
+class _WaitRig:
+    """read_frame_polled over a real pair of planes, a fake framed read and
+    a fake clock.  The framed read records the header's ``parked`` word on
+    entry; a BLOCKING one (timeout not 0) runs the scripted way out in
+    place of waiting, with the kick callback's Event as its wake-up.  Time
+    moves only when a spin slice ends: no sleeps, no latency thresholds."""
+
+    def __init__(self, monkeypatch, window_s=_WINDOW_S):
+        import types
+
+        self.now = 1000.0
+        monkeypatch.setattr(ctrlring, "time", types.SimpleNamespace(
+            monotonic=lambda: self.now,
+            monotonic_ns=lambda: int(self.now * 1e9)))
+        monkeypatch.setattr(ctrlring, "_SPIN_SLICE_US", 1)
+        self.producer = ctrlring.CtrlPlane("wait-a")
+        self.plane = ctrlring.CtrlPlane("wait-b")
+        self.plane._watch_s = window_s
+        assert self.producer.on_hello(self.plane.hello_blob())
+        real_spin = self.plane.spin
+
+        def spin():
+            if self.in_slice:
+                self.in_slice.pop(0)()
+            moved = real_spin()
+            self.log.append(("spin", moved))
+            self.now += 1e-6 if moved else _SLICE_S
+            return moved
+
+        self.plane.spin = spin
+        self.kicked = threading.Event()
+        self.log = []        # ("drain", parked, n) / ("read", timeout, parked) / ("spin", moved)
+        self.ops = []
+        self.in_slice = []   # callables run at the start of successive spin slices
+        self.probe_frames = []
+        self.on_block = None
+        self.stop = False
+        self.stop_when_parked = False
+        self.timeout = None
+
+    def close(self):
+        self.producer.close()
+        self.plane.close()
+
+    def parked(self):
+        rx = self.plane.rx
+        return ctrlring._PARKED.unpack_from(rx.region.buf,
+                                            ctrlring._PARKED_OFF)[0]
+
+    def prime(self, tag=b"earlier"):
+        """A record drained a moment ago: the next one is DENSE traffic."""
+        self.plane.unpark()
+        assert not self.post(tag)
+        assert self.look() == 1
+        self.now += _WINDOW_S / 5
+        self.log.clear()
+
+    def post(self, tag):
+        """One record from the producer; True when it had to kick."""
+        self.kicked.clear()
+        assert self.producer.post(3, 7, tag, 0, kick=self.kicked.set)
+        return self.kicked.is_set()
+
+    def drain(self):
+        parked = self.parked()
+        n = self.plane.drain(lambda op, sid, pl: self.ops.append(bytes(pl)),
+                             lambda: 1 << 30)
+        self.log.append(("drain", parked, n))
+        if parked and self.stop_when_parked:
+            self.stop = True
+        return n
+
+    def look(self):
+        """What the reader does with its own drain, for setting a scene."""
+        n = self.drain()
+        if n:
+            self.plane.saw(n)
+        return n
+
+    def read_frame(self, timeout=None):
+        parked = self.parked()
+        self.log.append(("read", timeout, parked))
+        if timeout == 0:
+            # a probe inside the busy window: unparked, and it never waits
+            assert parked == 0, self.log
+            if self.probe_frames:
+                return self.probe_frames.pop(0)
+            raise ctrlring.ReadTimeout()
+        # the only blocking read there is: flag up, then the re-drain that
+        # found nothing, then this — and for as long as the caller allows
+        assert parked == 1, self.log
+        assert self.log[-2] == ("drain", 1, 0), self.log
+        if self.timeout is None:
+            assert timeout is None
+        else:
+            assert 0 < timeout <= self.timeout
+        return self.on_block()
+
+    def call(self, timeout=None, with_stop=False):
+        self.timeout = timeout
+        return ctrlring.read_frame_polled(
+            self.read_frame, self.drain, self.plane, timeout,
+            (lambda: self.stop) if with_stop else None)
+
+    def blocking_reads(self):
+        return [e for e in self.log if e[0] == "read" and e[1] != 0]
+
+    def probes(self):
+        return [e for e in self.log if e[0] == "read" and e[1] == 0]
+
+
+@pytest.fixture
+def wait_rig(monkeypatch):
+    from tpurpc.core import _native
+
+    if _native.load_spin() is None:
+        pytest.skip("no native library: no GIL-free spin, so no busy window")
+    rig = _WaitRig(monkeypatch)
+    yield rig
+    rig.close()
+
+
+def _wait_cases():
+    for state in ("cold", "sparse", "hit", "expired"):
+        for timeout in (None, 5.0):
+            for with_stop in (False, True):
+                for way_out in ("frame", "exception", "timeout", "stop"):
+                    if way_out == "timeout" and timeout is None:
+                        continue
+                    if way_out == "stop" and not with_stop:
+                        continue
+                    yield pytest.param(
+                        state, timeout, with_stop, way_out,
+                        id=f"{state}-{'finite' if timeout else 'untimed'}-"
+                           f"{'stop' if with_stop else 'nostop'}-{way_out}")
+
+
+@pytest.mark.parametrize("state,timeout,with_stop,way_out", _wait_cases())
+def test_reader_is_watching_the_stamp_or_parked(wait_rig, state, timeout,
+                                                with_stop, way_out):
+    """The invariant of ``read_frame_polled``: a reader that is not looking
+    at its descriptor ring is in a bounded spin on the next stamp, or has
+    ``parked`` up behind a re-drain.  Every blocking framed read is entered
+    parked and after the re-drain; every way out leaves ``parked`` 0; a
+    record posted while the reader is blocked kicks, and is dispatched
+    before the frame that woke the reader."""
+    rig = wait_rig
+    if state == "sparse":
+        # a record is waiting, the first for a long while: no window
+        rig.post(b"first")
+    elif state == "hit":
+        # a record is waiting, close behind another: the first drain starts
+        # the watch, in which a third is found with no kick
+        rig.prime()
+        rig.post(b"first")
+        rig.in_slice = [lambda: rig.__setattr__(
+            "second_kicked", rig.post(b"second"))]
+    elif state == "expired":
+        rig.prime()
+        rig.post(b"first")
+        assert rig.look() == 1  # starts a watch ...
+        rig.now += 2 * _WINDOW_S  # ... that ran out before this call
+        rig.log.clear()
+    parks0 = ctrlring._PARKS.snapshot()
+
+    def blocked_frame():
+        assert rig.post(b"late"), "parked consumer: the post must kick"
+        assert rig.kicked.wait(0)
+        return _KICK_FRAME
+
+    def blocked_raises(exc):
+        def on_block():
+            raise exc
+        return on_block
+
+    if way_out == "frame":
+        rig.on_block = blocked_frame
+        assert rig.call(timeout, with_stop) is _KICK_FRAME
+        assert rig.ops[-1] == b"late"  # dispatched before the frame is
+    elif way_out == "exception":
+        rig.on_block = blocked_raises(OSError("link died"))
+        with pytest.raises(OSError):
+            rig.call(timeout, with_stop)
+    elif way_out == "timeout":
+        rig.on_block = blocked_raises(ctrlring.ReadTimeout())
+        with pytest.raises(TimeoutError):
+            rig.call(timeout, with_stop)
+    else:
+        rig.stop_when_parked = True
+        rig.on_block = lambda: pytest.fail("blocked past should_stop")
+        with pytest.raises(TimeoutError):
+            rig.call(timeout, with_stop)
+
+    assert rig.parked() == 0
+    assert len(rig.blocking_reads()) == (0 if way_out == "stop" else 1)
+    assert ctrlring._PARKS.snapshot() - parks0 == 1
+    if state == "hit":
+        assert rig.second_kicked is False
+        assert rig.ops[:3] == [b"earlier", b"first", b"second"]
+        # the window was watched to its end, probe and spin in turn: the
+        # slice that found the second record, then one window of them
+        kinds = [e[0] for e in rig.log if e[0] in ("read", "spin")]
+        n = len(rig.probes())
+        assert n == 1 + round(_WINDOW_S / _SLICE_S)
+        assert kinds[:2 * n] == ["read", "spin"] * n
+    else:
+        assert rig.probes() == []  # nothing to watch for: park at once
+        if state == "sparse":
+            assert rig.ops[0] == b"first"
+
+
+def test_reader_without_a_busy_window_parks_at_once(monkeypatch):
+    """No native library or one CPU: no window, so a hit changes nothing —
+    the next wait raises ``parked`` before it blocks, never spins."""
+    rig = _WaitRig(monkeypatch, window_s=0.0)
+    try:
+        rig.prime()
+        rig.post(b"first")
+        rig.on_block = lambda: _KICK_FRAME
+        assert rig.call() is _KICK_FRAME
+        assert rig.ops == [b"earlier", b"first"]
+        assert rig.probes() == [] and len(rig.blocking_reads()) == 1
+        assert [e for e in rig.log if e[0] == "spin"] == []
+        assert rig.parked() == 0
+    finally:
+        rig.close()
+
+
+def test_deadline_inside_the_busy_window_leaves_unparked(wait_rig):
+    rig = wait_rig
+    rig.prime()
+    rig.post(b"first")
+    rig.on_block = lambda: pytest.fail("a window outlasting the deadline")
+    with pytest.raises(TimeoutError):
+        rig.call(timeout=_WINDOW_S / 2)
+    assert rig.parked() == 0 and rig.blocking_reads() == []
+
+
+def test_records_faster_than_the_window_never_park(wait_rig):
+    """PR 13's steady state: a consumer whose records arrive inside its
+    busy window never raises ``parked``, so no producer ever kicks."""
+    rig = wait_rig
+    rig.on_block = lambda: pytest.fail("parked in a steady stream")
+    rig.prime()
+    rig.post(b"cold")
+    rig.probe_frames.append(_KICK_FRAME)
+    assert rig.call() is _KICK_FRAME  # its drain opens the window
+    n = 40
+    kicks = []
+    # a record in every second slice: 250 us after the one before
+    rig.in_slice = [
+        (lambda i=i: kicks.append(rig.post(b"r%d" % i))) if i % 2
+        else (lambda: None) for i in range(2 * n)]
+    rig.in_slice.append(lambda: rig.probe_frames.append(_KICK_FRAME))
+    parks0 = ctrlring._PARKS.snapshot()
+    hits0 = ctrlring._SPIN_HITS.snapshot()
+    records0 = ctrlring._RECORDS.snapshot()
+    assert rig.call() is _KICK_FRAME
+    assert kicks == [False] * n
+    assert rig.ops[-n:] == [b"r%d" % i for i in range(1, 2 * n, 2)]
+    assert ctrlring._PARKS.snapshot() == parks0
+    assert ctrlring._RECORDS.snapshot() - records0 == n
+    assert ctrlring._SPIN_HITS.snapshot() - hits0 == n
+    assert rig.blocking_reads() == [] and rig.parked() == 0
+
+
+def test_a_stream_slower_than_a_slice_costs_two_kicks_and_no_more(wait_rig):
+    """A record that comes within the watch of the one before is a stream's:
+    the first is sparse, the second shows the gap, and from then on the
+    reader is watching when the next arrives; after a silence longer than
+    the watch a record is sparse again."""
+    rig = wait_rig
+    period = 3  # slices before each record: 750 us
+    assert _SLICE_S < period * _SLICE_S < _WINDOW_S
+    kicks = []
+
+    def blocked():  # parked: the record kicks
+        kicks.append(rig.post(b"k%d" % len(kicks)))
+        return _KICK_FRAME
+
+    rig.on_block = blocked
+    assert rig.call() is _KICK_FRAME       # cold: the first record, no watch
+    assert not rig.plane.watching()
+    rig.now += period * _SLICE_S
+    assert rig.call() is _KICK_FRAME       # the second: the gap is seen
+    assert kicks == [True, True] and rig.probes() == []
+    assert rig.plane._hot_until == pytest.approx(rig.now + _WINDOW_S)
+    parks0 = ctrlring._PARKS.snapshot()
+    n = 12
+    rig.in_slice = [
+        (lambda i=i: kicks.append(rig.post(b"s%d" % i)))
+        if i % (period + 1) == period else (lambda: None)
+        for i in range((period + 1) * n)]
+    rig.in_slice.append(lambda: rig.probe_frames.append(_KICK_FRAME))
+    assert rig.call() is _KICK_FRAME
+    assert kicks[2:] == [False] * n and ctrlring._PARKS.snapshot() == parks0
+    rig.now += 10 * _WINDOW_S
+    rig.post(b"late")
+    assert rig.look() == 1 and not rig.plane.watching()
+
+
+@pytest.mark.parametrize("watching", [False, True])
+def test_a_senders_drain_leaves_the_readers_state_alone(wait_rig, watching):
+    """``RdvLink.ctrl_drain`` drains from sender threads (a sender waiting
+    for a grant): it dispatches, and neither starts nor stretches the
+    reader's watch, nor counts as the reader's find."""
+    rig = wait_rig
+    if watching:
+        rig.prime()
+        rig.post(b"first")
+        assert rig.look() == 1 and rig.plane.watching()
+    before = (rig.plane._hot_until, rig.plane._last_record,
+              ctrlring._SPIN_HITS.snapshot(), ctrlring._PARKS.snapshot())
+    for tag in (b"a", b"b"):  # two, close together: a stream's, had the
+        rig.now += _SLICE_S   # reader found them
+        rig.post(tag)
+        assert rig.drain() == 1
+    assert rig.ops[-2:] == [b"a", b"b"]
+    assert before == (rig.plane._hot_until, rig.plane._last_record,
+                      ctrlring._SPIN_HITS.snapshot(),
+                      ctrlring._PARKS.snapshot())
+
+
+# ---------------------------------------------------------------------------
 # end to end
 # ---------------------------------------------------------------------------
 

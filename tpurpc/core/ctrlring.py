@@ -42,6 +42,16 @@ mutants ``ctrl_publish_before_write``, ``ctrl_reuse_before_doorbell`` and
   and sends one framed kick when set; the consumer sets ``parked``, THEN
   re-drains once before blocking.  Either order of the race delivers.
 
+The consumer's wait (``read_frame_polled``): a reader that is not looking
+at its ring is either in a bounded GIL-free spin on the NEXT stamp word or
+has ``parked`` up behind the re-drain — no third state.  A record that
+came within ``_DENSE_WINDOWS`` busy windows (``busy_polling_timeout_us``)
+of the one before is a stream's: the reader stays unparked that long
+again, spin and framed probe in turn; when that runs out empty, and after
+any sparser record, it parks on the framed read until a kick or a frame.
+A steady stream never costs a kick, a sparse caller one ``CTRL_KICK`` a
+record and no spinning at all.
+
 Ordering with the framed path: every record carries ``frame_seq`` — the
 count of frames its sender had written when posting — and the consumer
 processes a record only once it has dispatched that many frames.  A control
@@ -68,13 +78,17 @@ import time
 from typing import Callable, Dict, Optional, Tuple
 
 from tpurpc.analysis.locks import make_lock
+from tpurpc.core import _native
 from tpurpc.core import pair as _pair
 from tpurpc.core import transport as _transport
+from tpurpc.core.endpoint import ReadTimeout
+from tpurpc.core.poller import _effective_cpus
 from tpurpc.obs import flight as _flight
 from tpurpc.obs import lens as _lens
 from tpurpc.obs import metrics as _metrics
 from tpurpc.obs import profiler as _profiler
 from tpurpc.utils import stats as _stats
+from tpurpc.utils.config import get_config
 
 # tpurpc-lens frame markers: a thread polling/draining/posting descriptor
 # rings is doing control-plane work — the waterfall's `ctrl` hop carries
@@ -83,6 +97,7 @@ _LENS_STAGES = {
     "read_frame_polled": "ctrl-ring",
     "drain": "ctrl-ring",
     "post": "ctrl-ring",
+    "spin": "ctrl-ring",
 }
 _profiler.register_stages(__file__, _LENS_STAGES)
 
@@ -102,6 +117,11 @@ _POSTS = _metrics.counter("ctrl_ring_posts")
 _RECORDS = _metrics.counter("ctrl_ring_records")
 _KICKS = _metrics.counter("ctrl_ring_kicks")
 _FULL = _metrics.counter("ctrl_ring_full_fallbacks")
+#: the reader's two legs: records it found while watching (their producer
+#: sent no kick), and times it raised ``parked`` to block on the framed path
+#: (the next record costs its producer a kick)
+_SPIN_HITS = _metrics.counter("ctrl_ring_spin_hits")
+_PARKS = _metrics.counter("ctrl_ring_parks")
 
 #: scrape-time truth for the watchdog's `ctrl-ring` stage: records posted
 #: into peers' rings that their consumers have not yet drained
@@ -168,6 +188,7 @@ class CtrlRing:
         self.head = 0          # consumed count (local truth)
         self._published = 0    # last cons_head stored into the header
         self.closed = False
+        self._pin = None       # (array, address) export spin() watches through
         self._lock = make_lock("CtrlRing._lock")
         _HDR.pack_into(self.region.buf, 0, _MAGIC, _VERSION, self.nslots,
                        self.slot_bytes, 0,
@@ -241,6 +262,34 @@ class CtrlRing:
         finally:
             self._lock.release()
 
+    def spin(self, timeout_us: int) -> bool:
+        """Bounded GIL-free wait on the NEXT record's stamp word (the u64
+        at slot ``head % nslots``, which the producer turns into
+        ``head + 1`` last).  True when the word moved or cannot be watched
+        (closed, no native library): the caller drains either way.  False
+        when the slice ran out quietly.  The region is pinned across the
+        call; ``close`` retries its release meanwhile, as for ``Pair.spin``."""
+        spin = _native.load_spin()
+        with self._lock:
+            if self.closed or spin is None:
+                return True
+            pin = self._pin
+            if pin is None:
+                pin = self._pin = _native.pin(self.region.buf, writable=False)
+            slot = _HDR_BYTES + (self.head % self.nslots) * self.slot_bytes
+            (seen,) = _STAMP.unpack_from(self.region.buf, slot)
+            if seen == self.head + 1:
+                return True
+        # watch for divergence from the value READ, not for the value
+        # wanted: a stamp published between the read and the call returns
+        # at once.  On a later lap the Python producer zeroes the old stamp
+        # with the record's fields before it publishes: wait that out too
+        addr = pin[1] + slot
+        moved = spin.tpr_spin_u64_change(addr, seen, timeout_us)
+        if moved and seen:
+            moved = spin.tpr_spin_u64_change(addr, 0, timeout_us)
+        return moved != 0
+
     def close(self) -> None:
         """Link death/teardown.  The region is released on OUR side only —
         a straggling producer still holds its window and may land a late
@@ -251,6 +300,7 @@ class CtrlRing:
             if self.closed:
                 return
             self.closed = True
+            self._pin = None  # drop our own export before releasing
         try:
             _pair.retry_buffer_op(self.region.buf.release, timeout_s=0.5)
             self.region._close()
@@ -365,22 +415,27 @@ class CtrlPeer:
 
 
 # ---------------------------------------------------------------------------
-# The per-connection plane: rx + tx + the adaptive poll/park state.
+# The per-connection plane: rx + tx + the reader's watch.
 # ---------------------------------------------------------------------------
 
-#: consumer-side adaptive gate, the poller's activity-EWMA discipline
-#: (core/poller.py) applied to ring polling: drains that find records are
-#: hits, empty probes are misses; below the floor the consumer PARKS on the
-#: framed path (fd wakeups) and the producer's kick re-heats it.
-_EWMA_HIT = 0.5
-_EWMA_MISS = 0.7
-_EWMA_FLOOR = 0.1
+#: records closer together than this many busy windows (4 x 500 us) are a
+#: stream, and its reader watches that long for the next instead of parking.
+#: On the chip (PERF.md §6, PR 29) a 4 MiB stream's records come 0.5 to 1 ms
+#: apart: a 500 us watch parks between them and costs the sender a kick
+#: frame a message (4.63 GB/s); 1.5 and 2 ms never park (4.86, 4.72).
+_DENSE_WINDOWS = 4
+#: one GIL-free slice on the stamp word between two framed probes: how long
+#: the FRAMED ring goes unwatched while the reader watches.  Each probe
+#: holds the interpreter lock ~13 us that a handler thread wanted: at 64 us
+#: one reader's probes cost that stream a tenth of its rate (same runs).
+_SPIN_SLICE_US = 250
 
 
 class CtrlPlane:
     """One connection's descriptor-ring control plane: the locally owned
     receive ring (advertised in the hello), the window onto the peer's
-    (opened from the peer's hello), and the consumer's hot/parked state.
+    (opened from the peer's hello), and the READER's state: watching behind
+    a stream's record (until ``_hot_until``), or parked.
     ``armed`` flips exactly once, when the peer's descriptor verifies —
     until then (and forever, for un-negotiated peers) every control op
     stays framed."""
@@ -390,8 +445,13 @@ class CtrlPlane:
         self.rx: Optional[CtrlRing] = None
         self.tx: Optional[CtrlPeer] = None
         self.armed = False
-        self._ewma = 0.0       # cold start: parked until the first hit
-        self._mode_hot = False
+        # no watch — park at once — where it cannot be GIL-free (no native
+        # library) or would steal the producer's only core
+        self._watch_s = (
+            0.0 if _native.load_spin() is None or _effective_cpus() < 2
+            else _DENSE_WINDOWS * get_config().busy_polling_timeout_us / 1e6)
+        self._last_record = 0.0  # when the reader last found one
+        self._hot_until = 0.0    # the watch's end; 0: none since a park
         self._closed = False
         try:
             self.rx = CtrlRing(kind=kind)
@@ -460,22 +520,36 @@ class CtrlPlane:
 
     def drain(self, on_op: Callable[[int, int, object], None],
               frames_dispatched: Callable[[], int]) -> int:
+        """Dispatch what is ready, from any thread (a sender waiting for a
+        grant drains too); the reader's state is not this call's to move."""
         rx = self.rx
-        if rx is None:
-            return 0
-        n = rx.drain(on_op, frames_dispatched)
-        if n:
-            self._ewma = self._ewma + _EWMA_HIT * (1.0 - self._ewma)
-            if not self._mode_hot:
-                self._mode_hot = True
-                _flight.emit(_flight.CTRL_SPIN, self._ftag, rx.head)
-        return n
+        return 0 if rx is None else rx.drain(on_op, frames_dispatched)
 
-    def note_miss(self) -> None:
-        self._ewma *= _EWMA_MISS
+    def saw(self, n: int) -> None:
+        """The reader's own drain found ``n`` records (reader thread only).
+        Close behind the one before, they are a stream's: watch as long
+        again for the next.  A sparser record opens nothing — watching for
+        it would only burn a core and the handlers' lock; the next finds
+        the reader parked and costs its producer a kick."""
+        now = time.monotonic()
+        if now < self._hot_until:
+            _SPIN_HITS.inc(n)  # found by watching: nobody was kicked
+        dense = now - self._last_record < self._watch_s
+        self._last_record = now
+        if dense:
+            if not self._hot_until:
+                _flight.emit(_flight.CTRL_SPIN, self._ftag,
+                             self.rx.head if self.rx is not None else 0)
+            self._hot_until = now + self._watch_s
 
-    def hot(self) -> bool:
-        return self._ewma >= _EWMA_FLOOR
+    def watching(self) -> bool:
+        """Behind a stream's record: the next one is worth watching for."""
+        return time.monotonic() < self._hot_until
+
+    def spin(self) -> bool:
+        """One bounded GIL-free slice on the receive ring's next stamp."""
+        rx = self.rx
+        return rx is None or rx.spin(_SPIN_SLICE_US)
 
     def park(self) -> None:
         """About to block on the framed path: raise the parked flag so the
@@ -485,8 +559,9 @@ class CtrlPlane:
         rx = self.rx
         if rx is not None:
             rx.set_parked(True)
-        if self._mode_hot:
-            self._mode_hot = False
+        _PARKS.inc()
+        if self._hot_until:
+            self._hot_until = 0.0
             _flight.emit(_flight.CTRL_PARK, self._ftag,
                          rx.head if rx is not None else 0)
 
@@ -514,24 +589,21 @@ class CtrlPlane:
 # The polled read loop shared by every connection reader/pump.
 # ---------------------------------------------------------------------------
 
-#: how long one framed-read probe blocks while the link is HOT — the upper
-#: bound on ring-record latency while frames are idle, and the slice that
-#: yields the core to the producer on a single-hart host
-_HOT_SLICE_S = 0.0005
-#: cheap scheduler-yield probes between drain attempts before paying a
-#: framed-read slice: a producer mid-memcpy posts within a few yields
-_YIELD_SPINS = 8
-
-
 def read_frame_polled(read_frame, drain: Callable[[], int],
                       plane: CtrlPlane, timeout: Optional[float] = None,
                       should_stop: Optional[Callable[[], bool]] = None):
-    """``read_frame`` with the descriptor-ring poll/park discipline.
+    """``read_frame`` with the descriptor-ring watch/park discipline.
 
-    HOT (recent drains): alternate ring drains with scheduler yields and
-    short framed-read slices — records are consumed in batches with no fd
-    wakeups, frames still flow.  COLD (EWMA below floor): raise the parked
-    flag, re-drain once, and block on the framed read — the producer's
+    The invariant: from the moment this thread stops looking at its ring
+    until it looks again, either (1) it is in a bounded GIL-free wait on
+    the ring's next stamp word, or (2) ``parked`` is up and was followed by
+    the mandatory re-drain, so the producer's post kicks.  No third state.
+
+    WATCHING (behind a stream's record, ``CtrlPlane.saw``): unparked,
+    alternate a non-blocking framed probe with one native spin on the stamp
+    word — neither ring is blind for more than a slice.  PARKED (the watch
+    ran out, or none was opened): raise the flag, re-drain once, and block
+    on the framed read for as long as the caller allows — the producer's
     kick (or any frame) wakes us.  ``should_stop`` (inline-pump callers:
     "my predicate is satisfied") raises ReadTimeout so the pump re-checks.
 
@@ -539,59 +611,54 @@ def read_frame_polled(read_frame, drain: Callable[[], int],
     ReadTimeout past ``timeout``.
     """
     deadline = None if timeout is None else time.monotonic() + timeout
-    while True:
-        drained = drain()
+
+    def look() -> int:
+        n = drain()
+        if n:
+            plane.saw(n)
+        return n
+
+    def check_stop() -> None:
         if should_stop is not None and should_stop():
-            raise _pair_ReadTimeout()
-        if drained or plane.hot():
-            if not drained:
-                spins = 0
-                while spins < _YIELD_SPINS:
-                    spins += 1
-                    time.sleep(0)
-                    if drain():
-                        break
-                    if should_stop is not None and should_stop():
-                        raise _pair_ReadTimeout()
-            slice_s = _HOT_SLICE_S
-            if deadline is not None:
-                remain = deadline - time.monotonic()
-                if remain <= 0:
-                    raise _pair_ReadTimeout()
-                slice_s = min(slice_s, remain)
+            raise ReadTimeout()
+
+    plane.unpark()  # a ring is born parked: its reader's look adopts it
+    while True:
+        look()
+        check_stop()
+        while plane.watching():
+            if deadline is not None and time.monotonic() >= deadline:
+                raise ReadTimeout()
             try:
-                f = read_frame(timeout=slice_s)
+                f = read_frame(timeout=0)
             except TimeoutError:
-                plane.note_miss()
-                if deadline is not None and time.monotonic() >= deadline:
-                    raise
-                continue
-            # a record posted BEFORE this frame was sent is visible in shm
-            # by store order — deliver it first, so per-stream order holds
-            # across the ring/framed split
-            drain()
-            return f
-        # cold: park on the framed path (fd wakeups); the mandatory
-        # re-drain closes the park/post race — a record posted before our
-        # flag store is found here, one posted after sees the flag and
-        # kicks
+                pass
+            else:
+                # a record posted BEFORE this frame was sent is visible in
+                # shm by store order — deliver it first, so per-stream
+                # order holds across the ring/framed split
+                look()
+                return f
+            moved = plane.spin()
+            found = look()
+            check_stop()
+            if moved and not found:
+                # published but not ours to take yet: ordered after frames
+                # still in flight (or another thread is mid-drain) — the
+                # framed read below is what delivers them
+                break
+        # the mandatory re-drain closes the park/post race — a record
+        # posted before our flag store is found here, one posted after
+        # sees the flag and kicks
         plane.park()
         try:
-            if drain():
-                plane.unpark()
+            if look():
                 continue
-            if should_stop is not None and should_stop():
-                raise _pair_ReadTimeout()
+            check_stop()
             remain = (None if deadline is None
                       else max(0.0, deadline - time.monotonic()))
             f = read_frame(timeout=remain)
-            drain()  # ring records posted before this frame deliver first
+            look()  # ring records posted before this frame deliver first
             return f
         finally:
             plane.unpark()
-
-
-def _pair_ReadTimeout():
-    from tpurpc.core.endpoint import ReadTimeout
-
-    return ReadTimeout()
