@@ -11,13 +11,11 @@ Drives the system's main path once, through the entry points a user calls
 1. *tensor RPC into HBM* — ``GRPC_PLATFORM_TYPE=RDMA_TPU``, a ``device=True``
    stream: seeded random ``float32[1024,1024]`` (4 MiB) tensors plus sizes that
    do not divide the 16 MiB device ring, enough to lap its credit window four
-   times a pass. On a TPU no view can alias the ring, so every message must
-   land directly: one ``device_put`` to its final array, no ring program
-   (``hbm_place_direct`` = messages, ``dma_d2d`` = 0); on the CPU rehearsal
-   the bytes go through the ring, so spans wrap and ``ring_scatter`` /
-   ``ring_window`` run (interpreted) on the request path. The handler insists
-   every leaf is a ``jax.Array`` on the device, folds a position-weighted
-   checksum on the device and reads it back once.
+   times a pass. Every message lands directly: one ``device_put`` to its
+   final array under the ring's credit, no device program
+   (``hbm_place_msgs`` = messages, ``dma_h2d`` = payload, ``dma_d2d`` = 0).
+   The handler insists every leaf is a ``jax.Array`` on the device, folds a
+   position-weighted checksum on the device and reads it back once.
 2. *serving* — ResNet-50, 1000 classes, 224x224x3, bf16, random weights from a
    seed, behind ``FanInBatcher(max_batch=8, fixed_bucket=True,
    transfer_dtype=bf16)``; 8 connections; each reply is compared with the same
@@ -53,14 +51,14 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 OUT_DIR = os.path.join(HERE, "chiprun_out", "chip_smoke")
 
 #: the run at full width, and the CPU dress rehearsal (KiB rings, the thin
-#: ResNet, Pallas interpreted) that exists so tests can drive this very script
+#: ResNet) that exists so tests can drive this very script
 REAL = dict(
     ring_kb=None,              # the default device ring (16 MiB), asserted
     rdv_min_kb=None,           # the default rendezvous bar (256 KiB)
     shapes=[(1024, 1024),      # 4 MiB: BASELINE config 3
             (768, 1024),       # 3 MiB: no divisor of the ring
             (1000, 1001),      # 4,004,000 B: leaves every later offset
-            (640, 1024)],      #   unaligned to the kernels' 512-byte rows
+            (640, 1024)],      #   off every power of two
     small=(100, 100),          # 40 KB: under the bar, rides the framed path
     laps=4,
     model="resnet50", image=224, classes=1000,
@@ -408,8 +406,8 @@ def plan_pass(cfg: dict, capacity: int, floor: int):
     capacities are nearly full, then one filler that ends the pass EXACTLY on
     a multiple of the capacity — the replay then meets every offset again,
     so what the warm-up pass compiled is all the measured pass needs.
-    Returns ``(shapes, wrapped)``: how many spans cross the ring's edge is
-    arithmetic, and the path counters must agree with it."""
+    Returns ``(shapes, wrapped)``: how many spans cross the credit window's
+    edge is arithmetic (the sizes do not divide it)."""
     total, used, shapes, i = cfg["laps"] * capacity, 0, [], 0
     biggest = max(4 * a * b for a, b in cfg["shapes"])
     while total - used >= biggest + floor:
@@ -461,8 +459,8 @@ def tensor_leg(say, cfg, srv: ServerChild, port: int, capacity: int,
 
     with Channel(f"127.0.0.1:{port}") as ch:
         cli = TensorClient(ch)
-        # pass 1 warms every program at every offset; pass 2, NEW data at
-        # the same offsets, is the one the counters are read across
+        # pass 1 builds the handler's program for every shape; pass 2, NEW
+        # data of the same shapes, is the one the counters are read across
         for name, seed in (("warm-up", 11), ("measured", 12)):
             s0, t0, sent0 = srv.stats(), time.monotonic(), ledger.snapshot()
             run_pass(cli, seed, shapes)
@@ -471,11 +469,9 @@ def tensor_leg(say, cfg, srv: ServerChild, port: int, capacity: int,
             # the one-sided write is the SENDER's movement: this process's
             led["rdma_write"] = (ledger.snapshot()["rdma_write"]
                                  - sent0["rdma_write"])
-            paths = {k: v for k, v in c.items()
-                     if k.startswith(("hbm_place_", "hbm_view_"))
-                     and not k.endswith(("msgs", "bytes"))}
-            say(f"  {name} pass: checksum ok, {wall:.2f}s wall; paths {paths}; "
-                f"ledger dma_h2d={led.get('dma_h2d', 0)} "
+            landed = c.get("hbm_place_msgs", 0)
+            say(f"  {name} pass: checksum ok, {wall:.2f}s wall; landed "
+                f"{landed}; ledger dma_h2d={led.get('dma_h2d', 0)} "
                 f"dma_d2d={led.get('dma_d2d', 0)} "
                 f"rdma_write={led.get('rdma_write', 0)} "
                 f"host_copy={led.get('host_copy', 0)} "
@@ -484,7 +480,7 @@ def tensor_leg(say, cfg, srv: ServerChild, port: int, capacity: int,
                 f"({c.get('xla_compile_ms', 0)} ms; persistent cache "
                 f"{c.get('xla_cache_hits', 0)} hits / "
                 f"{c.get('xla_cache_misses', 0)} misses)")
-            out[name] = {"wall_s": round(wall, 3), "paths": paths,
+            out[name] = {"wall_s": round(wall, 3), "landed": landed,
                          "ledger": led,
                          "compiles": c.get("xla_compiles", 0),
                          "compile_ms": c.get("xla_compile_ms", 0)}
@@ -498,26 +494,12 @@ def tensor_leg(say, cfg, srv: ServerChild, port: int, capacity: int,
         check(led.get("host_copy", 0) < floor,
               f"host_copy {led.get('host_copy')} B: a payload was copied on "
               f"the host (control frames alone stay under {floor})")
-        if on_tpu:
-            # no view of a TPU ring can alias it: every message lands in its
-            # final array by its one transfer, and nothing else is counted
-            check(paths == {"hbm_place_direct": len(shapes),
-                            "hbm_view_direct": len(shapes)},
-                  f"landings took {paths}, want {len(shapes)} direct")
-            check(not led.get("dma_d2d"),
-                  f"dma_d2d {led.get('dma_d2d')} B moved on the device")
-        else:
-            check(c.get("hbm_place_scatter", 0) == wrapped
-                  and c.get("hbm_view_window", 0) == wrapped,
-                  f"wrapped spans took {paths}, want {wrapped} through each "
-                  "kernel")
-            check(not c.get("hbm_place_split")
-                  and not c.get("hbm_view_concat"),
-                  f"a jax-op chain stood in for a kernel: {paths}")
-            check(c.get("hbm_place_update", 0) == len(shapes) - wrapped,
-                  f"unwrapped placements {paths}")
-            check(not c.get("hbm_place_direct"),
-                  f"an aliasing ring landed directly: {paths}")
+        # every message lands in its final array by its one transfer, and
+        # nothing else is counted
+        check(landed == len(shapes),
+              f"hbm_place_msgs {landed}, want {len(shapes)} messages")
+        check(not led.get("dma_d2d"),
+              f"dma_d2d {led.get('dma_d2d')} B moved on the device")
         check(not c.get("tensor_device_degraded"),
               "device=True degraded to the host decode")
         # one reply out of HBM: read back once, billed once, bit-exact
@@ -533,11 +515,10 @@ def tensor_leg(say, cfg, srv: ServerChild, port: int, capacity: int,
         got = {k: led.get(k, 0) for k in ("dma_d2h", "dma_d2h_ops",
                                           "zero_copy", "zero_copy_ops")
                if led.get(k, 0)}
-        # a host backend aliases the reply where it lies (and the aliasing
-        # ring's view of the request bills zero_copy too); a chip reads it
+        # a host backend aliases the reply where it lies; a chip reads it
         # back once and bills nothing else
         want = ({"dma_d2h": m.nbytes, "dma_d2h_ops": 1} if on_tpu else
-                {"zero_copy": 2 * m.nbytes, "zero_copy_ops": 2})
+                {"zero_copy": m.nbytes, "zero_copy_ops": 1})
         check(got == want and c.get("lens_d2h_ops", 0) == int(on_tpu),
               f"a reply of {m.nbytes} B billed {got} with "
               f"{c.get('lens_d2h_ops', 0)} d2h stage(s), want {want}")

@@ -2,8 +2,7 @@
 
 Tests (and every child they spawn, through the inherited environment) never
 want a chip; shardings are validated over ``xla_force_host_platform_device_count``
-CPU devices, Pallas kernels in interpret mode. The chip has its own check:
-``chip_smoke.py``.
+CPU devices. The chip has its own check: ``chip_smoke.py``.
 """
 
 import os

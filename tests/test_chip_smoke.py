@@ -1,7 +1,6 @@
 """chip_smoke.py from the CPU side: it must FAIL where there is no TPU, its
-CPU rehearsal must pass end to end without ever reading as a chip result, the
-compile cache must go where it is told and stop growing, and the kernels
-must still compile for a v5e (ahead of time, no chip needed)."""
+CPU rehearsal must pass end to end without ever reading as a chip result, and
+the compile cache must go where it is told and stop growing."""
 
 import json
 import os
@@ -76,67 +75,15 @@ def test_rehearsal_passes_twice_and_the_cache_stops_growing(tmp_path):
         assert result["setup"]["cache_dir"] == str(cache)
         tensor = result["legs"]["tensor"]
         assert tensor["measured"]["compiles"] == 0
-        assert (tensor["measured"]["paths"]["hbm_place_scatter"]
-                == tensor["wrapped_spans_per_pass"] >= 2)
+        # one landing, so no "paths" to tell apart (the key is absent):
+        # every message of the pass landed, and the sizes lap the window
+        assert "paths" not in tensor["measured"]
+        assert (tensor["measured"]["landed"]
+                == tensor["messages_per_pass"] > 4)
+        assert tensor["wrapped_spans_per_pass"] >= 2
+        assert "dma_d2d" not in tensor["measured"]["ledger"]
         assert result["legs"]["serving"]["batches"] < \
             result["legs"]["serving"]["rows"]
         assert result["data_plane"]["plane"] in ("native", "python")
         counts.append(len(os.listdir(cache)))
     assert counts[0] > 0 and counts[1] == counts[0], counts
-
-
-_AOT = r"""
-import sys
-try:
-    import libtpu  # noqa: F401
-    import jax, jax.numpy as jnp
-    from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
-    topo = topologies.get_topology_desc(platform="tpu",
-                                        topology_name="v5e:2x2")
-except Exception as exc:  # no libtpu, or another process holds its lock
-    print("SKIP", type(exc).__name__, str(exc)[:200])
-    sys.exit(0)
-dev = topo.devices[0]
-assert dev.device_kind == "TPU v5 lite", dev.device_kind
-sh = SingleDeviceSharding(dev)
-S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=sh)
-from tpurpc.ops.ring_scatter import _ring_scatter_impl
-from tpurpc.ops.ring_window import _ring_window_impl
-from tpurpc.tpu.hbm_ring import _ring_jits
-update, slice_, shaped = _ring_jits()
-cap = 1 << 20
-ring, word = S((cap,), jnp.uint8), S((1,), jnp.int32)
-for n in (1200, 4096, 1 << 16, 262148):  # 262148: odd, over the 4 KiB block
-    _ring_scatter_impl.lower(ring, S((n,), jnp.uint8), word, n_words=n // 4,
-                             interpret=False).compile()
-    _ring_window_impl.lower(ring, word, n_words=n // 4,
-                            interpret=False).compile()
-    update.lower(ring, S((n,), jnp.uint8), S((), jnp.int32)).compile()
-    slice_.lower(ring, S((), jnp.int32), n).compile()
-    shaped.lower(S((n,), jnp.uint8), jnp.dtype(jnp.float32),
-                 (n // 4,)).compile()
-print("COMPILED")
-"""
-
-
-@pytest.mark.slow
-def test_ring_programs_compile_for_v5e_ahead_of_time():
-    """From the CPU sandbox: lower and compile ring_scatter, ring_window and
-    HbmRing's update/slice/view programs for a ``TPU v5 lite`` device with
-    ``interpret=False`` — so a kernel PR learns that Mosaic (or the TPU
-    compiler's patience: see tpurpc.ops.layout) refuses it before it spends
-    a call to the chip. In a process of its own: libtpu takes a machine-wide
-    lock. Skips cleanly where libtpu cannot be had."""
-    import time
-
-    t0 = time.monotonic()
-    p = subprocess.run([sys.executable, "-c", _AOT], cwd=ROOT,
-                       env=dict(os.environ, PYTHONPATH=ROOT),
-                       capture_output=True, text=True, timeout=600)
-    assert p.returncode == 0, p.stderr[-3000:]
-    if p.stdout.startswith("SKIP"):
-        pytest.skip(p.stdout.strip())
-    assert "COMPILED" in p.stdout
-    # the whole-array flatten this guards against took 66 s per MiB
-    assert time.monotonic() - t0 < 120, "a ring program compiles slowly again"

@@ -6,9 +6,8 @@ no device whose memory the host cannot address, so the ``device`` cases make
 ``serialize._on_device`` say so of every ``jax.Array`` (the transfer calls are
 the real ones: ``copy_to_host_async`` and ``np.asarray`` work on any backend),
 and the order-of-transfers test uses leaves that record what is asked of
-them. Every RPC runs over the direct landing (``TPURPC_DLPACK_VIEW=0``, what
-every TPU takes), where the request path bills ``dma_h2d`` alone, so that
-``zero_copy`` and ``dma_d2h`` in a window are the serializer's.
+them. The request path bills ``dma_h2d`` alone, so ``zero_copy`` and
+``dma_d2h`` in a window are the serializer's.
 """
 
 import queue
@@ -34,7 +33,6 @@ def _d2h():
 
 def _server(monkeypatch, fn, kind, backend="device"):
     monkeypatch.setenv("GRPC_PLATFORM_TYPE", "RDMA_TPU")
-    monkeypatch.setenv("TPURPC_DLPACK_VIEW", "0")
     from tpurpc.utils import config as config_mod
 
     config_mod.set_config(None)

@@ -372,46 +372,6 @@ def test_batcher_depth_aware_flush_beats_max_delay():
         b.close()
 
 
-def test_place_many_ordering_views_see_placed_bytes():
-    """ISSUE 1 regression: ``HbmRing.place_many`` lands a batch with ONE
-    dispatch, and a view taken immediately after the batch place returns
-    exactly the placed bytes — the dlpack alias path must order its raw
-    read after the pending donated update (block_until_ready), or async
-    dispatch could surface stale ring bytes."""
-    import jax
-
-    from tpurpc.tpu.hbm_ring import HbmRing
-
-    ring = HbmRing(1 << 16, device=jax.devices("cpu")[0])
-    payloads = [bytes([i]) * (64 * (i + 1)) for i in range(5)]
-    spans = ring.place_many(payloads)
-    assert [n for _, n in spans] == [len(p) for p in payloads]
-    # offsets are consecutive: one contiguous packed batch
-    for (off_a, n_a), (off_b, _) in zip(spans, spans[1:]):
-        assert off_b == off_a + n_a
-    for payload, (off, n) in zip(payloads, spans):
-        with ring.view(off, n) as arr:
-            assert bytes(bytearray(np.asarray(arr))) == payload
-    # every span released -> head advances over the whole batch
-    assert ring.stats()["writable"] == ring.capacity
-
-
-def test_place_many_batches_one_landing_write():
-    """The batch is one h2d + one in-ring landing write (the dispatch
-    amortization place_many exists for), not one per payload."""
-    import jax
-
-    from tpurpc.tpu import ledger
-    from tpurpc.tpu.hbm_ring import HbmRing
-
-    ring = HbmRing(1 << 16, device=jax.devices("cpu")[0])
-    with ledger.track() as w:
-        spans = ring.place_many([b"a" * 128, b"b" * 128, b"c" * 128])
-    assert len(spans) == 3
-    assert w["dma_h2d_ops"] == 1, w.delta
-    assert w["dma_d2d_ops"] == 1, w.delta
-
-
 def test_decode_tree_many_walks_contiguous_records():
     """Batched decode: N tree records concatenated back-to-back decode in
     one memoryview walk; trailing slack bytes terminate cleanly."""

@@ -4,8 +4,8 @@ Covers the landing pool's lifetime rules (weakref-finalize recycling, size
 classes, budget refusal, death-path quarantine), the end-to-end transfer on
 the native-framing plane (TCP and ring platforms) and the gRPC wire plane,
 the copy-ledger zero-host-landing-copy proof, the framed fallback, the
-flight/watchdog evidence, and the TPU-plane halves (HbmRing region leases,
-SerializeFromDevice into a window, descriptor-only codec)."""
+flight/watchdog evidence, and the TPU-plane halves (SerializeFromDevice
+into a window, descriptor-only codec)."""
 
 import gc
 import threading
@@ -361,39 +361,8 @@ def test_h2_plane_big_payloads_bypass_data_frames(fresh_config):
 
 
 # ---------------------------------------------------------------------------
-# TPU plane: region leases, SerializeFromDevice, descriptor codec
+# TPU plane: SerializeFromDevice, descriptor codec
 # ---------------------------------------------------------------------------
-
-def test_hbm_lease_region_single_movement_ledger():
-    from tpurpc.tpu.hbm_ring import HbmRing
-
-    ring = HbmRing(1 << 20)
-    x = np.arange(65536, dtype=np.float32)
-    with ledger.track() as w:
-        lease = ring.lease_region(x.nbytes)
-        lease.fill(x)
-    # the single-movement claim, assertable via op counts: ONE h2d DMA +
-    # ONE in-ring landing write, zero host copies
-    assert w["dma_h2d_ops"] == 1 and w["dma_d2d_ops"] == 1, w.delta
-    assert w["host_copy"] == 0
-    hl = lease.view(dtype=np.float32, shape=(65536,))
-    assert np.allclose(np.asarray(hl.array), x)
-    hl.release()
-    lease.release()
-
-
-def test_hbm_lease_region_death_release_frees_credit():
-    from tpurpc.tpu.hbm_ring import HbmRing
-
-    ring = HbmRing(1 << 18)
-    writable0 = ring.writable()
-    lease = ring.lease_region(1 << 17)
-    assert ring.writable() == writable0 - (1 << 17)
-    lease.release()  # peer died before any fill
-    assert ring.writable() == writable0
-    with pytest.raises(RuntimeError):
-        lease.fill(np.zeros(1 << 17, np.uint8))  # released: no late landing
-
 
 def test_device_reply_leaves_by_rendezvous_zero_host_staging(fresh_config):
     """``SerializeFromDevice`` end to end (the product path that replaced

@@ -19,7 +19,7 @@ from tpurpc.tpu import HbmRing, ledger
 from tpurpc.tpu.endpoint import (DeviceMessage, TpuRingEndpoint,
                                  decode_tensor_to_ring, decode_tree_to_ring)
 
-from tests.test_tpu import _moved, _path_counters as _paths
+from tests.test_tpu import _landed, _moved
 
 
 def _tpu_server(monkeypatch, fn, kind="unary_unary", device=True,
@@ -39,17 +39,6 @@ def _tpu_server(monkeypatch, fn, kind="unary_unary", device=True,
     return srv, port
 
 
-@pytest.fixture(params=["alias", "direct"])
-def landing(request, monkeypatch):
-    """Both landings of the decode path: ``alias`` is a CPU ring by default
-    (bytes into the ring, dlpack views of them); ``direct`` is a ring whose
-    views cannot alias it, as on every TPU, reached here by
-    ``TPURPC_DLPACK_VIEW=0`` (each ring reads it once, as it is made)."""
-    if request.param == "direct":
-        monkeypatch.setenv("TPURPC_DLPACK_VIEW", "0")
-    return request.param
-
-
 # -- decode-to-ring units -----------------------------------------------------
 
 def test_decode_tensor_to_ring_zero_host_copy():
@@ -60,8 +49,8 @@ def test_decode_tensor_to_ring_zero_host_copy():
     with ledger.track() as w:
         lease, end = decode_tensor_to_ring(ring, wire)
     assert w["host_copy"] == 0
-    assert w["dma_h2d"] == x.nbytes
-    assert w["dma_d2d"] >= x.nbytes  # in-ring landing + view materialization
+    assert w["dma_h2d"] == x.nbytes and w["dma_h2d_ops"] == 1
+    assert w["dma_d2d"] == w["zero_copy"] == 0  # the one transfer, no more
     assert end == len(wire)
     with lease as arr:
         assert arr.shape == (2048,)
@@ -100,20 +89,18 @@ def _trees():
 
 
 @pytest.mark.parametrize("case", list(_trees()))
-def test_decode_tree_lands_directly_where_no_view_can_alias(monkeypatch,
-                                                            case):
-    """The TPU's landing, on the CPU: one transfer per message, each leaf
-    its final array (values, dtype, shape as the host decode gives them),
-    nothing moved on the device, nothing copied on the host."""
+def test_decode_tree_lands_directly_where_no_view_can_alias(case):
+    """The landing: one transfer per message, each leaf its final array
+    (values, dtype, shape as the host decode gives them), nothing moved on
+    the device, nothing copied on the host."""
     import jax
 
-    monkeypatch.setenv("TPURPC_DLPACK_VIEW", "0")
     tree = _trees()[case]
     wire = bytearray(codec.encode_tree_bytes(tree))
     want = codec.decode_tree(bytes(wire))
     payload = sum(v.nbytes for v in want.values())
     ring = HbmRing(1 << 16)
-    before = _paths()
+    before = _landed()
     with ledger.track() as w:
         out, leases = decode_tree_to_ring(ring, wire)
     wire[:] = bytes(len(wire))  # the wire buffer is reused; the arrays stay
@@ -125,30 +112,26 @@ def test_decode_tree_lands_directly_where_no_view_can_alias(monkeypatch,
         np.testing.assert_array_equal(np.asarray(leaf), ref)
     assert w["dma_h2d"] == payload and w["dma_h2d_ops"] == 1
     assert w["dma_d2d"] == w["host_copy"] == w["zero_copy"] == 0
-    assert _moved(before) == {
-        "hbm_place_direct": len(tree), "hbm_view_direct": len(tree),
-        "hbm_place_msgs": len(tree), "hbm_place_bytes": payload}
+    assert _moved(before) == {"hbm_place_msgs": len(tree),
+                              "hbm_place_bytes": payload}
     assert ring.stats()["tail"] == payload
     for lease in leases:
-        assert not lease.aliased
         lease.release()
     st = ring.stats()
     assert st["live_spans"] == 0 and st["head"] == st["tail"]
 
 
-def test_decode_tensor_lands_directly_where_no_view_can_alias(monkeypatch):
-    monkeypatch.setenv("TPURPC_DLPACK_VIEW", "0")
+def test_decode_tensor_lands_directly_where_no_view_can_alias():
     x = np.arange(2048, dtype=np.float32).reshape(2, 1024)
     wire = bytearray(codec.encode_tensor_bytes(x))
     ring = HbmRing(1 << 16)
-    before = _paths()
+    before = _landed()
     with ledger.track() as w:
         lease, end = decode_tensor_to_ring(ring, wire)
     assert end == len(wire)
     assert (w["dma_h2d"], w["dma_d2d"], w["host_copy"]) == (x.nbytes, 0, 0)
-    assert _moved(before) == {
-        "hbm_place_direct": 1, "hbm_view_direct": 1, "hbm_place_msgs": 1,
-        "hbm_place_bytes": x.nbytes}
+    assert _moved(before) == {"hbm_place_msgs": 1,
+                              "hbm_place_bytes": x.nbytes}
     with lease as arr:
         assert arr.shape == x.shape and arr.dtype == x.dtype
         assert arr.devices() == {ring.device}
@@ -156,9 +139,9 @@ def test_decode_tensor_lands_directly_where_no_view_can_alias(monkeypatch):
     assert ring.writable() == ring.capacity
 
 
-def test_ring_credit_blocks_until_lease_release(landing):
-    """An unreleased lease back-pressures placement (flow control), and a
-    release from another thread unblocks a waiting place()."""
+def test_ring_credit_blocks_until_lease_release():
+    """An unreleased lease back-pressures the landing (flow control), and a
+    release from another thread unblocks a waiting one."""
     x = np.zeros(3000, np.uint8)
     wire = bytearray(codec.encode_tensor_bytes(x))
     ring = HbmRing(1 << 12)  # 4 KiB: one message in flight
@@ -180,8 +163,8 @@ def test_oversized_payload_rejected():
 
 
 def test_empty_tensors_no_span_collision():
-    """Consecutive zero-size leaves must not collide on the (off, 0) span key
-    (reviewer finding: shared _live entry corrupted lease counts)."""
+    """Consecutive zero-size leaves share an offset and must not collide on
+    it: they hold no span and free nobody's."""
     tree = {"a": np.zeros((0,), np.float32), "b": np.zeros((0,), np.float64),
             "c": np.arange(4, dtype=np.int32)}
     ring = HbmRing(1 << 12)
@@ -194,7 +177,7 @@ def test_empty_tensors_no_span_collision():
     assert st["live_spans"] == 0 and st["writable"] == st["capacity"]
 
 
-def test_corrupt_trailer_releases_leases(landing):
+def test_corrupt_trailer_releases_leases():
     """A poison trailer must return every taken lease (reviewer finding:
     leaked credit = one-peer DoS on the connection's ring)."""
     tree = {"x": np.ones(64, np.float32)}
@@ -207,7 +190,7 @@ def test_corrupt_trailer_releases_leases(landing):
     assert st["live_spans"] == 0 and st["writable"] == st["capacity"]
 
 
-def test_misfit_header_returns_every_byte_of_credit(landing):
+def test_misfit_header_returns_every_byte_of_credit():
     """A header whose dtype does not fit ``nbytes`` (the sender writes it):
     the decode raises, alone or as the middle leaf of a tree, and the ring
     has all of its credit back."""
@@ -277,13 +260,13 @@ def test_factory_dispatches_tpu_endpoint(monkeypatch, spelling):
 
 # -- end-to-end tensor RPC on the TPU platform --------------------------------
 
-def test_e2e_device_tensor_rpc(monkeypatch, landing):
+def test_e2e_device_tensor_rpc(monkeypatch):
     """GRPC_PLATFORM_TYPE=TPU end to end: handler receives ring-backed device
     arrays, decode adds no host copies beyond frame assembly."""
     import jax
 
     seen = {}
-    before = _paths()
+    before = _landed()
 
     def fn(tree):
         seen["type"] = type(tree["x"])
@@ -296,66 +279,26 @@ def test_e2e_device_tensor_rpc(monkeypatch, landing):
             out = TensorClient(ch).call("Call", {"x": x}, timeout=30)
         np.testing.assert_array_equal(np.asarray(out["y"]), x * 2)
         assert issubclass(seen["type"], jax.Array)
-        took = {"alias": {"hbm_place_update": 1, "hbm_view_alias": 1},
-                "direct": {"hbm_place_direct": 1, "hbm_view_direct": 1}}
-        assert _moved(before) == {**took[landing], "hbm_place_msgs": 1,
+        assert _moved(before) == {"hbm_place_msgs": 1,
                                   "hbm_place_bytes": x.nbytes}
     finally:
         srv.stop(grace=0)
 
 
-def test_e2e_rpc_ledger_shows_zero_copy_views(monkeypatch):
-    """VERDICT r4 next #3 done-criterion: an end-to-end RPC on the emulated
-    TPU platform whose ledger shows zero_copy > 0 and NO view-side d2d for
-    eligible (aligned, unwrapped) leaves — the only d2d ops in the window
-    are the per-leaf landing writes, so every request view was an alias."""
-    import jax
-
-    seen = {}
-
-    def fn(tree):
-        seen["arrays"] = [tree["a"], tree["b"]]
-        return {"y": tree["a"] + 1}
-
-    srv, port = _tpu_server(monkeypatch, fn)
-    try:
-        # 4 KiB float32 leaves: span offsets 0 and 4096 on a fresh ring —
-        # aligned, unwrapped, dlpack-eligible
-        a = np.arange(1024, dtype=np.float32)
-        b = np.ones(1024, np.float32)
-        with Channel(f"127.0.0.1:{port}") as ch:
-            cli = TensorClient(ch)
-            with ledger.track() as w:
-                out = cli.call("Call", {"a": a, "b": b}, timeout=30)
-        np.testing.assert_array_equal(np.asarray(out["y"]), a + 1)
-        assert issubclass(type(seen["arrays"][0]), jax.Array)
-        # both request leaves were viewed as ALIASES (zero_copy, no
-        # materialization) and the whole tree landed as ONE batched
-        # placement (place_many: one h2d + one donated update per tree,
-        # not per leaf): view-side d2d == 0, so exactly one d2d op total
-        assert w["zero_copy"] >= a.nbytes + b.nbytes, w.delta
-        assert w["dma_d2d_ops"] == 1, w.delta  # the batch landing write ONLY
-        assert w["dma_h2d_ops"] == 1, w.delta  # one packed h2d per tree
-    finally:
-        srv.stop(grace=0)
-
-
 def test_e2e_concurrent_passthrough_echo_no_alias_corruption(monkeypatch):
-    """Round-5 serialize-then-release ordering: a device handler returning
-    an ALIASED request leaf verbatim must serialize it before the lease
-    releases — otherwise a concurrent RPC's in-place placement could
-    overwrite the span mid-serialization and corrupt the reply silently
-    (reviewer finding, round 5). Hammer two concurrent echo streams with
-    distinct payloads and verify every reply byte-exactly."""
+    """A device handler returning a request leaf verbatim: the reply is
+    the request's own bytes whatever lands on the connection meanwhile (a
+    landed array is a snapshot, and the reply is serialized inside the lease
+    window). Hammer two concurrent echo streams with distinct payloads and
+    verify every reply byte-exactly."""
     def fn(tree):
-        return {"y": tree["x"]}  # passthrough: the alias itself
+        return {"y": tree["x"]}  # passthrough: the landed array itself
 
     srv, port = _tpu_server(monkeypatch, fn)
     errors = []
     try:
         # ONE channel: both workers' RPCs multiplex one connection and so
-        # share one receive ring — the only topology where a concurrent
-        # placement can reuse a just-released span under a late serializer
+        # share one receive ring and its credit
         with Channel(f"127.0.0.1:{port}") as ch:
             cli = TensorClient(ch)
 
@@ -399,11 +342,11 @@ def test_e2e_client_device_response(monkeypatch):
         srv.stop(grace=0)
 
 
-def test_e2e_streaming_rolling_credit(monkeypatch, landing):
+def test_e2e_streaming_rolling_credit(monkeypatch):
     """A device-mode stream longer than the ring holds only one message's
     leases at a time (rolling release as the handler advances)."""
     monkeypatch.setenv("TPURPC_HBM_RING_SIZE_KB", "64")  # 64 KiB device ring
-    before = _paths()
+    before = _landed()
 
     def consume(trees):
         total = 0
@@ -418,7 +361,8 @@ def test_e2e_streaming_rolling_credit(monkeypatch, landing):
             replies = list(TensorClient(ch).duplex(
                 "Call", iter([{"x": x}] * 8), timeout=60))
         assert int(np.asarray(replies[0]["total"]).ravel()[0]) == 8 * 4096
-        assert _moved(before)["hbm_view_" + landing] == 8
+        assert _moved(before) == {"hbm_place_msgs": 8,
+                                  "hbm_place_bytes": 8 * x.nbytes}
     finally:
         srv.stop(grace=0)
 
@@ -449,42 +393,138 @@ def test_device_method_falls_back_off_platform(monkeypatch):
         srv.stop(grace=0)
 
 
-def test_e2e_wrapped_spans_take_pallas_consume(monkeypatch):
-    """A long device-mode stream through a SMALL ring forces spans across
-    the wrap point; every wrapped view must go through the fused Pallas
-    consume kernel (counted) and every payload must decode exactly —
-    the kernel exercised by the full transport→ring→lease path."""
-    monkeypatch.setenv("TPURPC_HBM_RING_SIZE_KB", "32")  # tiny: wrap often
+def _record_rings(monkeypatch):
+    """Every device ring a connection makes from here on, in order."""
+    import tpurpc.tpu.endpoint as endpoint_mod
 
-    import tpurpc.ops as ops_pkg
-    from tpurpc.ops.ring_window import ring_window as real_ring_window
+    made = []
 
-    calls = {"n": 0}
+    def make(capacity):
+        made.append(HbmRing(capacity))
+        return made[-1]
 
-    def counting(*a, **kw):
-        calls["n"] += 1
-        return real_ring_window(*a, **kw)
+    monkeypatch.setattr(endpoint_mod, "HbmRing", make)
+    return made
 
-    monkeypatch.setattr(ops_pkg, "ring_window", counting)
 
-    rng = np.random.default_rng(11)
-    payloads = [rng.standard_normal(1500).astype(np.float32)
-                for _ in range(12)]  # 6 KiB each through a 32 KiB ring
+@pytest.mark.parametrize("connections", [1, 8])
+def test_e2e_mixed_sizes_lap_the_credit_window(monkeypatch, connections):
+    """chip_smoke.py's tensor leg at its rehearsal sizes, over RPC: four
+    sizes that do not divide a 64 KiB window, enough messages to lap it four
+    times on each connection, a reply per message. Every message lands by
+    its one transfer, nothing moves on the device, and every window ends
+    empty."""
+    import jax
 
-    def consume(trees):
-        acc = 0.0
-        for t in trees:
-            acc += float(np.asarray(t["x"]).sum())
-        yield {"total": np.float64(acc)}
+    import chip_smoke
 
-    srv, port = _tpu_server(monkeypatch, consume, kind="stream_stream")
+    cfg = chip_smoke.REHEARSAL
+    monkeypatch.setenv("TPURPC_HBM_RING_SIZE_KB", str(cfg["ring_kb"]))
+    monkeypatch.setenv("TPURPC_RENDEZVOUS_MIN_KB", str(cfg["rdv_min_kb"]))
+    capacity = cfg["ring_kb"] << 10
+    shapes, wrapped = chip_smoke.plan_pass(cfg, capacity,
+                                           cfg["rdv_min_kb"] << 10)
+    sizes = {4 * int(np.prod(s)) for s in shapes}
+    assert wrapped >= 2 and any(capacity % n for n in sizes)
+    made = _record_rings(monkeypatch)
+
+    def sums(trees):
+        for tree in trees:
+            x = tree["x"]
+            assert isinstance(x, jax.Array)
+            yield {"check": np.uint32(chip_smoke.checksum_np(np.asarray(x))),
+                   "bytes": np.int64(x.nbytes)}
+
+    srv, port = _tpu_server(monkeypatch, sums, kind="stream_stream")
+    errors = []
+
+    def client(seed):
+        try:
+            rng = np.random.default_rng(seed)
+            msgs = [rng.standard_normal(s, dtype=np.float32) for s in shapes]
+            with Channel(f"127.0.0.1:{port}") as ch:
+                replies = list(TensorClient(ch).duplex(
+                    "Call", ({"x": m} for m in msgs), timeout=120))
+            assert len(replies) == len(msgs)
+            for m, r in zip(msgs, replies):
+                assert int(np.asarray(r["bytes"]).ravel()[0]) == m.nbytes
+                assert (int(np.asarray(r["check"]).ravel()[0])
+                        == chip_smoke.checksum_np(m))
+        except BaseException as exc:
+            errors.append(exc)
+
+    before = _landed()
+    try:
+        with ledger.track() as w:
+            ts = [threading.Thread(target=client, args=(40 + i,))
+                  for i in range(connections)]
+            [t.start() for t in ts]
+            [t.join(timeout=180) for t in ts]
+            assert not any(t.is_alive() for t in ts)
+        assert not errors, errors
+    finally:
+        srv.stop(grace=0)
+    messages, payload = connections * len(shapes), connections * 4 * capacity
+    assert _moved(before) == {"hbm_place_msgs": messages,
+                              "hbm_place_bytes": payload}
+    assert w["dma_h2d"] == payload and w["dma_h2d_ops"] == messages
+    assert w["dma_d2d"] == 0
+    assert len(made) == connections
+    for ring in made:
+        st = ring.stats()
+        assert st["head"] == st["tail"] == 4 * capacity and not st["live_spans"]
+
+
+def test_e2e_held_leases_time_out_the_landing_that_no_longer_fits(
+        monkeypatch):
+    """Two calls on one connection hold 48 of its window's 64 KiB for as
+    long as their handler runs: the third call's landing waits its timeout,
+    raises ``BufferError`` in the server, and the call ends UNKNOWN. Once
+    the handler lets go the window is whole again."""
+    import functools
+
+    import tpurpc.tpu.endpoint as endpoint_mod
+    from tpurpc.rpc.status import RpcError, StatusCode
+
+    monkeypatch.setenv("TPURPC_HBM_RING_SIZE_KB", "64")
+    made = _record_rings(monkeypatch)
+    monkeypatch.setattr(
+        endpoint_mod, "decode_tree_to_ring",
+        functools.partial(endpoint_mod.decode_tree_to_ring, timeout=0.3))
+    entered, let_go = threading.Semaphore(0), threading.Event()
+
+    def hold(tree):
+        entered.release()
+        assert let_go.wait(60)
+        return {"n": np.int64(tree["x"].nbytes)}
+
+    srv, port = _tpu_server(monkeypatch, hold)
+    x = np.ones(6144, np.float32)  # 24 KiB: two fit, a third does not
+    got = []
     try:
         with Channel(f"127.0.0.1:{port}") as ch:
-            replies = list(TensorClient(ch).duplex(
-                "Call", iter([{"x": p} for p in payloads]), timeout=60))
-        want = sum(float(p.sum()) for p in payloads)
-        got = float(np.asarray(replies[0]["total"]).ravel()[0])
-        assert abs(got - want) < 1e-3 * max(1.0, abs(want))
-        assert calls["n"] >= 1, "stream never crossed the wrap point"
+            cli = TensorClient(ch)
+
+            def call():
+                got.append(cli.call("Call", {"x": x}, timeout=60))
+
+            ts = [threading.Thread(target=call) for _ in range(2)]
+            [t.start() for t in ts]
+            assert entered.acquire(timeout=30) and entered.acquire(timeout=30)
+            (ring,) = made
+            assert ring.stats()["live_spans"] == 2
+            with pytest.raises(RpcError) as err:
+                cli.call("Call", {"x": x}, timeout=60)
+            assert err.value.code() == StatusCode.UNKNOWN
+            assert "HBM ring full" in err.value.details()
+            assert ring.stats()["tail"] == 2 * x.nbytes  # nothing was claimed
+            let_go.set()
+            [t.join(timeout=60) for t in ts]
+            assert not any(t.is_alive() for t in ts)
+        assert [int(np.asarray(r["n"]).ravel()[0]) for r in got] == [
+            x.nbytes] * 2
+        st = ring.stats()
+        assert st["head"] == st["tail"] == 2 * x.nbytes
     finally:
+        let_go.set()
         srv.stop(grace=0)

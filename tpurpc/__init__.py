@@ -27,7 +27,6 @@ Package map (SURVEY.md §7):
 ``tpurpc.tpu``     HBM rings, copy ledger, device serialization (north star)
 ``tpurpc.jaxshim`` grpcio-jax: jax.Array in/out, tensor services, pjit serving
 ``tpurpc.models``  flagship serving models (ResNet-50 inference server)
-``tpurpc.ops``     Pallas/XLA device kernels used by the data plane
 ``tpurpc.parallel`` mesh/sharding helpers for multi-chip serving
 =================  ===========================================================
 """

@@ -163,16 +163,12 @@ def add_tensor_method(server: Server, name: str,
 
     # device mode: identity deserializer (raw message bytes reach the
     # behavior), decode inside where ctx exposes the connection's ring.
-    # Responses are serialized INSIDE the behavior, before finish():
-    # round-5 ring views ALIAS ring memory (HbmRing._dlpack_view), so a
-    # passthrough response (``return {"y": tree["a"]}``) read by the RPC
-    # layer's serializer AFTER the lease release could see the span
-    # overwritten in place by a concurrent RPC on the same connection.
-    # Serialize-then-release makes the alias's whole read window sit
-    # inside the lease window; the handler's serializer is identity.
-    # tree_from_device is the one outbound leg: a reply's device leaves are
-    # read back there (on a TPU into fresh host buffers, so the bytes the
-    # writer places are the reply's own whatever lands in the ring next).
+    # Responses are serialized INSIDE the behavior, before finish(): a
+    # reply's read-back sits inside the request's lease window, so the
+    # credit a message holds covers the whole call; the handler's
+    # serializer is identity. tree_from_device is the one outbound leg: a
+    # reply's device leaves are read back there (on a TPU into fresh host
+    # buffers, so the bytes the writer places are the reply's own).
     from tpurpc.tpu.serialize import tree_from_device
 
     _ident = lambda b: b  # noqa: E731 — already-encoded bytes pass through

@@ -30,9 +30,8 @@ appended: the registry is append-only)::
     decode     codec parse of wire bytes back into tensors
                (jaxshim/codec.py decode_tree_at, tpu/endpoint.py
                decode_tree_to_ring)
-    hbm        host time to ENQUEUE the h2d transfer and, where the bytes
-               go through the ring, the landing write (tpu/hbm_ring.py
-               place/place_many/land_many; dispatch is asynchronous, so
+    hbm        host time to ENQUEUE a message's one h2d transfer
+               (tpu/hbm_ring.py land_many; dispatch is asynchronous, so
                this is not device time)
     jax_array  materialization as jax.Array — dlpack alias or the
                device_put staging copy (jaxshim/codec.py to_jax)
@@ -110,9 +109,8 @@ HOPS: Tuple[Tuple[str, str], ...] = (
                    "grant round trip (tpr_rdv.cc rdv_claim)"),
     ("peer_ring", "RingReader drain out of the local receive ring"),
     ("decode", "codec parse of wire bytes back into tensors"),
-    ("hbm", "host time to enqueue the h2d transfer and the landing "
-            "write, not device time (HbmRing.place/place_many/fill; "
-            "land_many: its one transfer)"),
+    ("hbm", "host time to enqueue a message's one h2d transfer, not "
+            "device time (HbmRing.land_many)"),
     ("jax_array", "materialization as jax.Array (dlpack alias or "
                   "device_put staging)"),
     # ISSUE 26: the server's per-message path, one stage per message on
@@ -128,11 +126,10 @@ HOPS: Tuple[Tuple[str, str], ...] = (
     ("srv_send", "serialize and write one response message"),
     ("srv_call", "whole server calls, start of the handler to its end "
                  "(the denominator of the stages' coverage)"),
-    ("hbm_credit", "a placement blocked waiting for ring credit "
+    ("hbm_credit", "a landing blocked waiting for ring credit "
                    "(HbmRing._space; no op where it never blocked)"),
-    ("hbm_view", "host time to enqueue the view of a placed span "
-                 "(slice / window / concat + shaped; a direct landing: "
-                 "the lease hand-off)"),
+    ("hbm_view", "the lease hand-off of a landed message "
+                 "(HbmRing.land_many)"),
     # ISSUE 28: the outbound leg, one stage per response that had a leaf
     # on a device (tpu/serialize.py _read_back, inside srv_handler)
     ("d2h", "a reply's device leaves read back: every leaf's "

@@ -106,11 +106,10 @@ def run() -> int:
             for i in range(8):
                 assert mc2(b"p%d" % i, timeout=10) == b"p%d" % i
 
-        # the hbm + jax_array device hops: a real HbmRing placement and a
-        # lease-backed view (emulated device plane, same accounting)
+        # the hbm device hop: a real HbmRing landing and its lease-backed
+        # array
         ring = HbmRing(1 << 16)
-        off, n = ring.place(np.arange(4096, dtype=np.uint8))
-        lease = ring.view(off, n)
+        lease = ring.land(np.arange(4096, dtype=np.uint8), np.uint8, (4096,))
         assert lease.array.shape == (4096,)
         lease.release()
 

@@ -22,10 +22,9 @@ device handles, host-memcpy = 0 after frame assembly"):
   the codec's host-visible tensor header, land the payload spans through
   the device ring (``HbmRing.land_many``) straight from the wire-assembly
   buffer (zero host memcpy — the ledger proves it), and hand back device
-  arrays whose leases gate the ring's credit return (hard-part #4: a
-  jax.Array aliasing ring memory must pin its span). Where a ring's views
-  cannot alias it (every TPU) the landing is ONE ``device_put`` per message
-  to the final arrays and the ring is the credit window over them.
+  arrays whose leases gate the ring's credit return. The landing is ONE
+  ``device_put`` per message to the final arrays, and the ring is the
+  credit window over them.
 
 The RPC layer reaches the device ring through ``ServerContext.device_ring``
 (server) and ``Channel.device_ring()`` (client); the jaxshim tensor service

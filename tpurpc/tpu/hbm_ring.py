@@ -1,92 +1,45 @@
-"""Device-resident receive ring: tensor payloads land in TPU HBM, consumers
-get lease-backed device arrays — the emulated form of the BASELINE north star.
+"""Device receive window: tensor payloads land in device memory, consumers get
+lease-backed device arrays, and the leases are the flow control.
 
-Real hardware path (not reachable in this environment): the NIC DMAs into a
-dmabuf-exported HBM ring, head/footer words stay host-visible, and ``Recv``
-returns device buffer handles. This module emulates the *architecture* with
-XLA-visible pieces so the protocol, lease discipline, and copy ledger are
-real even though the placement is a ``device_put``.
+An :class:`HbmRing` is a **credit window over directly landed buffers**, the
+same on every platform. ``land_many`` hands the typed host views of one
+message's leaves to ONE ``jax.device_put`` and leases the arrays it returns:
+1 byte moved per payload byte (ledger ``dma_h2d`` alone), no device program,
+no host scalar handed to a jit. No byte ring lives on the device: nothing
+could read a payload back out of one but one more copy, so the ring owns
+offsets and credit, never bytes.
 
-What the ring IS depends on one thing it observes about its device, once, as
-it is made (``HbmRing._aliasing``): can a view alias the ring's bytes?
+* one way in — :meth:`HbmRing.land` / :meth:`HbmRing.land_many`: the batch
+  waits up to ``timeout`` for its TOTAL to fit (:class:`BufferError` where it
+  cannot; at once where it exceeds the capacity), each non-empty leaf claims
+  its own span ``[tail, tail + nbytes)`` and the tail advances;
+* one way out — :meth:`HbmLease.release`: the span's credit returns, and the
+  head advances over every released prefix, in order. A landed array is a
+  snapshot and survives its release; its lease is the back-pressure: at most
+  ``capacity`` bytes of unreleased leases (credit-based flow control, the
+  ``pair.cc:276-284`` analog).
 
-* **It can** (a CPU device, unless ``TPURPC_DLPACK_VIEW=0``): the ring is a
-  byte ring. ``land_many``, the decode path's entry, is ``place_many`` +
-  ``view``: the bytes go INTO the ring and the arrays handed out alias them.
-* **It cannot** (every TPU): the ring is a **credit window over directly
-  landed buffers**. ``land_many`` hands the typed host views of a message's
-  leaves to ONE ``jax.device_put`` and leases the arrays it returns: 1 byte
-  moved per payload byte (ledger ``dma_h2d`` alone), no ring program, no host
-  scalar handed to a jit. Writing the bytes into the ring first would be a
-  dead store: nothing could read them back but one more copy. The ring array
-  stays allocated at its configured size, and ``place`` / ``view`` /
-  ``place_many`` / ``lease_region`` below still go through it for callers
-  that use them directly.
+The north star's landing, a NIC that DMAs into a dmabuf-exported HBM ring
+whose head and footer words stay host-visible, needs an export this
+environment cannot reach. If hardware ever offers it, that landing is written
+against the hardware; what is real here is the protocol, the lease discipline
+and the copy ledger.
 
-The two landings share the credit accounting (``_wait_credit``, ``tail``,
-``_live``, in-order ``_advance_locked``) and no landing logic: an aliasing
-device wants the bytes in the ring, a copying device gains nothing from them
-being there.
-
-* ``place`` — one h2d movement per payload (ledger: dma_h2d) followed by the
-  landing write that puts it in the ring: a donated ``dynamic_update_slice``,
-  or the ring_scatter kernel when the span wraps. The landing write moves
-  the payload a second time ON DEVICE, and the ledger records it as
-  ``dma_d2d`` — two honest entries for two real movements (on NIC hardware
-  the DMA writes the ring directly and both entries collapse into the NIC's
-  single placement write).
-* ``view`` — for aligned, unwrapped spans on an aliasing ring: a **dlpack
-  alias** of the ring bytes themselves — a ``jax.Array`` whose buffer
-  pointer is ``ring_base + offset``, zero bytes moved, ledger ``zero_copy``.
-  Aliasing is **verified per view** by pointer comparison — an import the
-  backend chose to copy (misaligned span, exotic dtype) is recorded as
-  ``dma_d2d``, honestly. Everywhere else a view is a device copy, recorded
-  as ``dma_d2d``: ``dynamic_slice`` (+ bitcast) for an unwrapped span, the
-  ring_window kernel for a wrapped one (on real hardware the aliasing seam
-  is the dmabuf export, out of this environment's reach). Payload bytes
-  never touch the host either way.
-
-  Which path each placement and each view took is counted
-  (``hbm_place_{update,scatter,split,direct}``, ``hbm_view_{alias,slice,
-  window,concat,direct}`` in the metrics registry), and a kernel that fails
-  raises: no path gives way to another behind the caller's back.
-
-  The alias relies on one invariant the real hardware has by construction
-  (a pinned ring is never reallocated): XLA's donation must keep the ring
-  allocation at the same address across ``place`` updates. ``place``
-  asserts this after every rebind and refuses to continue (loud
-  RuntimeError, not silent corruption) if the allocation ever moved while
-  aliased leases were outstanding.
-* lease/credit — a message's span stays pinned until every handle is
-  released; only then does the head advance (SURVEY.md §7 hard-part #4: a
-  ``jax.Array`` aliasing ring memory must gate credit return). A directly
-  landed array is a snapshot and survives its release; its lease is the
-  back-pressure: at most ``capacity`` bytes of unreleased leases.
-
-Thread model: ``self.buf`` is rebound by donating jits in ``place`` while
-``view`` slices it — both run under ``self._lock`` for their whole device
-op, because a donated buffer is DELETED the moment the update launches and a
-concurrent slice of the old binding would fault (advisor r1 finding). The
-lock spans an XLA dispatch (a direct landing's one transfer too), which is
-acceptable for the emulation: one ring has one producer (the receive path)
-and its consumers.
+Thread model: claims, releases and the wait for credit run under
+``self._lock``; the lock spans the landing's one asynchronous dispatch, which
+is what keeps spans in tail order: one ring has one producer (the
+connection's receive path) and its consumers.
 
 Capacity is a power of two; offsets are monotonic 64-bit counters — the same
 invariants as the host ring (tpurpc/core/ring.py), so the flow-control math
 is shared by inspection.
-
-Reference analog: the creation path ``rdma_bp_posix.cc:706-796`` (pool take →
-init → bootstrap → poller) and the receive drain ``ring_buffer.cc:122-191``;
-here the drain's landing target is device memory.
 """
 
 from __future__ import annotations
 
-import functools
-import os
 import threading
 import time
-from typing import Dict, Optional, Tuple
+from typing import Dict, Optional
 
 import numpy as np
 
@@ -95,39 +48,23 @@ from tpurpc.obs import metrics as _metrics
 from tpurpc.obs import profiler as _profiler
 from tpurpc.tpu import ledger
 
-# tpurpc-scope (ISSUE 4): device-ring placement totals + scrape-time
-# occupancy over live HBM rings (one counter bump per placement BATCH; the
-# per-byte movement accounting stays the copy ledger's job)
+# tpurpc-scope (ISSUE 4): landing totals + scrape-time occupancy over live
+# rings (one counter bump per landed BATCH; the per-byte movement accounting
+# stays the copy ledger's job)
 _HBM_PLACE_MSGS = _metrics.counter("hbm_place_msgs")
 _HBM_PLACE_BYTES = _metrics.counter("hbm_place_bytes")
 _HBM_RINGS = _metrics.fleet("hbm_ring_occupancy_bytes",
                             lambda r: r.tail - r.head)
-#: which code path each landing write and each view took — read these, do
-#: not guess: `update` one donated dynamic_update_slice, `scatter` the
-#: ring_scatter kernel, `split` two updates across the wrap (kernel
-#: ineligible); `alias` dlpack view, `slice` one dynamic_slice, `window` the
-#: ring_window kernel, `concat` slice+slice+concatenate (kernel ineligible);
-#: `direct` (both) a leaf that `land_many` put straight into its final array
-_PLACE_PATH = {k: _metrics.counter(f"hbm_place_{k}")
-               for k in ("update", "scatter", "split", "direct")}
-_VIEW_PATH = {k: _metrics.counter(f"hbm_view_{k}")
-              for k in ("alias", "slice", "window", "concat", "direct")}
 
-# tpurpc-lens (ISSUE 8, 26): three hops, one `lens.stage` each per call.
-# `hbm_credit` the wait for ring credit (no op where a placement never
-# blocked), `hbm` the host time to ENQUEUE the h2d transfer and the landing
-# write (dispatch is asynchronous: not device time), `hbm_view` the host
-# time to enqueue the view. A direct landing (`land_many` on a ring that
-# cannot alias) is one `hbm` round its single transfer and one `hbm_view`
-# round the lease hand-off, per message. The emulated placement stages
-# host→device (dma_h2d), so every placed byte is also a copy byte of `hbm`.
+# tpurpc-lens (ISSUE 8, 26): three hops, one `lens.stage` each per message.
+# `hbm_credit` the wait for credit (no op where a landing never blocked),
+# `hbm` the host time to ENQUEUE the message's one transfer and claim its
+# spans (dispatch is asynchronous: not device time), `hbm_view` the lease
+# hand-off. The landing moves host→device (dma_h2d), so every landed byte is
+# also a copy byte of `hbm`.
 
 _LENS_STAGES = {
-    "place": "hbm-place",
-    "place_many": "hbm-place",
     "land_many": "hbm-place",
-    "_land": "hbm-place",
-    "view": "device-dispatch",
 }
 _profiler.register_stages(__file__, _LENS_STAGES)
 
@@ -140,183 +77,29 @@ def _u8(payload) -> np.ndarray:
     return np.frombuffer(payload, np.uint8)
 
 
-@functools.lru_cache(maxsize=None)
-def _ring_jits():
-    """``(update, slice, shaped)``, jitted once per PROCESS, not per ring:
-    jit's in-memory cache is keyed by function identity, so per-ring closures
-    made every new connection retrace and reload each payload size it had
-    already seen on another. ``update`` donates the ring; ``slice``'s length
-    is static (one program per payload size); ``shaped`` reinterprets a
-    span's bytes as ``dtype[shape]`` in one dispatch. A jitted program is
-    named after its function, and a device trace's reduction finds it by
-    that name (``jit_tpurpc_ring_update`` ...): the names are the ring's
-    own, and stable."""
-    import jax
-    from jax import lax
-
-    def tpurpc_ring_update(buf, payload, start):
-        return lax.dynamic_update_slice(buf, payload, (start,))
-
-    def tpurpc_ring_slice(buf, start, n):
-        return lax.dynamic_slice(buf, (start,), (n,))
-
-    def tpurpc_ring_shaped(seg, dtype, shape):
-        from tpurpc.ops.layout import bytes_as
-
-        out = bytes_as(seg, dtype)
-        return out if shape is None else out.reshape(shape)
-
-    return (jax.jit(tpurpc_ring_update, donate_argnums=0),
-            jax.jit(tpurpc_ring_slice, static_argnums=2),
-            jax.jit(tpurpc_ring_shaped, static_argnums=(1, 2)))
-
-
 class HbmRing:
-    """Byte ring in device memory with host-tracked head/tail + leases."""
+    """Credit window over device-resident landings: host-tracked head/tail
+    + leases."""
 
     def __init__(self, capacity: int, device=None):
-        import jax
-        import jax.numpy as jnp
-
         if capacity < 64 or capacity & (capacity - 1):
             raise ValueError("capacity must be a power of two >= 64")
         self.capacity = capacity
-        self._mask = capacity - 1
         if device is None:
             from tpurpc.utils.jaxenv import default_device
 
             device = default_device()
         self.device = device
-        self.buf = jax.device_put(jnp.zeros((capacity,), jnp.uint8), device)
-        self.tail = 0   # absolute bytes ever placed
+        self.tail = 0   # absolute bytes ever landed
         self.head = 0   # absolute bytes ever freed
         self._lock = threading.Lock()
         #: signaled whenever the head advances (space became writable)
         self._space = threading.Condition(self._lock)
-        #: span -> [outstanding leases, ever_released] — a span frees only
-        #: after at least one lease was taken AND all were released, so a
-        #: placed-but-unconsumed message can never be reclaimed under it
-        self._live: Dict[Tuple[int, int], list] = {}
-        #: outstanding leases whose array ALIASES ring memory (dlpack views):
-        #: while > 0, the allocation-stability assert in place() is fatal
-        self._aliased = 0
+        #: offset -> [nbytes, released] of every span the head has not
+        #: passed, in tail order (claims are made under the lock): a span
+        #: released out of order waits here for the ones before it
+        self._live: Dict[int, list] = {}
         _HBM_RINGS.track(self)
-        #: ring base address (unsafe_buffer_pointer), or None where the
-        #: backend doesn't expose one — the dlpack view path needs it both
-        #: to build the alias and to verify stability across donations
-        self._base_ptr = self._ptr_of(self.buf)
-        #: whether a view of this ring CAN alias its bytes, decided here
-        #: once: a CPU device, a base address to build the alias on, and no
-        #: ``TPURPC_DLPACK_VIEW=0``; ``_dlpack_view`` latches it off for
-        #: good where the import lands on another device. No TPU ring
-        #: aliases. It picks the landing (see :meth:`land_many`): bytes that
-        #: nothing can alias have no reason to pass through the ring.
-        self._aliasing = (device.platform == "cpu"
-                          and self._base_ptr is not None
-                          and os.environ.get("TPURPC_DLPACK_VIEW", "1") != "0")
-
-        self._update, self._slice, self._shaped = _ring_jits()
-
-    @staticmethod
-    def _ptr_of(arr) -> Optional[int]:
-        """Device buffer address of a jax.Array, or None (backend-
-        dependent introspection; every consumer tolerates None)."""
-        try:
-            return arr.addressable_shards[0].data.unsafe_buffer_pointer()
-        except Exception:
-            return None
-
-    def _dlpack_view(self, p: int, n: int, dt, shape):
-        """Aliasing ``jax.Array`` of ring bytes ``[p, p+n)`` — the round-5
-        zero-copy receive path (VERDICT r4 next #3). Returns ``(array,
-        is_alias)`` or None to use the slice chain.
-
-        Builds a numpy view over the raw span (``ctypes.from_address`` on
-        the ring base — free), applies dtype/shape numpy-side (views,
-        free), and imports via dlpack. On the CPU-backed emulated platform
-        XLA adopts the buffer in place; ``is_alias`` is PROVEN by pointer
-        equality, never assumed — a copying import (misaligned offset) is
-        still a correct result, just billed as ``dma_d2d``.
-
-        Lifetime: the jax.Array holds the dlpack capsule → the numpy view
-        → nothing (the raw span has no owner). The ring allocation is the
-        owner, kept alive by ``self.buf`` (the lease holds the ring) and
-        kept *in place* by the donation-stability assert in ``place``.
-        Consumers must not donate a leased array into a jit — that would
-        hand XLA a write alias into ring memory (same contract as the
-        reference's borrowed ring slices, ``ring_buffer.cc:122-191``)."""
-        if not self._aliasing:
-            return None
-        import ctypes
-
-        import jax.numpy as jnp
-
-        # Order the alias read after every pending placement: the raw-pointer
-        # view below has NO dataflow dependency on the donated
-        # dynamic_update_slice that landed the span, and under JAX async
-        # dispatch (default-on for CPU) a consumer could otherwise read the
-        # span before place()'s update executed — stale tensor bytes on the
-        # zero-copy path (ADVICE r5, medium). Real hardware gets this
-        # ordering from the NIC's completion; the emulation must ask for it.
-        self.buf.block_until_ready()
-        try:
-            raw = (ctypes.c_uint8 * n).from_address(self._base_ptr + p)
-            npv = np.ctypeslib.as_array(raw)
-            npdt = np.dtype(dt)
-            if npdt != np.uint8:
-                npv = npv.view(npdt)  # numpy view: free; bf16 et al raise
-            npv = npv.reshape(shape if shape is not None else (-1,))
-            arr = jnp.from_dlpack(npv)  # raises for dlpack-unsupported dt
-        except Exception:
-            return None  # per-span/per-dtype failure: slice chain is law
-        if arr.devices() != {self.device}:
-            # from_dlpack landed the alias on a different jax device than
-            # the ring's (virtual multi-device mesh): consumers would trip
-            # cross-device errors. Latch off — this is a property of the
-            # ring's device, not of one span.
-            self._aliasing = False
-            return None
-        return arr, self._ptr_of(arr) == self._base_ptr + p
-
-    def _pallas_ok(self, p: int, n: int, min_capacity: int) -> bool:
-        """Shared eligibility guard for the place/view kernels: 4-byte
-        alignment, capacity floor, validated platforms, env opt-out
-        (``TPURPC_PALLAS=0``). A kernel that is eligible and fails RAISES —
-        nothing here remembers a failure and quietly takes the jax-op chain,
-        on any platform: the path counters must mean what they say."""
-        return not (p % 4 or n % 4 or self.capacity < min_capacity
-                    or self.device.platform not in ("cpu", "tpu")
-                    or os.environ.get("TPURPC_PALLAS", "1") == "0")
-
-    def _land(self, dev_payload, p: int, n: int) -> None:
-        """The in-ring landing write of ``n`` device-resident bytes at
-        physical offset ``p`` (caller holds ``self._lock``): ONE write per
-        placement — a donated ``dynamic_update_slice`` when the span fits
-        before the edge, the aliased ring_scatter kernel
-        (tpurpc.ops.ring_scatter; compiled on TPU, interpreted on CPU) when
-        it wraps — and two donated updates only where the kernel is
-        ineligible, which the ledger and the path counter both say.
-        Rebinding ``self.buf`` under the lock: view() must never slice a
-        just-donated (deleted) binding."""
-        first = min(n, self.capacity - p)
-        if first >= n:
-            self.buf = self._update(self.buf, dev_payload, p)
-            ledger.dma_d2d(n)
-            _PLACE_PATH["update"].inc()
-        elif self._pallas_ok(p, n, 2 * 9 * 512):
-            from tpurpc.ops.ring_scatter import ring_scatter
-
-            self.buf = ring_scatter(self.buf, dev_payload, p,
-                                    interpret=self.device.platform == "cpu")
-            ledger.dma_d2d(n)
-            _PLACE_PATH["scatter"].inc()
-        else:
-            self.buf = self._update(self.buf, dev_payload[:first], p)
-            ledger.dma_d2d(first)
-            self.buf = self._update(self.buf, dev_payload[first:], 0)
-            ledger.dma_d2d(n - first)
-            _PLACE_PATH["split"].inc()
-        self._assert_stable()
 
     # -- producer ------------------------------------------------------------
 
@@ -338,94 +121,6 @@ class HbmRing:
         if n > self.writable():
             raise BufferError(f"HBM ring full: {n} > {self.writable()}")
 
-    def place(self, payload, timeout: Optional[float] = None) -> Tuple[int, int]:
-        """DMA one payload into the ring; returns its (offset, nbytes) span.
-
-        Emulates the NIC's placement write: one h2d movement plus the in-ring
-        landing write (dma_d2d); zero host memcpy (the payload view is
-        consumed in place).
-
-        Blocks up to ``timeout`` seconds for lease releases to free space
-        (credit-based flow control, ``pair.cc:276-284`` analog); with
-        ``timeout=None`` a full ring raises :class:`BufferError` immediately.
-        A payload larger than the whole ring always raises.
-        """
-        import jax
-
-        src = _u8(payload)
-        n = src.nbytes
-        if n == 0:
-            # Zero-size spans never enter _live: they'd all share the key
-            # (tail, 0) and corrupt each other's lease counts. An empty
-            # payload needs no ring bytes and no credit.
-            return self.tail, 0
-        if n > self.capacity:
-            raise BufferError(f"payload {n} exceeds ring capacity {self.capacity}")
-        with self._lock:
-            self._wait_credit(n, timeout)
-            with _lens.stage("hbm", n) as st:
-                st.copy = n
-                off = self.tail
-                self.tail += n
-                self._live[(off, n)] = [0, False]
-                p = off & self._mask
-                # The h2d transfer and the landing write stay separate
-                # movements: XLA cannot land a host transfer at an offset of
-                # an existing device buffer (a NIC-DMA'd ring would fuse
-                # them).
-                dev = jax.device_put(src, self.device)
-                ledger.dma_h2d(n)
-                self._land(dev, p, n)
-        _HBM_PLACE_MSGS.inc()
-        _HBM_PLACE_BYTES.inc(n)
-        return off, n
-
-    def place_many(self, payloads,
-                   timeout: Optional[float] = None) -> "list[Tuple[int, int]]":
-        """DMA a BATCH of payloads into the ring with ONE landing dispatch.
-
-        The payloads pack host-side into one contiguous image (one pass), move
-        with one h2d, and land with a single donated ``dynamic_update_slice``
-        (or one aliased ring_scatter kernel across the wrap) — one XLA
-        dispatch per *batch* instead of per tensor, the device half of the
-        batched receive pipeline.  Returns the per-payload ``(offset,
-        nbytes)`` spans, each leased/credited independently exactly as if
-        placed by :meth:`place` back to back.
-
-        Flow control matches :meth:`place` with the batch treated as one
-        unit: blocks up to ``timeout`` for the TOTAL to fit; a batch larger
-        than the whole ring raises."""
-        import jax
-
-        srcs = [_u8(p) for p in payloads]
-        lens = [s.nbytes for s in srcs]
-        total = sum(lens)
-        if total == 0:
-            return [(self.tail, 0) for _ in srcs]
-        if total > self.capacity:
-            raise BufferError(
-                f"batch of {total} bytes exceeds ring capacity {self.capacity}")
-        with self._lock:
-            self._wait_credit(total, timeout)
-            with _lens.stage("hbm", total) as st:
-                st.copy = total
-                off = self.tail
-                self.tail += total
-                spans = []
-                for n in lens:
-                    if n:  # zero-size spans hold no credit (see place())
-                        self._live[(off, n)] = [0, False]
-                    spans.append((off, n))
-                    off += n
-                packed = np.concatenate(srcs) if len(srcs) > 1 else srcs[0]
-                p = spans[0][0] & self._mask
-                dev = jax.device_put(packed, self.device)
-                ledger.dma_h2d(total)
-                self._land(dev, p, total)
-        _HBM_PLACE_MSGS.inc(len(spans))
-        _HBM_PLACE_BYTES.inc(total)
-        return spans
-
     def land(self, payload, dtype, shape,
              timeout: Optional[float] = None) -> "HbmLease":
         """:meth:`land_many` of one leaf."""
@@ -433,60 +128,32 @@ class HbmRing:
 
     def land_many(self, leaves,
                   timeout: Optional[float] = None) -> "list[HbmLease]":
-        """The decode path's landing: one message's ``(payload, dtype,
-        shape)`` leaves in, one lease-backed device array per leaf out.
+        """The landing: one message's ``(payload, dtype, shape)`` leaves in,
+        one lease-backed device array per leaf out.
 
-        Credit is the same on every ring: the batch waits up to ``timeout``
-        for its TOTAL to fit (:class:`BufferError` where it cannot, at once
-        over capacity), each non-empty leaf holds its own span, and the
-        head advances in order as leases are released. Where the bytes land
-        depends on what the ring's device allows (``_aliasing``):
-
-        * views can alias the ring: :meth:`place_many` then :meth:`view`
-          per leaf: the bytes go INTO the ring and the arrays alias them
-          (ledger ``dma_h2d`` + ``dma_d2d`` + ``zero_copy``);
-        * they cannot (every TPU): nothing would ever read the bytes back
-          out of the ring but one more copy, so the typed host views go to
-          the device in ONE ``jax.device_put`` and ARE the arrays handed
-          out (ledger ``dma_h2d`` and nothing else; no ring program, no
-          host scalar handed to a jit). The ring is then a credit window
-          over directly landed buffers: at most ``capacity`` bytes of
-          unreleased leases.
+        The typed host views go to the device in ONE ``jax.device_put`` and
+        ARE the arrays handed out (ledger ``dma_h2d`` and nothing else). The
+        batch waits up to ``timeout`` for its TOTAL to fit
+        (:class:`BufferError` where it cannot, at once over capacity), each
+        non-empty leaf holds its own span, and the head advances in order as
+        leases are released.
 
         A leaf whose dtype or shape does not fit its bytes raises, with
         every byte of the batch's credit returned."""
+        import jax
+
         srcs = [_u8(p) for p, _, _ in leaves]
         total = sum(src.nbytes for src in srcs)
         if total > self.capacity:
             raise BufferError(
                 f"message payloads total {total} bytes > ring capacity "
                 f"{self.capacity}; raise TPURPC_HBM_RING_SIZE_KB")
-        if self._aliasing:
-            spans = self.place_many(srcs, timeout)
-            leases: "list[HbmLease]" = []
-            try:
-                for (_, dt, shape), (off, n) in zip(leaves, spans):
-                    leases.append(self.view(off, n, dtype=dt, shape=shape))
-            except BaseException:
-                # the leases taken go back, and the spans placed but never
-                # viewed count as consumed, or they block the head for ever
-                for lease in leases:
-                    lease.release()
-                with self._lock:
-                    for span in spans[len(leases):]:
-                        if span in self._live:
-                            self._live[span][1] = True
-                    self._advance_locked()
-                raise
-            return leases
-        import jax
-
         # the host decode's own views (codec.decode_tensor): free, and a
         # leaf that does not fit its bytes raises here, before any credit.
         # The transfer reads them AFTER device_put returns (chip probe,
-        # PERF.md 6, PR 27); as in place(), what keeps the wire buffer from
-        # being recycled meanwhile is the reference jax holds on the view,
-        # which chains to the buffer's owner, until the transfer completes.
+        # PERF.md 6, PR 27); what keeps the wire buffer from being recycled
+        # meanwhile is the reference jax holds on the view, which chains to
+        # the buffer's owner, until the transfer completes.
         host = [src.view(dt).reshape(shape)
                 for src, (_, dt, shape) in zip(srcs, leaves)]
         if self.device.platform == "cpu":
@@ -506,8 +173,10 @@ class HbmRing:
                 # raises leaves nothing to undo
                 spans, off = [], self.tail
                 for h in host:
-                    if h.nbytes:  # zero-size spans hold no credit
-                        self._live[(off, h.nbytes)] = [1, False]
+                    if h.nbytes:
+                        # zero-size spans hold no credit: they would all
+                        # share one offset and free each other's
+                        self._live[off] = [h.nbytes, False]
                     spans.append((off, h.nbytes))
                     off += h.nbytes
                 self.tail = off
@@ -516,139 +185,29 @@ class HbmRing:
                       for (off, n), arr in zip(spans, arrays)]
         _HBM_PLACE_MSGS.inc(len(leases))
         _HBM_PLACE_BYTES.inc(total)
-        _PLACE_PATH["direct"].inc(len(leases))
-        _VIEW_PATH["direct"].inc(len(leases))
         return leases
-
-    def _assert_stable(self) -> None:
-        """Donation-stability invariant behind the dlpack aliases (called
-        under the lock after every ``self.buf`` rebind): real hardware pins
-        the ring for the NIC, so a moved allocation is an emulation-breaking
-        event — fatal while aliased leases exist (their pointers now dangle),
-        a silent re-base when none do."""
-        if self._base_ptr is None:
-            return
-        now = self._ptr_of(self.buf)
-        if now == self._base_ptr:
-            return
-        if self._aliased:
-            raise RuntimeError(
-                f"HBM ring allocation moved ({self._base_ptr:#x} -> "
-                f"{now and hex(now)}) with {self._aliased} aliased lease(s) "
-                "outstanding — XLA stopped reusing the donated ring buffer; "
-                "set TPURPC_DLPACK_VIEW=0 on this backend")
-        self._base_ptr = now
 
     # -- consumer ------------------------------------------------------------
 
-    def view(self, off: int, n: int, dtype=np.uint8,
-             shape: Optional[tuple] = None) -> "HbmLease":
-        """Device view of a placed span; pins it until the lease is released.
-
-        Unwrapped spans on the CPU-backed platform come back as dlpack
-        ALIASES of ring memory (ledger: zero_copy, pointer-verified);
-        everything else is a device-side materialization (dma_d2d). Payload
-        bytes never return to the host either way, and the ledger records
-        which of the two actually happened for every message.
-        """
-        import jax.numpy as jnp
-
-        if n == 0:
-            dt = jnp.dtype(dtype)
-            empty = jnp.zeros((0,), dt).reshape(shape if shape is not None
-                                                else (0,))
-            return HbmLease(self, off, 0, empty)
-        with _lens.stage("hbm_view", n):
-            return self._view(off, n, dtype, shape)
-
-    def _view(self, off: int, n: int, dtype, shape) -> "HbmLease":
-        import jax.numpy as jnp
-
-        with self._lock:
-            if (off, n) not in self._live:
-                raise KeyError(f"span ({off}, {n}) not live")
-            self._live[(off, n)][0] += 1
-            # Everything between the count increment and the HbmLease
-            # hand-off must UNDO the increment on failure, or a poison
-            # view request (bad dtype/shape vs nbytes — wire-reachable
-            # through decode_tensor_to_ring's header) pins the span's
-            # credit forever with no lease anyone could release.
-            try:
-                p = off & self._mask
-                first = min(n, self.capacity - p)
-                if first >= n:  # unwrapped: the zero-copy aliasing path
-                    got = self._dlpack_view(p, n, dtype, shape)
-                    if got is not None:
-                        seg, is_alias = got
-                        if is_alias:
-                            self._aliased += 1
-                            ledger.zero_copy(n)
-                        else:  # backend copied on import: correct + billed
-                            ledger.dma_d2d(n)
-                        _VIEW_PATH["alias"].inc()
-                        return HbmLease(self, off, n, seg, aliased=is_alias)
-                    seg = self._slice(self.buf, p, n)
-                    _VIEW_PATH["slice"].inc()
-                elif self._pallas_ok(p, n, 9 * 512):
-                    # wrapped span: the fused gather (tpurpc.ops.ring_window;
-                    # compiled on TPU, interpreted on CPU) — ONE kernel/d2d
-                    # pass instead of slice+slice+concatenate
-                    from tpurpc.ops import ring_window
-
-                    seg = ring_window(self.buf, p, n,
-                                      interpret=self.device.platform == "cpu")
-                    _VIEW_PATH["window"].inc()
-                else:
-                    seg = jnp.concatenate(
-                        [self._slice(self.buf, p, first),
-                         self._slice(self.buf, 0, n - first)])
-                    _VIEW_PATH["concat"].inc()
-            except BaseException:
-                self._live[(off, n)][0] -= 1
-                self._advance_locked()  # cnt may now be 0 on a consumed span
-                raise
-        try:
-            dt = jnp.dtype(dtype)
-            if dt != jnp.uint8 or shape is not None:
-                seg = self._shaped(seg, dt, None if shape is None
-                                   else tuple(shape))
-        except BaseException:
-            # failed shaping does NOT consume the span (another consumer may
-            # still take a correct view of it)
-            self._release(off, n, consumed=False)
-            raise
-        ledger.dma_d2d(n)  # slice materialization: a device copy, not an alias
-        return HbmLease(self, off, n, seg)
-
-    def _release(self, off: int, n: int, aliased: bool = False, *,
-                 consumed: bool = True) -> None:
-        """Return one lease's credit. ``consumed=False`` (internal, error
-        unwinding) decrements without marking the span consumed — a failed
-        view attempt must not let the head advance over bytes nobody read."""
+    def _release(self, off: int, n: int) -> None:
+        """Return one lease's credit."""
         if n == 0:
             return  # zero-size spans hold no credit (never entered _live)
         with self._lock:
-            if aliased:
-                self._aliased -= 1
-            entry = self._live[(off, n)]
-            entry[0] -= 1
-            if consumed:
-                entry[1] = True
-            if entry[0] > 0:
-                return
+            self._live[off][1] = True
             self._advance_locked()
 
     def _advance_locked(self) -> None:
-        """Advance head over every consumed (leased-and-released) prefix.
-        Caller holds ``self._lock``."""
+        """Advance head over every released prefix. Caller holds
+        ``self._lock``."""
         advanced = False
         while self._live:
-            first_key = min(self._live)
-            cnt, consumed = self._live[first_key]
-            if first_key[0] != self.head or cnt > 0 or not consumed:
+            off = next(iter(self._live))
+            n, released = self._live[off]
+            if not released:
                 break
-            del self._live[first_key]
-            self.head += first_key[1]
+            del self._live[off]
+            self.head = off + n
             advanced = True
         if advanced:
             self._space.notify_all()
@@ -659,81 +218,29 @@ class HbmRing:
                     "tail": self.tail, "live_spans": len(self._live),
                     "writable": self.writable()}
 
-    # -- rendezvous landing leases (tpurpc-express, ISSUE 9) ------------------
-
-    def lease_region(self, nbytes: int,
-                     timeout: Optional[float] = None) -> "HbmRegionLease":
-        """Reserve a ring span as a RENDEZVOUS LANDING REGION: the span is
-        claimed (credit held, placement deferred) and advertised to a bulk
-        sender; :meth:`HbmRegionLease.fill` later lands the payload with
-        exactly one h2d DMA + one in-ring landing write — the accelerator-
-        plane half of the peer-advertised landing region (the shm/verbs
-        pools play this role on the host planes). Release without fill
-        (peer death with the region claimed) returns the credit.
-
-        Blocks up to ``timeout`` for credit like :meth:`place`; raises
-        :class:`BufferError` when the ring cannot ever hold ``nbytes``."""
-        if nbytes <= 0:
-            raise ValueError("lease_region needs a positive size")
-        if nbytes > self.capacity:
-            raise BufferError(
-                f"payload {nbytes} exceeds ring capacity {self.capacity}")
-        with self._lock:
-            self._wait_credit(nbytes, timeout)
-            off = self.tail
-            self.tail += nbytes
-            self._live[(off, nbytes)] = [0, False]
-        return HbmRegionLease(self, off, nbytes)
-
-    def _fill_span(self, off: int, nbytes: int, payload) -> None:
-        """Land ``payload`` into a reserved span (lease_region's deferred
-        placement): ONE h2d transfer + the single landing write, same
-        discipline and ledger accounting as :meth:`place`."""
-        import jax
-
-        src = _u8(payload)
-        if src.nbytes != nbytes:
-            raise ValueError(f"fill of {src.nbytes} bytes into a "
-                             f"{nbytes}-byte lease")
-        with self._lock, _lens.stage("hbm", nbytes) as st:
-            st.copy = nbytes
-            if (off, nbytes) not in self._live:
-                raise KeyError(f"span ({off}, {nbytes}) not live")
-            p = off & self._mask
-            dev = jax.device_put(src, self.device)
-            ledger.dma_h2d(nbytes)
-            self._land(dev, p, nbytes)
-        _HBM_PLACE_MSGS.inc()
-        _HBM_PLACE_BYTES.inc(nbytes)
-
 
 class HbmLease:
-    """A device view pinning its ring span; release returns the credit.
+    """A landed device array holding its span of the window; release returns
+    the credit. The array is a snapshot and survives the release.
 
     ``release()`` is idempotent; dropping the lease without releasing leaks
     the span until process exit (deliberate: silent auto-free under GC
     pressure would make flow control nondeterministic — the reference's
     credits are explicit too, ``pair.cc:276-284``)."""
 
-    __slots__ = ("_ring", "_off", "_n", "array", "_released", "aliased")
+    __slots__ = ("_ring", "_off", "_n", "array", "_released")
 
-    def __init__(self, ring: HbmRing, off: int, n: int, array,
-                 aliased: bool = False):
+    def __init__(self, ring: HbmRing, off: int, n: int, array):
         self._ring = ring
         self._off = off
         self._n = n
         self.array = array
-        #: True when ``array`` ALIASES ring memory (dlpack view, ledger
-        #: zero_copy): valid only within the lease window — after release
-        #: the span may be overwritten in place under it. Copied views
-        #: (False) are snapshots and survive release.
-        self.aliased = aliased
         self._released = False
 
     def release(self) -> None:
         if not self._released:
             self._released = True
-            self._ring._release(self._off, self._n, self.aliased)
+            self._ring._release(self._off, self._n)
 
     def __enter__(self):
         return self.array
@@ -741,55 +248,3 @@ class HbmLease:
     def __exit__(self, *exc):
         self.release()
         return False
-
-
-class HbmRegionLease:
-    """A reserved-but-unfilled ring span advertised as a rendezvous landing
-    region (see :meth:`HbmRing.lease_region`).
-
-    Lifecycle mirrors the rendezvous protocol the ringcheck model proves:
-    claim (this object) → :meth:`fill` (the one-sided placement) →
-    :meth:`view` (zero-copy consumption) → :meth:`release`; release without
-    fill is the peer-death path and simply returns the credit."""
-
-    __slots__ = ("ring", "offset", "nbytes", "filled", "_released")
-
-    def __init__(self, ring: HbmRing, offset: int, nbytes: int):
-        self.ring = ring
-        self.offset = offset
-        self.nbytes = nbytes
-        self.filled = False
-        self._released = False
-
-    def fill(self, payload) -> None:
-        """Land the payload: one dma_h2d + one in-ring landing write (the
-        ledger's op counts assert the single-movement claim)."""
-        if self._released:
-            raise RuntimeError("lease already released")
-        self.ring._fill_span(self.offset, self.nbytes, payload)
-        self.filled = True
-
-    def view(self, dtype=np.uint8, shape: Optional[tuple] = None
-             ) -> HbmLease:
-        """Device view of the landed payload (dlpack alias on eligible
-        backends, ledger-billed either way). Only valid after fill."""
-        if not self.filled:
-            raise RuntimeError("view before fill: the landing write has "
-                               "not happened")
-        return self.ring.view(self.offset, self.nbytes, dtype=dtype,
-                              shape=shape)
-
-    def release(self) -> None:
-        """Return the span's credit (idempotent). An unfilled release is
-        the peer-death path: the span is marked consumed so the head can
-        advance over it."""
-        if self._released:
-            return
-        self._released = True
-        with self.ring._lock:
-            entry = self.ring._live.get((self.offset, self.nbytes))
-            if entry is None:
-                return
-            entry[1] = True  # consumed (possibly without any fill/view)
-            if entry[0] == 0:
-                self.ring._advance_locked()

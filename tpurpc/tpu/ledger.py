@@ -9,10 +9,9 @@ reports its copies here, so "zero-copy" is a measured claim, not a slogan:
                      assembly, codec copy=True, staging)
 * ``dma_h2d``      — host buffer → device memory (jax device_put of wire bytes)
 * ``dma_d2h``      — device memory → host buffer (serialize-from-device)
-* ``dma_d2d``      — device → device movement (ring in-place update, slice
-                     materialization in ``HbmRing.view`` — XLA's dynamic_slice
-                     produces a NEW buffer, which is a copy, and the ledger
-                     says so; see VERDICT r1 "the copy ledger lies")
+* ``dma_d2d``      — device → device movement (a copy between device
+                     buffers: an XLA slice produces a NEW buffer, and the
+                     ledger says so). The landing makes none
 * ``zero_copy``    — payload bytes delivered by aliasing (dlpack import of a
                      wire buffer): no bytes moved anywhere
 * ``rdma_write``   — one-sided rendezvous placement into a peer-advertised
