@@ -8,6 +8,7 @@ flight/watchdog evidence, and the TPU-plane halves (SerializeFromDevice
 into a window, descriptor-only codec)."""
 
 import gc
+import heapq
 import threading
 import time
 
@@ -108,6 +109,437 @@ def test_standing_doorbell_rings_on_alias_death():
     del body2
     gc.collect()
     lease.release()
+
+
+# ---------------------------------------------------------------------------
+# credit flow control (ISSUE 31): a full window waits for its own doorbell
+# ---------------------------------------------------------------------------
+
+_MSG = 60_000                    # one 64 KiB-class message
+_REGION = 65_536 + 64 + 16 + 8   # what the pool charges for its region
+_CREDIT_COUNTERS = ("rdv_fallbacks", "rdv_claims_refused",
+                    "rdv_credit_waits", "rdv_credit_expired")
+
+
+class _FakeTime:
+    """Stands in for the ``time`` module inside core/rendezvous.py, and for
+    the link's ``pump`` seam (the sender's nap): the clock moves only when
+    the sender sleeps, and the consumer's frees are events on it."""
+
+    TICK_NS = 50_000  # what a sleep(0) of the yield-poll costs
+
+    def __init__(self):
+        self.ns = 10 ** 12
+        self._due = []
+        self._n = 0
+
+    def monotonic_ns(self):
+        return self.ns
+
+    def monotonic(self):
+        return self.ns / 1e9
+
+    def ms(self):
+        return (self.ns - 10 ** 12) / 1e6
+
+    def after(self, ms, fn):
+        self._n += 1
+        heapq.heappush(self._due, (self.ns + int(ms * 1e6), self._n, fn))
+
+    def run_until(self, ns, pred=None):
+        while self._due and self._due[0][0] <= ns:
+            at, _, fn = heapq.heappop(self._due)
+            self.ns = max(self.ns, at)
+            fn()
+            if pred is not None and pred():
+                return
+        self.ns = max(self.ns, ns)
+
+    def sleep(self, s):
+        self.run_until(self.ns + max(int(s * 1e9), self.TICK_NS))
+
+    def pump(self, pred, deadline):
+        if not pred():
+            self.run_until(int(round(deadline * 1e9)), pred)
+
+
+class _CreditRig:
+    """Real ``RdvLink`` pairs back to back (control ops delivered
+    synchronously) over one ``LandingPool`` of four regions, on a fake
+    clock. The consumer keeps each message for ``residence(seq)`` fake ms
+    (None: until ``free()``); a send that falls back is delivered by a
+    framed stand-in, so ``got`` is what the stream layer would see."""
+
+    def __init__(self, monkeypatch, regions=4):
+        from tpurpc.obs import metrics as _metrics
+
+        self.clock = _FakeTime()
+        monkeypatch.setattr(rdv, "time", self.clock)
+        self.pool = rdv.LandingPool("local", budget=regions * _REGION)
+        monkeypatch.setattr(rdv, "_pools", {"local": self.pool})
+        self._metrics = _metrics.registry().metrics()
+        self.got = []         # sequence numbers, in delivery order
+        self.held = []        # [seq, body] the consumer has not let go of
+        self.residence = lambda seq: 0.0
+        self.links = []
+        self.a, self.b = self.pair()
+        self.seq = 0
+        self.base = self.counters()
+
+    def pair(self):
+        ends = {}
+        a = rdv.RdvLink("a", lambda *op: ends["b"].on_op(*op),
+                        lambda *m: None, pool_kinds=("local",),
+                        open_kinds=("local",), pump=self.clock.pump)
+        b = rdv.RdvLink("b", lambda *op: ends["a"].on_op(*op),
+                        self.deliver, pool_kinds=("local",),
+                        open_kinds=("local",))
+        ends["a"], ends["b"] = a, b
+        a.negotiated = b.negotiated = True
+        self.links += [a, b]
+        return a, b
+
+    def deliver(self, stream_id, flags, body):
+        seq = int.from_bytes(bytes(body[:8]), "little")
+        assert bytes(body[8:16]) == bytes([seq % 251]) * 8
+        self.got.append(seq)
+        cell = [seq, body]
+        del body
+        self.held.append(cell)
+        ms = self.residence(seq)
+        if ms is not None:
+            self.clock.after(ms, lambda: self.free(cell))
+
+    def free(self, cell=None):
+        """Let go of one message (the oldest held, unless told which)."""
+        cell = self.held[0] if cell is None else cell
+        if cell in self.held:
+            self.held.remove(cell)
+            cell[1] = None  # the wrapper's last alias: rings the doorbell
+
+    def next_payload(self):
+        seq, self.seq = self.seq, self.seq + 1
+        return seq, (seq.to_bytes(8, "little")
+                     + bytes([seq % 251]) * (_MSG - 8))
+
+    def send(self, link=None):
+        """One message; True if it went one-sided."""
+        _, payload = self.next_payload()
+        ok = (link or self.a).send_message(1, 0, [payload], _MSG)
+        if not ok:
+            self.deliver(1, 0, memoryview(bytearray(payload)))
+        return ok
+
+    def counters(self):
+        return {k: self._metrics[k].snapshot() for k in _CREDIT_COUNTERS}
+
+    def moved(self):
+        now = self.counters()
+        return {k.replace("rdv_", ""): now[k] - self.base[k]
+                for k in _CREDIT_COUNTERS}
+
+    def warm(self):
+        """Five messages to a consumer that lets go at once: the link ends
+        with ``_PREGRANT_DEPTH`` standing regions, all free, the pool with
+        nothing, and no residence has been measured (every free was found
+        at a send's first look)."""
+        for _ in range(5):
+            assert self.send()
+            self.clock.sleep(0)
+        assert self.pool.lease(_MSG, 0) is None
+        assert len(self.a._grants[65_536]) == rdv._PREGRANT_DEPTH
+        assert not self.a._residence
+        self.base = self.counters()
+
+    def learn(self, ms=1.0):
+        """Fill the window for a consumer that lets go ``ms`` later and
+        send once more: the yield-poll watches the doorbells ring, which
+        is one measurement."""
+        self.residence = lambda seq: ms
+        for _ in range(5):
+            assert self.send()
+        assert self.a._residence[65_536].armed
+        self.clock.sleep(ms / 1e3)
+        self.base = self.counters()
+
+    def fill(self):
+        for _ in range(rdv._PREGRANT_DEPTH):
+            assert self.send()
+
+    def close(self):
+        self.held.clear()
+        for link in self.links:
+            link.close()
+        self.pool.trim()
+
+
+@pytest.fixture
+def credit_rig(monkeypatch):
+    rig = _CreditRig(monkeypatch)
+    yield rig
+    rig.close()
+    rdv.window_share().drain()
+
+
+def _credit_no_history(rig):
+    rig.warm()
+    rig.residence = lambda seq: None
+    rig.fill()
+    t0 = rig.clock.ms()
+    assert not rig.send()                      # the parent's path, at once
+    assert rig.clock.ms() - t0 < 2.2           # the 2 ms yield-poll only
+    assert rig.moved() == {"fallbacks": 1, "claims_refused": 1,
+                           "credit_waits": 0, "credit_expired": 0}
+
+
+def _credit_free_inside_estimate(rig):
+    rig.warm()
+    rig.learn(1.0)
+    est = rig.a._residence[65_536]
+    assert 0.9e6 <= est.mean_ns <= 1.2e6 and est.bound_ns() >= 2.9e6
+    rig.residence = lambda seq: 2.5    # past the poll, inside the bound
+    rig.fill()
+    t0 = rig.clock.ms()
+    assert rig.send()                          # waited, then one-sided
+    assert 2.4 <= rig.clock.ms() - t0 <= 2.8
+    assert rig.moved() == {"fallbacks": 0, "claims_refused": 1,
+                           "credit_waits": 1, "credit_expired": 0}
+    assert est.mean_ns > 1.1e6                 # the wait fed the estimate
+
+
+def _credit_steady_consumer(rig):
+    import random
+
+    rig.warm()
+    rng = random.Random(31)
+    rig.residence = lambda seq: rng.uniform(40.0, 60.0)
+    first_wait = None
+    for _ in range(400):
+        rig.send()
+        if first_wait is None and rig.moved()["credit_waits"]:
+            first_wait = rig.moved()
+    end = rig.moved()
+    # until a doorbell rings inside a yield-poll there is no history and
+    # the sender falls back, as the parent does; from the first wait on it
+    # pays neither the copy nor an expiry
+    assert first_wait is not None and first_wait["fallbacks"] < 100
+    assert end["fallbacks"] == first_wait["fallbacks"]
+    assert end["credit_expired"] == 0
+    assert end["credit_waits"] > 250
+    # and a refusal stands for a residence bound: the receiver is asked
+    # again once a window's turn, not once a message
+    asked = end["claims_refused"] - first_wait["claims_refused"]
+    assert 20 < asked < end["credit_waits"] // 3
+    est = rig.a._residence[65_536]
+    assert 40e6 <= est.mean_ns <= 70e6 and est.bound_ns() <= 120e6
+    assert rig.got == list(range(rig.seq))
+
+
+def _credit_consumer_stops(rig):
+    rig.warm()
+    rig.learn(1.0)
+    bound_ms = rig.a._residence[65_536].bound_ns() / 1e6
+    rig.residence = lambda seq: None           # keeps everything
+    rig.fill()
+    t0 = rig.clock.ms()
+    assert not rig.send()                      # expired: framed, once
+    assert rig.clock.ms() - t0 <= bound_ms + 0.1
+    assert rig.moved() == {"fallbacks": 1, "claims_refused": 1,
+                           "credit_waits": 1, "credit_expired": 1}
+    assert not rig.a._residence[65_536].armed
+    for n in (2, 3):                           # disarmed: no second wait
+        t0 = rig.clock.ms()
+        assert not rig.send()
+        assert rig.clock.ms() - t0 < 2.2
+        assert rig.moved() == {"fallbacks": n, "claims_refused": n,
+                               "credit_waits": 1, "credit_expired": 1}
+    rig.free()                                 # a doorbell rings: re-armed
+    assert rig.send()
+    assert rig.a._residence[65_536].armed
+    assert not rig.send()                      # waits again, expires again
+    # ... without asking again: the refusal of a moment ago still stands
+    assert rig.moved() == {"fallbacks": 4, "claims_refused": 3,
+                           "credit_waits": 2, "credit_expired": 2}
+    assert rig.got == list(range(rig.seq))
+
+
+def _credit_retaining_consumer(rig):
+    """A handler that gathers six messages (a window and a half) before it
+    lets any go, so the fifth always expires: every message arrives, in
+    order, and the bound settles. Fed the residences as read, which hold
+    the sender's own expired wait, it grows 1.75x a batch without end."""
+    rig.warm()
+    rig.learn(1.0)
+
+    def gather(seq):
+        if len(rig.held) == 6:
+            rig.clock.after(0.5, lambda: [rig.free() for _ in range(6)])
+        return None
+
+    rig.residence = gather
+    bounds = []
+    for _batch in range(40):
+        for _ in range(6):
+            rig.send()
+        bounds.append(rig.a._residence[65_536].bound_ns())
+    assert rig.got == list(range(rig.seq))
+    moved = rig.moved()
+    assert moved["credit_expired"] == 40 and moved["fallbacks"] == 80
+    assert max(bounds[20:]) <= max(bounds[:20]) <= 4 * bounds[0]
+
+
+def _credit_cut(rig, how):
+    rig.warm()
+    rig.learn(1.0)
+    rig.residence = lambda seq: None
+    rig.fill()
+    t0 = rig.clock.ms()
+    stopped = []
+    kw = {}
+    if how == "closed":
+        rig.clock.after(2.3, rig.a.close)
+    elif how == "stopped":
+        rig.clock.after(2.3, lambda: stopped.append(1))
+        kw["should_stop"] = lambda: bool(stopped)
+    else:
+        kw["deadline"] = rig.clock.monotonic() + 0.0023
+    seq, payload = rig.next_payload()
+    if how == "closed":
+        # the framed path is next, where the dead transport raises
+        assert not rig.a.send_message(1, 0, [payload], _MSG, **kw)
+    else:
+        with pytest.raises(rdv.SendAbandoned):
+            rig.a.send_message(1, 0, [payload], _MSG, **kw)
+    assert 2.25 <= rig.clock.ms() - t0 <= 2.45   # not the bound's 3 ms
+    moved = rig.moved()
+    assert moved["credit_waits"] == 1 and moved["credit_expired"] == 0
+    assert moved["fallbacks"] == (1 if how == "closed" else 0)
+    assert seq not in rig.got                    # no copy was started
+
+
+def _credit_no_standing_region(rig):
+    """A second link on the exhausted pool (the ninth connection of eight)
+    holds no standing region: refused, it falls back, whatever the first
+    link has learnt."""
+    rig.warm()
+    rig.learn(1.0)
+    a2, _b2 = rig.pair()
+    rig.residence = lambda seq: None
+    t0 = rig.clock.ms()
+    assert not rig.send(a2)
+    assert rig.clock.ms() == t0                  # no poll, no wait
+    assert rig.moved() == {"fallbacks": 1, "claims_refused": 1,
+                           "credit_waits": 0, "credit_expired": 0}
+
+
+def _credit_non_view_domain(rig, monkeypatch):
+    """A domain whose window has no host view cannot read a doorbell:
+    ``_standing_free`` answers False, nothing is ever measured, and the
+    refused sender takes the parent's path."""
+    rig.warm()
+
+    class _Blind:
+        view = None
+
+        def __init__(self, win):
+            self.write = lambda off, data: win.view.__setitem__(
+                slice(off, off + len(data)), data)
+
+    real = rig.a._window_for
+    monkeypatch.setattr(rig.a, "_window_for", lambda c: _Blind(real(c)))
+    rig.residence = lambda seq: 1.0
+    for _ in range(6):
+        assert not rig.send()
+    assert not rig.a._residence
+    assert rig.moved() == {"fallbacks": 6, "claims_refused": 6,
+                           "credit_waits": 0, "credit_expired": 0}
+
+
+@pytest.mark.parametrize("case", [
+    "no_history", "free_inside_estimate", "steady_consumer_40_to_60_ms",
+    "consumer_stops_then_frees", "retaining_consumer", "link_closed",
+    "should_stop", "deadline", "no_standing_region", "non_view_domain"])
+def test_full_window_waits_for_its_credit(credit_rig, monkeypatch, case):
+    {"no_history": _credit_no_history,
+     "free_inside_estimate": _credit_free_inside_estimate,
+     "steady_consumer_40_to_60_ms": _credit_steady_consumer,
+     "consumer_stops_then_frees": _credit_consumer_stops,
+     "retaining_consumer": _credit_retaining_consumer,
+     "link_closed": lambda rig: _credit_cut(rig, "closed"),
+     "should_stop": lambda rig: _credit_cut(rig, "stopped"),
+     "deadline": lambda rig: _credit_cut(rig, "deadline"),
+     "no_standing_region": _credit_no_standing_region,
+     "non_view_domain": lambda rig: _credit_non_view_domain(
+         rig, monkeypatch)}[case](credit_rig)
+
+
+def test_fan_in_over_a_small_pool_stays_on_rendezvous(fresh_config):
+    """``stream4m_c8`` at KiB size: eight connections stream messages over
+    the size bar to a slow handler, and the landing pool holds one region
+    fewer than 8 x ``_PREGRANT_DEPTH``, so it is held whole by standing
+    leases and every OFFER of a full window is refused. Everything arrives,
+    in order, and once a link has seen a doorbell ring it waits for the
+    next instead of copying the message through the framed ring."""
+    _reset_platform(fresh_config, "TCP")
+    conns, msgs, nbytes = 8, 60, 300 * 1024     # the 512 KiB class
+    region = 512 * 1024 + 64 + 16 + 8
+    fresh_config.setenv("TPURPC_RENDEZVOUS_POOL_MB", "16")
+    assert (16 << 20) // region == conns * rdv._PREGRANT_DEPTH - 1
+    old_pools = dict(rdv._pools)
+    rdv._pools.clear()
+    from tpurpc.obs import metrics as _metrics
+    from tpurpc.rpc.channel import Channel
+    from tpurpc.rpc.server import Server, stream_unary_rpc_method_handler
+
+    def sink(req_iter, ctx):
+        conn, want = None, 0
+        for m in req_iter:
+            head = bytes(m[:16])
+            c, seq = (int.from_bytes(head[:8], "little"),
+                      int.from_bytes(head[8:], "little"))
+            assert len(m) == nbytes and seq == want and conn in (None, c)
+            conn, want = c, want + 1
+            del m
+            time.sleep(0.004)
+        return want.to_bytes(8, "little")
+
+    srv = Server(max_workers=conns + 2, native_dataplane=False)
+    srv.add_method("/rdv.S/Sink", stream_unary_rpc_method_handler(sink))
+    port = srv.add_insecure_port("127.0.0.1:0")
+    srv.start()
+    counters = _metrics.registry().metrics()
+    names = ("rdv_fallbacks", "rdv_credit_waits", "rdv_credit_expired",
+             "rdv_transfers_sent")
+    before = {k: counters[k].snapshot() for k in names}
+    got, errors = {}, []
+
+    def client(c):
+        try:
+            with Channel(f"127.0.0.1:{port}") as ch:
+                mc = ch.stream_unary("/rdv.S/Sink", tpurpc_native=False)
+                body = bytes([c]) * (nbytes - 16)
+                out = mc((c.to_bytes(8, "little") + s.to_bytes(8, "little")
+                          + body for s in range(msgs)), timeout=120)
+                got[c] = int.from_bytes(bytes(out), "little")
+        except Exception as exc:  # surfaced below, on the test's thread
+            errors.append(exc)
+
+    threads = [threading.Thread(target=client, args=(c,))
+               for c in range(conns)]
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+        assert not any(t.is_alive() for t in threads) and not errors, errors
+        assert got == {c: msgs for c in range(conns)}
+        d = {k: counters[k].snapshot() - before[k] for k in names}
+        assert d["rdv_credit_waits"] > 0
+        assert d["rdv_fallbacks"] <= conns * msgs // 4, d   # the parent: 30%
+    finally:
+        srv.stop(grace=1)
+        rdv._pools.clear()
+        rdv._pools.update(old_pools)
 
 
 # ---------------------------------------------------------------------------

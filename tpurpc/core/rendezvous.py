@@ -43,6 +43,29 @@ pre-registered-buffer discipline. Pre-granted transfers emit no flight
 events (edges, not traffic); solicited offers/claims/releases do, which is
 exactly the evidence the stall watchdog's ``rendezvous`` stage reads.
 
+Credit flow control (ISSUE 31): a link's standing regions ARE its credit
+window. A sender that finds them all still with the consumer and whose
+OFFER the receiver then refuses ("no more memory: what you hold is your
+window") waits for one of its own doorbells, as the framed ring's writer
+waits for ring credit, and places the message one-sided when it rings.
+How long is learnt, not configured: the link measures each class's
+RESIDENCE (a region's COMPLETE to the doorbell read that finds it free,
+taken only while the sender is watching) and waits for the oldest region
+until it has been out for the smoothed residence plus four deviations
+(``_Residence``). A refusal is believed for that same bound, so a full
+window asks the receiver again once a window's turn, not once a message.
+Degradation, never a hang: every wait is finite and
+ends in a path that existed before it. A link with no measured residence,
+or no standing region of the class, does not wait; a region that outlives
+its estimate (a consumer that retains more than a window, or stalled)
+sends this message framed and disarms the class until a doorbell is next
+seen to free; the link's close ends the wait into the framed path (where
+the dead transport raises); the call's deadline or the stream's end
+raises ``SendAbandoned`` and starts no copy for a call that is over. So a
+refused claim, a claim timeout, a write failure, an un-negotiated peer or
+a compressed payload still falls back to the framed path at once, and a
+full window falls back after at most one learnt residence.
+
 Lifetime/recycling: a delivered payload is a numpy wrapper over the landing
 region. Every downstream alias — codec decode views, 64B-aligned dlpack
 imports into jax.Arrays — transitively references the wrapper, so a
@@ -83,7 +106,7 @@ __all__ = [
     "LandingPool", "RegionLease", "RdvLink", "landing_pool",
     "link_for_endpoint", "enabled", "min_bytes", "size_class",
     "OP_OFFER", "OP_CLAIM", "OP_COMPLETE", "OP_RELEASE", "HELLO_PAYLOAD",
-    "BlockGrant", "GrantWriter",
+    "BlockGrant", "GrantWriter", "SendAbandoned",
 ]
 
 # tpurpc-lens: the one-sided bulk write is its own waterfall hop — the
@@ -93,6 +116,7 @@ _LENS_RDV_BYTES, _LENS_RDV_NS, _LENS_RDV_COPY = _lens.hop_counters(
 
 _LENS_STAGES = {
     "send_message": "rendezvous",
+    "_await_credit": "rdv_credit",
     "_rdv_write": "rendezvous",
     "rdv_claim": "rendezvous",
     "on_offer": "rendezvous",
@@ -115,6 +139,14 @@ _RDV_SENT_BYTES = _metrics.counter("rdv_bytes_sent")
 _RDV_RECV_BYTES = _metrics.counter("rdv_bytes_received")
 _RDV_FALLBACK = _metrics.counter("rdv_fallbacks")
 _RDV_REFUSED = _metrics.counter("rdv_claims_refused")
+#: the sender's credit wait (ISSUE 31): sends that waited for one of their
+#: own doorbells after a failed claim, the time they waited (the ``rdv_credit``
+#: lens hop's busy time under the name beside its siblings), and the waits
+#: that outlived the link's residence estimate and ended in the framed path
+#: (each also one of rdv_fallbacks)
+_RDV_CREDIT_WAITS = _metrics.counter("rdv_credit_waits")
+_RDV_CREDIT_WAIT_NS = _metrics.counter("rdv_credit_wait_ns")
+_RDV_CREDIT_EXPIRED = _metrics.counter("rdv_credit_expired")
 #: control ops that rode the FRAMED path (tpurpc-pulse: a descriptor-ring
 #: link in steady state holds this flat — the ctrlring smoke and bench's
 #: ctrl_wakeups_per_msg both read it as the zero-control-frames proof)
@@ -405,8 +437,12 @@ class LandingPool:
 
     def lease(self, nbytes: int, lease_id: int) -> Optional[RegionLease]:
         """A region of capacity ≥ ``nbytes``, or None when the budget is
-        exhausted (the claim is then refused and the sender falls back to
-        the framed path — degradation, never a deadlock)."""
+        exhausted. The claim is then refused, which tells the sender that
+        the standing regions it holds are its whole window: it waits for
+        one of their doorbells for as long as the link's measured residence
+        says one is due, and otherwise (none held, none ever seen to free,
+        or the wait outlived the estimate) falls back to the framed path —
+        degradation, never a deadlock."""
         cls = size_class(nbytes)
         with self._lock:
             zombies, self._zombies = self._zombies, []
@@ -552,7 +588,7 @@ class _Claim:
     steady-state transfer."""
 
     __slots__ = ("lease_id", "kind", "handle", "offset", "capacity",
-                 "nonce", "standing", "used", "inflight")
+                 "nonce", "standing", "used", "inflight", "done_ns")
 
     def __init__(self, lease_id, kind, handle, offset, capacity, nonce,
                  standing=False):
@@ -565,6 +601,10 @@ class _Claim:
         self.standing = standing
         self.used = 0
         self.inflight = False  # a sender thread owns this claim right now
+        #: monotonic_ns of the COMPLETE that handed the region's current
+        #: use to the consumer (0: not out, or not stamped): where its
+        #: residence is measured from
+        self.done_ns = 0
 
 
 def _unpack_claim(payload) -> Tuple[int, Optional[_Claim]]:
@@ -581,6 +621,44 @@ def _unpack_claim(payload) -> Tuple[int, Optional[_Claim]]:
     handle = buf[pos + klen:].decode()
     return req_id, _Claim(lease_id, kind, handle, offset, capacity, nonce,
                           standing=bool(standing))
+
+
+class SendAbandoned(Exception):
+    """``RdvLink.send_message`` gave a message up inside its credit wait
+    because the call that owns it ended (its deadline passed, or the
+    caller's stop condition held): nothing was sent, and the framed path
+    must not send it either. The caller's own error path takes over."""
+
+
+class _Residence:
+    """How long one link's standing regions of one size class stay with
+    the consumer, as the sender measures it: a smoothed mean and mean
+    deviation in ns, the retransmission timer's estimator (Jacobson and
+    Karels; RFC 6298 §2 with its gains 1/8 and 1/4, the first measurement
+    R seeding mean R and deviation R/2, and the doorbell read slice as the
+    clock granularity G). ``armed`` is False from an expired wait until a
+    doorbell of the class is next seen to free. ``refused_ns`` is when a
+    claim of the class last failed (0: never)."""
+
+    __slots__ = ("mean_ns", "dev_ns", "armed", "refused_ns")
+
+    def __init__(self, sample_ns: int):
+        self.mean_ns = sample_ns
+        self.dev_ns = sample_ns // 2
+        self.armed = True
+        self.refused_ns = 0
+
+    def feed(self, sample_ns: int) -> None:
+        self.dev_ns += (abs(sample_ns - self.mean_ns) - self.dev_ns) // 4
+        self.mean_ns += (sample_ns - self.mean_ns) // 8
+
+    def slice_ns(self) -> int:
+        """Sleep between two doorbell reads of a credit wait."""
+        return max(1, self.mean_ns // 8)
+
+    def bound_ns(self) -> int:
+        """A region out for longer than this is overdue."""
+        return self.mean_ns + max(self.slice_ns(), 4 * self.dev_ns)
 
 
 # ---------------------------------------------------------------------------
@@ -800,7 +878,7 @@ class RdvLink:
     _GUARDED_BY = {"_reqs": "_lock", "_grants": "_lock",
                    "_leases": "_lock", "_req_lease": "_lock",
                    "_pregrants_out": "_lock", "_windows": "_lock",
-                   "_window_order": "_lock"}
+                   "_window_order": "_lock", "_residence": "_lock"}
 
     def __init__(self, name: str,
                  send_op: Callable[[int, int, bytes], None],
@@ -839,6 +917,7 @@ class RdvLink:
         self._lease_ids = itertools.count(1)
         self._reqs: Dict[int, dict] = {}            # sender: req -> state
         self._grants: Dict[int, List[_Claim]] = {}  # sender: cls -> claims
+        self._residence: Dict[int, _Residence] = {}  # sender: cls -> est.
         self._leases: Dict[int, RegionLease] = {}   # receiver: id -> lease
         self._req_lease: Dict[int, int] = {}        # receiver: req -> lease
         self._pregrants_out: Dict[int, int] = {}    # receiver: cls -> count
@@ -898,11 +977,20 @@ class RdvLink:
                 and threading.get_ident() != self.disallowed_thread)
 
     def send_message(self, stream_id: int, flags: int,
-                     segs: Sequence, total: int) -> bool:
+                     segs: Sequence, total: int,
+                     deadline: Optional[float] = None,
+                     should_stop: Optional[Callable[[], bool]] = None
+                     ) -> bool:
         """Move one whole MESSAGE payload via rendezvous. True when the
         payload was placed and COMPLETE sent (the framed path must NOT also
-        send it); False to fall back to the framed path — refused claim,
-        timeout, write failure — never an exception for fallback cases."""
+        send it); False to fall back to the framed path — refused claim
+        with no credit worth waiting for, timeout, write failure — never an
+        exception for fallback cases.
+
+        ``deadline`` (a ``time.monotonic()`` instant) and ``should_stop``
+        are the owning call's end, where the caller has one; they bound
+        only the credit wait (``_await_credit``), which raises
+        :class:`SendAbandoned` when either cuts it."""
         cls = size_class(total)
         claim = self._take_grant(cls, total)
         if claim is None and self._has_standing(cls, total):
@@ -912,18 +1000,26 @@ class RdvLink:
             # yield-poll of the doorbells (draining our ctrl ring for
             # pregrant top-ups as we go) hands the core to the consumer
             # and almost always turns up a freed region in a few slices.
-            deadline = time.monotonic() + 0.002
+            poll_until = time.monotonic() + 0.002
             drain = self.ctrl_drain
-            while claim is None and time.monotonic() < deadline:
+            while claim is None and time.monotonic() < poll_until:
                 if drain is not None:
                     try:
                         drain()
                     except Exception:
                         drain = None
                 time.sleep(0)
-                claim = self._take_grant(cls, total)
+                claim = self._take_grant(cls, total, watching=True)
         if claim is None:
-            claim = self.rdv_claim(stream_id, total, cls)
+            if not self._refusal_stands(cls):
+                claim = self.rdv_claim(stream_id, total, cls)
+                if claim is None:
+                    self._claim_failed(cls)
+            if claim is None:
+                # no more memory: what this link holds is its window, and
+                # a full window waits for credit
+                claim = self._await_credit(cls, total, deadline,
+                                           should_stop)
         if claim is None:
             _RDV_FALLBACK.inc()
             return False
@@ -944,11 +1040,15 @@ class RdvLink:
         _RDV_SENT_BYTES.inc(total)
         return True
 
-    def _take_grant(self, cls: int, total: int) -> Optional[_Claim]:
+    def _take_grant(self, cls: int, total: int,
+                    watching: bool = False) -> Optional[_Claim]:
         """A usable cached grant: a one-shot claim is consumed; a STANDING
         claim is acquired (inflight flag) and reused only when its doorbell
         shows every previous delivery's aliases died — the zero-frame
-        steady-state path."""
+        steady-state path. ``watching``: this look follows, by no more than
+        a poll slice, one that found every region busy, so a region it
+        finds free has just come free and its residence is a measurement
+        (``_saw_free``)."""
         with self._lock:
             if self.closed:
                 return None
@@ -968,10 +1068,146 @@ class RdvLink:
                     continue
                 claim.inflight = True
             if self._standing_free(claim):
+                if claim.used:
+                    self._saw_free(cls, claim, watching)
                 return claim
             with self._lock:
                 claim.inflight = False
         return None
+
+    def _refusal_stands(self, cls: int) -> bool:
+        """Was this class's last claim refused so lately that asking again
+        would get the same answer? The pool gains room when a region comes
+        back, which takes a residence, so a refusal is believed for one
+        residence bound: a full window asks the receiver once a window's
+        turn, not once a message (each refused OFFER is the receiver's
+        interpreter, the fan-in's limit, taken from the very handlers the
+        sender waits for). Only where the link can wait instead: a class
+        with a measured residence, armed."""
+        with self._lock:
+            est = self._residence.get(cls)
+            return (est is not None and est.armed and est.refused_ns != 0
+                    and time.monotonic_ns() - est.refused_ns
+                    < est.bound_ns())
+
+    def _claim_failed(self, cls: int) -> None:
+        with self._lock:
+            est = self._residence.get(cls)
+            if est is not None:
+                est.refused_ns = time.monotonic_ns()
+
+    def _saw_free(self, cls: int, claim: _Claim, watching: bool) -> None:
+        """A region that was out has come back: the class's credit wait is
+        armed again, and where the sender was watching, the time since the
+        region's COMPLETE is one measurement of the class's residence. A
+        free found at a send's first look says only that the residence was
+        at most that long (an idle sender would read its own idle time),
+        so it is no measurement."""
+        with self._lock:
+            done, claim.done_ns = claim.done_ns, 0
+            est = self._residence.get(cls)
+            if est is not None:
+                est.armed = True
+            if watching and done:
+                sample = time.monotonic_ns() - done
+                if est is None:
+                    self._residence[cls] = _Residence(sample)
+                else:
+                    est.feed(sample)
+
+    def _await_credit(self, cls: int, total: int,
+                      deadline: Optional[float],
+                      should_stop: Optional[Callable[[], bool]]
+                      ) -> Optional[_Claim]:
+        """The window is full and the receiver has no more memory: wait for
+        one of this link's own doorbells, for as long as the link's history
+        says one is due. The OLDEST region out is waited for until it has
+        been out for the class's residence bound, measured from its own
+        COMPLETE; any region that frees meanwhile is taken.
+
+        None (the caller falls back to the framed path, as before this
+        wait existed) at once where there is nothing to expect: no
+        measured residence of the class, a class disarmed by an expired
+        wait, no stamped standing region out; when the link closes; and
+        when the oldest region outlives the bound, which also disarms the
+        class until a doorbell is next seen to free. Raises
+        :class:`SendAbandoned` when the caller's ``should_stop`` holds or
+        its ``deadline`` passes inside the wait."""
+        with self._lock:
+            est = self._residence.get(cls)
+            out = [c.done_ns for c in self._grants.get(cls) or ()
+                   if c.standing and c.capacity >= total and c.done_ns
+                   and not c.inflight]
+            if est is None or not est.armed or not out or self.closed:
+                return None
+            limit_ns = min(out) + est.bound_ns()
+            slice_ns = est.slice_ns()
+        _RDV_CREDIT_WAITS.inc()
+        deadline_ns = None if deadline is None else deadline * 1e9
+        drain = self.ctrl_drain
+
+        def over() -> bool:
+            return self.closed or (should_stop is not None
+                                   and should_stop())
+
+        t0_ns = time.monotonic_ns()
+        st = _lens.stage("rdv_credit", total).begin()
+        try:
+            while True:
+                if drain is not None:
+                    try:
+                        drain()
+                    except Exception:
+                        drain = None
+                claim = self._take_grant(cls, total, watching=True)
+                if claim is not None or self.closed:
+                    return claim
+                if should_stop is not None and should_stop():
+                    raise SendAbandoned("stream ended in the credit wait")
+                now_ns = time.monotonic_ns()
+                wake_ns = min(now_ns + slice_ns, limit_ns)
+                if deadline_ns is not None:
+                    if now_ns >= deadline_ns:
+                        raise SendAbandoned("deadline passed in the "
+                                            "credit wait")
+                    wake_ns = min(wake_ns, deadline_ns)
+                if now_ns >= limit_ns:
+                    self._credit_expired(cls, now_ns - t0_ns)
+                    return None
+                self._idle(over, wake_ns / 1e9)
+        finally:
+            _RDV_CREDIT_WAIT_NS.inc(st.end())
+
+    def _credit_expired(self, cls: int, waited_ns: int) -> None:
+        """The oldest region outlived its estimate: the consumer retains
+        more than a window, or has stalled. Disarm the class, and take the
+        time this sender just spent waiting out of the residence of every
+        region still out: the consumer may have been waiting for this very
+        message, and a residence that contained the sender's own stall
+        would raise the next bound by it, batch after batch."""
+        _RDV_CREDIT_EXPIRED.inc()
+        with self._lock:
+            est = self._residence.get(cls)
+            if est is not None:
+                est.armed = False
+            for c in self._grants.get(cls) or ():
+                if c.done_ns:
+                    c.done_ns += waited_ns
+
+    def _idle(self, pred: Callable[[], bool], deadline: float) -> None:
+        """Block until ``pred()`` holds or ``deadline`` (a
+        ``time.monotonic()`` instant) passes: pumping the transport where
+        the waiting sender must drive the reader itself, else on the
+        link's condition (``close`` and every CLAIM notify it)."""
+        if self._pump is not None:
+            self._pump(pred, deadline)
+            return
+        with self._cond:
+            while not pred():
+                remain = deadline - time.monotonic()
+                if remain <= 0:
+                    break
+                self._cond.wait(remain)
 
     def _has_standing(self, cls: int, total: int) -> bool:
         """Any STANDING cached grant big enough (busy or not) — the signal
@@ -1034,15 +1270,7 @@ class RdvLink:
         def pred() -> bool:
             return st["claim"] is not _SENTINEL_PENDING or self.closed
 
-        if self._pump is not None:
-            self._pump(pred, deadline)
-        else:
-            with self._cond:
-                while not pred():
-                    remain = deadline - time.monotonic()
-                    if remain <= 0:
-                        break
-                    self._cond.wait(remain)
+        self._idle(pred, deadline)
         with self._lock:
             self._reqs.pop(req, None)
             claim = st["claim"]
@@ -1144,6 +1372,8 @@ class RdvLink:
         with self._lock:
             claim.used += 1
             claim.inflight = False
+            if claim.standing:
+                claim.done_ns = time.monotonic_ns()
             # a view-backed (synchronous shm/local) landing write is
             # visible the moment it returns, so its COMPLETE may ride the
             # ring; an async domain's bytes are still in flight on the

@@ -135,6 +135,10 @@ HOPS: Tuple[Tuple[str, str], ...] = (
     ("d2h", "a reply's device leaves read back: every leaf's "
             "device-to-host transfer started, then each awaited (the wait "
             "covers what the device still had to finish for them)"),
+    # ISSUE 31: the sender role's credit wait, one op a send that waited
+    ("rdv_credit", "a refused sender waiting for one of its own standing "
+                   "regions' doorbells (RdvLink._await_credit; no op "
+                   "where a send never waited)"),
 )
 
 HOP_NAMES: Tuple[str, ...] = tuple(name for name, _ in HOPS)

@@ -1888,12 +1888,14 @@ class _MultiCallable:
                               channel=self._channel)
 
     def _send_one(self, conn: _Connection, st: _ClientStream, request,
-                  end_stream: bool) -> None:
+                  end_stream: bool,
+                  deadline: Optional[float] = None) -> None:
         try:
             flags = ((fr.FLAG_END_STREAM if end_stream else 0)
                      | self._channel._compress_flag)
             conn.writer.send(fr.MESSAGE, flags, st.stream_id,
-                             self._ser(request))
+                             self._ser(request), deadline=deadline,
+                             should_stop=lambda: st.done)
         except (EndpointError, OSError) as exc:
             raise RpcError(StatusCode.UNAVAILABLE,
                            f"transport failed: {exc}") from exc
@@ -1946,13 +1948,19 @@ class _MultiCallable:
             for request in request_iterator:
                 if st.done:
                     return  # server already terminated the call
-                self._send_one(conn, st, request, end_stream=False)
+                self._send_one(conn, st, request, end_stream=False,
+                               deadline=call._deadline)
             # Pure half-close marker, NOT an empty message (FLAG_NO_MESSAGE).
             conn.writer.send(fr.MESSAGE,
                              fr.FLAG_END_STREAM | fr.FLAG_NO_MESSAGE,
                              st.stream_id, b"")
         except (RpcError, EndpointError, OSError):
             pass  # reader thread surfaces the transport failure with a status
+        except _rdv.SendAbandoned:
+            # the call ended (terminated by the server, or past its
+            # deadline) while a message waited for rendezvous credit:
+            # whoever reads the call gets that status; nothing more to send
+            pass
         except Exception as exc:
             # The *user's* request iterator (or serializer) raised: terminate the
             # stream both ways or the call would hang until its deadline and the

@@ -26,7 +26,7 @@ convention); all other values are utf-8 text.
 from __future__ import annotations
 
 import struct
-from typing import List, Optional, Sequence, Tuple
+from typing import Callable, List, Optional, Sequence, Tuple
 
 from tpurpc.core.endpoint import Endpoint
 from tpurpc.obs import profiler as _profiler
@@ -342,13 +342,21 @@ class FrameWriter:
         self._flushing = False
 
     def send(self, ftype: int, flags: int, stream_id: int,
-             payload: "bytes | Sequence" = b"") -> None:
+             payload: "bytes | Sequence" = b"",
+             deadline: Optional[float] = None,
+             should_stop: Optional[Callable[[], bool]] = None) -> None:
         """Write one logical frame.
 
         MESSAGE payloads may be a gather list of buffers (the tensor codec's
         segment output) — they are fragmented and scatter-written with zero
         joins/copies; the endpoint's gather write (ring slice-send /
         ``sendmsg``) does the placement.
+
+        ``deadline`` (a ``time.monotonic()`` instant) and ``should_stop``
+        are the end of the call the MESSAGE belongs to, where the caller
+        has one: a bulk payload waiting for rendezvous credit gives up
+        there and ``rendezvous.SendAbandoned`` propagates; nothing of the
+        message was sent.
         """
         segs = ([memoryview(s).cast("B") for s in payload]
                 if isinstance(payload, (list, tuple)) else
@@ -361,7 +369,8 @@ class FrameWriter:
                 and rdv.eligible(total,
                                  flags_compressed=bool(
                                      flags & FLAG_COMPRESSED))
-                and rdv.send_message(stream_id, flags, segs, total)):
+                and rdv.send_message(stream_id, flags, segs, total,
+                                     deadline, should_stop)):
             return  # payload one-sided-written; COMPLETE already framed
         if ftype == MESSAGE and flags & FLAG_COMPRESSED:
             segs, total, did = _compress_segs(segs, total)

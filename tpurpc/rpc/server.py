@@ -1162,7 +1162,9 @@ class _ServerConnection:
                             fr.MESSAGE,
                             fr.FLAG_COMPRESSED if st.peer_compressed else 0,
                             st.stream_id,
-                            handler.response_serializer(response))
+                            handler.response_serializer(response),
+                            deadline=ctx._deadline,
+                            should_stop=ctx._cancelled.is_set)
                     finally:
                         st.stages.send_end(tx)
                 if ctx.is_active():
@@ -1202,6 +1204,12 @@ class _ServerConnection:
                 return code is StatusCode.OK
         except AbortError as exc:
             self._send_trailers(st, exc.code, exc.details, ctx._trailing)
+        except _rdv.SendAbandoned:
+            # a response's wait for rendezvous credit ended with the call:
+            # cancelled (nobody to tell), or past its deadline
+            if ctx.is_active():
+                self._send_trailers(st, StatusCode.DEADLINE_EXCEEDED,
+                                    "deadline exceeded", ctx._trailing)
         except (EndpointError, OSError):
             pass  # connection already gone
         except Exception as exc:  # handler bug → UNKNOWN, like grpcio
