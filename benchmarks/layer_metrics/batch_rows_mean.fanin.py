@@ -1,0 +1,11 @@
+"""Request rows in a dispatched batch, mean over the window
+(program_counter): ``batcher_rows`` / ``batcher_batches``. ``max_rows`` (8 in
+``fanin4m_c8``) is a full batch; pad rows are not counted. A program whose
+batcher counts neither gives nothing to read."""
+
+
+def read(run):
+    c = run["counters"]
+    if not c.get("batcher_batches"):
+        return None
+    return c.get("batcher_rows", 0) / c["batcher_batches"]

@@ -1,0 +1,14 @@
+"""The consumer's dispatch, ``fn(batch, rows)``, in us per batch
+(program_counter): ``lens_batch_run_busy_ns`` / ``lens_batch_run_ops``, hop
+``batch_run`` of ``tpurpc/obs/lens.py``. The cell's consumer returns nothing,
+so the result is ready when ``fn`` returns and nothing is read back.
+Asynchronous: not device time. On the batcher's thread, between the stack's
+dispatch and the wait for it; read it against ``batch_period_us``. A program
+without the hop gives nothing to read."""
+
+
+def read(run):
+    c = run["counters"]
+    if not c.get("lens_batch_run_ops"):
+        return None
+    return c.get("lens_batch_run_busy_ns", 0) / c["lens_batch_run_ops"] / 1e3

@@ -63,10 +63,15 @@ uint32_t ctrl_slots() {
   return (uint32_t)n;
 }
 
+// Four classes an octave (4, 5, 6, 7 x a power of two), as
+// core/rendezvous.py size_class: the two planes grant each other regions
+// and must agree on the class of a size.
 uint64_t size_class(uint64_t nbytes) {
   uint64_t c = kMinClass;
   while (c < nbytes) c <<= 1;
-  return c;
+  if (c == kMinClass) return c;
+  uint64_t step = c >> 3;
+  return c - step * ((c - nbytes) / step);
 }
 
 // -- little helpers ----------------------------------------------------------

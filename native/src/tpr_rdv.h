@@ -76,7 +76,7 @@ uint64_t pool_budget();         // TPURPC_RENDEZVOUS_POOL_MB (default 256) MiB
 double claim_timeout_s();       // TPURPC_RENDEZVOUS_CLAIM_TIMEOUT_S (5)
 bool ctrl_enabled();            // TPURPC_CTRL_RING (default on)
 uint32_t ctrl_slots();          // TPURPC_CTRL_RING_SLOTS (default 64, min 8)
-uint64_t size_class(uint64_t nbytes);  // pow2 >= nbytes, floor 64 KiB
+uint64_t size_class(uint64_t nbytes);  // 4 classes an octave, floor 64 KiB
 
 // -- process-global counters (the ledger the shim/tests read) ----------------
 // Indices are ABI for tpr_rdv_counters (native_client.py binds them).

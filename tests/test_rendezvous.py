@@ -39,10 +39,35 @@ def _reset_platform(monkeypatch, platform):
 # landing pool
 # ---------------------------------------------------------------------------
 
+@pytest.mark.parametrize("nbytes,cls", [
+    (1, 64 << 10), (64 << 10, 64 << 10),         # the floor
+    ((64 << 10) + 1, 80 << 10), (100_000, 112 << 10),
+    (128 << 10, 128 << 10),                      # a power of two is a class
+    ((4 << 20) + 183, 5 << 20),                  # a 4 MiB tensor + header
+    (5 << 20, 5 << 20), ((5 << 20) + 1, 6 << 20),
+    ((7 << 20) + 1, 8 << 20), ((8 << 20) + 1, 10 << 20),
+])
+def test_size_class_four_an_octave(nbytes, cls):
+    """A region is at most a quarter larger than its transfer, and a class
+    is its own class (grants are filed under their capacity)."""
+    assert rdv.size_class(nbytes) == cls
+    assert rdv.size_class(cls) == cls
+    assert cls % 64 == 0 and cls < nbytes * 1.25 + (64 << 10)
+
+
+def test_default_budget_holds_a_window_for_eight_links():
+    """8 links x _PREGRANT_DEPTH standing regions of a 4 MiB tensor message
+    fit the default pool with regions to spare for one-shot claims: a
+    fan-in's late link finds memory (PERF.md 6, PR 33)."""
+    cls = rdv.size_class((4 << 20) + 183)
+    per_region = cls + 64 + 16 + 8
+    assert (256 << 20) // per_region >= 8 * rdv._PREGRANT_DEPTH + 8
+
+
 def test_pool_size_classes_and_alignment():
     pool = rdv.LandingPool("local")
     lease = pool.lease(100_000, 1)
-    assert lease.pr.capacity == 128 * 1024  # next pow2 ≥ 64 KiB floor
+    assert lease.pr.capacity == 112 * 1024  # its class, floor 64 KiB
     wrapper = lease.deliver(100_000)
     flat = np.frombuffer(wrapper, np.uint8)
     assert flat.ctypes.data % 64 == 0  # dlpack-aliasable landing span
@@ -67,7 +92,7 @@ def test_pool_recycles_only_after_last_alias_dies():
 
 
 def test_pool_budget_refuses_not_raises():
-    pool = rdv.LandingPool("local", budget=256 * 1024)
+    pool = rdv.LandingPool("local", budget=200 * 1024)  # one 112 KiB region
     l1 = pool.lease(100_000, 1)
     assert l1 is not None
     assert pool.lease(100_000, 2) is None  # over budget: refusal
@@ -258,7 +283,7 @@ class _CreditRig:
         self.residence = lambda seq: ms
         for _ in range(5):
             assert self.send()
-        assert self.a._residence[65_536].armed
+        assert 65_536 in self.a._residence
         self.clock.sleep(ms / 1e3)
         self.base = self.counters()
 
@@ -302,7 +327,8 @@ def _credit_free_inside_estimate(rig):
     t0 = rig.clock.ms()
     assert rig.send()                          # waited, then one-sided
     assert 2.4 <= rig.clock.ms() - t0 <= 2.8
-    assert rig.moved() == {"fallbacks": 0, "claims_refused": 1,
+    # ... without asking first: a full window is the link's credit
+    assert rig.moved() == {"fallbacks": 0, "claims_refused": 0,
                            "credit_waits": 1, "credit_expired": 0}
     assert est.mean_ns > 1.1e6                 # the wait fed the estimate
 
@@ -326,10 +352,9 @@ def _credit_steady_consumer(rig):
     assert end["fallbacks"] == first_wait["fallbacks"]
     assert end["credit_expired"] == 0
     assert end["credit_waits"] > 250
-    # and a refusal stands for a residence bound: the receiver is asked
-    # again once a window's turn, not once a message
-    asked = end["claims_refused"] - first_wait["claims_refused"]
-    assert 20 < asked < end["credit_waits"] // 3
+    # and a full window that knows its residence asks for nothing beyond
+    # itself: the receiver is not asked again
+    assert end["claims_refused"] == first_wait["claims_refused"]
     est = rig.a._residence[65_536]
     assert 40e6 <= est.mean_ns <= 70e6 and est.bound_ns() <= 120e6
     assert rig.got == list(range(rig.seq))
@@ -346,28 +371,27 @@ def _credit_consumer_stops(rig):
     assert rig.clock.ms() - t0 <= bound_ms + 0.1
     assert rig.moved() == {"fallbacks": 1, "claims_refused": 1,
                            "credit_waits": 1, "credit_expired": 1}
-    assert not rig.a._residence[65_536].armed
-    for n in (2, 3):                           # disarmed: no second wait
-        t0 = rig.clock.ms()
+    for n in (2, 3):                           # one message a bound, not a
+        t0 = rig.clock.ms()                    # flood: each waits again
         assert not rig.send()
-        assert rig.clock.ms() - t0 < 2.2
+        assert bound_ms - 0.1 <= rig.clock.ms() - t0 <= bound_ms + 2.2
+        # ... and asks once it has: an overdue window is when to ask
         assert rig.moved() == {"fallbacks": n, "claims_refused": n,
-                               "credit_waits": 1, "credit_expired": 1}
-    rig.free()                                 # a doorbell rings: re-armed
+                               "credit_waits": n, "credit_expired": n}
+    rig.free()                                 # a doorbell rings
     assert rig.send()
-    assert rig.a._residence[65_536].armed
     assert not rig.send()                      # waits again, expires again
-    # ... without asking again: the refusal of a moment ago still stands
-    assert rig.moved() == {"fallbacks": 4, "claims_refused": 3,
-                           "credit_waits": 2, "credit_expired": 2}
+    assert rig.moved() == {"fallbacks": 4, "claims_refused": 4,
+                           "credit_waits": 4, "credit_expired": 4}
     assert rig.got == list(range(rig.seq))
 
 
 def _credit_retaining_consumer(rig):
     """A handler that gathers six messages (a window and a half) before it
-    lets any go, so the fifth always expires: every message arrives, in
-    order, and the bound settles. Fed the residences as read, which hold
-    the sender's own expired wait, it grows 1.75x a batch without end."""
+    lets any go, so the fifth and the sixth each wait out a bound (one
+    message beyond the window a bound): every message arrives, in order,
+    and the bound settles. Fed the residences as read, which hold the
+    sender's own expired waits, it grows 1.75x a batch without end."""
     rig.warm()
     rig.learn(1.0)
 
@@ -384,7 +408,7 @@ def _credit_retaining_consumer(rig):
         bounds.append(rig.a._residence[65_536].bound_ns())
     assert rig.got == list(range(rig.seq))
     moved = rig.moved()
-    assert moved["credit_expired"] == 40 and moved["fallbacks"] == 80
+    assert moved["credit_expired"] == 80 and moved["fallbacks"] == 80
     assert max(bounds[20:]) <= max(bounds[:20]) <= 4 * bounds[0]
 
 
@@ -455,12 +479,65 @@ def _credit_non_view_domain(rig, monkeypatch):
                            "credit_waits": 0, "credit_expired": 0}
 
 
+def _credit_overdue_window_is_given_more(monkeypatch):
+    """A pool with room to spare (six regions, four of them the link's
+    window): the full window waits for its own credit first, and the
+    overdue one asks and is given a one-shot region, so the message still
+    goes one-sided."""
+    rig = _CreditRig(monkeypatch, regions=6)
+    try:
+        for _ in range(5):
+            assert rig.send()
+            rig.clock.sleep(0)
+        assert len(rig.a._grants[65_536]) == rdv._PREGRANT_DEPTH
+        rig.learn(1.0)
+        bound_ms = rig.a._residence[65_536].bound_ns() / 1e6
+        rig.residence = lambda seq: None       # keeps everything
+        rig.fill()
+        t0 = rig.clock.ms()
+        assert rig.send()                      # waited, asked, one-sided
+        assert 2.0 < rig.clock.ms() - t0 <= bound_ms + 0.1
+        assert rig.moved() == {"fallbacks": 0, "claims_refused": 0,
+                               "credit_waits": 1, "credit_expired": 1}
+        assert rig.got == list(range(rig.seq))
+    finally:
+        rig.close()
+
+
+def _credit_estimate_seeded_from_below(rig):
+    """No doorbell rings inside a yield-poll (the consumer lets go 31 ms
+    on, the sender looks for 2 ms of every 7): the longest a region was
+    SEEN out seeds the estimate, so the link leaves the framed path after
+    one lap of its window, and not never."""
+    rig.warm()
+    rig.residence = lambda seq: 31.0
+    rig.fill()
+    while not rig.a._residence:
+        rig.send()
+        rig.clock.sleep(0.005)
+        assert rig.seq < 20
+    framed = rig.moved()["fallbacks"]
+    assert 4 <= framed <= 6
+    est = rig.a._residence[65_536]
+    assert 20e6 <= est.mean_ns <= 31e6         # from below
+    for _ in range(12):
+        assert rig.send()                      # waits now, and is fed
+    moved = rig.moved()
+    assert moved["fallbacks"] == framed and moved["credit_expired"] == 0
+    assert moved["credit_waits"] >= 2 and moved["claims_refused"] == framed
+    assert rig.got == list(range(rig.seq))
+
+
 @pytest.mark.parametrize("case", [
+    "overdue_window_is_given_more", "estimate_seeded_from_below",
     "no_history", "free_inside_estimate", "steady_consumer_40_to_60_ms",
     "consumer_stops_then_frees", "retaining_consumer", "link_closed",
     "should_stop", "deadline", "no_standing_region", "non_view_domain"])
 def test_full_window_waits_for_its_credit(credit_rig, monkeypatch, case):
-    {"no_history": _credit_no_history,
+    {"overdue_window_is_given_more":
+        lambda rig: _credit_overdue_window_is_given_more(monkeypatch),
+     "estimate_seeded_from_below": _credit_estimate_seeded_from_below,
+     "no_history": _credit_no_history,
      "free_inside_estimate": _credit_free_inside_estimate,
      "steady_consumer_40_to_60_ms": _credit_steady_consumer,
      "consumer_stops_then_frees": _credit_consumer_stops,

@@ -44,22 +44,32 @@ events (edges, not traffic); solicited offers/claims/releases do, which is
 exactly the evidence the stall watchdog's ``rendezvous`` stage reads.
 
 Credit flow control (ISSUE 31): a link's standing regions ARE its credit
-window. A sender that finds them all still with the consumer and whose
-OFFER the receiver then refuses ("no more memory: what you hold is your
-window") waits for one of its own doorbells, as the framed ring's writer
-waits for ring credit, and places the message one-sided when it rings.
+window. A sender that holds a whole window (``_PREGRANT_DEPTH`` regions of
+the class) and finds them all still with the consumer waits for one of its
+own doorbells, as the framed ring's writer waits for ring credit, and
+places the message one-sided when it rings; it asks the receiver for a
+one-shot region beyond its window only where it has nothing to wait for
+or the wait has run out (ISSUE 33: under a fan-in every region asked for
+beyond a window is one more message parked in the receiver's queue, and
+the pool's spare regions are what a late link's window and an overdue
+wait's claim are made of). A link short of its window asks first, and
+waits when the receiver refuses ("no more memory: what you hold is your
+window").
 How long is learnt, not configured: the link measures each class's
 RESIDENCE (a region's COMPLETE to the doorbell read that finds it free,
 taken only while the sender is watching) and waits for the oldest region
 until it has been out for the smoothed residence plus four deviations
-(``_Residence``). A refusal is believed for that same bound, so a full
-window asks the receiver again once a window's turn, not once a message.
-Degradation, never a hang: every wait is finite and
-ends in a path that existed before it. A link with no measured residence,
-or no standing region of the class, does not wait; a region that outlives
-its estimate (a consumer that retains more than a window, or stalled)
-sends this message framed and disarms the class until a doorbell is next
-seen to free; the link's close ends the wait into the framed path (where
+(``_Residence``). A refusal is believed for that same bound, so a link
+short of its window asks the receiver again once a window's turn, not
+once a message.
+Degradation, never a hang: every wait is finite and ends in a path that
+existed before it. A link with no measured residence, or no standing
+region of the class, does not wait (the first estimate is seeded from
+below by the longest a region was seen out, ``_saw_free``); a region that
+outlives its estimate (a late consumer, one that retains more than a
+window, or a stalled one) sends this message by another path, a one-shot
+claim or framed, and the next message waits again: one message beyond the
+window a bound; the link's close ends the wait into the framed path (where
 the dead transport raises); the call's deadline or the stream's end
 raises ``SendAbandoned`` and starts no copy for a call that is over. So a
 refused claim, a claim timeout, a write failure, an un-negotiated peer or
@@ -232,16 +242,25 @@ def _claim_timeout() -> float:
 
 
 def size_class(nbytes: int) -> int:
-    """Round a transfer size up to its pool size class (power of two,
-    floor 64 KiB) — the granularity at which regions pool and pre-grants
-    match."""
+    """Round a transfer size up to its pool size class — the granularity
+    at which regions pool and pre-grants match. Four classes an octave
+    (4, 5, 6, 7 x a power of two, floor 64 KiB), so a region is at most a
+    quarter larger than its transfer: a tensor is a power of two of
+    payload plus a header, which whole octaves round up to twice its size
+    — half the pool's budget, and with it the standing window of every
+    second link of a fan-in (8 links x ``_PREGRANT_DEPTH`` of a 4 MiB
+    message wanted 32 regions of the 31 the default budget held at 8 MiB
+    each; it holds 51 at 5 MiB). A class is its own class."""
     if nbytes > _MAX_TRANSFER:
         raise ValueError(f"transfer of {nbytes} bytes exceeds the "
                          f"{_MAX_TRANSFER} rendezvous bound")
     c = _MIN_CLASS
     while c < nbytes:
         c <<= 1
-    return c
+    if c == _MIN_CLASS:
+        return c
+    step = c >> 3  # a quarter of the octave below c
+    return c - step * ((c - nbytes) // step)
 
 
 # ---------------------------------------------------------------------------
@@ -401,9 +420,9 @@ class LandingPool:
 
     Regions are allocated from the :class:`~tpurpc.core.pair.MemoryDomain`
     named by ``kind`` (shm for cross-process on one host, the pair's own
-    domain on ring planes, verbs on RDMA hardware), pooled by power-of-two
-    size class under a byte budget, and recycled only when provably
-    unobservable (see :meth:`RegionLease.deliver`)."""
+    domain on ring planes, verbs on RDMA hardware), pooled by size class
+    (:func:`size_class`) under a byte budget, and recycled only when
+    provably unobservable (see :meth:`RegionLease.deliver`)."""
 
     #: lint rule `lock`: the free lists, zombie quarantine and byte budget
     #: are shared between reader threads, finalizers and lease callers
@@ -588,7 +607,8 @@ class _Claim:
     steady-state transfer."""
 
     __slots__ = ("lease_id", "kind", "handle", "offset", "capacity",
-                 "nonce", "standing", "used", "inflight", "done_ns")
+                 "nonce", "standing", "used", "inflight", "done_ns",
+                 "busy_ns")
 
     def __init__(self, lease_id, kind, handle, offset, capacity, nonce,
                  standing=False):
@@ -605,6 +625,9 @@ class _Claim:
         #: use to the consumer (0: not out, or not stamped): where its
         #: residence is measured from
         self.done_ns = 0
+        #: monotonic_ns of the last look that found this use still with
+        #: the consumer (0: none yet)
+        self.busy_ns = 0
 
 
 def _unpack_claim(payload) -> Tuple[int, Optional[_Claim]]:
@@ -636,17 +659,16 @@ class _Residence:
     deviation in ns, the retransmission timer's estimator (Jacobson and
     Karels; RFC 6298 §2 with its gains 1/8 and 1/4, the first measurement
     R seeding mean R and deviation R/2, and the doorbell read slice as the
-    clock granularity G). ``armed`` is False from an expired wait until a
-    doorbell of the class is next seen to free. ``refused_ns`` is when a
-    claim of the class last failed (0: never)."""
+    clock granularity G). ``refused_ns`` is when a claim of the class last
+    failed, ``expired_ns`` when a wait for it last ran out (0: never)."""
 
-    __slots__ = ("mean_ns", "dev_ns", "armed", "refused_ns")
+    __slots__ = ("mean_ns", "dev_ns", "refused_ns", "expired_ns")
 
     def __init__(self, sample_ns: int):
         self.mean_ns = sample_ns
         self.dev_ns = sample_ns // 2
-        self.armed = True
         self.refused_ns = 0
+        self.expired_ns = 0
 
     def feed(self, sample_ns: int) -> None:
         self.dev_ns += (abs(sample_ns - self.mean_ns) - self.dev_ns) // 4
@@ -1010,14 +1032,24 @@ class RdvLink:
                         drain = None
                 time.sleep(0)
                 claim = self._take_grant(cls, total, watching=True)
+        waited = False
+        if claim is None and self._window_full(cls, total):
+            # a full standing window IS this link's credit: it waits for
+            # one of its own doorbells before it asks the receiver for
+            # memory beyond it (a one-shot region more is a message more
+            # parked in the receiver's queue, and the pool's spare regions
+            # are what a late link's window and an overdue wait's claim
+            # are made of)
+            claim = self._await_credit(cls, total, deadline, should_stop)
+            waited = True
         if claim is None:
             if not self._refusal_stands(cls):
                 claim = self.rdv_claim(stream_id, total, cls)
                 if claim is None:
                     self._claim_failed(cls)
-            if claim is None:
+            if claim is None and not waited:
                 # no more memory: what this link holds is its window, and
-                # a full window waits for credit
+                # a window waits for credit (once a message)
                 claim = self._await_credit(cls, total, deadline,
                                            should_stop)
         if claim is None:
@@ -1073,6 +1105,7 @@ class RdvLink:
                 return claim
             with self._lock:
                 claim.inflight = False
+                claim.busy_ns = time.monotonic_ns()
         return None
 
     def _refusal_stands(self, cls: int) -> bool:
@@ -1083,10 +1116,10 @@ class RdvLink:
         turn, not once a message (each refused OFFER is the receiver's
         interpreter, the fan-in's limit, taken from the very handlers the
         sender waits for). Only where the link can wait instead: a class
-        with a measured residence, armed."""
+        with a measured residence."""
         with self._lock:
             est = self._residence.get(cls)
-            return (est is not None and est.armed and est.refused_ns != 0
+            return (est is not None and est.refused_ns != 0
                     and time.monotonic_ns() - est.refused_ns
                     < est.bound_ns())
 
@@ -1097,40 +1130,51 @@ class RdvLink:
                 est.refused_ns = time.monotonic_ns()
 
     def _saw_free(self, cls: int, claim: _Claim, watching: bool) -> None:
-        """A region that was out has come back: the class's credit wait is
-        armed again, and where the sender was watching, the time since the
-        region's COMPLETE is one measurement of the class's residence. A
-        free found at a send's first look says only that the residence was
-        at most that long (an idle sender would read its own idle time),
-        so it is no measurement."""
+        """A region that was out has come back: where the sender was
+        watching, the time since the region's COMPLETE is one measurement
+        of the class's residence. A free found at a send's first look says
+        only that the residence was at most that long (an idle sender
+        would read its own idle time), so it is no measurement; where the
+        class has no estimate yet, the last look that found the region
+        still out seeds one, from below."""
         with self._lock:
             done, claim.done_ns = claim.done_ns, 0
+            busy, claim.busy_ns = claim.busy_ns, 0
             est = self._residence.get(cls)
-            if est is not None:
-                est.armed = True
             if watching and done:
                 sample = time.monotonic_ns() - done
                 if est is None:
                     self._residence[cls] = _Residence(sample)
                 else:
                     est.feed(sample)
+            elif est is None and busy > done > 0:
+                # no estimate yet, and nobody was watching: the region was
+                # last SEEN out ``busy - done`` after its COMPLETE, which
+                # its residence is at least. That seeds the estimate (the
+                # waits it arms are watched and correct it). Without it a
+                # link whose window filled before any doorbell rang inside
+                # a yield-poll sends framed, so it polls for 2 ms of every
+                # long framed send, sees no ring, and stays without an
+                # estimate: 150 to 280 framed messages on two to four
+                # links of eight in a fan-in (PERF.md 6, PR 33)
+                self._residence[cls] = _Residence(busy - done)
 
     def _await_credit(self, cls: int, total: int,
                       deadline: Optional[float],
                       should_stop: Optional[Callable[[], bool]]
                       ) -> Optional[_Claim]:
-        """The window is full and the receiver has no more memory: wait for
-        one of this link's own doorbells, for as long as the link's history
-        says one is due. The OLDEST region out is waited for until it has
-        been out for the class's residence bound, measured from its own
-        COMPLETE; any region that frees meanwhile is taken.
+        """The window is full (or short, and the receiver has no more
+        memory): wait for one of this link's own doorbells, for as long as
+        the link's history says one is due. The OLDEST region out is waited
+        for until it has been out for the class's residence bound, measured
+        from its own COMPLETE or from the class's last expired wait, if
+        that is later; any region that frees meanwhile is taken.
 
         None (the caller falls back to the framed path, as before this
         wait existed) at once where there is nothing to expect: no
-        measured residence of the class, a class disarmed by an expired
-        wait, no stamped standing region out; when the link closes; and
-        when the oldest region outlives the bound, which also disarms the
-        class until a doorbell is next seen to free. Raises
+        measured residence of the class, no stamped standing region out;
+        when the link closes; and when the oldest region outlives the
+        bound (``_credit_expired``). Raises
         :class:`SendAbandoned` when the caller's ``should_stop`` holds or
         its ``deadline`` passes inside the wait."""
         with self._lock:
@@ -1138,9 +1182,11 @@ class RdvLink:
             out = [c.done_ns for c in self._grants.get(cls) or ()
                    if c.standing and c.capacity >= total and c.done_ns
                    and not c.inflight]
-            if est is None or not est.armed or not out or self.closed:
+            if est is None or not out or self.closed:
                 return None
-            limit_ns = min(out) + est.bound_ns()
+            # after an expired wait, a bound from THAT: a window sends one
+            # message beyond itself a bound, not one a look
+            limit_ns = max(min(out), est.expired_ns) + est.bound_ns()
             slice_ns = est.slice_ns()
         _RDV_CREDIT_WAITS.inc()
         deadline_ns = None if deadline is None else deadline * 1e9
@@ -1179,17 +1225,24 @@ class RdvLink:
             _RDV_CREDIT_WAIT_NS.inc(st.end())
 
     def _credit_expired(self, cls: int, waited_ns: int) -> None:
-        """The oldest region outlived its estimate: the consumer retains
-        more than a window, or has stalled. Disarm the class, and take the
-        time this sender just spent waiting out of the residence of every
-        region still out: the consumer may have been waiting for this very
-        message, and a residence that contained the sender's own stall
-        would raise the next bound by it, batch after batch."""
+        """The oldest region outlived its estimate: the consumer is late
+        (a tail of its residence), retains more than a window, or has
+        stalled. THIS message goes by another path, which is what a
+        consumer that waits for it needs; the next one waits again, so a
+        full window sends one message beyond itself a bound and no more
+        (messages sent unwaited until a doorbell is next seen to free are,
+        under a fan-in, 10 to 20 framed ones an expired wait, each 15 MB
+        of host copies under the receiver's interpreter, which makes the
+        other links' regions late in their turn). The time this sender
+        just spent waiting is taken out of the residence of every region
+        still out: the consumer may have been waiting for this very
+        message, a residence that contained the sender's own stall would
+        raise the next bound by it, batch after batch."""
         _RDV_CREDIT_EXPIRED.inc()
         with self._lock:
             est = self._residence.get(cls)
             if est is not None:
-                est.armed = False
+                est.expired_ns = time.monotonic_ns()
             for c in self._grants.get(cls) or ():
                 if c.done_ns:
                     c.done_ns += waited_ns
@@ -1208,6 +1261,18 @@ class RdvLink:
                 if remain <= 0:
                     break
                 self._cond.wait(remain)
+
+    def _window_full(self, cls: int, total: int) -> bool:
+        """Does this link hold all the STANDING regions of the class that a
+        receiver grants one link (``_PREGRANT_DEPTH``)? Then they are its
+        credit window, and a send that finds them busy waits for one
+        (``_await_credit``) before it asks for a one-shot region beyond
+        them; a link still short of its window asks at once, which is how
+        it is topped up."""
+        with self._lock:
+            bucket = self._grants.get(cls) or ()
+            return sum(1 for c in bucket if c.standing
+                       and c.capacity >= total) >= _PREGRANT_DEPTH
 
     def _has_standing(self, cls: int, total: int) -> bool:
         """Any STANDING cached grant big enough (busy or not) — the signal

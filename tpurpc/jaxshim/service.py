@@ -17,14 +17,18 @@ replacement for "more pollers": one big matmul beats eight small ones.
 
 from __future__ import annotations
 
+import functools
+import itertools
 import threading
 import time
+from concurrent.futures import Future, InvalidStateError
 from typing import Any, Callable, Iterator, List, Optional, Sequence
 
 import numpy as np
 
 from tpurpc.jaxshim import codec
 from tpurpc.obs import flight as _flight
+from tpurpc.obs import lens as _lens
 from tpurpc.obs import metrics as _metrics
 from tpurpc.obs import profiler as _profiler
 from tpurpc.obs import tracing as _tracing
@@ -41,6 +45,7 @@ trace_jax = TraceFlag("jaxshim")
 _LENS_STAGES = {
     "_loop": "batcher",
     "_split_compatible": "batcher",
+    "_stack": "batcher",
     "_concat_pad": "batcher",
     "_complete_loop": "batcher",
     "_run": "device-dispatch",
@@ -85,16 +90,20 @@ def _device_decoder(ctx):
     """Per-call request decoder: device-ring placement when the transport is
     the TPU platform, host-aliasing decode otherwise.
 
-    Returns ``(decode(buf) -> tree, finish())``. Credit discipline: each
-    ``decode`` releases the PREVIOUS message's leases (the handler advancing
-    the request iterator means it is done with that message — the rolling
-    analog of the host ring's drain-then-credit, ``pair.cc:276-284``), and
-    ``finish`` releases the last message's when the handler returns
-    (SURVEY §7 hard-part #4: leases gate the ring's credit return)."""
+    Returns ``(decode(buf) -> tree, finish(), take() -> leases)``. Credit
+    discipline: each ``decode`` releases the PREVIOUS message's leases (the
+    handler advancing the request iterator means it is done with that
+    message — the rolling analog of the host ring's drain-then-credit,
+    ``pair.cc:276-284``), and ``finish`` releases the last message's when the
+    handler returns (SURVEY §7 hard-part #4: leases gate the ring's credit
+    return). A handler that is NOT done with a message when it asks for the
+    next (it gave the row to a batcher) calls ``take``: the leases of the
+    message it was just handed become the taker's to release, and neither
+    the rolling rule nor ``finish`` touches them."""
     ring = getattr(ctx, "device_ring", None)
     if ring is None:
         _DEVICE_DEGRADED.inc()
-        return codec.tree_deserializer, lambda: None
+        return codec.tree_deserializer, lambda: None, list
     from tpurpc.tpu.endpoint import decode_tree_to_ring
 
     held = []
@@ -112,7 +121,43 @@ def _device_decoder(ctx):
             lease.release()
         held.clear()
 
-    return decode, finish
+    def take():
+        leases = list(held)
+        held.clear()
+        return leases
+
+    return decode, finish, take
+
+
+class DeviceRequests:
+    """The request iterator a ``device=True`` stream handler is given.
+
+    Iterating it is what it always was: each ``next`` decodes one message
+    into the connection's device ring and returns the credit of the message
+    before. :meth:`take_leases` is for a handler that passes a message on
+    (to a :class:`FanInBatcher`, with ``submit(row, leases=...)``) and goes
+    on to the next while the first is still in use."""
+
+    __slots__ = ("_raws", "_decode", "_take")
+
+    def __init__(self, raws, decode, take):
+        self._raws = iter(raws)
+        self._decode = decode
+        self._take = take
+
+    def __iter__(self) -> "DeviceRequests":
+        return self
+
+    def __next__(self):
+        return self._decode(next(self._raws))
+
+    def take_leases(self) -> list:
+        """The leases (:class:`tpurpc.tpu.hbm_ring.HbmLease`) of the message
+        the last ``next`` returned: the caller's to release from now on,
+        each exactly once; the iterator's advance and the end of the call no
+        longer do. Empty where the message holds none (a call that arrived
+        without a device ring, or a second take)."""
+        return self._take()
 
 
 def add_tensor_method(server: Server, name: str,
@@ -129,7 +174,10 @@ def add_tensor_method(server: Server, name: str,
     With ``device=True`` and the TPU platform
     (``GRPC_PLATFORM_TYPE=RDMA_TPU``), request payloads are placed into the
     connection's HBM receive ring and ``fn`` gets lease-backed device arrays;
-    the leases (ring credit) are released when ``fn`` returns. Device arrays
+    the leases (ring credit) are released when ``fn`` returns; a
+    ``stream_stream`` ``fn`` is handed a :class:`DeviceRequests`, each
+    message's credit returned as it asks for the next unless it took the
+    message's leases over (``take_leases``). Device arrays
     in what ``fn`` returns (or yields) leave through
     :func:`tpurpc.tpu.serialize.tree_from_device`: every leaf's transfer to
     the host is started before any is awaited, each billed ``dma_d2h`` once,
@@ -174,7 +222,7 @@ def add_tensor_method(server: Server, name: str,
     _ident = lambda b: b  # noqa: E731 — already-encoded bytes pass through
     if kind == "unary_unary":
         def behavior(raw, ctx):
-            decode, finish = _device_decoder(ctx)
+            decode, finish, _ = _device_decoder(ctx)
             try:
                 return tree_from_device(fn(decode(raw)))
             finally:
@@ -183,7 +231,7 @@ def add_tensor_method(server: Server, name: str,
             behavior, codec.raw_view, _ident)
     elif kind == "unary_stream":
         def behavior(raw, ctx):
-            decode, finish = _device_decoder(ctx)
+            decode, finish, _ = _device_decoder(ctx)
             try:
                 for item in fn(decode(raw)):
                     yield tree_from_device(item)
@@ -193,9 +241,9 @@ def add_tensor_method(server: Server, name: str,
             behavior, codec.raw_view, _ident)
     elif kind == "stream_stream":
         def behavior(raw_iter, ctx):
-            decode, finish = _device_decoder(ctx)
+            decode, finish, take = _device_decoder(ctx)
             try:
-                for item in fn(decode(raw) for raw in raw_iter):
+                for item in fn(DeviceRequests(raw_iter, decode, take)):
                     yield tree_from_device(item)
             finally:
                 finish()
@@ -329,19 +377,72 @@ class _NativePipeline:
 # Fan-in batching (BASELINE config #4)
 # ---------------------------------------------------------------------------
 
-class _Pending:
-    __slots__ = ("tree", "event", "result", "error", "tctx", "t_enq")
+#: the name the stack program has in a device trace (``jit_<name>``): the
+#: benchmark's ``batch_stack_roofline`` finds its device time by it
+STACK_PROGRAM = "tpurpc_batch_stack"
 
-    def __init__(self, tree):
+
+@functools.lru_cache(maxsize=None)
+def _stack_program(lift: bool = False):
+    """The one program that gathers a batch of device-resident rows:
+    ``stack(*rows) -> batch``, every leaf concatenated along its leading
+    axis (``lift``: stacked along a new one, for rows that came without).
+    Its compiled shape is fixed by the shapes of its arguments, so a batcher
+    that always hands it ``bucket`` one-row arguments (requests first,
+    resident zero rows after) compiles it once whatever the occupancy.
+    Nothing is donated: the batch is a new buffer and aliases no row."""
+    import jax
+    import jax.numpy as jnp
+
+    gather = jnp.stack if lift else jnp.concatenate
+
+    def stack(*rows):
+        return jax.tree_util.tree_map(
+            lambda *xs: gather(xs, axis=0), *rows)
+
+    stack.__name__ = stack.__qualname__ = STACK_PROGRAM
+    return jax.jit(stack)
+
+
+def _nbytes(x) -> int:
+    n = getattr(x, "nbytes", None)
+    return int(n) if n is not None else np.asarray(x).nbytes
+
+
+class _Pending:
+    __slots__ = ("tree", "leases", "one_row", "future", "tctx", "t_enq")
+
+    def __init__(self, tree, leases=(), one_row=False):
         self.tree = tree
-        self.event = threading.Event()
-        self.result = None
-        self.error: Optional[Exception] = None
+        self.one_row = one_row
+        #: ring credit the row holds (``HbmLease``-like: ``release()``);
+        #: the batcher returns it once, when the row has been stacked or has
+        #: failed (:meth:`FanInBatcher._release`)
+        self.leases = tuple(leases)
+        self.future: Future = Future()
         #: tpurpc-scope: the calling RPC's trace context (captured from the
-        #: handler thread's ambient) + enqueue stamp — the batcher thread
-        #: turns them into "batch-wait"/"infer" spans per request
+        #: handler thread's ambient) — the batcher thread turns it and the
+        #: enqueue stamp into "batch-wait"/"infer" spans per request; the
+        #: stamp alone feeds the `batch_wait` hop, always
         self.tctx = _tracing.current() if _tracing.LIVE else None
-        self.t_enq = time.monotonic_ns() if self.tctx is not None else 0
+        self.t_enq = time.monotonic_ns()
+
+    def resolve(self, result) -> None:
+        try:
+            self.future.set_result(result)
+        except InvalidStateError:
+            pass  # the caller cancelled: nobody is waiting
+
+    def fail(self, error: BaseException) -> None:
+        try:
+            self.future.set_exception(error)
+        except InvalidStateError:
+            pass
+
+
+def _fail_all(batch: "Sequence[_Pending]", error: BaseException) -> None:
+    for p in batch:
+        p.fail(error)
 
 
 class FanInBatcher:
@@ -357,21 +458,91 @@ class FanInBatcher:
     as the reference's busy-poll timeout (``GRPC_RDMA_BUSY_POLLING_TIMEOUT_US``,
     README.md:17-25), applied at the request level instead of the byte level.
 
-    Reply delivery is a two-stage pipeline: the batcher thread only
-    *dispatches* the jitted call (XLA dispatch is async — it returns as soon
-    as the computation is enqueued on the device) and hands the in-flight
-    batch to a completion thread, which materializes the result to host in
-    ONE transfer per output leaf (``jax.device_get`` of the whole batch) and
-    splits replies as numpy views. Three properties of that pipeline:
+    One way in, :meth:`submit`: FIFO, non-blocking, returns a
+    ``concurrent.futures.Future`` of the request's share of ``fn``'s result.
+    ``batcher(tree)`` is ``submit(tree).result()``: a unary handler parks in
+    it as it always did. A stream handler that must not park (its thread is
+    what lands the connection's next message) submits the row **with its
+    leases** and goes on; what bounds the rows it can have landed and not
+    yet stacked is the connection's credit window, which the batcher gives
+    back (below). Order is kept: batches are cut from the queue in arrival
+    order, stacked and handed to ``fn`` one at a time by one thread, so row
+    ``k`` of a producer is in the batch of its row ``k + 1`` or an earlier
+    one, and before it within a batch.
+
+    Where the rows come from decides how they are gathered (hop
+    ``batch_stack``), and nothing else differs:
+
+    * **host leaves** (numpy views over the receive buffer: what every
+      method registered without ``device=True`` hands over): concatenated
+      and padded in numpy, shipped by ONE ``jax.device_put``;
+    * **device leaves** (the lease-backed arrays of
+      ``add_tensor_method(..., device=True)`` on ``RDMA_TPU``): ONE dispatch
+      of the stack program (:data:`STACK_PROGRAM`) on the device the leaves
+      are on, handed the requests' rows and then resident zero rows up to
+      the bucket. Each payload byte moves on the device once (ledger
+      ``dma_d2d``, the payload alone, once a batch); with one-row requests
+      and ``fixed_bucket`` the program has one compiled shape for every
+      occupancy, so nothing compiles after the first dispatch. On a v5e the
+      program gathers eight rows of 4 MiB at 80% of the HBM roofline
+      (``batch_stack_roofline.fanin``, PERF.md 6, PR 33);
+    * **a mix** (a TCP client reaching a ``device=True`` method beside
+      ``RDMA_TPU`` ones: ``tensor_device_degraded``): the host leaves are
+      landed on the batch's device by one ``device_put`` (``dma_h2d``), then
+      stacked there with the rest. Leaves on two different devices do not
+      batch: the later request fails alone, as a mis-shaped one does.
+      ``transfer_dtype`` applies to all-host batches only.
+
+    **Credit returns when the stack has run.** A batch whose rows came with
+    leases is awaited (``block_until_ready`` on the stacked batch, on the
+    batcher's thread) and every lease of its rows is released then, in queue
+    order, which is each ring's own order: a row's HBM is in use until the
+    copy is done, and the batch aliases nothing of a released row. The wait
+    comes AFTER ``fn`` has been dispatched on the batch: both of the
+    batcher's dispatches are made while the producers that wait for this
+    credit are still parked, and not in a queue for the interpreter behind
+    the eight threads the release wakes (on a v5e ``fn``'s dispatch took
+    6.7 ms after the release and takes 2.7 ms before it, PERF.md 6, PR 33).
+    A lease is released exactly once on every path: a row that cannot stack
+    fails alone and returns its credit at once, a stack or an ``fn`` that
+    raises returns the whole batch's, and ``close()`` serves what is queued
+    first. ``submit`` on a closed batcher raises and takes nothing: the
+    leases stay the caller's. A batch with no lease is not awaited.
+
+    **One row, no batch axis.** ``submit(tree, one_row=True)`` takes a
+    request as it landed, ``dtype[*shape]``, and the gather gives it its
+    leading axis (``jnp.stack`` where it would be ``jnp.concatenate``): a
+    stream handler then pays no dispatch of its own to make a row of a
+    message. Its share of ``fn``'s result comes back without the axis too.
+
+    **The row count.** With ``occupancy=True`` the consumer learns how many
+    leading rows of the padded batch are requests: ``fn(batch, rows)``,
+    ``rows`` an ``int32`` scalar resident on the batch's device (one of a
+    handful of constants placed there once, so nothing crosses from the host
+    per batch and the consumer's own program takes it as an operand). A
+    consumer that keeps state needs it: pad rows are zeros, not requests.
+
+    **The reply.** ``fn``'s result is split along the leading axis, each
+    request's rows to its future. Where the result has a ``jax.Array`` leaf
+    the batch goes to a completion thread, which materializes it to host in
+    ONE transfer per output leaf (``jax.device_get`` of the whole batch,
+    hop ``batch_d2h``) and splits replies as numpy views:
 
     * one d2h per batch, not one per request — splitting device arrays
       per-request would pay max_batch round trips;
-    * batch N+1's host-side stacking and device dispatch overlap batch N's
-      d2h (bounded depth, so backpressure still reaches callers);
+    * batch N+1's stacking and device dispatch overlap batch N's d2h
+      (bounded depth, so backpressure still reaches callers);
     * ``d2h_workers`` completion threads materialize different batches
       concurrently, so a latency-bound device→host hop stops bounding the
       batch rate. (The default of 4 was chosen on a link that no longer
-      exists; it has not been measured on the chip.)
+      exists; ``fanin4m_c8``'s consumer returns nothing, so the chip has not
+      judged it.)
+
+    Where it has none (``None``, or host leaves: an ingest consumer's count
+    a batch) no read-back is started and no completion thread touches the
+    batch: the futures resolve on the batcher's thread as soon as ``fn``
+    returns, and what ``fn`` left running on the device is ``fn``'s to
+    await.
     """
 
     #: lock map (lint rule `lock`) + shard contract (lint rule `shard`,
@@ -380,11 +551,12 @@ class FanInBatcher:
     #: confined to the device merger's declared ``_MERGE_BOUNDARY``
     _GUARDED_BY = {"_queue": "_lock", "_closed": "_lock"}
 
-    def __init__(self, fn: Callable[[Any], Any], max_batch: int = 8,
+    def __init__(self, fn: Callable[..., Any], max_batch: int = 8,
                  max_delay_s: float = 0.002, pad_to_bucket: bool = True,
                  fixed_bucket: bool = False, d2h_workers: int = 4,
                  transfer_dtype=None,
-                 inflight_fn: Optional[Callable[[], int]] = None):
+                 inflight_fn: Optional[Callable[[], int]] = None,
+                 occupancy: bool = False):
         #: depth-aware flush (ISSUE 3): a callable reporting how many
         #: requests are currently in flight at the transport (arrived or
         #: being read, response not yet finished — Server.inflight_requests).
@@ -405,6 +577,12 @@ class FanInBatcher:
         #: None = ship requests in their wire dtype.
         self.transfer_dtype = transfer_dtype
         self._fn = fn
+        self.occupancy = occupancy
+        #: resident constants of the device path, placed once each and kept:
+        #: zero rows by (device, signature, rows), occupancies by (device, n)
+        self._pads: dict = {}
+        self._occupancies: dict = {}
+        self._ordinals = itertools.count(1)  # a batch's `call` in its spans
         self.max_batch = max_batch
         self.max_delay_s = max_delay_s
         self.pad_to_bucket = pad_to_bucket
@@ -478,7 +656,7 @@ class FanInBatcher:
         # Shutdown race sweep: if the batcher thread outlived its join
         # timeout its final batch can land after the workers exited on
         # sentinels — fail those callers instead of stranding them on
-        # p.event forever. (A put racing this sweep is covered by the
+        # their futures forever. (A put racing this sweep is covered by the
         # _reaped check in the dispatch loop: either the sweep sees the
         # item, or the put times out and fails the batch itself.)
         while True:
@@ -486,24 +664,34 @@ class FanInBatcher:
                 item = self._inflight.get_nowait()
             except _queue.Empty:
                 break
-            if item is None:
-                continue
-            batch = item[0]
-            for p in batch:
-                p.error = RuntimeError("batcher closed")
-                p.event.set()
+            if item is not None:
+                _fail_all(item[0], RuntimeError("batcher closed"))
 
-    def __call__(self, tree: Any) -> Any:
-        p = _Pending(tree)
+    def submit(self, tree: Any, leases: Sequence = (),
+               one_row: bool = False) -> Future:
+        """Queue one request and return at once: a future of its rows of
+        ``fn``'s result. ``leases`` is the ring credit the request's arrays
+        hold (what :meth:`DeviceRequests.take_leases` returned): the
+        batcher's from here on, released when the row has been stacked (see
+        the class docstring). ``one_row``: the leaves are ONE row with no
+        batch axis. Raises ``RuntimeError`` on a closed batcher, the leases
+        then still the caller's."""
+        p = _Pending(tree, leases, one_row)
         with self._lock:
             if self._closed:
                 raise RuntimeError("batcher closed")
             self._queue.append(p)
-            self._kick.notify_all()
-        p.event.wait()
-        if p.error is not None:
-            raise p.error
-        return p.result
+            # wake the batcher's thread only where it has something to
+            # decide: a first row starts its timer, a full batch goes out,
+            # and the depth-aware flush looks at every arrival
+            n = len(self._queue)
+            if (n == 1 or n >= self.max_batch
+                    or self._inflight_fn is not None):
+                self._kick.notify_all()
+        return p.future
+
+    def __call__(self, tree: Any) -> Any:
+        return self.submit(tree).result()
 
     # -- batcher thread ------------------------------------------------------
 
@@ -567,40 +755,64 @@ class FanInBatcher:
         floor = min(self.max_batch, max(self._recent_batches, default=1))
         return q >= max(1, pending) and q >= floor
 
+    @staticmethod
+    def _release(batch: "Sequence[_Pending]") -> None:
+        """Return the credit of every row of ``batch``, in queue order. A
+        row's leases are dropped from it as they go, so that no second path
+        finds them."""
+        for p in batch:
+            leases, p.leases = p.leases, ()
+            for lease in leases:
+                lease.release()
+
     def _split_compatible(self, batch: List[_Pending]) -> List[_Pending]:
-        """Fail (individually) requests whose pytree structure or leaf
-        row-shape/dtype can't stack with the batch's first valid row —
-        one bad request must not poison its siblings' futures."""
+        """Fail (individually) requests whose pytree structure, leaf
+        row-shape/dtype or device can't stack with the batch's first valid
+        row — one bad request must not poison its siblings' futures. A row
+        that fails here is never stacked: its credit goes back at once."""
         import jax
 
         good: List[_Pending] = []
-        ref = None
+        ref = ref_dev = None
         for p in batch:
             err: Optional[Exception] = None
-            sig = None
+            sig = dev = None
             try:
                 leaves, td = jax.tree_util.tree_flatten(p.tree)
                 if not leaves:
                     raise ValueError("empty request tree")
                 for x in leaves:
-                    if np.ndim(x) < 1:
+                    if np.ndim(x) < 1 and not p.one_row:
                         raise ValueError(
                             "batched request leaves need a leading batch axis")
-                sig = (td, tuple((np.shape(x)[1:], np.dtype(
+                    if isinstance(x, jax.Array):
+                        if len(x.devices()) != 1 or dev not in (
+                                None, x.devices()):
+                            raise ValueError(
+                                "batched request leaves must sit on one "
+                                f"device, not {x.devices()} beside {dev}")
+                        dev = x.devices()
+                lead = 0 if p.one_row else 1
+                sig = (td, tuple((np.shape(x)[lead:], np.dtype(
                     getattr(x, "dtype", None) or np.asarray(x).dtype))
-                    for x in leaves))
+                    for x in leaves), p.one_row)
             except Exception as exc:
                 err = exc
-            if err is None:
-                if ref is None or sig == ref:
-                    ref = ref or sig
-                    good.append(p)
-                    continue
+            if err is None and ref is not None and sig != ref:
                 err = ValueError(
                     "request incompatible with batch: leaf shapes/dtypes "
-                    f"{sig[1]} vs {ref[1]} (or differing tree structure)")
-            p.error = err
-            p.event.set()
+                    f"{sig[1]} vs {ref[1]} (or differing tree structure, or "
+                    "one with a batch axis and one without)")
+            if err is None and None not in (dev, ref_dev) and dev != ref_dev:
+                err = ValueError(
+                    f"request incompatible with batch: its leaves are on "
+                    f"{dev}, the batch's on {ref_dev}")
+            if err is None:
+                ref, ref_dev = ref or sig, ref_dev or dev
+                good.append(p)
+                continue
+            self._release((p,))
+            p.fail(err)
         return good
 
     def _bucket(self, n: int) -> int:
@@ -614,42 +826,76 @@ class FanInBatcher:
         return min(b, self.max_batch)
 
     def _run(self, batch: List[_Pending]) -> None:
-        """Stage 1 (batcher thread): stack, pad, dispatch, enqueue in-flight.
+        """Stage 1 (batcher thread): stack, dispatch ``fn``, return the
+        rows' credit, and either resolve the futures (a result with no
+        device leaf) or enqueue the batch in flight.
 
-        Does NOT wait for the device: ``self._fn`` on a jitted function
-        returns after async dispatch, and materialization happens on the
-        completion thread so the next batch's stacking overlaps this batch's
-        device time + d2h."""
+        Does NOT wait for ``fn``'s work on the device: ``self._fn`` on a
+        jitted function returns after async dispatch, and materialization
+        happens on the completion thread so the next batch's stacking
+        overlaps this batch's device time + d2h. What it does wait for is
+        the stacked batch of rows that hold credit, AFTER ``fn`` has been
+        dispatched on it (see the class docstring)."""
         import jax
 
         batch = self._split_compatible(batch)
         if not batch:
             return
+        ordinal = next(self._ordinals)
         t_disp = time.monotonic_ns()
         for p in batch:
+            # enqueue → dispatch: one op of `batch_wait` a request, and its
+            # "batch-wait" span where the call is traced
+            _lens.account("batch_wait", t_disp - p.t_enq)
             if p.tctx is not None:
-                # enqueue → dispatch: the per-request "batch-wait" span
                 _tracing.record("batch-wait", p.tctx, p.t_enq,
                                 t_disp - p.t_enq)
+        lift = batch[0].one_row
+        leased = any(p.leases for p in batch)
+        rows = [p.tree for p in batch]
+        sizes = [1 if lift else np.shape(jax.tree_util.tree_leaves(t)[0])[0]
+                 for t in rows]
+        total = sum(sizes)
+        bucket = max(self._bucket(total), total)
         try:
-            rows = [p.tree for p in batch]
-            sizes = [jax.tree_util.tree_leaves(t)[0].shape[0] for t in rows]
-            total = sum(sizes)
-            bucket = max(self._bucket(total), total)
-            stacked = jax.tree_util.tree_map(
-                lambda *xs: self._concat_pad(xs, bucket), *rows)
-            out = self._fn(stacked)
-            # Start the d2h NOW (enqueued behind the compute, overlapping
-            # everything after it), so that readback latency is hidden
-            # rather than added to every batch. (Not measured on the chip.)
-            for leaf in jax.tree_util.tree_leaves(out):
-                hint = getattr(leaf, "copy_to_host_async", None)
-                if hint is not None:
-                    hint()
+            with _lens.stage("batch_stack", call=ordinal,
+                             seq=total) as stacking:
+                try:
+                    stacked, stacking.nbytes = self._stack(rows, sizes,
+                                                           bucket, lift)
+                    stacking.copy = stacking.nbytes
+                    del rows
+                    running = _lens.stage("batch_run", call=ordinal,
+                                          seq=total).begin()
+                    try:
+                        if self.occupancy:
+                            out = self._fn(stacked, self._resident_rows(
+                                stacked, total))
+                        else:
+                            out = self._fn(stacked)
+                        away = [leaf
+                                for leaf in jax.tree_util.tree_leaves(out)
+                                if isinstance(leaf, jax.Array)]
+                        # Start the d2h NOW (enqueued behind the compute),
+                        # so the completion thread waits for one thing, the
+                        # result on the host, and not for the compute and
+                        # then for a transfer it has yet to ask for
+                        for leaf in away:
+                            leaf.copy_to_host_async()
+                    finally:
+                        stacking.exclude(running.end())
+                        if leased:
+                            # a row's HBM is in use until the copy is done
+                            jax.block_until_ready(stacked)
+                finally:
+                    stacked = None
+                    self._release(batch)
         except Exception as e:  # deliver failure to every caller in the batch
-            for p in batch:
-                p.error = e
-                p.event.set()
+            _fail_all(batch, e)
+            return
+        if not away:
+            # nothing to read back: no completion thread touches the batch
+            self._deliver(batch, sizes, total, out, t_disp)
             return
         # Bounded-backpressure put that stays shutdown-safe: once close()
         # has reaped the completion workers (_reaped), nobody will ever
@@ -657,18 +903,14 @@ class FanInBatcher:
         # them behind a put that can no longer complete.
         import queue as _queue
 
-        def fail_batch(b):
-            for p in b:
-                p.error = RuntimeError("batcher closed")
-                p.event.set()
-
+        closed = RuntimeError("batcher closed")
         while True:
             if self._reaped:
-                fail_batch(batch)
+                _fail_all(batch, closed)
                 return
             try:
-                self._inflight.put((batch, sizes, total, out, t_disp),
-                                   timeout=0.25)
+                self._inflight.put(
+                    (batch, sizes, total, out, t_disp, ordinal), timeout=0.25)
                 break
             except _queue.Full:
                 continue
@@ -681,7 +923,7 @@ class FanInBatcher:
                 except _queue.Empty:
                     return
                 if item is not None:
-                    fail_batch(item[0])
+                    _fail_all(item[0], closed)
 
     def _complete_loop(self) -> None:
         """Stage 2: one whole-batch device→host transfer, numpy reply split."""
@@ -691,60 +933,130 @@ class FanInBatcher:
             item = self._inflight.get()
             if item is None:
                 return
-            batch, sizes, total, out, t_disp = item
+            batch, sizes, total, out, t_disp, ordinal = item
             try:
                 # ONE d2h per output leaf for the whole batch; per-request
                 # splits below are host views, free of device round trips
-                host = jax.device_get(out)
-                t_done = time.monotonic_ns()
-                for p in batch:
-                    if p.tctx is not None:
-                        # dispatch → materialized: the "infer" span (jitted
-                        # call + whole-batch d2h, shared by the batch)
-                        _tracing.record("infer", p.tctx, t_disp,
-                                        t_done - t_disp, rows=total)
-                _BATCHER_BATCHES.inc()
-                _BATCHER_ROWS.inc(total)
-                with self._lock:
-                    self.batches_run += 1
-                    self.rows_run += total
-                off = 0
-                for p, n in zip(batch, sizes):
-                    s = slice(off, off + n)
-                    p.result = jax.tree_util.tree_map(lambda x: x[s], host)
-                    off += n
-                    p.event.set()
+                with _lens.stage("batch_d2h", call=ordinal, seq=total) as st:
+                    host = jax.device_get(out)
+                    st.nbytes = sum(_nbytes(x) for x in
+                                    jax.tree_util.tree_leaves(host))
+                self._deliver(batch, sizes, total, host, t_disp)
             except Exception as e:
-                for p in batch:
-                    p.error = e
-                    p.event.set()
+                _fail_all(batch, e)
 
-    def _concat_pad(self, xs: Sequence, bucket: int):
+    def _deliver(self, batch: List[_Pending], sizes: List[int], total: int,
+                 host: Any, t_disp: int) -> None:
+        """Split a batch's host-side result along the leading axis, each
+        request's rows to its future (a ``one_row`` request's without the
+        axis)."""
         import jax
-        import jax.numpy as jnp
-        import numpy as np
 
-        # Requests arrive from the wire as HOST arrays: concat+pad in numpy
-        # and ship the batch in ONE h2d. An N-array device-side concatenate
-        # turns one bulk transfer into N small ones plus an extra device
-        # launch. (Not measured on the chip.)
-        if all(not isinstance(x, jax.Array) for x in xs):
-            cat = np.concatenate([np.asarray(x) for x in xs], axis=0)
-            if (self.transfer_dtype is not None
-                    and np.issubdtype(cat.dtype, np.floating)):
-                cat = cat.astype(self.transfer_dtype)  # halve h2d bytes
-            deficit = bucket - cat.shape[0]
-            if deficit > 0:
-                pad = [(0, deficit)] + [(0, 0)] * (cat.ndim - 1)
-                cat = np.pad(cat, pad)
-            return jax.device_put(cat)
-        # device-resident inputs (in-process callers): keep them on device
-        cat = jnp.concatenate([jnp.asarray(x) for x in xs], axis=0)
+        try:
+            t_done = time.monotonic_ns()
+            for p in batch:
+                if p.tctx is not None:
+                    # dispatch → materialized: the "infer" span (jitted
+                    # call + whole-batch d2h, shared by the batch)
+                    _tracing.record("infer", p.tctx, t_disp,
+                                    t_done - t_disp, rows=total)
+            _BATCHER_BATCHES.inc()
+            _BATCHER_ROWS.inc(total)
+            with self._lock:
+                self.batches_run += 1
+                self.rows_run += total
+            off = 0
+            for p, n in zip(batch, sizes):
+                s = off if p.one_row else slice(off, off + n)
+                p.resolve(jax.tree_util.tree_map(lambda x: x[s], host))
+                off += n
+        except Exception as e:
+            _fail_all(batch, e)
+
+    def _stack(self, rows: List[Any], sizes: List[int], bucket: int,
+               lift: bool = False):
+        """``(batch, payload bytes)``: the requests' rows gathered along the
+        leading axis (``lift``: along a new one) and padded with zero rows
+        up to ``bucket``, on a device. See the class docstring for the three
+        cases."""
+        import jax
+
+        from tpurpc.tpu import ledger
+
+        leaves = [x for t in rows for x in jax.tree_util.tree_leaves(t)]
+        payload = sum(_nbytes(x) for x in leaves)
+        on_device = [isinstance(x, jax.Array) for x in leaves]
+        if not any(on_device):
+            return jax.tree_util.tree_map(
+                lambda *xs: self._concat_pad(xs, bucket, lift), *rows), payload
+        (device,) = leaves[on_device.index(True)].devices()
+        if not all(on_device):
+            strays = [i for i, there in enumerate(on_device) if not there]
+            landed = jax.device_put([np.asarray(leaves[i]) for i in strays],
+                                    device)
+            ledger.dma_h2d(sum(x.nbytes for x in landed))
+            for i, x in zip(strays, landed):
+                leaves[i] = x
+            treedef = jax.tree_util.tree_structure(rows[0])
+            n = treedef.num_leaves
+            rows = [treedef.unflatten(leaves[k:k + n])
+                    for k in range(0, len(leaves), n)]
+        # pad with resident zero rows shaped like the first request (so that
+        # one-row requests always make `bucket` arguments of one shape) and
+        # one shorter remainder where its row count does not divide the gap
+        gap, unit = bucket - sum(sizes), sizes[0]
+        pads = [self._resident_zeros(rows[0], n, device, lift)
+                for n in [unit] * (gap // unit) + [gap % unit] if n]
+        batch = _stack_program(lift)(*rows, *pads)
+        ledger.dma_d2d(payload)
+        return batch, payload
+
+    def _resident_zeros(self, like, n: int, device, lift: bool = False):
+        """A tree shaped like request ``like`` with ``n`` zero rows (``lift``:
+        one row, with no batch axis, as ``like`` has none), placed on
+        ``device`` once and kept."""
+        import jax
+
+        leaves, treedef = jax.tree_util.tree_flatten(like)
+        shapes = [x.shape if lift else (n,) + x.shape[1:] for x in leaves]
+        key = (device, treedef, tuple(shapes),
+               tuple(x.dtype for x in leaves))
+        zeros = self._pads.get(key)
+        if zeros is None:
+            zeros = self._pads[key] = jax.device_put(treedef.unflatten(
+                [np.zeros(s, x.dtype) for s, x in zip(shapes, leaves)]),
+                device)
+        return zeros
+
+    def _resident_rows(self, batch, n: int):
+        """``n`` as an ``int32`` scalar on the device ``batch`` is on: one
+        of a handful of constants, each placed once and kept."""
+        import jax
+
+        (device,) = jax.tree_util.tree_leaves(batch)[0].devices()
+        rows = self._occupancies.get((device, n))
+        if rows is None:
+            rows = self._occupancies[device, n] = jax.device_put(
+                np.int32(n), device)
+        return rows
+
+    def _concat_pad(self, xs: Sequence, bucket: int, lift: bool = False):
+        """One leaf of an all-host batch: concat (``lift``: stack) and pad
+        in numpy, ship the batch in ONE h2d (an N-array device-side
+        concatenate would turn one bulk transfer into N small ones plus a
+        device launch)."""
+        import jax
+
+        gather = np.stack if lift else np.concatenate
+        cat = gather([np.asarray(x) for x in xs], axis=0)
+        if (self.transfer_dtype is not None
+                and np.issubdtype(cat.dtype, np.floating)):
+            cat = cat.astype(self.transfer_dtype)  # halve h2d bytes
         deficit = bucket - cat.shape[0]
         if deficit > 0:
             pad = [(0, deficit)] + [(0, 0)] * (cat.ndim - 1)
-            cat = jnp.pad(cat, pad)
-        return cat
+            cat = np.pad(cat, pad)
+        return jax.device_put(cat)
 
 
 # ---------------------------------------------------------------------------

@@ -13,8 +13,9 @@ under load is, by construction, the one to attack.
 
 The hop chain, in data-flow order (the ISSUE 8 vocabulary; the server's
 per-message hops ``srv_*``, ``hbm_credit`` and ``hbm_view`` of ISSUE 26 are
-listed with their sites in :data:`HOPS`, where ``d2h`` of ISSUE 28 is
-appended: the registry is append-only)::
+listed with their sites in :data:`HOPS`, where ``d2h`` of ISSUE 28 and the
+fan-in batcher's four ``batch_*`` of ISSUE 33 are appended: the registry is
+append-only)::
 
     d2h        a reply's device leaves read back into host landing buffers
                (tpu/serialize.py: start every transfer, await each)
@@ -139,6 +140,27 @@ HOPS: Tuple[Tuple[str, str], ...] = (
     ("rdv_credit", "a refused sender waiting for one of its own standing "
                    "regions' doorbells (RdvLink._await_credit; no op "
                    "where a send never waited)"),
+    # ISSUE 33: the fan-in batcher (jaxshim/service.py FanInBatcher), on
+    # the batcher's own threads. A handler that submits a row and goes on
+    # does not contain them in its srv_handler; one that parks in
+    # `batcher(tree)` does. The three per-batch spans carry the batch's
+    # ordinal as `call` and its occupancy (request rows) as `seq`
+    ("batch_wait", "a row queued in the batcher, from submit to the "
+                   "dispatch of its batch (counters only: one op a row)"),
+    ("batch_stack", "one batch gathered: device leaves by one dispatch of "
+                    "the stack program, host leaves by numpy and one h2d; "
+                    "where rows hold credit, until the batch is ready on "
+                    "the device and the leases are back (fn's dispatch, "
+                    "made in between, taken out); bytes and copy are the "
+                    "rows' payload, pad rows not counted (batcher thread)"),
+    ("batch_run", "the consumer's dispatch, fn(batch), until it returns; a "
+                  "result with a device leaf has its read-back started here "
+                  "and is awaited under batch_d2h (batcher thread; "
+                  "asynchronous, so not device time)"),
+    ("batch_d2h", "a batch's result with a device leaf awaited on the host, "
+                  "jax.device_get: what the device still had to finish for "
+                  "it, and the read-back (completion thread; no op where "
+                  "the result has no device leaf)"),
 )
 
 HOP_NAMES: Tuple[str, ...] = tuple(name for name, _ in HOPS)
