@@ -205,6 +205,71 @@ def test_lockmap_wrong_lock_flagged():
     assert _rules(vs) == ["lock"]
 
 
+LOCKFREE = '''
+class Batcher:
+    _GUARDED_BY = {"_queue": None, "_parked": None, "_closed": None}
+    _MERGE_BOUNDARY = ("_merge_loop",)
+
+    def __init__(self):
+        self._queue = []      # __init__ exempt, as for a locked attribute
+        self._parked = 0
+
+    def submit(self, p):
+        self._queue.append(p)         # one step: a mutator call
+        self._parked = 0              # one step: a constant stored
+
+    def park(self, want):
+        self._parked = want           # one step: a local stored
+
+    def close(self):
+        self._closed = True
+
+    def step(self):
+        STEP
+
+def elsewhere(other):
+    other._queue.append(1)
+'''
+
+
+@pytest.mark.parametrize("step,flagged", [
+    ("self._queue.popleft()", False),
+    ("self._queue.remove(self.p)", False),
+    ("self._parked += 1", True),                      # read-modify-write
+    ("self._queue[0] = 1", True),
+    ("self._queue = self._queue[8:]", True),          # the old cut
+    ("self._parked = len(self._queue)", True),
+    ("self._parked += 1  # tpr: allow(lock)", False),
+])
+def test_lockmap_lock_free_attributes_take_atomic_steps_only(step, flagged):
+    """``_GUARDED_BY = {"attr": None}`` (ISSUE 35, ``FanInBatcher``): no lock
+    by design, so every mutation must be one step under the interpreter."""
+    vs = [v for v in lint_source(LOCKFREE.replace("STEP", step), "x.py")
+          if v.rule == "lock"]
+    assert len(vs) == int(flagged)
+    if flagged:
+        assert "lock-free" in vs[0].message and "(in step)" in vs[0].message
+
+
+def test_lockmap_lock_free_attributes_stay_shard_local():
+    v = [x for x in lint_source(LOCKFREE.replace("STEP", "pass"), "x.py")
+         if x.rule == "shard"]
+    assert len(v) == 1 and "Batcher._queue" in v[0].message
+
+
+def test_the_batcher_declares_its_hand_over_lock_free():
+    """The declaration follows the code: the queue and both flags lock-free,
+    the two tallies under a lock no producer takes."""
+    from tpurpc.jaxshim.service import FanInBatcher
+
+    assert FanInBatcher._GUARDED_BY == {
+        "_queue": None, "_parked": None, "_closed": None,
+        "batches_run": "_tally", "rows_run": "_tally"}
+    path = lint.tree_root() + "/jaxshim/service.py"
+    with open(path) as f:
+        assert lint_source(f.read(), path) == []
+
+
 # ---------------------------------------------------------------------------
 # lint: monotonic clocks
 # ---------------------------------------------------------------------------

@@ -22,6 +22,12 @@ hand (ISSUE 2) and that no general-purpose linter knows about:
   ``with self._lock:`` (``__init__`` is exempt: construction happens-before
   sharing). This is the bug class of the round-5 ``xds.py`` finding — an
   unlocked ``subscribed[:]`` mutation racing a locked snapshot.
+  ``{"attr": None}`` declares the opposite promise (``FanInBatcher``'s
+  queue and flags): NO lock, so ``self.attr`` is only mutated by a step the
+  interpreter cannot split: a mutator call on the container
+  (``append`` / ``popleft`` / ``remove`` ...) or a plain store of a
+  constant or a local. ``+=``, a subscript or slice store, and a store of
+  anything computed from ``self`` are read-modify-write and are flagged.
 * ``wallclock``— monotonic clocks: ``time.time()`` is banned for anything
   that could feed duration/interval math; genuinely absolute timestamps
   (channelz report fields, human-facing log stamps) carry an explicit
@@ -710,7 +716,8 @@ def _check_stage(tree: ast.AST, path: str,
 # -- rule: lock --------------------------------------------------------------
 
 def _guarded_by_decl(cls: ast.ClassDef) -> Dict[str, Tuple[str, ...]]:
-    """Parse a class-level ``_GUARDED_BY = {"attr": "_lock" | ("_a","_b")}``."""
+    """Parse a class-level ``_GUARDED_BY = {"attr": "_lock" | ("_a","_b") |
+    None}``. ``None`` (no lock: atomic steps only) parses to ``()``."""
     for stmt in cls.body:
         if (isinstance(stmt, ast.Assign) and len(stmt.targets) == 1
                 and isinstance(stmt.targets[0], ast.Name)
@@ -722,6 +729,8 @@ def _guarded_by_decl(cls: ast.ClassDef) -> Dict[str, Tuple[str, ...]]:
                     continue
                 if isinstance(v, ast.Constant) and isinstance(v.value, str):
                     decl[k.value] = (v.value,)
+                elif isinstance(v, ast.Constant) and v.value is None:
+                    decl[k.value] = ()
                 elif isinstance(v, (ast.Tuple, ast.List)):
                     locks = tuple(e.value for e in v.elts
                                   if isinstance(e, ast.Constant)
@@ -806,9 +815,19 @@ def _check_locks(tree: ast.AST, path: str,
                 attr = _is_self_attr(tgt)
                 if attr not in decl:
                     continue
-                if _with_holds(node, decl[attr]):
-                    continue
                 if "lock" in _allowed_rules(lines, node.lineno):
+                    continue
+                if not decl[attr]:
+                    if not _atomic_step(node):
+                        out.append(LintViolation(
+                            path, node.lineno, node.col_offset, "lock",
+                            f"{cls.name}.{attr} is declared lock-free "
+                            "(_GUARDED_BY None) but is mutated by a "
+                            f"read-modify-write (in {fn.name}): only a "
+                            "mutator call or a plain store of a constant "
+                            "or a local is one step"))
+                    continue
+                if _with_holds(node, decl[attr]):
                     continue
                 out.append(LintViolation(
                     path, node.lineno, node.col_offset, "lock",
@@ -816,6 +835,18 @@ def _check_locks(tree: ast.AST, path: str,
                     f"{'/'.join(decl[attr])} but is mutated outside "
                     f"'with self.{decl[attr][0]}:' (in {fn.name})"))
     return out
+
+
+def _atomic_step(node: ast.AST) -> bool:
+    """Is this mutation of a lock-free attribute one step under the
+    interpreter? A mutator call is (``self.q.append(x)``); so is
+    ``self.flag = <constant or local name>``. ``+=``, ``self.q[i] = x`` and
+    ``self.q = self.q[n:]`` read, compute and write."""
+    if isinstance(node, ast.Call):
+        return True
+    return (isinstance(node, ast.Assign) and len(node.targets) == 1
+            and _is_self_attr(node.targets[0]) is not None
+            and isinstance(node.value, (ast.Constant, ast.Name)))
 
 
 # -- rule: shard -------------------------------------------------------------

@@ -21,6 +21,7 @@ import functools
 import itertools
 import threading
 import time
+from collections import deque
 from concurrent.futures import Future, InvalidStateError
 from typing import Any, Callable, Iterator, List, Optional, Sequence
 
@@ -64,12 +65,20 @@ _profiler.register_stages(__file__, _LENS_STAGES)
 _FANIN_BATCH = _metrics.histogram("fanin_batch")
 _BATCHER_BATCHES = _metrics.counter("batcher_batches")
 _BATCHER_ROWS = _metrics.counter("batcher_rows")
+#: the hand-over (ISSUE 35): times the batcher's thread went to sleep, and
+#: submits that found it asleep and woke it. 1 - wakes / rows is the share
+#: of hand-overs that touched nothing but the queue
+_BATCHER_PARKS = _metrics.counter("batcher_parks")
+_BATCHER_WAKES = _metrics.counter("batcher_handoff_wakes")
+#: batches whose rows may wait for the reaper thread (FanInBatcher._retire)
+_SPENT_BATCHES = 4
 _FLUSH_REASONS = {
     reason: _metrics.counter(f"batcher_flush_{reason}")
     for reason in ("size", "timer", "drained", "close")
 }
 #: tpurpc-blackbox (ISSUE 5): live batcher queue depth at sweep/scrape
-#: time — the watchdog's "batcher-wait" stage evidence
+#: time — the watchdog's "batcher-wait" stage evidence (the queue is a
+#: deque: its len() is one step, and no lock is there to take)
 _BATCHER_DEPTH = _metrics.fleet("batcher_queue_depth",
                                 lambda b: len(b._queue))
 
@@ -410,7 +419,14 @@ def _nbytes(x) -> int:
 
 
 class _Pending:
-    __slots__ = ("tree", "leases", "one_row", "future", "tctx", "t_enq")
+    """One request on its way through a :class:`FanInBatcher`. Everything the
+    batcher needs to know of the ROW is worked out here, by the thread that
+    submits it: its signature (what it must share with its batch's first
+    row to stack with it), its device, its rows and bytes. The batcher's one
+    thread compares and adds; it flattens no request tree."""
+
+    __slots__ = ("tree", "leases", "one_row", "future", "tctx", "t_enq",
+                 "sig", "dev", "rows", "nbytes", "strays", "err")
 
     def __init__(self, tree, leases=(), one_row=False):
         self.tree = tree
@@ -425,7 +441,44 @@ class _Pending:
         #: enqueue stamp into "batch-wait"/"infer" spans per request; the
         #: stamp alone feeds the `batch_wait` hop, always
         self.tctx = _tracing.current() if _tracing.LIVE else None
+        #: why the row can stack with no batch (a scalar, an empty tree,
+        #: leaves on two devices): it fails alone, through its future, when
+        #: the batcher's thread comes to it; ``submit`` does not raise it
+        self.err: Optional[Exception] = None
+        self.sig = self.dev = None
+        self.rows = self.nbytes = self.strays = 0
         self.t_enq = time.monotonic_ns()
+        try:
+            self._sign()
+        except Exception as exc:
+            self.err = exc
+
+    def _sign(self) -> None:
+        import jax
+
+        leaves, treedef = jax.tree_util.tree_flatten(self.tree)
+        if not leaves:
+            raise ValueError("empty request tree")
+        for x in leaves:
+            if np.ndim(x) < 1 and not self.one_row:
+                raise ValueError(
+                    "batched request leaves need a leading batch axis")
+            if not isinstance(x, jax.Array):
+                self.strays += 1  # a host leaf: landed with its batch
+                continue
+            here = x.devices()
+            if len(here) != 1 or self.dev not in (None, here):
+                raise ValueError(
+                    "batched request leaves must sit on one device, not "
+                    f"{here} beside {self.dev}")
+            self.dev = here
+        lead = 0 if self.one_row else 1
+        #: (tree structure, each leaf's row shape and dtype, one_row)
+        self.sig = (treedef, tuple((np.shape(x)[lead:], np.dtype(
+            getattr(x, "dtype", None) or np.asarray(x).dtype))
+            for x in leaves), self.one_row)
+        self.rows = 1 if self.one_row else np.shape(leaves[0])[0]
+        self.nbytes = sum(_nbytes(x) for x in leaves)
 
     def resolve(self, result) -> None:
         try:
@@ -458,7 +511,7 @@ class FanInBatcher:
     as the reference's busy-poll timeout (``GRPC_RDMA_BUSY_POLLING_TIMEOUT_US``,
     README.md:17-25), applied at the request level instead of the byte level.
 
-    One way in, :meth:`submit`: FIFO, non-blocking, returns a
+    One way in, :meth:`submit`: FIFO, returns a
     ``concurrent.futures.Future`` of the request's share of ``fn``'s result.
     ``batcher(tree)`` is ``submit(tree).result()``: a unary handler parks in
     it as it always did. A stream handler that must not park (its thread is
@@ -469,6 +522,50 @@ class FanInBatcher:
     order, stacked and handed to ``fn`` one at a time by one thread, so row
     ``k`` of a producer is in the batch of its row ``k + 1`` or an earlier
     one, and before it within a batch.
+
+    **The hand-over crosses no lock** (ISSUE 35). Many producers, ONE
+    consumer (the batcher's thread), and nothing between them that can
+    block: the queue is a ``collections.deque``, whose ``append`` and
+    ``popleft`` are atomic under the interpreter and cannot give it up. A
+    lock here, however short its critical sections, is handed by the OS to
+    a thread that then stands in line for the interpreter with the lock in
+    hand, and everybody who arrives meanwhile queues behind it: on a v5e
+    with eight saturated producers a ``submit`` waited 4.2 ms for the one
+    lock this class had, 99% of the hand-over, and the batcher's thread
+    1.2 ms twice a batch (PERF.md 6, PR 35). So:
+
+    * ``submit`` works out the row's signature on ITS thread
+      (:class:`_Pending`: the one ``tree_flatten`` a row costs), appends,
+      and looks at ``_parked``. The batcher's thread compares precomputed
+      tuples and does per-batch work only.
+    * **Park, then look once more.** Before it sleeps, the batcher's thread
+      raises ``_parked`` to the queue length it is waiting for (1: a first
+      row, which starts ``max_delay_s``; ``max_batch``: a full batch; with
+      ``inflight_fn`` one more than it has seen, since the depth-aware flush
+      looks at every arrival) and re-reads the queue: a row appended before
+      the flag went up is found by the re-read, one appended after sees the
+      flag and sets ``_wake`` (``core/ctrlring.py``'s discipline). A
+      ``submit`` that finds the flag down, or the queue still short of it,
+      touches nothing but the queue: a saturated batcher never parks, and a
+      closed-loop caller pays one wake a batch (``batcher_parks``,
+      ``batcher_handoff_wakes``).
+    * **Spent rows die on a thread of their own.** Letting go of a device
+      array gives the interpreter up (its buffer is freed outside it), and
+      a thread that does so eight times a batch stands in line for the
+      interpreter eight times: on a v5e that was 7 ms of the batcher's
+      10 ms a batch, at the one place where the last reference to the
+      rows of the batch before went (PERF.md 6, PR 35). Once a batch is
+      stacked and its credit returned, its rows' trees go to ``_spent``
+      and the reaper thread drops them (at most ``_SPENT_BATCHES`` wait
+      there: a reaper that is behind hands the work back).
+    * ``close()`` raises ``_closed`` and wakes. A ``submit`` that races it
+      either raises with the leases still the caller's, or its row is
+      served or failed and its leases released by the batcher, exactly
+      once: it reads ``_closed`` again AFTER its append and, finding it up,
+      takes its own row back out (``deque.remove``, atomic); if the row is
+      gone the batcher's thread has it and will serve it. The thread ends
+      only on an empty queue seen after ``_closed``, so a row whose
+      ``submit`` saw the flag down is always found.
 
     Where the rows come from decides how they are gathered (hop
     ``batch_stack``), and nothing else differs:
@@ -546,10 +643,16 @@ class FanInBatcher:
     """
 
     #: lock map (lint rule `lock`) + shard contract (lint rule `shard`,
-    #: tpurpc-manycore): the request queue and close flag are SHARD-LOCAL —
-    #: only this batcher's own threads mutate them; cross-shard access is
-    #: confined to the device merger's declared ``_MERGE_BOUNDARY``
-    _GUARDED_BY = {"_queue": "_lock", "_closed": "_lock"}
+    #: tpurpc-manycore). ``None``: no lock, by design: the queue, the park
+    #: flag and the close flag are touched by atomic steps alone (a deque's
+    #: ``append`` / ``popleft`` / ``remove``, a constant stored in a flag),
+    #: many producers and this batcher's ONE thread as the consumer. They
+    #: are SHARD-LOCAL all the same: cross-shard access is confined to the
+    #: device merger's declared ``_MERGE_BOUNDARY``. The two tallies have
+    #: more than one writer (the completion threads) and a lock of their
+    #: own, which no producer ever takes.
+    _GUARDED_BY = {"_queue": None, "_parked": None, "_closed": None,
+                   "batches_run": "_tally", "rows_run": "_tally"}
 
     def __init__(self, fn: Callable[..., Any], max_batch: int = 8,
                  max_delay_s: float = 0.002, pad_to_bucket: bool = True,
@@ -564,8 +667,6 @@ class FanInBatcher:
         #: arrival can happen until responses go out, so waiting out
         #: max_delay_s is pure latency: flush now. None = timer/size only.
         self._inflight_fn = inflight_fn
-        from collections import deque
-
         #: recent dispatched batch sizes — the depth-aware flush's
         #: hysteresis floor is their max, so one small ramp-up batch can't
         #: drag the floor down while the occupancy the server recently
@@ -595,10 +696,13 @@ class FanInBatcher:
         #: guarantee assumes ≤1 row per request or callers sizing max_batch
         #: to the true row bound.
         self.fixed_bucket = fixed_bucket
-        self._lock = threading.Lock()
-        self._queue: List[_Pending] = []
-        self._kick = threading.Condition(self._lock)
+        self._queue: "deque[_Pending]" = deque()
+        #: 0 while the batcher's thread is awake; asleep, the queue length
+        #: it wants to be woken at (see the class docstring)
+        self._parked = 0
+        self._wake = threading.Event()
         self._closed = False
+        self._tally = threading.Lock()
         self.batches_run = 0
         self.rows_run = 0
         import queue as _queue
@@ -608,6 +712,8 @@ class FanInBatcher:
         #: thread, and through it the callers, when the device falls behind
         self._inflight: "_queue.Queue" = _queue.Queue(maxsize=max(2, d2h_workers))
         self._reaped = False  # set by close() after the workers are gone
+        #: stacked batches' rows, on their way to the thread that drops them
+        self._spent: "_queue.SimpleQueue" = _queue.SimpleQueue()
         _BATCHER_DEPTH.track(self)
         self._thread = threading.Thread(target=self._loop, daemon=True,
                                         name="tpurpc-batcher")
@@ -615,7 +721,10 @@ class FanInBatcher:
             threading.Thread(target=self._complete_loop, daemon=True,
                              name=f"tpurpc-batcher-d2h-{i}")
             for i in range(max(1, d2h_workers))]
+        self._reaper = threading.Thread(target=self._reap_loop, daemon=True,
+                                        name="tpurpc-batcher-reap")
         self._thread.start()
+        self._reaper.start()
         for c in self._completers:
             c.start()
 
@@ -624,17 +733,16 @@ class FanInBatcher:
         batches not yet materialized) — the tpurpc-fleet load report's
         queue-depth field (Server.set_load_provider wiring in serve_jax):
         on a model server THIS is where overload actually accumulates."""
-        with self._lock:
-            queued = len(self._queue)
-        return queued + self._inflight.qsize()
+        return len(self._queue) + self._inflight.qsize()
 
     def close(self) -> None:
         import queue as _queue
 
-        with self._lock:
-            self._closed = True
-            self._kick.notify_all()
+        self._closed = True
+        self._wake.set()
         self._thread.join(timeout=5)
+        self._spent.put(None)
+        self._reaper.join(timeout=5)
         for _ in self._completers:   # one sentinel per completion worker,
             try:                      # after the last dispatched batch.
                 # Generous timeout: a merely-backlogged (healthy) queue
@@ -676,18 +784,25 @@ class FanInBatcher:
         the class docstring). ``one_row``: the leaves are ONE row with no
         batch axis. Raises ``RuntimeError`` on a closed batcher, the leases
         then still the caller's."""
+        if self._closed:
+            raise RuntimeError("batcher closed")
         p = _Pending(tree, leases, one_row)
-        with self._lock:
-            if self._closed:
-                raise RuntimeError("batcher closed")
-            self._queue.append(p)
-            # wake the batcher's thread only where it has something to
-            # decide: a first row starts its timer, a full batch goes out,
-            # and the depth-aware flush looks at every arrival
-            n = len(self._queue)
-            if (n == 1 or n >= self.max_batch
-                    or self._inflight_fn is not None):
-                self._kick.notify_all()
+        queue = self._queue
+        queue.append(p)
+        if self._closed:
+            # close() came between the look and the append. The row is the
+            # caller's again if it is still there to take back; if not, the
+            # batcher's thread has it and serves it
+            try:
+                queue.remove(p)
+            except ValueError:
+                return p.future
+            raise RuntimeError("batcher closed")
+        want = self._parked
+        if want and len(queue) >= want:
+            self._parked = 0  # one wake a park: the next arrival sees 0
+            _BATCHER_WAKES.inc()
+            self._wake.set()
         return p.future
 
     def __call__(self, tree: Any) -> Any:
@@ -695,32 +810,52 @@ class FanInBatcher:
 
     # -- batcher thread ------------------------------------------------------
 
+    def _park(self, want: int, timeout: Optional[float] = None) -> None:
+        """Sleep until the queue holds ``want`` rows, ``close()``, or
+        ``timeout``. The flag goes up first and the queue is read once more
+        after it, so no arrival falls between the look that decided to
+        sleep and the sleep."""
+        self._parked = want
+        if len(self._queue) < want and not self._closed:
+            _BATCHER_PARKS.inc()
+            self._wake.wait(timeout)
+        self._parked = 0
+        self._wake.clear()
+
     def _loop(self) -> None:
+        queue = self._queue
         while True:
-            with self._lock:
-                while not self._queue and not self._closed:
-                    self._kick.wait()
-                if self._closed and not self._queue:
+            while not queue:
+                # `_closed` before the queue: a submit that saw the flag
+                # down appended before it went up, so this look finds it
+                if self._closed and not queue:
                     return
-                deadline = time.monotonic() + self.max_delay_s
-                reason = None
-                while (len(self._queue) < self.max_batch and not self._closed):
-                    if self._drained_inflight():
-                        reason = "drained"  # nobody else is coming
-                        break
-                    left = deadline - time.monotonic()
-                    if left <= 0:
-                        reason = "timer"
-                        break
-                    self._kick.wait(timeout=left)
-                if reason is None:
-                    reason = ("size" if len(self._queue) >= self.max_batch
-                              else "close")
-                batch, self._queue = (self._queue[:self.max_batch],
-                                      self._queue[self.max_batch:])
-                if batch:
-                    self._recent_batches.append(len(batch))
+                self._park(1)
+            deadline = time.monotonic() + self.max_delay_s
+            reason = None
+            while len(queue) < self.max_batch and not self._closed:
+                seen = len(queue)
+                if self._drained_inflight():
+                    reason = "drained"  # nobody else is coming
+                    break
+                left = deadline - time.monotonic()
+                if left <= 0:
+                    reason = "timer"
+                    break
+                self._park(self.max_batch if self._inflight_fn is None
+                           else seen + 1, left)
+            if reason is None:
+                reason = ("size" if len(queue) >= self.max_batch
+                          else "close")
+            batch = []
+            try:
+                while len(batch) < self.max_batch:
+                    batch.append(queue.popleft())
+            except IndexError:
+                pass  # short: a timer, a drain, a close (or a submit that
+                # lost to close() took its row back)
             if batch:
+                self._recent_batches.append(len(batch))
                 _FLUSH_REASONS[reason].inc()
                 _FANIN_BATCH.record(len(batch))
                 # flight: one event per DISPATCHED batch — the flush
@@ -732,7 +867,7 @@ class FanInBatcher:
     def _drained_inflight(self) -> bool:
         """True when the transport says every arrived-and-unanswered
         request is already in our queue — the depth-aware flush signal
-        (runs under self._lock via the _loop wait).
+        (runs on the batcher's thread, between its looks at the queue).
 
         Hysteresis: the early flush also requires the queue to have
         reached the max RECENT batch size. "Every in-flight request is
@@ -755,6 +890,25 @@ class FanInBatcher:
         floor = min(self.max_batch, max(self._recent_batches, default=1))
         return q >= max(1, pending) and q >= floor
 
+    def _reap_loop(self) -> None:
+        """Drop what the batcher's thread is done with: each ``get`` lets
+        go of the rows of one batch, here."""
+        while self._spent.get() is not None:
+            pass
+
+    def _retire(self, batch: "Sequence[_Pending]") -> None:
+        """The rows of a batch that has been stacked (or has failed) are
+        nobody's any more: their last references go to the reaper, unless
+        it is behind. Spent rows hold HBM whose credit has gone back, so
+        what may wait for the reaper is bounded; beyond it the rows die
+        here, on the batcher's thread, which is what slows the producers
+        down."""
+        trees = [p.tree for p in batch]
+        for p in batch:
+            p.tree = None
+        if self._spent.qsize() < _SPENT_BATCHES:
+            self._spent.put(trees)
+
     @staticmethod
     def _release(batch: "Sequence[_Pending]") -> None:
         """Return the credit of every row of ``batch``, in queue order. A
@@ -769,46 +923,25 @@ class FanInBatcher:
         """Fail (individually) requests whose pytree structure, leaf
         row-shape/dtype or device can't stack with the batch's first valid
         row — one bad request must not poison its siblings' futures. A row
-        that fails here is never stacked: its credit goes back at once."""
-        import jax
-
+        that fails here is never stacked: its credit goes back at once.
+        Each row's signature was worked out by its ``submit``: this compares
+        them."""
         good: List[_Pending] = []
         ref = ref_dev = None
         for p in batch:
-            err: Optional[Exception] = None
-            sig = dev = None
-            try:
-                leaves, td = jax.tree_util.tree_flatten(p.tree)
-                if not leaves:
-                    raise ValueError("empty request tree")
-                for x in leaves:
-                    if np.ndim(x) < 1 and not p.one_row:
-                        raise ValueError(
-                            "batched request leaves need a leading batch axis")
-                    if isinstance(x, jax.Array):
-                        if len(x.devices()) != 1 or dev not in (
-                                None, x.devices()):
-                            raise ValueError(
-                                "batched request leaves must sit on one "
-                                f"device, not {x.devices()} beside {dev}")
-                        dev = x.devices()
-                lead = 0 if p.one_row else 1
-                sig = (td, tuple((np.shape(x)[lead:], np.dtype(
-                    getattr(x, "dtype", None) or np.asarray(x).dtype))
-                    for x in leaves), p.one_row)
-            except Exception as exc:
-                err = exc
-            if err is None and ref is not None and sig != ref:
+            err = p.err
+            if err is None and ref is not None and p.sig != ref:
                 err = ValueError(
                     "request incompatible with batch: leaf shapes/dtypes "
-                    f"{sig[1]} vs {ref[1]} (or differing tree structure, or "
-                    "one with a batch axis and one without)")
-            if err is None and None not in (dev, ref_dev) and dev != ref_dev:
+                    f"{p.sig[1]} vs {ref[1]} (or differing tree structure, "
+                    "or one with a batch axis and one without)")
+            if err is None and None not in (p.dev, ref_dev) and (
+                    p.dev != ref_dev):
                 err = ValueError(
                     f"request incompatible with batch: its leaves are on "
-                    f"{dev}, the batch's on {ref_dev}")
+                    f"{p.dev}, the batch's on {ref_dev}")
             if err is None:
-                ref, ref_dev = ref or sig, ref_dev or dev
+                ref, ref_dev = ref or p.sig, ref_dev or p.dev
                 good.append(p)
                 continue
             self._release((p,))
@@ -843,28 +976,25 @@ class FanInBatcher:
             return
         ordinal = next(self._ordinals)
         t_disp = time.monotonic_ns()
+        # enqueue → dispatch: one op of `batch_wait` a request (billed once
+        # a batch), and its "batch-wait" span where the call is traced
+        waited = len(batch) * t_disp
         for p in batch:
-            # enqueue → dispatch: one op of `batch_wait` a request, and its
-            # "batch-wait" span where the call is traced
-            _lens.account("batch_wait", t_disp - p.t_enq)
+            waited -= p.t_enq
             if p.tctx is not None:
                 _tracing.record("batch-wait", p.tctx, p.t_enq,
                                 t_disp - p.t_enq)
-        lift = batch[0].one_row
+        _lens.account("batch_wait", waited, ops=len(batch))
         leased = any(p.leases for p in batch)
-        rows = [p.tree for p in batch]
-        sizes = [1 if lift else np.shape(jax.tree_util.tree_leaves(t)[0])[0]
-                 for t in rows]
+        sizes = [p.rows for p in batch]
         total = sum(sizes)
         bucket = max(self._bucket(total), total)
         try:
             with _lens.stage("batch_stack", call=ordinal,
                              seq=total) as stacking:
                 try:
-                    stacked, stacking.nbytes = self._stack(rows, sizes,
-                                                           bucket, lift)
+                    stacked, stacking.nbytes = self._stack(batch, bucket)
                     stacking.copy = stacking.nbytes
-                    del rows
                     running = _lens.stage("batch_run", call=ordinal,
                                           seq=total).begin()
                     try:
@@ -890,6 +1020,7 @@ class FanInBatcher:
                 finally:
                     stacked = None
                     self._release(batch)
+                    self._retire(batch)
         except Exception as e:  # deliver failure to every caller in the batch
             _fail_all(batch, e)
             return
@@ -962,9 +1093,13 @@ class FanInBatcher:
                                     t_done - t_disp, rows=total)
             _BATCHER_BATCHES.inc()
             _BATCHER_ROWS.inc(total)
-            with self._lock:
+            with self._tally:
                 self.batches_run += 1
                 self.rows_run += total
+            if not jax.tree_util.tree_leaves(host):
+                for p in batch:  # nothing to split: an ingest consumer
+                    p.resolve(host)
+                return
             off = 0
             for p, n in zip(batch, sizes):
                 s = off if p.one_row else slice(off, off + n)
@@ -973,43 +1108,46 @@ class FanInBatcher:
         except Exception as e:
             _fail_all(batch, e)
 
-    def _stack(self, rows: List[Any], sizes: List[int], bucket: int,
-               lift: bool = False):
-        """``(batch, payload bytes)``: the requests' rows gathered along the
-        leading axis (``lift``: along a new one) and padded with zero rows
-        up to ``bucket``, on a device. See the class docstring for the three
-        cases."""
+    def _stack(self, batch: "Sequence[_Pending]", bucket: int):
+        """``(stacked, payload bytes)``: the requests' rows gathered along
+        the leading axis (``one_row`` requests: along a new one) and padded
+        with zero rows up to ``bucket``, on a device. See the class
+        docstring for the three cases."""
         import jax
 
         from tpurpc.tpu import ledger
 
-        leaves = [x for t in rows for x in jax.tree_util.tree_leaves(t)]
-        payload = sum(_nbytes(x) for x in leaves)
-        on_device = [isinstance(x, jax.Array) for x in leaves]
-        if not any(on_device):
+        lift = batch[0].one_row
+        rows = [p.tree for p in batch]
+        payload = sum(p.nbytes for p in batch)
+        there = next((p.dev for p in batch if p.dev is not None), None)
+        if there is None:
             return jax.tree_util.tree_map(
                 lambda *xs: self._concat_pad(xs, bucket, lift), *rows), payload
-        (device,) = leaves[on_device.index(True)].devices()
-        if not all(on_device):
-            strays = [i for i, there in enumerate(on_device) if not there]
+        (device,) = there
+        if any(p.strays for p in batch):
+            leaves = [x for t in rows for x in jax.tree_util.tree_leaves(t)]
+            strays = [i for i, x in enumerate(leaves)
+                      if not isinstance(x, jax.Array)]
             landed = jax.device_put([np.asarray(leaves[i]) for i in strays],
                                     device)
             ledger.dma_h2d(sum(x.nbytes for x in landed))
             for i, x in zip(strays, landed):
                 leaves[i] = x
-            treedef = jax.tree_util.tree_structure(rows[0])
+            treedef = batch[0].sig[0]
             n = treedef.num_leaves
             rows = [treedef.unflatten(leaves[k:k + n])
                     for k in range(0, len(leaves), n)]
         # pad with resident zero rows shaped like the first request (so that
         # one-row requests always make `bucket` arguments of one shape) and
         # one shorter remainder where its row count does not divide the gap
-        gap, unit = bucket - sum(sizes), sizes[0]
+        unit = batch[0].rows
+        gap = bucket - sum(p.rows for p in batch)
         pads = [self._resident_zeros(rows[0], n, device, lift)
                 for n in [unit] * (gap // unit) + [gap % unit] if n]
-        batch = _stack_program(lift)(*rows, *pads)
+        stacked = _stack_program(lift)(*rows, *pads)
         ledger.dma_d2d(payload)
-        return batch, payload
+        return stacked, payload
 
     def _resident_zeros(self, like, n: int, device, lift: bool = False):
         """A tree shaped like request ``like`` with ``n`` zero rows (``lift``:
@@ -1096,13 +1234,13 @@ class DeviceMerger:
     device dispatch (tpurpc-manycore tentpole part 3).
 
     Shards batch independently — each :class:`FanInBatcher` keeps its own
-    lock, queue, and flush policy — and meet the single accelerator only
+    queue, thread and flush policy — and meet the single accelerator only
     here: sub-batches are published through a lock-free
     :class:`~tpurpc.core.handoff.HandoffRing` (no cross-shard mutex on the
     hot path), and the one merger thread gathers whatever the other shards
     already committed, concatenates shape-compatible sub-batches along the
     batch axis, and dispatches once. The device stays saturated without the
-    transport serializing on a shared batcher lock.
+    transport serializing on one shared batcher.
 
     Failure isolation extends PR 3's poison semantics across the boundary:
     a merged dispatch that fails is retried per sub-batch, so a mis-shaped
@@ -1280,7 +1418,8 @@ class ShardedFanIn:
 
     Callers are striped round-robin across shards (one GIL-atomic
     ``next()`` — no shared lock on the request path); each shard batches
-    under its OWN lock and publishes through the merger's handoff ring.
+    on its OWN queue and thread and publishes through the merger's handoff
+    ring.
     Drop-in for FanInBatcher where serve_jax wires one (``__call__``,
     ``queue_depth``, ``batches_run``, ``close``)."""
 
@@ -1333,7 +1472,7 @@ def serve_jax(fn: Callable[[Any], Any], address: str = "127.0.0.1:0", *,
 
     ``batch_shards > 1`` (tpurpc-manycore) splits the batcher into that many
     independent shards merging only at the device boundary
-    (:class:`ShardedFanIn`): callers stop contending on one batcher lock,
+    (:class:`ShardedFanIn`): callers stop queueing behind one batcher thread,
     the accelerator still sees merged dispatches.
     """
     srv = Server(max_workers=max_workers)

@@ -277,13 +277,14 @@ class stage:
         return False
 
 
-def account(hop: str, busy_ns: int, nbytes: int = 0) -> None:
+def account(hop: str, busy_ns: int, nbytes: int = 0, ops: int = 1) -> None:
     """One operation of ``hop`` timed by the caller: counters only, no span
     (``srv_call``: the duration the call path already computes;
-    ``srv_queue``: a wait that is no thread's time)."""
+    ``srv_queue``: a wait that is no thread's time). ``ops``: that many,
+    their times added up by the caller (``batch_wait``: a batch's rows)."""
     _NS[hop].inc(busy_ns)
     _BYTES[hop].inc(nbytes)
-    _OPS[hop].inc()
+    _OPS[hop].inc(ops)
 
 
 class CallStages:
