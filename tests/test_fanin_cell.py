@@ -312,11 +312,13 @@ def test_the_manifest_gained_the_cell_and_lost_nothing():
     assert manifest_check.problems(MANIFEST, ROOT) == []
     assert sorted(e["name"] for e in FANIN) == sorted(EXPECT)
     assert all(e["moves"] == "hbm_gbytes_s" for e in FANIN)
-    cell = MANIFEST["workloads"][-1]
-    assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) == (
-        "fanin4m_c8", "tensor_fanin_batch_4m", "stream_c8", 1)
-    assert MANIFEST["end_to_end"][0]["workloads"][-1] == "fanin4m_c8"
-    entry = MANIFEST["configs"][-1]
+    # found by name: cells and configurations appended later change no test
+    (cell,) = [w for w in MANIFEST["workloads"] if w["name"] == "fanin4m_c8"]
+    assert (cell["config"], cell["traffic"], cell["chips"]) == (
+        "tensor_fanin_batch_4m", "stream_c8", 1)
+    assert "fanin4m_c8" in MANIFEST["end_to_end"][0]["workloads"]
+    (entry,) = [c for c in MANIFEST["configs"]
+                if c["name"] == "tensor_fanin_batch_4m"]
     with open(os.path.join(ROOT, entry["file"])) as f:
         cfg = json.load(f)
     assert cfg["source"] == entry["source"] and cfg["reduced"] == []

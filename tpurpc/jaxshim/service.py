@@ -17,6 +17,7 @@ replacement for "more pollers": one big matmul beats eight small ones.
 
 from __future__ import annotations
 
+import bisect
 import functools
 import itertools
 import threading
@@ -186,7 +187,12 @@ def add_tensor_method(server: Server, name: str,
     the leases (ring credit) are released when ``fn`` returns; a
     ``stream_stream`` ``fn`` is handed a :class:`DeviceRequests`, each
     message's credit returned as it asks for the next unless it took the
-    message's leases over (``take_leases``). Device arrays
+    message's leases over (``take_leases``), and may yield a
+    ``concurrent.futures.Future`` of a reply where it would yield the reply
+    (what :meth:`FanInBatcher.submit` returns): the server writes the
+    stream's replies in the order they were yielded, each once it and every
+    earlier one are resolved, and ``fn``'s thread goes on to the next
+    request meanwhile (``rpc/server.py`` ``_DeferredReplies``). Device arrays
     in what ``fn`` returns (or yields) leave through
     :func:`tpurpc.tpu.serialize.tree_from_device`: every leaf's transfer to
     the host is started before any is awaited, each billed ``dma_d2h`` once,
@@ -253,11 +259,16 @@ def add_tensor_method(server: Server, name: str,
             decode, finish, take = _device_decoder(ctx)
             try:
                 for item in fn(DeviceRequests(raw_iter, decode, take)):
-                    yield tree_from_device(item)
+                    # a future (a row's share of a batcher's result) goes
+                    # on as it is, and this thread back to the next request
+                    yield (item if isinstance(item, Future)
+                           else tree_from_device(item))
             finally:
                 finish()
         handler = stream_stream_rpc_method_handler(
             behavior, codec.raw_view, _ident)
+        # a future's result is a tree, serialized where it is written
+        handler.late_serializer = tree_from_device
     else:
         raise ValueError(f"unsupported tensor method kind {kind}")
     server.add_method(_method_path(name), handler)
@@ -411,6 +422,64 @@ def _stack_program(lift: bool = False):
 
     stack.__name__ = stack.__qualname__ = STACK_PROGRAM
     return jax.jit(stack)
+
+
+#: the largest piece a batch's result is read back in. On a v5e a
+#: device-to-host transfer of up to 16 MiB runs at 13 GB/s among others
+#: started with it and one of 32 MiB or more at 2.4 to 3.6 GB/s, holding up
+#: the landings queued behind it (PERF.md 6, PR 36: 1, 2, 4, 8, 16 MiB read
+#: 11.3, 13.4, 13.4, 13.4, 13.0 GB/s; 32 and 64 MiB 3.6 and 3.0): half the
+#: largest size that was seen to run fast
+_D2H_PIECE_BYTES = 8 << 20
+#: the name the program that cuts a result into such pieces has in a device
+#: trace (``jit_<name>``)
+CUT_PROGRAM = "tpurpc_batch_cut"
+
+
+@functools.lru_cache(maxsize=None)
+def _cut_program(cuts: tuple):
+    """The one program that cuts a result leaf along its leading axis at
+    ``cuts`` (row offsets, first 0, last the leaf's rows): ``cut(x) ->
+    pieces``, one dispatch whatever their number, one compiled shape a
+    batch shape."""
+    import jax
+
+    def cut(x):
+        return tuple(x[a:b] for a, b in zip(cuts, cuts[1:]))
+
+    cut.__name__ = cut.__qualname__ = CUT_PROGRAM
+    return jax.jit(cut)
+
+
+class _Cut:
+    """A leaf of a batch's result that is read back in pieces: rows
+    ``cuts[i] .. cuts[i + 1]`` are ``pieces[i]`` (device arrays on the
+    batcher's thread, their host copies after the completion thread).
+    Indexed like the leaf it stands for, by a row or a slice of rows: a view
+    of one piece where the rows lie in one (a one-row request always does),
+    a copy where a request's rows straddle two. No pytree node: jax's tree
+    functions hand it on as a leaf."""
+
+    __slots__ = ("pieces", "cuts")
+
+    def __init__(self, pieces, cuts):
+        self.pieces, self.cuts = tuple(pieces), cuts
+
+    @property
+    def nbytes(self) -> int:
+        return sum(p.nbytes for p in self.pieces)
+
+    def __getitem__(self, rows):
+        one = not isinstance(rows, slice)
+        a, b = (rows, rows + 1) if one else (rows.start, rows.stop)
+        i = bisect.bisect_right(self.cuts, a) - 1
+        parts = []
+        while a < b:
+            lo, hi = self.cuts[i], self.cuts[i + 1]
+            parts.append(self.pieces[i][a - lo:min(b, hi) - lo])
+            a, i = min(b, hi), i + 1
+        got = parts[0] if len(parts) == 1 else np.concatenate(parts)
+        return got[0] if one else got
 
 
 def _nbytes(x) -> int:
@@ -621,19 +690,36 @@ class FanInBatcher:
 
     **The reply.** ``fn``'s result is split along the leading axis, each
     request's rows to its future. Where the result has a ``jax.Array`` leaf
-    the batch goes to a completion thread, which materializes it to host in
-    ONE transfer per output leaf (``jax.device_get`` of the whole batch,
-    hop ``batch_d2h``) and splits replies as numpy views:
+    the batch goes to a completion thread, which materializes it on the host
+    (hop ``batch_d2h``; ledger ``dma_d2h`` once an output leaf) and splits
+    replies as numpy views:
 
-    * one d2h per batch, not one per request — splitting device arrays
-      per-request would pay max_batch round trips;
+    * the read-back is asked for a batch, not a request (splitting device
+      arrays per request would pay ``max_batch`` dispatches), and NOW, on
+      the batcher's thread, before its wait for the stacked batch, so that
+      it queues behind the consumer and not behind the next stack (asked
+      for by the completion thread instead, ``fanex4m_c8`` read 1.36 and
+      1.82 GB/s where this order reads 1.85 and 1.86);
+    * a leaf of more than ``_D2H_PIECE_BYTES`` that the host cannot address
+      is cut on the device first, by ONE dispatch (:data:`CUT_PROGRAM`), and
+      comes back in pieces (:class:`_Cut`): on a v5e one transfer of 32 MiB
+      runs at 2.4 GB/s and holds up every landing queued behind it, its
+      four pieces of 8 MiB at 13 GB/s (``fanex4m_c8`` 1.30 -> 1.84 GB/s,
+      PERF.md 6, PR 36). A request whose rows lie in one piece gets a view
+      of it; the pieces of a batch stay alive until the last reply that
+      views them has been written;
     * batch N+1's stacking and device dispatch overlap batch N's d2h
-      (bounded depth, so backpressure still reaches callers);
-    * ``d2h_workers`` completion threads materialize different batches
-      concurrently, so a latency-bound device→host hop stops bounding the
-      batch rate. (The default of 4 was chosen on a link that no longer
-      exists; ``fanin4m_c8``'s consumer returns nothing, so the chip has not
-      judged it.)
+      (``_inflight``'s bounded depth, so backpressure still reaches the
+      batcher's thread and, through the credit it holds, the senders);
+    * ``d2h_workers`` completion threads take different batches side by
+      side. Where a stream's replies are written by the thread that resolved
+      them (``rpc/server.py`` ``_DeferredReplies``) they are also the
+      writers: eight 4 MiB replies a batch are 17 to 28 ms of one thread.
+      ``fanex4m_c8`` judged the default of 4 with whole 32 MiB read-backs
+      (1, 2 and 4 workers: 1.3025, 1.3171, 1.3205 GB/s: with one the
+      completion thread was the pace at 25 ms a batch, with four the
+      batcher's): the transfer set the rate whatever their number, and
+      more than one keeps the pace on the batcher's thread.
 
     Where it has none (``None``, or host leaves: an ingest consumer's count
     a batch) no read-back is started and no completion thread touches the
@@ -1003,15 +1089,7 @@ class FanInBatcher:
                                 stacked, total))
                         else:
                             out = self._fn(stacked)
-                        away = [leaf
-                                for leaf in jax.tree_util.tree_leaves(out)
-                                if isinstance(leaf, jax.Array)]
-                        # Start the d2h NOW (enqueued behind the compute),
-                        # so the completion thread waits for one thing, the
-                        # result on the host, and not for the compute and
-                        # then for a transfer it has yet to ask for
-                        for leaf in away:
-                            leaf.copy_to_host_async()
+                        out, away = self._ask_back(out)
                     finally:
                         stacking.exclude(running.end())
                         if leased:
@@ -1056,9 +1134,61 @@ class FanInBatcher:
                 if item is not None:
                     _fail_all(item[0], closed)
 
-    def _complete_loop(self) -> None:
-        """Stage 2: one whole-batch device→host transfer, numpy reply split."""
+    @staticmethod
+    def _ask_back(out):
+        """``(out, away)``: ask for every device leaf of ``fn``'s result on
+        the host NOW (enqueued behind the compute), so that the completion
+        thread waits for one thing, the result on the host, and not for the
+        compute and then for a transfer it has yet to ask for. ``away``:
+        the leaves asked for. A leaf the host cannot address and that is
+        larger than ``_D2H_PIECE_BYTES`` is cut on the device first, by one
+        dispatch, and comes back in pieces (a :class:`_Cut` in its place in
+        ``out``): a transfer that large is several times slower a byte."""
         import jax
+
+        from tpurpc.tpu.serialize import _on_device
+
+        away: list = []
+
+        def ask(leaf):
+            if not isinstance(leaf, jax.Array):
+                return leaf
+            rows = leaf.shape[0] if leaf.ndim else 0
+            if (leaf.nbytes > _D2H_PIECE_BYTES and rows > 1
+                    and _on_device(leaf)):
+                step = max(1, _D2H_PIECE_BYTES // (leaf.nbytes // rows))
+                cuts = tuple(range(0, rows, step)) + (rows,)
+                leaf = _Cut(_cut_program(cuts)(leaf), cuts)
+                for piece in leaf.pieces:
+                    piece.copy_to_host_async()
+            else:
+                leaf.copy_to_host_async()
+            away.append(leaf)
+            return leaf
+
+        return jax.tree_util.tree_map(ask, out), away
+
+    def _complete_loop(self) -> None:
+        """Stage 2: the batch's result awaited on the host (asked for by
+        :meth:`_ask_back`), numpy reply split. Each output leaf is billed
+        once, as ``tpu/serialize.py`` bills a reply's: ``dma_d2h`` where the
+        host cannot address it, however many pieces carried it."""
+        import jax
+
+        from tpurpc.tpu import ledger
+        from tpurpc.tpu.serialize import _on_device
+
+        def to_host(leaf):
+            if isinstance(leaf, _Cut):
+                ledger.dma_d2h(leaf.nbytes)
+                return _Cut([np.asarray(p) for p in leaf.pieces], leaf.cuts)
+            if not isinstance(leaf, jax.Array):
+                return leaf
+            if _on_device(leaf):
+                ledger.dma_d2h(leaf.nbytes)
+            else:
+                ledger.zero_copy(leaf.nbytes)
+            return np.asarray(leaf)
 
         while True:
             item = self._inflight.get()
@@ -1066,10 +1196,11 @@ class FanInBatcher:
                 return
             batch, sizes, total, out, t_disp, ordinal = item
             try:
-                # ONE d2h per output leaf for the whole batch; per-request
-                # splits below are host views, free of device round trips
+                # one read-back a batch (in pieces where a leaf is large);
+                # per-request splits below are host views, free of device
+                # round trips
                 with _lens.stage("batch_d2h", call=ordinal, seq=total) as st:
-                    host = jax.device_get(out)
+                    host = jax.tree_util.tree_map(to_host, out)
                     st.nbytes = sum(_nbytes(x) for x in
                                     jax.tree_util.tree_leaves(host))
                 self._deliver(batch, sizes, total, host, t_disp)

@@ -13,9 +13,9 @@ under load is, by construction, the one to attack.
 
 The hop chain, in data-flow order (the ISSUE 8 vocabulary; the server's
 per-message hops ``srv_*``, ``hbm_credit`` and ``hbm_view`` of ISSUE 26 are
-listed with their sites in :data:`HOPS`, where ``d2h`` of ISSUE 28 and the
-fan-in batcher's four ``batch_*`` of ISSUE 33 are appended: the registry is
-append-only)::
+listed with their sites in :data:`HOPS`, where ``d2h`` of ISSUE 28, the
+fan-in batcher's four ``batch_*`` of ISSUE 33 and ``srv_reply_wait`` of
+ISSUE 36 are appended: the registry is append-only)::
 
     d2h        a reply's device leaves read back into host landing buffers
                (tpu/serialize.py: start every transfer, await each)
@@ -158,9 +158,17 @@ HOPS: Tuple[Tuple[str, str], ...] = (
                   "and is awaited under batch_d2h (batcher thread; "
                   "asynchronous, so not device time)"),
     ("batch_d2h", "a batch's result with a device leaf awaited on the host, "
-                  "jax.device_get: what the device still had to finish for "
-                  "it, and the read-back (completion thread; no op where "
-                  "the result has no device leaf)"),
+                  "leaf by leaf (a large one piece by piece): what the "
+                  "device still had to finish for it, and the read-back "
+                  "(completion thread; no op where the result has no "
+                  "device leaf)"),
+    # ISSUE 36: a stream handler may answer with a future
+    # (rpc/server.py _DeferredReplies): a reply's wait for its turn
+    ("srv_reply_wait", "a reply yielded as a future, from its resolution "
+                       "until its srv_send starts: the wait behind an "
+                       "earlier reply of its stream and for the thread "
+                       "that writes (counters only: one op a deferred "
+                       "reply; no one thread's time)"),
 )
 
 HOP_NAMES: Tuple[str, ...] = tuple(name for name, _ in HOPS)
@@ -325,12 +333,19 @@ class CallStages:
             self.handling = None
             self.seq += 1
 
-    def send_begin(self) -> stage:
-        return stage("srv_send").begin()
+    def send_begin(self, seq: Optional[int] = None) -> stage:
+        """``seq``: the response's ordinal in its stream, for a response
+        written by another thread than the call's own (a reply that was
+        yielded as a future): that thread's span then carries this call."""
+        if seq is None:
+            return stage("srv_send").begin()
+        return stage("srv_send", call=self.call, seq=seq).begin()
 
-    def send_end(self, tx: stage) -> None:
+    def send_end(self, tx: stage, inside: bool = True) -> None:
+        """``inside=False``: the send ran on another thread, beside the
+        handler's stage and not within it, so nothing is taken out."""
         dt = tx.end()
-        if self.handling is not None:
+        if inside and self.handling is not None:
             self.handling.exclude(dt)
         self._touch()
 

@@ -22,7 +22,7 @@ import logging
 import queue
 import threading
 import time
-from concurrent.futures import ThreadPoolExecutor
+from concurrent.futures import CancelledError, Future, ThreadPoolExecutor
 from typing import (Callable, Dict, Iterator, List, Optional,
                     Sequence, Tuple)
 
@@ -66,6 +66,10 @@ _SRV_CALLS = _obs_metrics.labeled_counter("srv_calls", ("method", "code"))
 #: flight tags for the emission sites below (pure-int plumbing — the
 #: `flight` lint rule covers this module)
 _SRV_SHED = _obs_metrics.counter("srv_admission_rejected")
+#: ISSUE 36: responses a stream handler yielded as futures, and those of
+#: them that were resolved while an earlier response of their stream was not
+_SRV_DEFERRED = _obs_metrics.counter("srv_replies_deferred")
+_SRV_OVERTAKEN = _obs_metrics.counter("srv_replies_overtaken")
 _SRV_INLINE_TAG = _flight.tag_for("srv-inline")
 _SRV_ADMIT_TAG = _flight.tag_for("srv-admission")
 _SRV_DRAIN_TAG = _flight.tag_for("srv-drain")
@@ -242,10 +246,17 @@ class RpcMethodHandler:
     ``native/include/tpurpc/server.h``; gRPC's inlineable callback methods
     are the upstream analog). The handler MUST NOT block: it stalls every
     stream on its connection.
+
+    A response-streaming behavior may yield a ``concurrent.futures.Future``
+    where it would yield a response (:class:`_DeferredReplies`): the
+    response is the future's result, serialized where it is written by
+    ``late_serializer`` (an attribute, None: ``response_serializer``; the
+    tensor shim sets it for behaviors that serialize what they yield
+    themselves).
     """
 
     __slots__ = ("kind", "behavior", "request_deserializer",
-                 "response_serializer", "inline")
+                 "response_serializer", "inline", "late_serializer")
 
     KINDS = ("unary_unary", "unary_stream", "stream_unary", "stream_stream")
 
@@ -262,6 +273,7 @@ class RpcMethodHandler:
         self.behavior = behavior
         self.request_deserializer = request_deserializer
         self.response_serializer = response_serializer
+        self.late_serializer: Optional[Serializer] = None
 
     @property
     def request_streaming(self) -> bool:
@@ -579,6 +591,245 @@ class _ServerStream:
                 yield message
             finally:
                 self.stages.handled()
+
+
+class _Reply:
+    """One response of a stream that answers with futures, in yield order:
+    the response, or (``deferred``) the future of it. ``t_done``: when it
+    was ready to go (0: its future is still open)."""
+
+    __slots__ = ("value", "deferred", "seq", "t_done")
+
+    def __init__(self, value, seq: int):
+        self.value, self.seq = value, seq
+        self.deferred = isinstance(value, Future)
+        self.t_done = 0
+
+
+class _DeferredReplies:
+    """The ordered writer of ONE stream whose behavior answers with futures
+    (ISSUE 36).
+
+    A response-streaming behavior hands its responses to the loop that
+    runs it, which is also the only thread that can pull (and, under
+    ``device=True``, land) the call's next request. A behavior that owes
+    each request an answer it does not have yet (a row it gave to a
+    :class:`tpurpc.jaxshim.FanInBatcher`) can therefore not wait for it
+    without stopping its own intake, and cannot go on without leaving its
+    answers unwritten until a client that is waiting for them sends again.
+    It yields the answer's ``concurrent.futures.Future`` instead. From the
+    first one on the call's responses go through this queue:
+
+    * **order**: responses leave in the order they were yielded, each as
+      soon as it and every earlier one of its stream are ready, whatever
+      order (and on whatever threads) the futures resolve; a plain response
+      yielded after a future queues behind it.
+    * **who writes**: whoever made the head of the queue ready. One thread
+      at a time holds ``_writing`` and writes ready responses from the head
+      until it meets one that is not; a thread that finds the flag up
+      leaves its response to the holder, who looks at the head again under
+      the lock before it lets go. No thread of this class's own: a reply
+      resolved by a batcher's completion thread is serialized
+      (``RpcMethodHandler.late_serializer``) and placed on the wire by that
+      thread, its bytes alive in ``_Reply.value`` until ``send`` returns.
+    * **bound**: at most ``stream_queue_depth`` responses wait here (the
+      bound a stream's requests have); the behavior's thread parks in
+      ``push`` beyond it, which stops its intake as a blocking send did.
+    * **failure**: a future that failed (or was cancelled) ends the call
+      with its error, once: trailers from the thread that found it
+      (``AbortError``: its status; else ``UNKNOWN``, as a behavior that
+      raised), nothing of the queue written after, and the stream
+      cancelled so that a handler thread parked in ``next(requests)``
+      wakes and unwinds its generator (which returns what it holds).
+    * **the end**: the handler thread closes the call. ``drain`` waits
+      for the queue to empty, then it writes the trailers; before it ends
+      the call for any other reason it calls ``stop``, after which no
+      other thread writes to the stream. Cancellation and the deadline
+      are looked at before every write and in every wait.
+
+    Spans and counters: ``srv_send`` one op a response, on the thread that
+    wrote it, carrying the call and the response's ordinal; hop
+    ``srv_reply_wait`` (counters only) from a future's resolution to the
+    start of its send; ``srv_replies_deferred``, ``srv_replies_overtaken``.
+    """
+
+    #: lock map (lint rule `lock`)
+    _GUARDED_BY = {"_queue": "_lock", "_writing": "_lock", "_end": "_lock"}
+
+    #: `_end` of a queue the handler thread stopped: it ends the call itself
+    _STOPPED = "stopped"
+
+    def __init__(self, conn: "_ServerConnection", handler: RpcMethodHandler,
+                 st: "_ServerStream", ctx: ServerContext, path: str):
+        self._conn, self._st, self._ctx, self._path = conn, st, ctx, path
+        #: a plain response is serialized as on the plain path; a future's
+        #: result by the handler's late serializer, where it has one
+        self._serialize = handler.response_serializer
+        self._serialize_late = (handler.late_serializer
+                                or handler.response_serializer)
+        self._depth = max(1, get_config().stream_queue_depth)
+        self._owner = threading.get_ident()  # the call's handler thread
+        self._lock = threading.Lock()
+        #: signalled when the queue shortens and when a writer lets go
+        self._moved = threading.Condition(self._lock)
+        self._queue: "collections.deque[_Reply]" = collections.deque()
+        self._writing = False
+        #: None while responses flow; else what ended them: `_STOPPED`, or
+        #: the error a writer met (that writer has ended the call)
+        self._end: object = None
+        self._seq = 0
+
+    @property
+    def failed(self) -> bool:
+        """A writer met an error and has ended the call with it."""
+        return self._end is not None and self._end is not self._STOPPED
+
+    # -- the handler thread -----------------------------------------------------
+
+    def push(self, response) -> None:
+        """Queue the behavior's next response: a future, or a plain response
+        that must not pass the futures before it."""
+        ctx = self._ctx
+        reply = _Reply(response, self._seq)
+        self._seq += 1
+        with self._lock:
+            while (len(self._queue) >= self._depth and self._end is None
+                   and ctx.is_active() and not ctx._deadline_exceeded()):
+                self._moved.wait(0.25)
+            self._queue.append(reply)
+        if reply.deferred:
+            _SRV_DEFERRED.inc()
+            # runs here and now where the future is already resolved
+            response.add_done_callback(
+                lambda _f, reply=reply: self._resolved(reply))
+        else:
+            reply.t_done = time.monotonic_ns()
+            self._pump()
+
+    def drain(self) -> bool:
+        """The behavior has yielded its last: wait until every response is
+        on the wire. False where the wait ended another way (a failure, a
+        cancel, the deadline): the caller looks which."""
+        ctx = self._ctx
+        with self._lock:
+            while ((self._queue or self._writing) and self._end is None
+                   and ctx.is_active() and not ctx._deadline_exceeded()):
+                self._moved.wait(0.25)
+            return (not self._queue and not self._writing
+                    and self._end is None)
+
+    def stop(self) -> bool:
+        """The handler thread is about to end the call: no response is
+        written after this returns. True where a writer has ended it
+        already, with a reply's error (the caller then sends nothing)."""
+        with self._lock:
+            if self._end is None:
+                self._end = self._STOPPED
+            while self._writing:
+                self._moved.wait()
+            self._queue.clear()
+        return self.failed
+
+    # -- whoever made a response ready ------------------------------------------
+
+    def _resolved(self, reply: _Reply) -> None:
+        with self._lock:
+            for earlier in self._queue:
+                if earlier is reply:
+                    break
+                if not earlier.t_done:
+                    _SRV_OVERTAKEN.inc()
+                    break
+        reply.t_done = time.monotonic_ns()
+        self._pump()
+
+    def _pump(self) -> None:
+        """Write ready responses from the head of the queue, unless another
+        thread is at it (it will see what this one made ready)."""
+        with self._lock:
+            if self._writing:
+                return
+            self._writing = True
+        while True:
+            with self._lock:
+                head = self._queue[0] if self._queue else None
+                if (self._end is not None or head is None
+                        or not head.t_done):
+                    self._writing = False
+                    self._moved.notify_all()
+                    return
+            error = self._write(head)
+            with self._lock:
+                if error is None:
+                    self._queue.popleft()
+                    self._moved.notify_all()
+                    continue
+                if self._end is not None:
+                    # the handler thread stopped the queue meanwhile: it
+                    # ends the call itself
+                    self._writing = False
+                    self._moved.notify_all()
+                    return
+                self._end = error
+            break
+        try:
+            self._end_call(error)
+        finally:
+            with self._lock:
+                self._queue.clear()
+                self._writing = False
+                self._moved.notify_all()
+
+    def _write(self, reply: _Reply) -> Optional[BaseException]:
+        """Serialize and send the head of the queue; what went wrong, if
+        anything did."""
+        ctx, st = self._ctx, self._st
+        if not ctx.is_active():
+            return fr.FrameError("call cancelled")  # nobody to tell
+        if ctx._deadline_exceeded():
+            return _rdv.SendAbandoned("deadline exceeded")
+        value, serialize = reply.value, self._serialize
+        if reply.deferred:
+            if value.cancelled():
+                return CancelledError()
+            error = value.exception()
+            if error is not None:
+                return error
+            value, serialize = value.result(), self._serialize_late
+            _lens.account("srv_reply_wait",
+                          time.monotonic_ns() - reply.t_done)
+        own = threading.get_ident() == self._owner
+        tx = st.stages.send_begin(None if own else reply.seq)
+        try:
+            self._conn.writer.send(
+                fr.MESSAGE,
+                fr.FLAG_COMPRESSED if st.peer_compressed else 0,
+                st.stream_id, serialize(value),
+                deadline=ctx._deadline, should_stop=ctx._cancelled.is_set)
+        except BaseException as exc:
+            return exc
+        finally:
+            st.stages.send_end(tx, inside=own)
+        return None
+
+    def _end_call(self, error: BaseException) -> None:
+        """A response could not be written: the call ends here, with its
+        error, on this thread (``_writing`` is still up, so the handler
+        thread's ``stop`` waits for the trailers)."""
+        conn, st, ctx = self._conn, self._st, self._ctx
+        if isinstance(error, (EndpointError, OSError)) or not ctx.is_active():
+            pass  # the connection is gone, or the caller is
+        elif isinstance(error, AbortError):
+            conn._send_trailers(st, error.code, error.details, ctx._trailing)
+        elif isinstance(error, _rdv.SendAbandoned):
+            conn._send_trailers(st, StatusCode.DEADLINE_EXCEEDED,
+                                "deadline exceeded", ctx._trailing)
+        else:
+            _log.error("a deferred response of %s failed", self._path,
+                       exc_info=error)
+            conn._send_trailers(st, StatusCode.UNKNOWN,
+                                f"Exception calling application: {error}")
+        st.cancel()
 
 
 class _ServerSink(fr.MessageSink):
@@ -1145,6 +1396,11 @@ class _ServerConnection:
 
             if handler.response_streaming:
                 for response in result:
+                    if isinstance(response, Future):
+                        # an answer that is not ready: this and the rest of
+                        # the call's responses go through an ordered queue
+                        return self._answer_deferred(
+                            handler, st, ctx, path, result, response)
                     if not ctx.is_active():
                         return
                     if ctx._deadline_exceeded():
@@ -1218,6 +1474,39 @@ class _ServerConnection:
                                 f"Exception calling application: {exc}")
         finally:
             self._finish_stream(st)
+        return False
+
+    def _answer_deferred(self, handler: RpcMethodHandler, st: _ServerStream,
+                         ctx: ServerContext, path: str, result,
+                         first: Future) -> bool:
+        """The rest of a response stream whose behavior has yielded a
+        future (:class:`_DeferredReplies`): the handler thread goes straight
+        back to the generator after each response, so it pulls the call's
+        next request while the answers to earlier ones are still open.
+        Runs inside ``_run_handler_inner``'s ``try``: what is raised here is
+        handled there, after ``stop`` has made this thread the stream's
+        only writer again."""
+        replies = _DeferredReplies(self, handler, st, ctx, path)
+        try:
+            replies.push(first)
+            for response in result:
+                if replies.failed or not ctx.is_active():
+                    return False
+                if ctx._deadline_exceeded():
+                    break
+                replies.push(response)
+            if replies.drain():
+                code = ctx._code if ctx._code is not None else StatusCode.OK
+                self._send_trailers(st, code, ctx._details, ctx._trailing)
+                return code is StatusCode.OK
+        except BaseException:
+            if replies.stop():
+                return False  # the call has failed already, with a reply's error
+            raise
+        if replies.stop() or not ctx.is_active():
+            return False
+        self._send_trailers(st, StatusCode.DEADLINE_EXCEEDED,
+                            "deadline exceeded", ctx._trailing)
         return False
 
     def _send_trailers(self, st: _ServerStream, code: StatusCode, details: str,
