@@ -113,7 +113,7 @@ uint64_t message_at(const uint8_t* ring, uint64_t cap, uint64_t mask,
 
 extern "C" {
 
-int tpr_abi_version() { return 7; }
+int tpr_abi_version() { return 8; }
 
 // --- waiter-advertisement protocol (the futex-style sleep handshake) --------
 //
@@ -433,6 +433,24 @@ int tpr_spin_u64_change(const uint8_t* addr, uint64_t old_val,
     sched_yield();
     if (now_ns() >= deadline) return 0;
   }
+}
+
+// --- one-sided placement, the interpreter released ---------------------------
+//
+// The rendezvous sender's copy into the peer's landing region
+// (rendezvous.py place_released): srcs[i] goes to base + offs[i]. Bound
+// through the CDLL handle, so the whole gather is ONE give-up of the
+// interpreter a placement, where a memoryview slice assignment is a memcpy
+// made with it held from first byte to last. The caller has checked every
+// span against the window and pins both sides (exported buffer views) for
+// the length of the call; the window's close retries on BufferError
+// meanwhile, as for the spins above.
+void tpr_place(uint8_t* base, const uint64_t* offs, const void* const* srcs,
+               const uint64_t* lens, uint32_t n) {
+  for (uint32_t i = 0; i < n; ++i)
+    std::memcpy(base + offs[i], srcs[i], lens[i]);
+  // the COMPLETE that follows (a ring post or a frame) must not pass the bytes
+  std::atomic_thread_fence(std::memory_order_release);
 }
 
 }  // extern "C"

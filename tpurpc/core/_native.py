@@ -22,7 +22,7 @@ _TRIED = False
 #: be what ran without anyone being able to say so
 _WHY = "load() not called yet"
 
-ABI_VERSION = 7
+ABI_VERSION = 8
 
 
 def _src_dir() -> str:
@@ -234,7 +234,8 @@ def load() -> "Optional[ctypes.CDLL]":
 
     # Second handle via CDLL: these calls RELEASE the GIL — they are the
     # bounded busy-poll windows (BP/BPEV disciplines), and a spinning waiter
-    # must not starve the very threads that produce what it waits for.
+    # must not starve the very threads that produce what it waits for; and
+    # the one payload-sized copy (tpr_place).
     # Callers pin the watched memory (an exported buffer view) across the
     # call; Region.close retries on BufferError until waiters unpin.
     spin = ctypes.CDLL(_lib_path())
@@ -242,6 +243,12 @@ def load() -> "Optional[ctypes.CDLL]":
     spin.tpr_ring_wait_message.argtypes = [pu8, u64, u64, u64, u64]
     spin.tpr_spin_u64_change.restype = ctypes.c_int
     spin.tpr_spin_u64_change.argtypes = [pu8, u64, u64]
+    # the rendezvous sender's gather copy into the peer's landing region
+    # (rendezvous.place_released): on THIS handle because a payload-sized
+    # memcpy made holding the GIL holds every other thread of the process
+    spin.tpr_place.restype = None
+    spin.tpr_place.argtypes = [pu8, pu64, ctypes.POINTER(ctypes.c_void_p),
+                               pu64, ctypes.c_uint32]
     global _SPIN
     _SPIN = spin
     return _LIB

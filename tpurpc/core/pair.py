@@ -185,7 +185,7 @@ class Window:
     """A write handle onto the *peer's* region (ref: ``MemoryRegion`` envelope shipping
     an ``ibv_mr`` descriptor, ``memory_region.h:14-47``)."""
 
-    __slots__ = ("write", "view", "_close")
+    __slots__ = ("write", "view", "_close", "touched")
 
     def __init__(self, write: Callable[[int, bytes], None],
                  close: Callable[[], None] = lambda: None,
@@ -193,6 +193,9 @@ class Window:
         self.write = write  # write(offset, data) — one-sided, no peer CPU involved
         self.view = view    # mapped memory when host-addressable (native path)
         self._close = close
+        #: bytes from the start of ``view`` this process has written through
+        #: it, so their pages are mapped here (rendezvous._place_spans)
+        self.touched = 0
 
     def close(self) -> None:
         self._close()

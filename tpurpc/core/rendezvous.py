@@ -92,7 +92,9 @@ the framed path, default 5).
 
 from __future__ import annotations
 
+import ctypes
 import itertools
+import mmap
 import os
 import struct
 import threading
@@ -103,6 +105,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from tpurpc.analysis.locks import make_condition, make_lock
+from tpurpc.core import _native
 from tpurpc.core import pair as _pair
 from tpurpc.core import transport as _transport
 from tpurpc.obs import flight as _flight
@@ -143,6 +146,12 @@ _RDV_RECV = _metrics.counter("rdv_transfers_received")
 #: on this path; what fell back to the framed path is the rest (a server's
 #: replies are what it sends, so on a server this is the reply side)
 _RDV_SENT_BYTES = _metrics.counter("rdv_bytes_sent")
+#: placements whose copy into the peer's window ran with the interpreter
+#: released (``place_released``), and their bytes: a sender whose every
+#: message left one-sided through a view-backed window reads
+#: rdv_place_released_bytes == rdv_bytes_sent
+_RDV_PLACE_RELEASED = _metrics.counter("rdv_place_released")
+_RDV_PLACE_RELEASED_BYTES = _metrics.counter("rdv_place_released_bytes")
 #: payload bytes those received transfers delivered (the Python plane's
 #: twin of the C table's native_rdv_recv_bytes): over the payload a
 #: receiver took in all, the share of traffic that stayed on this path
@@ -190,6 +199,7 @@ _DOORBELL = struct.Struct("<Q")     # consumer-freed count (see below)
 
 _MIN_CLASS = 64 * 1024
 _ALIGN = 64
+_PAGE = mmap.PAGESIZE
 _NONCE_BYTES = 16
 _MAX_TRANSFER = 1 << 30  # sanity bound on one offer
 _WINDOW_CACHE = 64       # open peer-region windows kept per link
@@ -205,8 +215,105 @@ _SENTINEL_REFUSED = object()
 #: test seams (tests/test_chaos.py, tools/rendezvous_smoke.py): a receiver
 #: with drop_offers set ignores OFFERs entirely (claim-starved sender); a
 #: sender with wedge_after_claim set blocks there until the event fires or
-#: the link dies (peer-death-mid-rendezvous chaos scenario)
+#: the link dies (peer-death-mid-rendezvous chaos scenario); place_pinned
+#: is called by a placement that holds its window's pin and has copied
+#: nothing yet (a close that meets a placement in flight)
 TEST_HOOKS: Dict[str, object] = {}
+
+
+def place_released(view: memoryview,
+                   placed: Sequence[Tuple[int, memoryview]]) -> int:
+    """The sender's one payload-sized copy: each ``(offset, bytes)`` of
+    ``placed`` goes into ``view``, a peer region's mapped window, WITHOUT
+    the interpreter. ``view[a:b] = src`` is a memcpy made holding it from
+    first byte to last: 4 MiB is half a millisecond in which no other
+    thread of the process runs, eight times a batch on a server that
+    answers a fan-in (PERF.md 6, PR 38). The spans go in one native call on
+    the handle that releases it (``tpr_place``: one give-up a placement,
+    header and leaves together); without the native library numpy copies
+    span by span, which releases it for all but the smallest (numpy keeps
+    it under 500 elements, so a header is copied as it always was).
+
+    What the interpreter used to guarantee is pinned instead: an exported
+    array over ``view`` for the length of the copy, so a close of the
+    window from another thread meets ``BufferError`` and retries
+    (``_close_window``) and nothing is unmapped under a copy in flight;
+    the sources are pinned the same way. A window already closed raises
+    ``ValueError`` here, before any byte moves, as the slice assignment
+    did. Returns the bytes placed."""
+    dst, base = _native.pin(view, writable=True)
+    hook = TEST_HOOKS.get("place_pinned")
+    if hook is not None:
+        hook()
+    offs, lens, pins = [], [], []
+    for off, src in placed:
+        n = len(src)
+        if n == 0:
+            continue
+        if off < 0 or off + n > len(dst):
+            raise ValueError(f"placement of {n} bytes at {off} leaves the "
+                             f"{len(dst)}-byte window")
+        offs.append(off)
+        lens.append(n)
+        pins.append(_native.pin(src, writable=False))
+    if not pins:
+        return 0
+    spin = _native.load_spin()
+    if spin is not None:
+        k = len(pins)
+        u64s = ctypes.c_uint64 * k
+        spin.tpr_place(base, u64s(*offs),
+                       (ctypes.c_void_p * k)(*[addr for _, addr in pins]),
+                       u64s(*lens), k)
+    else:
+        for off, n, (src, _) in zip(offs, lens, pins):
+            np.copyto(dst[off:off + n], src)
+    total = sum(lens)
+    _RDV_PLACE_RELEASED.inc()
+    _RDV_PLACE_RELEASED_BYTES.inc(total)
+    return total
+
+
+def _place_spans(win: _pair.Window,
+                 placed: Sequence[Tuple[int, memoryview]]) -> None:
+    """One placement, whatever the window's domain: the released copy
+    where the peer's region is mapped here, the domain's own one-sided
+    write a span where it is not (verbs WRs, tcp_window records).
+
+    Pages this mapping has not written yet are touched first, a byte a
+    page, WITH the interpreter: a first-touch fault taken while the other
+    threads of the process run is ten times one taken while they stand
+    still, on the host the cells run on (a 4 MiB placement into a fresh
+    region: 12 ms under the slice assignment, 139 to 152 ms released, 0.5 ms
+    into pages already mapped; eight clients' warm-up messages added 1.2
+    to 2.8 s to ``setup_s``; PERF.md 6, PR 38). The touch costs what the
+    faults always cost, once a region a process."""
+    view = win.view
+    if view is None:
+        for off, src in placed:
+            win.write(off, src)
+        return
+    end = max((off + len(src) for off, src in placed), default=0)
+    if end > win.touched:
+        for off, src in placed:
+            n = len(src)
+            if n:
+                view[off:off + n:_PAGE] = bytes(-(-n // _PAGE))
+        win.touched = end
+    place_released(view, placed)
+
+
+def _close_window(win: _pair.Window) -> None:
+    """Close a window that no cache holds any more. A placement still in
+    flight through it (another sender thread's: ``place_released`` runs
+    without the interpreter) pins the mapping, so the release meets
+    ``BufferError`` until that copy returns; a window whose pin outlives
+    the retry stays mapped, which is a leak and not a write into unmapped
+    memory."""
+    try:
+        _pair.retry_buffer_op(win.close)
+    except Exception:
+        pass
 
 
 def _env(name: str, default: str) -> str:
@@ -806,10 +913,7 @@ class _WindowShare:
                     except ValueError:
                         pass
         if stale is not None:
-            try:
-                stale[0].close()
-            except Exception:
-                pass
+            _close_window(stale[0])
         win = self._domain(kind).open_window(handle, nbytes)
         with self._lock:
             if key not in self._entries:
@@ -838,10 +942,7 @@ class _WindowShare:
             else:
                 close_now.append(win)  # private window (see acquire)
         for w in close_now:
-            try:
-                w.close()
-            except Exception:
-                pass
+            _close_window(w)
 
     def stats(self) -> Dict[str, int]:
         with self._lock:
@@ -861,10 +962,7 @@ class _WindowShare:
             domains = list(self._domains.values())
             self._domains.clear()
         for w in wins:
-            try:
-                w.close()
-            except Exception:
-                pass
+            _close_window(w)
         for d in domains:
             try:
                 d.close()
@@ -1387,8 +1485,9 @@ class RdvLink:
     def _rdv_write(self, claim: _Claim, segs: Sequence, total: int) -> None:
         """The one-sided placement: every gather segment lands directly in
         the claimed region — no staging join, no landing copy on the other
-        side. One RDMA WRITE per segment on the verbs domain, one
-        memoryview copy per segment on the software domains."""
+        side. One RDMA WRITE per segment on the verbs domain; on the
+        software domains one copy of the whole gather list, made with the
+        interpreter released (``place_released``)."""
         t0 = time.monotonic_ns()
         win = self._window_for(claim)
         view = win.view
@@ -1401,23 +1500,16 @@ class RdvLink:
                               "claimed handle resolves to different memory "
                               "on this host")
 
-        def _place() -> None:
-            off = claim.offset
-            if view is not None:
-                for seg in segs:
-                    sv = memoryview(seg).cast("B")
-                    view[off:off + len(sv)] = sv
-                    off += len(sv)
-            else:
-                for seg in segs:
-                    sv = memoryview(seg).cast("B")
-                    win.write(off, sv)
-                    off += len(sv)
-
+        off = claim.offset
+        placed = []
+        for seg in segs:
+            sv = memoryview(seg).cast("B")
+            placed.append((off, sv))
+            off += len(sv)
         # the one-sided landing is a cross-process message: under simnet
         # the store itself becomes a deliverable, reorderable event (a
         # straggler's write must land only in quarantined memory)
-        _transport.dispatch("write", self, _place)
+        _transport.dispatch("write", self, _place_spans, win, placed)
         _ledger.rdma_write(total)
         dt = time.monotonic_ns() - t0
         _LENS_RDV_NS.inc(dt)
@@ -1811,16 +1903,9 @@ class GrantWriter:
             placed.append((off, sv))
             total += len(sv)
 
-        def _place() -> None:
-            for off, sv in placed:
-                if view is not None:
-                    view[off:off + len(sv)] = sv
-                else:
-                    win.write(off, sv)
-
         # the block placement is a cross-process one-sided write: simnet
         # reorders/crashes it against the COMPLETE that must follow it
-        _transport.dispatch("write", self, _place)
+        _transport.dispatch("write", self, _place_spans, win, placed)
         _ledger.rdma_write(total)
         dt = time.monotonic_ns() - t0
         _LENS_RDV_NS.inc(dt)
