@@ -113,7 +113,7 @@ uint64_t message_at(const uint8_t* ring, uint64_t cap, uint64_t mask,
 
 extern "C" {
 
-int tpr_abi_version() { return 8; }
+int tpr_abi_version() { return 9; }
 
 // --- waiter-advertisement protocol (the futex-style sleep handshake) --------
 //
@@ -445,12 +445,18 @@ int tpr_spin_u64_change(const uint8_t* addr, uint64_t old_val,
 // span against the window and pins both sides (exported buffer views) for
 // the length of the call; the window's close retries on BufferError
 // meanwhile, as for the spins above.
-void tpr_place(uint8_t* base, const uint64_t* offs, const void* const* srcs,
-               const uint64_t* lens, uint32_t n) {
+//
+// Returns the monotonic stamp taken when the copy is done (CLOCK_MONOTONIC,
+// the clock time.monotonic_ns() reads): the caller, which has the
+// interpreter again only some time after this returns, subtracts it from its
+// own clock and so measures what the give-up cost it (lens hop place_return).
+uint64_t tpr_place(uint8_t* base, const uint64_t* offs,
+                   const void* const* srcs, const uint64_t* lens, uint32_t n) {
   for (uint32_t i = 0; i < n; ++i)
     std::memcpy(base + offs[i], srcs[i], lens[i]);
   // the COMPLETE that follows (a ring post or a frame) must not pass the bytes
   std::atomic_thread_fence(std::memory_order_release);
+  return now_ns();
 }
 
 }  // extern "C"

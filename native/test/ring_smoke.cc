@@ -439,7 +439,7 @@ void test_rdv_closed_link_falls_back() {
 }  // namespace
 
 int main() {
-  CHECK(tpr_abi_version() == 8);
+  CHECK(tpr_abi_version() == 9);
   test_roundtrip();
   test_lease();
   test_spsc_threads();

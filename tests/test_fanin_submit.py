@@ -14,7 +14,7 @@ import pytest
 
 from tpurpc.jaxshim import service
 from tpurpc.jaxshim.service import FanInBatcher
-from tpurpc.obs import metrics
+from tpurpc.obs import lens, metrics
 from tpurpc.tpu import ledger
 from tpurpc.tpu.hbm_ring import HbmRing
 
@@ -149,6 +149,49 @@ def test_flush_by_size_timer_and_close_returns_every_lease_once(
                    "lens_batch_d2h_ops": 0,
                    "lens_batch_stack_bytes": rows * 128,
                    "lens_batch_stack_copy_bytes": rows * 128}
+
+
+@pytest.mark.parametrize("leased", [True, False])
+def test_a_leased_batch_waits_once_under_batch_ready_inside_its_stack(
+        leased, events, monkeypatch):
+    """ISSUE 39: the batcher thread's one wait for the device is a hop of
+    its own, a child of ``batch_stack`` (not taken out of it), one op a
+    batch whose rows hold credit and none for a batch without leases."""
+    monkeypatch.setattr(lens, "_CPU_EVERY", 1)   # every batch is clocked
+    fn, seen = recorder(events)
+    hops = ("lens_batch_ready_ops", "lens_batch_ready_busy_ns",
+            "lens_batch_ready_cpu_ns", "lens_batch_ready_bytes",
+            "lens_batch_stack_ops", "lens_batch_stack_busy_ns",
+            "lens_batch_stack_cpu_ns", "lens_batch_run_ops",
+            "lens_batch_run_busy_ns", "lens_batch_run_cpu_ns")
+    before = counters()
+    b = FanInBatcher(fn, max_batch=4, max_delay_s=60.0, fixed_bucket=True,
+                     occupancy=True)
+    try:
+        futures = [b.submit(device_row(k), leases=[FakeLease(events, k)]
+                            if leased else ()) for k in range(4)]
+        for f in futures:
+            assert f.result(30) is None
+    finally:
+        b.close()
+    got = moved(before, *hops)
+    assert got["lens_batch_stack_ops"] == got["lens_batch_run_ops"] == 1
+    assert events.count(("ready", 4)) == (1 if leased else 0)
+    if leased:
+        assert got["lens_batch_ready_ops"] == 1
+        assert got["lens_batch_ready_bytes"] == 4 * 128
+        assert 0 < got["lens_batch_ready_busy_ns"] <= (
+            got["lens_batch_stack_busy_ns"])
+        assert got["lens_batch_ready_cpu_ns"] <= (
+            got["lens_batch_stack_cpu_ns"])
+    else:
+        assert got["lens_batch_ready_ops"] == 0
+    # fn's dispatch on its own, on a core at most all of the time (no
+    # `> 0`: these stages are short of a step of a coarse thread clock; the
+    # stack less the dispatch keeps the dispatch's own reads of the clock
+    # on its CPU and not on its wall, so it is held to no such bound)
+    assert 0 <= got["lens_batch_run_cpu_ns"] <= got["lens_batch_run_busy_ns"]
+    assert 0 <= got["lens_batch_stack_cpu_ns"]
 
 
 # -- one program, one shape ------------------------------------------------------

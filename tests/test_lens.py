@@ -5,6 +5,7 @@ The profiler tests drive ``sample_once`` with SYNTHETIC frames so the
 stage attribution is deterministic; the scrape/shard tests run real
 servers (the routes exist to be curled)."""
 
+import itertools
 import json
 import socket
 import threading
@@ -518,7 +519,7 @@ def test_stage_begin_end_pair_and_exclude():
     before = _hop("srv_handler")
     st = lens.stage("srv_handler", 5).begin()
     time.sleep(0.02)
-    st.exclude(15_000_000)  # a sibling's 15 ms ran inside this interval
+    st.exclude(15_000_000, 0)  # a sibling's 15 ms ran inside this interval
     dt = st.end()
     after = _hop("srv_handler")
     assert after["ops"] == before["ops"] + 1
@@ -526,10 +527,306 @@ def test_stage_begin_end_pair_and_exclude():
     assert 4_000_000 <= dt < 20_000_000
 
 
+# -- two clocks a stage (ISSUE 39): thread CPU beside wall ----------------------
+# No timing assert a loaded runner can break: a sleep's wall has a floor and
+# its CPU a ceiling far below it; a spin is driven by the thread's own clock.
+
+@pytest.fixture(autouse=True)
+def every_message_clocked(monkeypatch):
+    """One message in N reads the CPU clock: N pinned to 1, so that a test
+    can assert on the ``cpu_ns`` of the one stage it runs. And this thread
+    as one that was never given a message, whatever an earlier test left
+    on it."""
+    monkeypatch.setattr(lens, "_CPU_EVERY", 1)
+    for name in ("ids", "clocked"):
+        lens._tls.__dict__.pop(name, None)
+
+
+def _clocks(name):
+    snap = metrics.registry().counters_snapshot()
+    return {k: snap[f"lens_{name}_{k}"] for k in ("busy_ns", "cpu_ns", "ops")}
+
+
+def _since(name, before):
+    after = _clocks(name)
+    return {k: after[k] - before[k] for k in after}
+
+
+def _spin_cpu(ns):
+    """Burn ``ns`` of THIS thread's CPU, by its own clock."""
+    until = time.thread_time_ns() + ns
+    while time.thread_time_ns() < until:
+        pass
+
+
+def test_a_stage_that_sleeps_reads_wall_and_nearly_no_cpu():
+    before = _clocks("jax_array")
+    with lens.stage("jax_array") as st:
+        time.sleep(0.05)
+    got = _since("jax_array", before)
+    assert got["ops"] == 1
+    assert got["busy_ns"] >= 50_000_000
+    assert got["cpu_ns"] == st.cpu_ns < 25_000_000
+
+
+def test_a_stage_that_spins_reads_its_threads_cpu():
+    before = _clocks("jax_array")
+    with lens.stage("jax_array") as st:
+        _spin_cpu(10_000_000)
+    got = _since("jax_array", before)
+    assert got["cpu_ns"] == st.cpu_ns >= 10_000_000
+    # on a core at most all of the time
+    assert got["busy_ns"] >= got["cpu_ns"] - 1_000_000
+
+
+def test_a_stage_reads_its_own_threads_cpu_and_no_other():
+    """A thread that burns CPU beside a sleeping stage is not billed to it:
+    the second clock is the calling thread's, not the process's."""
+    stop = threading.Event()
+
+    def burn():
+        while not stop.is_set():
+            pass
+
+    t = threading.Thread(target=burn, daemon=True)
+    t.start()
+    try:
+        before = _clocks("jax_array")
+        with lens.stage("jax_array"):
+            time.sleep(0.05)
+        got = _since("jax_array", before)
+    finally:
+        stop.set()
+        t.join(5)
+    assert not t.is_alive()
+    assert got["busy_ns"] >= 50_000_000 and got["cpu_ns"] < 25_000_000
+
+
+def test_exclude_takes_a_sibling_out_on_both_clocks():
+    before = {h: _clocks(h) for h in ("srv_handler", "srv_send")}
+    outer = lens.stage("srv_handler").begin()
+    _spin_cpu(5_000_000)
+    inner = lens.stage("srv_send").begin()
+    _spin_cpu(10_000_000)
+    dt = inner.end()
+    outer.exclude(dt, inner.cpu_ns)
+    outer.end()
+    handler = _since("srv_handler", before["srv_handler"])
+    send = _since("srv_send", before["srv_send"])
+    assert send["cpu_ns"] == inner.cpu_ns >= 10_000_000
+    assert send["busy_ns"] == dt
+    # the outer stage keeps its own 5 ms and none of the sibling's 10
+    assert 5_000_000 <= handler["cpu_ns"] == outer.cpu_ns < 10_000_000
+    assert handler["busy_ns"] >= 5_000_000
+
+
+def test_call_stages_stay_additive_on_both_clocks():
+    """``CallStages.send_end`` inside an open ``srv_handler``: the send's
+    CPU is the send's, not the handler's too."""
+    before = {h: _clocks(h) for h in ("srv_handler", "srv_send")}
+    stages = lens.CallStages(lambda: None)
+    stages.handle(0)
+    tx = stages.send_begin()
+    _spin_cpu(10_000_000)
+    stages.send_end(tx)
+    stages.handled()
+    assert _since("srv_send", before["srv_send"])["cpu_ns"] >= 10_000_000
+    assert _since("srv_handler", before["srv_handler"])["cpu_ns"] < 10_000_000
+
+
+def test_begin_and_end_across_a_generators_yield():
+    """What the call path does: a stage opened before a ``yield`` and ended
+    after it, on the thread that drives the generator."""
+    def behavior():
+        st = lens.stage("srv_handler", 3).begin()
+        _spin_cpu(2_000_000)
+        yield "reply"
+        _spin_cpu(2_000_000)
+        st.end()
+        yield st
+
+    before = _clocks("srv_handler")
+    gen = behavior()
+    assert next(gen) == "reply"
+    time.sleep(0.02)       # the consumer's own time, inside the interval
+    st = next(gen)
+    got = _since("srv_handler", before)
+    assert got["ops"] == 1
+    assert got["busy_ns"] >= 20_000_000
+    assert 4_000_000 <= got["cpu_ns"] == st.cpu_ns < got["busy_ns"]
+
+
+def test_a_message_is_clocked_whole_or_not_at_all(monkeypatch):
+    """Where reading the thread's clock is dear, one message in N reads it:
+    the stage that opens the message on its thread decides (its hop's every
+    N-th), the stages nested under it inherit that, a clocked stage bills
+    its CPU times N and an unclocked one reads no clock at all."""
+    monkeypatch.setattr(lens, "_CPU_EVERY", 4)
+    monkeypatch.setitem(lens._OPENED, "srv_recv", itertools.count())
+    reads = metrics.counter("lens_cpu_clock_reads")
+    call = next(lens._CALL_IDS)
+    billed = []
+    for seq in range(6):
+        before = {h: _clocks(h) for h in ("srv_recv", "decode", "hbm")}
+        r0 = reads.snapshot()
+        with lens.stage("srv_recv", call=call, seq=seq) as outer:
+            with lens.stage("decode") as mid:
+                _spin_cpu(2_000_000)
+                with lens.stage("hbm") as inner:      # inherits too
+                    _spin_cpu(1_000_000)
+        got = {h: _since(h, before[h]) for h in before}
+        billed.append((outer.cpu_ns, mid.cpu_ns, inner.cpu_ns,
+                       reads.snapshot() - r0, got))
+    for k in (0, 4):
+        outer_cpu, mid_cpu, inner_cpu, n_reads, got = billed[k]
+        assert n_reads == 6
+        assert outer_cpu >= mid_cpu >= 3_000_000 > inner_cpu >= 1_000_000
+        assert got["decode"]["cpu_ns"] == 4 * mid_cpu
+        assert got["hbm"]["cpu_ns"] == 4 * inner_cpu
+        assert got["srv_recv"]["cpu_ns"] == 4 * outer_cpu
+    for k in (1, 2, 3, 5):
+        outer_cpu, mid_cpu, inner_cpu, n_reads, got = billed[k]
+        assert (outer_cpu, mid_cpu, inner_cpu, n_reads) == (0, 0, 0, 0)
+        assert all(g["cpu_ns"] == 0 and g["ops"] == 1 and g["busy_ns"] > 0
+                   for g in got.values())
+    # the same pair again (a batch's second stage) opens no new message: it
+    # counts nothing (a count would raise here) and inherits the last
+    # decision, which was not to read
+    monkeypatch.setattr(lens, "_clocked", lambda hop: 1 / 0)
+    r0 = reads.snapshot()
+    with lens.stage("batch_run", call=call, seq=5) as again:
+        pass
+    assert reads.snapshot() - r0 == 0 and again.cpu_ns == 0
+
+
+def test_every_nth_opening_of_a_hop_is_clocked_whoever_opens_it(monkeypatch):
+    """``_clocked``: a hop's every N-th opening, its first among them, and
+    exactly so when threads open it at once (the count is one step of the
+    interpreter: none is lost and none counted twice)."""
+    monkeypatch.setattr(lens, "_CPU_EVERY", 7)
+    monkeypatch.setitem(lens._OPENED, "jax_array", itertools.count())
+    assert [lens._clocked("jax_array") for _ in range(15)] == [
+        k % 7 == 0 for k in range(15)]
+    monkeypatch.setitem(lens._OPENED, "jax_array", itertools.count())
+    tally = []
+
+    def open_many():
+        tally.append(sum(lens._clocked("jax_array") for _ in range(2_500)))
+
+    threads = [threading.Thread(target=open_many) for _ in range(4)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(30)
+    assert sum(tally) == -(-10_000 // 7)
+    # a thread that was never given a message decides a stage at a time
+    out = []
+
+    def bare():
+        monkeypatch.setitem(lens._OPENED, "jax_array", itertools.count())
+        for _ in range(8):
+            with lens.stage("jax_array") as st:
+                _spin_cpu(200_000)
+            out.append(st.cpu_ns > 0)
+
+    t = threading.Thread(target=bare)
+    t.start()
+    t.join(30)
+    assert out == [True] + [False] * 6 + [True]
+
+
+def test_account_bumps_no_cpu():
+    before = _clocks("srv_call")
+    lens.account("srv_call", 7_000_000, 11)
+    assert _since("srv_call", before) == {
+        "busy_ns": 7_000_000, "cpu_ns": 0, "ops": 1}
+
+
+def test_every_waterfall_row_has_cpu_ms_and_the_text_a_column():
+    before = _clocks("jax_array")
+    with lens.stage("jax_array", 1 << 20):
+        _spin_cpu(3_000_000)
+    got = _since("jax_array", before)
+    assert 3_000_000 <= got["cpu_ns"] <= got["busy_ns"]
+    doc = lens.waterfall()
+    assert all("cpu_ms" in r for r in doc["hops"])
+    row = next(r for r in doc["hops"] if r["hop"] == "jax_array")
+    assert row["cpu_ms"] >= (before["cpu_ns"] + 3_000_000) / 1e6 - 0.001
+    text = lens.render_text(doc).splitlines()
+    assert text[0].split()[:6] == ["hop", "GB/s", "MiB", "busy_ms", "cpu_ms",
+                                   "copy_MiB"]
+    # which messages read the second clock and what a read costs here, and
+    # the observers' own share, in the document and under the table
+    assert doc["cpu_clock"]["every"] == 1 and doc["cpu_clock"]["reads"] >= 2
+    assert set(doc["observers"]) == {"cpu_ms", "ticks", "us_a_tick"}
+    assert text[-1].startswith("observers: ") and "us a tick" in text[-1]
+    # a document of a member that predates the column still renders
+    old = {"hops": [{k: v for k, v in r.items() if k != "cpu_ms"}
+                    for r in doc["hops"]], "slowest_hop": None}
+    assert "jax_array" in lens.render_text(old)
+
+
+def test_the_process_clocks_advance_with_every_snapshot():
+    a = metrics.registry().counters_snapshot()
+    _spin_cpu(2_000_000)
+    b = metrics.registry().counters_snapshot()
+    assert b["proc_wall_ns"] - a["proc_wall_ns"] >= 2_000_000
+    assert b["proc_cpu_ns"] - a["proc_cpu_ns"] >= 2_000_000
+    now = time.monotonic_ns()
+    assert 0 <= now - b["proc_wall_ns"] < 60_000_000_000
+
+
+def _observers():
+    """Each background loop under ``obs/``, as (start, stop): private
+    instances at a period short enough to tick within the test; the
+    watchdog's sweeper has no stop, so it is the process's own (a sweep
+    every 0.25 s, as under any server)."""
+    from tpurpc.obs import collector, slo, tsdb, watchdog
+
+    sampler = StageProfiler(hz=200)
+    historian = tsdb.Tsdb(fine_s=0.01)
+    pager = slo.SloEvaluator(eval_s=0.01, tsdb=historian)
+    fleet = collector.FleetCollector([], poll_s=0.01)
+    return {
+        "profiler": (sampler.start, sampler.stop),
+        "tsdb": (historian.start, historian.stop),
+        "slo": (pager.start, pager.stop),
+        "watchdog": (watchdog.get()._ensure_thread, lambda: None),
+        "collector": (fleet.start, fleet.stop),
+    }
+
+
+@pytest.mark.parametrize("loop", ["profiler", "tsdb", "slo", "watchdog",
+                                  "collector"])
+def test_every_observer_loop_bills_its_own_ticks(loop):
+    ticks = metrics.counter("obs_bg_ticks")
+    cpu = metrics.counter("obs_bg_cpu_ns")
+    start, stop = _observers()[loop]
+    t0, c0 = ticks.snapshot(), cpu.snapshot()
+    start()
+    try:
+        deadline = time.monotonic() + 10
+        while ticks.snapshot() < t0 + 2 and time.monotonic() < deadline:
+            time.sleep(0.01)
+    finally:
+        stop()
+    assert ticks.snapshot() >= t0 + 2
+    assert cpu.snapshot() > c0
+    # the operator's view of the pair: GET /debug/waterfall
+    seen = lens.waterfall()["observers"]
+    assert seen["ticks"] >= t0 + 2
+    assert seen["cpu_ms"] > 0 and seen["us_a_tick"] > 0
+
+
 def test_every_hop_has_an_ops_counter_and_the_list_only_grows():
     snap = metrics.registry().counters_snapshot()
     for hop in lens.HOP_NAMES:
         assert f"lens_{hop}_ops" in snap
+        assert f"lens_{hop}_cpu_ns" in snap
+    # ISSUE 39 appended its two hops at the end and moved nothing
+    assert lens.HOP_NAMES[-3:] == ("srv_reply_wait", "batch_ready",
+                                   "place_return")
+    assert lens.HOP_NAMES.index("srv_reply_wait") == 25
     assert lens.HOP_NAMES[:12] == (
         "device", "send_ring", "wire", "rendezvous", "ctrl", "native_send",
         "native_recv", "native_rdv", "peer_ring", "decode", "hbm",

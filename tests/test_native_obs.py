@@ -51,6 +51,19 @@ def obs_plane(ring_platform):
     flight.RECORDER.reset()
 
 
+def test_the_plane_outlives_a_reload_of_the_library(obs_plane):
+    """``_native.reset_for_tests()`` (a test that ran the Python data plane
+    in this worker before) hands out a new handle: the plane declares its
+    signatures on it again, and still reads the region's name as bytes."""
+    from tpurpc.core import _native
+
+    assert obs_plane.available()
+    _native.reset_for_tests()
+    assert _native.load() is not None
+    assert obs_plane.available()
+    assert isinstance(obs_plane.counters(), dict) and obs_plane.counters()
+
+
 def _run_child(script, **env_extra):
     """``script`` in a fresh interpreter on the ring platform: for what the C
     side reads once, at first use."""

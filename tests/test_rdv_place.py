@@ -14,6 +14,7 @@ after the copy, not under it."""
 import os
 import sys
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -161,6 +162,63 @@ def test_placement_outside_the_window_raises_before_any_byte(plane):
     finally:
         win.close()
         region.close()
+
+
+def _place_return():
+    snap = _metrics.registry().counters_snapshot()
+    return {k: snap[f"lens_place_return_{k}"]
+            for k in ("ops", "busy_ns", "bytes", "cpu_ns")}
+
+
+def test_a_native_placement_is_one_op_of_place_return_and_numpy_none(plane):
+    """ISSUE 39: the price of the give-up is measured where it is paid. The
+    native copy stamps its own end and ``place_released`` reads the clock
+    as its first act on return: one op a placement, its bytes the bytes
+    placed, a time that cannot be negative and no thread's CPU; numpy's
+    copy has no stamp and makes no op. Empty placements copy nothing and
+    count nothing."""
+    dom = _pair.make_domain("local")
+    region = dom.alloc(1 << 20)
+    win = dom.open_window(region.handle, 1 << 20)
+    try:
+        placed, end = _spans(_SEGMENTS["header_and_leaf"](), 64)
+        before = _place_return()
+        t0 = time.monotonic_ns()
+        n = rdv.place_released(win.view, placed)
+        t1 = time.monotonic_ns()
+        assert rdv.place_released(win.view, [(5, memoryview(b""))]) == 0
+        after = _place_return()
+        got = {k: after[k] - before[k] for k in after}
+        if plane == "native":
+            assert got["ops"] == 1 and got["bytes"] == n == end - 64
+            assert 0 <= got["busy_ns"] <= t1 - t0
+            assert got["cpu_ns"] == 0
+        else:
+            assert got == {"ops": 0, "busy_ns": 0, "bytes": 0, "cpu_ns": 0}
+    finally:
+        win.close()
+        region.close()
+
+
+def test_the_native_stamp_is_on_the_callers_clock():
+    """``tpr_place`` returns ``CLOCK_MONOTONIC`` as ``time.monotonic_ns``
+    reads it: taken after the copy, it lies between the caller's own
+    stamps before the call and after it."""
+    import ctypes
+
+    spin = _native.load_spin()
+    if spin is None:
+        pytest.skip(f"no native library: {_native.status()}")
+    dst, base = _native.pin(bytearray(_LEAF), writable=True)
+    src, addr = _native.pin(bytes(_pattern(_LEAF, 12)), writable=False)
+    one = ctypes.c_uint64 * 1
+    for _ in range(3):
+        t0 = time.monotonic_ns()
+        stamp = spin.tpr_place(base, one(0), (ctypes.c_void_p * 1)(addr),
+                               one(_LEAF), 1)
+        t1 = time.monotonic_ns()
+        assert t0 <= stamp <= t1
+    assert bytes(dst) == bytes(src)
 
 
 @pytest.mark.parametrize("kind", _KINDS)

@@ -22,7 +22,7 @@ _TRIED = False
 #: be what ran without anyone being able to say so
 _WHY = "load() not called yet"
 
-ABI_VERSION = 8
+ABI_VERSION = 9
 
 
 def _src_dir() -> str:
@@ -245,8 +245,9 @@ def load() -> "Optional[ctypes.CDLL]":
     spin.tpr_spin_u64_change.argtypes = [pu8, u64, u64]
     # the rendezvous sender's gather copy into the peer's landing region
     # (rendezvous.place_released): on THIS handle because a payload-sized
-    # memcpy made holding the GIL holds every other thread of the process
-    spin.tpr_place.restype = None
+    # memcpy made holding the GIL holds every other thread of the process;
+    # it returns the monotonic stamp of the copy's end (hop place_return)
+    spin.tpr_place.restype = u64
     spin.tpr_place.argtypes = [pu8, pu64, ctypes.POINTER(ctypes.c_void_p),
                                pu64, ctypes.c_uint32]
     global _SPIN

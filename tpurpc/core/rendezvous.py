@@ -240,7 +240,13 @@ def place_released(view: memoryview,
     (``_close_window``) and nothing is unmapped under a copy in flight;
     the sources are pinned the same way. A window already closed raises
     ``ValueError`` here, before any byte moves, as the slice assignment
-    did. Returns the bytes placed."""
+    did. Returns the bytes placed.
+
+    The native call stamps the end of its copy on the monotonic clock and
+    the first thing done on return is to read that clock again: the
+    difference is one op of hop ``place_return``, what this thread paid to
+    have the interpreter back (numpy's copy has no such stamp and makes no
+    op)."""
     dst, base = _native.pin(view, writable=True)
     hook = TEST_HOOKS.get("place_pinned")
     if hook is not None:
@@ -259,16 +265,20 @@ def place_released(view: memoryview,
     if not pins:
         return 0
     spin = _native.load_spin()
+    total = sum(lens)
     if spin is not None:
         k = len(pins)
         u64s = ctypes.c_uint64 * k
-        spin.tpr_place(base, u64s(*offs),
-                       (ctypes.c_void_p * k)(*[addr for _, addr in pins]),
-                       u64s(*lens), k)
+        copied_ns = spin.tpr_place(
+            base, u64s(*offs),
+            (ctypes.c_void_p * k)(*[addr for _, addr in pins]),
+            u64s(*lens), k)
+        # the price of the give-up, taken where it is paid: the copy ended
+        # at C's stamp, and this thread runs again only now
+        _lens.account("place_return", time.monotonic_ns() - copied_ns, total)
     else:
         for off, n, (src, _) in zip(offs, lens, pins):
             np.copyto(dst[off:off + n], src)
-    total = sum(lens)
     _RDV_PLACE_RELEASED.inc()
     _RDV_PLACE_RELEASED_BYTES.inc(total)
     return total

@@ -1091,10 +1091,14 @@ class FanInBatcher:
                             out = self._fn(stacked)
                         out, away = self._ask_back(out)
                     finally:
-                        stacking.exclude(running.end())
+                        stacking.exclude(running.end(), running.cpu_ns)
                         if leased:
-                            # a row's HBM is in use until the copy is done
-                            jax.block_until_ready(stacked)
+                            # a row's HBM is in use until the copy is done:
+                            # the thread's one wait for the device, a hop
+                            # of its own inside batch_stack
+                            with _lens.stage("batch_ready", stacking.nbytes,
+                                             call=ordinal, seq=total):
+                                jax.block_until_ready(stacked)
                 finally:
                     stacked = None
                     self._release(batch)

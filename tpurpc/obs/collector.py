@@ -48,6 +48,7 @@ import time
 import urllib.request
 from typing import Dict, List, Optional, Tuple
 
+from tpurpc.obs import metrics as _metrics
 from tpurpc.obs.tsdb import ResetClamp
 
 __all__ = ["FleetCollector", "resolve_targets"]
@@ -160,6 +161,7 @@ class FleetCollector:
                 self.poll_once()
             except Exception:
                 pass  # a collector crash helps nobody
+            _metrics.observer_tick()
 
     def start(self) -> None:
         if self._thread is not None and self._thread.is_alive():

@@ -14,8 +14,9 @@ under load is, by construction, the one to attack.
 The hop chain, in data-flow order (the ISSUE 8 vocabulary; the server's
 per-message hops ``srv_*``, ``hbm_credit`` and ``hbm_view`` of ISSUE 26 are
 listed with their sites in :data:`HOPS`, where ``d2h`` of ISSUE 28, the
-fan-in batcher's four ``batch_*`` of ISSUE 33 and ``srv_reply_wait`` of
-ISSUE 36 are appended: the registry is append-only)::
+fan-in batcher's four ``batch_*`` of ISSUE 33, ``srv_reply_wait`` of
+ISSUE 36 and ``batch_ready`` and ``place_return`` of ISSUE 39 are
+appended: the registry is append-only)::
 
     d2h        a reply's device leaves read back into host landing buffers
                (tpu/serialize.py: start every transfer, await each)
@@ -41,10 +42,20 @@ Cost model — why this is ALWAYS on, like the rest of the obs stack:
 
 * accounting sites run once per **batched operation** (a drain, a gathered
   writev, a tree decode), never per byte: two ``time.monotonic_ns`` reads
-  and three or four GIL-atomic Counter bumps per op;
+  and three or four GIL-atomic Counter bumps per op, and for the second
+  clock (below) a thread-local look, or one step of a counter where the
+  stage opens a message. ``thread_time_ns`` is a system call: 0.27 us a
+  read in the sandbox, 5.6 to 6.2 on the chip's host in a tight loop (13
+  as the clocked stages of a loaded server time it, 46 cold), where
+  ``monotonic_ns`` is 0.07 | 0.09 and none; and that host's clock moves in
+  steps of 10 ms. Read at both ends of every stage it took 18% off
+  ``stream4m_c1`` (PERF.md 6, PR 39); hence one message in N. What a whole
+  ``with`` of an empty stage costs, clocked and not, on both hosts:
+  PERF.md 6, PR 39. Five to nine stages a 4 MiB message;
 * a site is one :class:`stage` (``with lens.stage("hbm", n): ...``): it
-  bumps the hop's ``bytes``, ``busy_ns``, ``ops`` (and ``copy_bytes``) on
-  exit, and in a process that has imported jax it is also a
+  bumps the hop's ``bytes``, ``busy_ns``, ``cpu_ns``, ``ops`` (and
+  ``copy_bytes``) on exit, and in a process that has imported jax it is
+  also a
   ``jax.profiler.TraceAnnotation`` named ``tpurpc.<hop>``, so the stages
   lie on the device trace's own clock whenever a profiler session runs
   (no session, or no jax: no annotation is made). Older
@@ -57,6 +68,43 @@ Cost model — why this is ALWAYS on, like the rest of the obs stack:
   that matters holds regardless: every hop's effective GB/s is an upper
   bound on the end-to-end rate through it, so the MINIMUM names the
   bottleneck.
+
+Two clocks a stage (ISSUE 39). ``busy_ns`` is the wall clock between
+``begin`` and ``end``; ``cpu_ns`` is the CALLING THREAD's CPU clock over the
+same interval (``CLOCK_THREAD_CPUTIME_ID``: time the thread was on a core,
+in Python or under it, whoever held the interpreter). ``busy_ns - cpu_ns``
+is the time the thread spent OFF a core, and what that is depends on the
+kind of stage:
+
+* a stage with no blocking call of its own (``decode`` less its children,
+  ``hbm_view``, the fan-in cells' hand-over ``srv_handler - decode``): the
+  line for the interpreter, and the OS's run queue;
+* a stage around a dispatch (``hbm``: ``jax.device_put``; ``batch_run``:
+  ``fn``, the cut, the asks; ``batch_stack`` less ``batch_ready``: the stack
+  program's dispatch): the line, plus whatever the runtime blocks on inside
+  the call;
+* a stage that IS a wait (``srv_recv``, ``hbm_credit``, ``rdv_credit``,
+  ``d2h``'s awaits, ``batch_d2h``, ``batch_ready``): the wait; its ``cpu_ns``
+  is what the wait itself costs (a spin, a poll, the wake).
+
+CPU spent with the interpreter held and CPU spent beside it (a released
+``memcpy``, the runtime's own work on the caller's thread) are one number:
+the thread's clock cannot tell them apart. Hops billed by :func:`account`
+or by hand (:func:`hop_counters`) are no one thread's interval and keep
+``cpu_ns`` 0. ``cpu_ns`` is an ESTIMATE, because the read of the clock is
+dear ("the second clock" at :class:`stage`): one message in N is clocked,
+whole, and billed times N (``_CPU_EVERY``, a constant).
+``lens_cpu_clock_reads`` counts the reads made, and a hop's ``cpu_ns`` over its ``busy_ns`` is a
+ratio of a sample's CPU to everybody's wall, to be read over thousands of
+ops, not tens.
+
+``proc_cpu_ns`` / ``proc_wall_ns`` (``obs/metrics.py``, set at every export)
+are the process's CPU and the monotonic clock: a window's delta of the two
+is the cores the process kept busy, and the denominator of any share "of
+the window"; ``obs_bg_cpu_ns`` / ``obs_bg_ticks`` are the observers' own
+threads (the sampler, the tsdb, the SLO loop, the watchdog, a collector),
+bumped by each once a tick; ``GET /debug/waterfall`` shows both, and their
+quotient, a tick's CPU, under ``observers``.
 
 The copy ledger is folded in: each hop row carries ``copy_bytes`` (bytes
 that hop moved via a host memcpy / staging copy) so the table shows copies
@@ -169,6 +217,17 @@ HOPS: Tuple[Tuple[str, str], ...] = (
                        "earlier reply of its stream and for the thread "
                        "that writes (counters only: one op a deferred "
                        "reply; no one thread's time)"),
+    # ISSUE 39: the two places where a thread of the server stands still
+    # for something that is not the interpreter, each by itself
+    ("batch_ready", "the batcher's one wait for the device: until the "
+                    "stacked batch of rows that hold credit is ready "
+                    "(block_until_ready), inside batch_stack; one op a "
+                    "leased batch (batcher thread)"),
+    ("place_return", "a released placement's way back: from the end of "
+                     "tpr_place's copy, stamped in C with the interpreter "
+                     "given up, until its thread has the interpreter again "
+                     "(counters only: one op a native placement; the "
+                     "interval starts on no Python thread)"),
 )
 
 HOP_NAMES: Tuple[str, ...] = tuple(name for name, _ in HOPS)
@@ -177,12 +236,14 @@ _BYTES: Dict[str, _metrics.Counter] = {}
 _NS: Dict[str, _metrics.Counter] = {}
 _COPY: Dict[str, _metrics.Counter] = {}
 _OPS: Dict[str, _metrics.Counter] = {}
+_CPU: Dict[str, _metrics.Counter] = {}
 _SPAN: Dict[str, str] = {}
 for _name, _desc in HOPS:
     _BYTES[_name] = _metrics.counter(f"lens_{_name}_bytes")
     _NS[_name] = _metrics.counter(f"lens_{_name}_busy_ns")
     _COPY[_name] = _metrics.counter(f"lens_{_name}_copy_bytes")
     _OPS[_name] = _metrics.counter(f"lens_{_name}_ops")
+    _CPU[_name] = _metrics.counter(f"lens_{_name}_cpu_ns")
     _SPAN[_name] = f"tpurpc.{_name}"
 
 
@@ -201,12 +262,62 @@ def hop_counters(name: str) -> Tuple[_metrics.Counter, _metrics.Counter,
 
 # -- the stage primitive (ISSUE 26) ---------------------------------------------
 
-#: the thread's current ``(call, seq)``: set by the call path's top-level
-#: stage of each message, read by the stages nested under it so that every
-#: span of one message carries the same pair
-_tls = threading.local()
+class _Thread(threading.local):
+    """What a thread's stages share. Defaults on the class: a thread that
+    was never given a message reads them at the price of a hit (a miss on a
+    ``threading.local`` costs eight times one)."""
+
+    #: the thread's current ``(call, seq)``: set by the call path's
+    #: top-level stage of each message, read by the stages nested under it
+    #: so that every span of one message carries the same pair
+    ids: Optional[Tuple[int, int]] = None
+    #: whether that message's stages read the CPU clock (below)
+    clocked: Optional[bool] = None
+
+
+_tls = _Thread()
 _CALL_IDS = itertools.count(1)
 _annotation = None  # jax.profiler.TraceAnnotation, once this process has it
+
+# -- the second clock, and what reading it costs (ISSUE 39) ---------------------
+#
+# ``time.thread_time_ns()`` is a system call (CLOCK_THREAD_CPUTIME_ID has no
+# vDSO path) made holding the interpreter: 0.27 us on a plain Linux host; on
+# the sandboxed kernel of the chip's host 6 us in a tight loop and 13 to 19
+# on a loaded server, where read at both ends of EVERY stage it took 18%
+# off ``stream4m_c1`` (PERF.md 6, PR 39). So the clock is read for one message
+# (or batch, or reply) in N, whole: the decision is made by the stage that
+# opens it on its thread (the one given ``call=`` with a pair the thread
+# does not have yet; on a thread that was never given one, every stage for
+# itself) and the stages nested under it inherit it, so that a parent less
+# its children, and ``exclude``, stay exact within every clocked message. A
+# clocked stage bills its CPU times N: every N-th message a hop opens is
+# clocked, whatever it holds (a systematic sample: fair to all of them
+# unless a hop's costs repeat with a period that divides N: a prime, so
+# that no rotation of two, four or eight connections does). N is a
+# constant: at 31 the reads, ten a clocked message, cost ``stream4m_c1``
+# 1.5% on that host (370 reads a second: a sparse read costs the pace
+# about 40 us, three times what it is timed at) and nothing that pairs of
+# runs resolve in the other cells, and a hop that opens a hundred messages
+# a second still has fifty clocked in a 15 s window; the cost goes as 1/N
+# and the error of a twin as its root (PERF.md 6, PR 39). (Timed when the
+# module is loaded, the read gave 6 us or 45, process by process, on that
+# host, so nothing is timed. On a host with a fine clock and a cheap read
+# one message in 31 is sample enough.)
+_CPU_EVERY = 31
+#: per deciding hop: the messages it has opened
+_OPENED = {name: itertools.count() for name in HOP_NAMES}
+_CPU_READS = _metrics.counter("lens_cpu_clock_reads")
+_OBS_BG_CPU = _metrics.counter("obs_bg_cpu_ns")
+_OBS_BG_TICKS = _metrics.counter("obs_bg_ticks")
+
+
+def _clocked(hop: str) -> bool:
+    """Whether the message that a stage of ``hop`` opens now reads the CPU
+    clock: the hop's every ``_CPU_EVERY``-th does, its first among them
+    (``next`` of a count is one step of the interpreter: threads share it
+    without a lock and none is counted twice)."""
+    return next(_OPENED[hop]) % _CPU_EVERY == 0
 
 
 def _annotation_cls():
@@ -225,7 +336,11 @@ class stage:
     between them).
 
     On exit, also when the body raised: ``lens_<hop>_busy_ns`` += elapsed,
-    ``lens_<hop>_bytes`` += ``nbytes``, ``lens_<hop>_ops`` += 1,
+    ``lens_<hop>_cpu_ns`` += the calling thread's CPU over the same
+    interval where this message reads the CPU clock, times the N it is
+    one of (see "the second clock" above; what was read is left in
+    ``cpu_ns``, 0 where it was not, for a caller that takes this stage out
+    of another), ``lens_<hop>_bytes`` += ``nbytes``, ``lens_<hop>_ops`` += 1,
     ``lens_<hop>_copy_bytes`` += ``copy``. ``nbytes`` / ``copy`` may be
     set on the object inside the body, for sites that only know the size
     once the work is done. In a process that has imported jax the stage is
@@ -236,12 +351,16 @@ class stage:
     the thread (the call path does, once per message); nested stages
     inherit it. ``begin`` and ``end`` run on one thread."""
 
-    __slots__ = ("hop", "nbytes", "copy", "_t0", "_span")
+    __slots__ = ("hop", "nbytes", "copy", "cpu_ns", "_t0", "_c0", "_span")
 
     def __init__(self, hop: str, nbytes: int = 0, *,
                  call: Optional[int] = None, seq: int = 0):
         if call is not None:
-            _tls.ids = (call, seq)
+            ids = (call, seq)
+            if _tls.ids != ids:
+                # a pair the thread does not have yet: a new message on it
+                _tls.ids = ids
+                _tls.clocked = _clocked(hop)
         self.hop = hop
         self.nbytes = nbytes
         self.copy = 0
@@ -251,19 +370,33 @@ class stage:
         # no session: a 50 ns look, not a 0.5 us annotation that records
         # nothing
         if ann is not None and ann.is_enabled():
-            call, seq = getattr(_tls, "ids", (0, 0))
+            call, seq = _tls.ids or (0, 0)
             self._span = ann(_SPAN[self.hop], call=call, seq=seq)
             self._span.__enter__()
         else:
             self._span = None
+        clocked = _tls.clocked
+        if clocked is None:  # a thread that was never given a message
+            clocked = _clocked(self.hop)
+        # the wall interval encloses the CPU interval, so a clocked stage's
+        # cpu_ns <= its busy_ns whatever the reads themselves cost
         self._t0 = time.monotonic_ns()
+        self._c0 = time.thread_time_ns() if clocked else None
         return self
 
     def end(self) -> int:
-        dt = time.monotonic_ns() - self._t0
+        hop = self.hop
+        c0 = self._c0
+        if c0 is None:
+            dt = time.monotonic_ns() - self._t0
+            self.cpu_ns = 0
+        else:
+            self.cpu_ns = cpu = time.thread_time_ns() - c0
+            dt = time.monotonic_ns() - self._t0
+            _CPU[hop].inc(cpu * _CPU_EVERY)
+            _CPU_READS.inc(2)
         if self._span is not None:
             self._span.__exit__(None, None, None)
-        hop = self.hop
         _NS[hop].inc(dt)
         _BYTES[hop].inc(self.nbytes)
         _OPS[hop].inc()
@@ -271,12 +404,18 @@ class stage:
             _COPY[hop].inc(self.copy)
         return dt
 
-    def exclude(self, ns: int) -> None:
-        """Take ``ns`` of a sibling stage that ran inside this one's
-        interval out of this one's busy time (a response sent while the
-        handler's stage is open), so that the call path's top-level stages
-        stay additive."""
+    def exclude(self, ns: int, cpu_ns: int) -> None:
+        """Take a sibling stage that ran inside this one's interval out of
+        this one, ``ns`` of its busy time and ``cpu_ns`` of its CPU (the
+        sibling's ``cpu_ns`` after its ``end``: as read, before it is
+        billed times N; a response sent while the handler's stage is
+        open), so that the call path's top-level stages stay additive on
+        both clocks. (To a read: a clocked sibling's own two reads of the
+        thread's clock lie inside its wall and half outside its CPU, so
+        this stage keeps about one read of CPU that its wall gave away.)"""
         self._t0 += ns
+        if self._c0 is not None:
+            self._c0 += cpu_ns
 
     __enter__ = begin
 
@@ -287,9 +426,10 @@ class stage:
 
 def account(hop: str, busy_ns: int, nbytes: int = 0, ops: int = 1) -> None:
     """One operation of ``hop`` timed by the caller: counters only, no span
-    (``srv_call``: the duration the call path already computes;
-    ``srv_queue``: a wait that is no thread's time). ``ops``: that many,
-    their times added up by the caller (``batch_wait``: a batch's rows)."""
+    and no ``cpu_ns`` (``srv_call``: the duration the call path already
+    computes; ``srv_queue``: a wait that is no thread's time). ``ops``:
+    that many, their times added up by the caller (``batch_wait``: a
+    batch's rows)."""
     _NS[hop].inc(busy_ns)
     _BYTES[hop].inc(nbytes)
     _OPS[hop].inc(ops)
@@ -346,7 +486,7 @@ class CallStages:
         handler's stage and not within it, so nothing is taken out."""
         dt = tx.end()
         if inside and self.handling is not None:
-            self.handling.exclude(dt)
+            self.handling.exclude(dt, tx.cpu_ns)
         self._touch()
 
     def finish(self) -> None:
@@ -389,12 +529,21 @@ def waterfall() -> dict:
             "hop": name,
             "bytes": b,
             "busy_ms": round(ns / 1e6, 3),
+            "cpu_ms": round(_CPU[name].snapshot() / 1e6, 3),
             "gbps": round(b / ns, 3) if ns else 0.0,
             "copy_bytes": cp,
             "ops": _OPS[name].snapshot(),
             "what": desc,
         })
-    out = {"hops": rows, "slowest_hop": slowest_hop(rows)}
+    bg_cpu, bg_ticks = _OBS_BG_CPU.snapshot(), _OBS_BG_TICKS.snapshot()
+    out = {"hops": rows, "slowest_hop": slowest_hop(rows),
+           # which messages read the second clock, and how often it was read
+           "cpu_clock": {"every": _CPU_EVERY,
+                         "reads": _CPU_READS.snapshot()},
+           # what the measurement takes: the obs/ loops' own threads
+           "observers": {"cpu_ms": round(bg_cpu / 1e6, 3), "ticks": bg_ticks,
+                         "us_a_tick": (round(bg_cpu / bg_ticks / 1e3, 1)
+                                       if bg_ticks else 0.0)}}
     try:
         from tpurpc.tpu import ledger
 
@@ -428,14 +577,21 @@ def render_text(doc: Optional[dict] = None) -> str:
     doc = doc if doc is not None else waterfall()
     rows = doc["hops"]
     lines = [f"{'hop':<10} {'GB/s':>8} {'MiB':>10} {'busy_ms':>10} "
-             f"{'copy_MiB':>9}  what"]
+             f"{'cpu_ms':>10} {'copy_MiB':>9}  what"]
     lines.append("-" * len(lines[0]))
     for r in rows:
         mark = " <-- slowest" if r["hop"] == doc.get("slowest_hop") else ""
         lines.append(
             f"{r['hop']:<10} {r['gbps']:>8.3f} "
             f"{r['bytes'] / (1 << 20):>10.1f} {r['busy_ms']:>10.1f} "
+            f"{r.get('cpu_ms', 0.0):>10.1f} "
             f"{r['copy_bytes'] / (1 << 20):>9.1f}  {r['what'][:46]}{mark}")
     if doc.get("slowest_hop") is None:
         lines.append("(no traffic yet: every hop idle)")
+    obs, clock = doc.get("observers"), doc.get("cpu_clock")
+    if obs and clock:  # a member that predates them sends neither
+        lines.append(
+            f"observers: {obs['cpu_ms']:.1f} ms of CPU in {obs['ticks']} "
+            f"ticks ({obs['us_a_tick']:.1f} us a tick); cpu_ms: every "
+            f"{clock['every']}. message clocked ({clock['reads']} reads)")
     return "\n".join(lines) + "\n"

@@ -28,6 +28,7 @@ from __future__ import annotations
 
 import math
 import threading
+import time
 import weakref
 from collections import defaultdict
 from typing import Callable, Dict, Optional, Tuple
@@ -35,7 +36,7 @@ from typing import Callable, Dict, Optional, Tuple
 __all__ = [
     "Counter", "Gauge", "Histogram", "FleetGauge", "LabeledCounter",
     "Registry", "registry", "counter", "gauge", "histogram", "fleet",
-    "labeled_counter", "snapshot", "reset",
+    "labeled_counter", "snapshot", "reset", "observer_tick",
 ]
 
 
@@ -399,6 +400,42 @@ class Registry:
 
 
 _REGISTRY = Registry()
+
+# ISSUE 39: the process's own two clocks, as of every export. A window's
+# delta of the pair is the CPU the process spent (every thread, Python or
+# not) and the window's length, so a share "of the window" and the cores
+# kept busy need nothing from outside the snapshot. Assigned, not bumped:
+# the clocks own the totals (as the C table owns ``native_*``).
+_PROC_CPU = _REGISTRY.counter("proc_cpu_ns")
+_PROC_WALL = _REGISTRY.counter("proc_wall_ns")
+
+
+def _sync_proc_clocks() -> None:
+    _PROC_CPU.value = time.process_time_ns()
+    _PROC_WALL.value = time.monotonic_ns()
+
+
+_REGISTRY.add_collector(_sync_proc_clocks)
+
+# ...and the observers' own share of it: every background loop under obs/
+# (the stage sampler, the tsdb, the SLO loop, the watchdog, a collector)
+# bills its thread's CPU here once a tick
+_OBS_BG_CPU = _REGISTRY.counter("obs_bg_cpu_ns")
+_OBS_BG_TICKS = _REGISTRY.counter("obs_bg_ticks")
+
+
+_obs_tls = threading.local()
+
+
+def observer_tick() -> None:
+    """One tick of an ``obs/`` background loop is over: bill the calling
+    thread's CPU since its tick before (since the thread began, at its
+    first: ``time.thread_time_ns()`` counts from there) to
+    ``obs_bg_cpu_ns`` and count the tick in ``obs_bg_ticks``."""
+    now = time.thread_time_ns()
+    _OBS_BG_CPU.inc(now - getattr(_obs_tls, "cpu", 0))
+    _obs_tls.cpu = now
+    _OBS_BG_TICKS.inc()
 
 
 def registry() -> Registry:

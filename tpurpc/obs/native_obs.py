@@ -84,7 +84,9 @@ _U64 = struct.Struct("<Q")
 _U32 = struct.Struct("<I")
 
 _lock = threading.Lock()
-_bound = False
+#: the handle whose tpr_obs_* signatures are declared: a reload of the
+#: library (_native.reset_for_tests) hands out a new one that has none
+_bound: Optional[object] = None
 
 
 class _Map:
@@ -134,7 +136,7 @@ def _lib():
     if lib is None or not hasattr(lib, "tpr_obs_enabled"):
         return None
     global _bound
-    if not _bound:
+    if _bound is not lib:
         import ctypes
 
         lib.tpr_obs_enabled.restype = ctypes.c_int
@@ -146,7 +148,7 @@ def _lib():
         lib.tpr_obs_reset.argtypes = []
         lib.tpr_obs_postfork.restype = None
         lib.tpr_obs_postfork.argtypes = []
-        _bound = True
+        _bound = lib
     return lib
 
 
@@ -354,4 +356,4 @@ def reset_for_tests() -> None:
         if isinstance(_state, _Map):
             _state.close()
         _state = None
-        _bound = False
+        _bound = None

@@ -45,6 +45,8 @@ import time
 from collections import deque
 from typing import Dict, List, Optional, Tuple
 
+from tpurpc.obs import metrics as _metrics
+
 __all__ = [
     "STAGES", "DEFAULT_HZ", "register_stages", "markers", "StageProfiler",
     "get", "ensure_started", "stop", "snapshot", "collapsed_text",
@@ -221,6 +223,7 @@ class StageProfiler:
                 self._refresh_names()
             except Exception:
                 pass  # the profiler must never take anything down
+            _metrics.observer_tick()
 
     # -- lifecycle -----------------------------------------------------------
 

@@ -354,7 +354,7 @@ def aggregate_waterfall() -> dict:
             hop = row.get("hop")
             if hop not in merged:
                 merged[hop] = {"hop": hop, "bytes": 0, "busy_ms": 0.0,
-                               "copy_bytes": 0, "ops": 0,
+                               "cpu_ms": 0.0, "copy_bytes": 0, "ops": 0,
                                "what": row.get("what", "")}
                 order.append(hop)
             # tpurpc-argus: these SUM raw per-shard counters — exactly the
@@ -364,6 +364,8 @@ def aggregate_waterfall() -> dict:
                 (k, hop, "bytes"), int(row.get("bytes") or 0)))
             merged[hop]["busy_ms"] += clamp.clamp(
                 (k, hop, "busy_ms"), float(row.get("busy_ms") or 0.0))
+            merged[hop]["cpu_ms"] += clamp.clamp(
+                (k, hop, "cpu_ms"), float(row.get("cpu_ms") or 0.0))
             merged[hop]["copy_bytes"] += int(clamp.clamp(
                 (k, hop, "copy_bytes"), int(row.get("copy_bytes") or 0)))
             merged[hop]["ops"] += int(clamp.clamp(
@@ -374,6 +376,7 @@ def aggregate_waterfall() -> dict:
         ns = r["busy_ms"] * 1e6
         r["gbps"] = round(r["bytes"] / ns, 3) if ns else 0.0
         r["busy_ms"] = round(r["busy_ms"], 3)
+        r["cpu_ms"] = round(r["cpu_ms"], 3)
         rows.append(r)
     live = [r for r in rows if r["bytes"] > 0 and r["busy_ms"] > 0]
     return {"hops": rows,

@@ -351,6 +351,7 @@ class SloEvaluator:
                 self.evaluate_once()
             except Exception:
                 pass  # the pager must never take the server down
+            _metrics.observer_tick()
 
     def start(self) -> None:
         if self._thread is not None and self._thread.is_alive():

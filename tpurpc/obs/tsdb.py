@@ -296,6 +296,7 @@ class Tsdb:
                 self.sample_once()
             except Exception:
                 pass  # the historian must never take anything down
+            _metrics.observer_tick()
 
     def start(self) -> None:
         if self._thread is not None and self._thread.is_alive():

@@ -254,6 +254,7 @@ class StallWatchdog:
                 self.sweep_once()
             except Exception:  # the watchdog must never take anything down
                 _log.exception("watchdog sweep failed")
+            _metrics.observer_tick()
 
     def _stall_bar_ns(self, method: str) -> int:
         bar = int(self.min_stall_s * 1e9)

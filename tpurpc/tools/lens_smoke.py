@@ -6,8 +6,8 @@ asserts the three lens faces work end to end:
 
 * the stage-tagged sampling profiler attributes samples to >=3 known
   stages (and the unattributed share stays under the 20% bar);
-* ``/debug/waterfall`` reports EVERY declared hop with nonzero bytes and
-  names a slowest hop;
+* ``/debug/waterfall`` reports EVERY declared hop, nonzero bytes on each
+  hop the traffic crosses, and names a slowest hop;
 * ``python -m tpurpc.tools.timeline`` against this process + the
   subprocess emits a Perfetto-loadable chrome-trace JSON with >=2 named
   process lanes, rebased on per-process clock anchors.
@@ -31,6 +31,12 @@ import urllib.request
 os.environ.setdefault("GRPC_PLATFORM_TYPE", "RDMA_BPEV")
 os.environ.setdefault("TPURPC_LENS_HZ", "200")  # smoke: sample fast
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
+
+#: the hops a ring-plane tensor duplex, unary echoes and one HbmRing
+#: landing move bytes through
+_DRIVEN_HOPS = ("device", "send_ring", "wire", "rendezvous", "ctrl",
+                "peer_ring", "decode", "hbm", "jax_array", "srv_recv",
+                "srv_queue", "srv_handler", "hbm_view")
 
 _PEER_CODE = r"""
 import sys, time
@@ -127,8 +133,13 @@ def run() -> int:
         wf = _get_json(port, "/debug/waterfall")
         by_hop = {r["hop"]: r for r in wf["hops"]}
         assert tuple(by_hop) == lens.HOP_NAMES, by_hop.keys()
-        idle = [h for h, r in by_hop.items() if r["bytes"] == 0]
+        # every hop this smoke's traffic crosses; the hops appended since
+        # for paths it does not drive (the native plane, device replies,
+        # the fan-in batcher, a refused or released sender) stay idle here
+        # and have their own tests
+        idle = [h for h in _DRIVEN_HOPS if by_hop[h]["bytes"] == 0]
         assert not idle, f"hops with zero bytes after traffic: {idle}"
+        assert all("cpu_ms" in r for r in wf["hops"]), wf["hops"][0]
         assert wf["slowest_hop"] in by_hop, wf["slowest_hop"]
         assert "ledger" in wf, "copy ledger not folded into the waterfall"
         text = _get_text(port, "/debug/waterfall?text=1")

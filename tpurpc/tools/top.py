@@ -254,9 +254,20 @@ def render(cur: Dict, prev: Optional[Dict], dt: float,
                                                  else "")
                 for r in hops)
             lines.append(f"flow  GB/s by hop: {cells}")
+            # the second clock: of a hop's busy time, what its threads
+            # spent on a core (the rest: a wait, or the line for the
+            # interpreter; lens.py says which by kind of stage)
+            lines.append("      cpu/busy ms: " + "  ".join(
+                f"{r['hop']} {r.get('cpu_ms', 0.0):.0f}/{r['busy_ms']:.0f}"
+                for r in hops))
             if slow:
                 lines.append(f"      slowest hop: {slow} "
                              "(* = the hop to attack)")
+        obs = waterfall.get("observers")
+        if obs:  # what the obs/ loops' own threads take (lens.waterfall)
+            lines.append(
+                f"      observers: {obs['cpu_ms']:.0f} ms cpu in "
+                f"{obs['ticks']} ticks ({obs['us_a_tick']:.0f} us a tick)")
     # tpurpc-argus SLO alerts pane (/debug/slo): objective/track states
     # with burn rates — the page an operator would get, rendered live
     if slo is not None:
