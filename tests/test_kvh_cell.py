@@ -300,7 +300,8 @@ def test_an_admission_that_never_waited_reads_zero():
 def test_the_manifest_gained_the_cell_and_lost_nothing():
     assert manifest_check.problems(MANIFEST, ROOT) == []
     assert [e["name"] for e in KVH] == list(EXPECT)
-    assert MANIFEST["per_layer"][-len(EXPECT):] == KVH
+    start = MANIFEST["per_layer"].index(KVH[0])
+    assert MANIFEST["per_layer"][start:start + len(EXPECT)] == KVH
     assert all(e["moves"] == "hbm_gbytes_s" for e in KVH)
     assert [w["name"] for w in MANIFEST["workloads"]] == [
         "stream4m_c1", "stream4m_c8", "pingpong4m_c1", "fanin4m_c8",

@@ -7,10 +7,13 @@ the copy-ledger zero-host-landing-copy proof, the framed fallback, the
 flight/watchdog evidence, and the TPU-plane halves (SerializeFromDevice
 into a window, descriptor-only codec)."""
 
+import collections
 import gc
 import heapq
+import mmap
 import threading
 import time
+import uuid
 
 import numpy as np
 import pytest
@@ -195,17 +198,20 @@ class _CreditRig:
     (None: until ``free()``); a send that falls back is delivered by a
     framed stand-in, so ``got`` is what the stream layer would see."""
 
-    def __init__(self, monkeypatch, regions=4):
+    def __init__(self, monkeypatch, regions=4, budget=None):
         from tpurpc.obs import metrics as _metrics
 
         self.clock = _FakeTime()
         monkeypatch.setattr(rdv, "time", self.clock)
-        self.pool = rdv.LandingPool("local", budget=regions * _REGION)
+        self.pool = rdv.LandingPool("local", budget=budget or
+                                    regions * _REGION)
         monkeypatch.setattr(rdv, "_pools", {"local": self.pool})
         self._metrics = _metrics.registry().metrics()
         self.got = []         # sequence numbers, in delivery order
         self.held = []        # [seq, body] the consumer has not let go of
         self.residence = lambda seq: 0.0
+        self.ops = collections.Counter()  # (side, op) -> control ops sent
+        self._tail = b""
         self.links = []
         self.a, self.b = self.pair()
         self.seq = 0
@@ -213,12 +219,18 @@ class _CreditRig:
 
     def pair(self):
         ends = {}
-        a = rdv.RdvLink("a", lambda *op: ends["b"].on_op(*op),
-                        lambda *m: None, pool_kinds=("local",),
-                        open_kinds=("local",), pump=self.clock.pump)
-        b = rdv.RdvLink("b", lambda *op: ends["a"].on_op(*op),
-                        self.deliver, pool_kinds=("local",),
-                        open_kinds=("local",))
+
+        def to(side, peer):
+            def send(*op):
+                self.ops[side, op[0]] += 1
+                ends[peer].on_op(*op)
+            return send
+
+        a = rdv.RdvLink("a", to("a", "b"), lambda *m: None,
+                        pool_kinds=("local",), open_kinds=("local",),
+                        pump=self.clock.pump)
+        b = rdv.RdvLink("b", to("b", "a"), self.deliver,
+                        pool_kinds=("local",), open_kinds=("local",))
         ends["a"], ends["b"] = a, b
         a.negotiated = b.negotiated = True
         self.links += [a, b]
@@ -242,17 +254,21 @@ class _CreditRig:
             self.held.remove(cell)
             cell[1] = None  # the wrapper's last alias: rings the doorbell
 
-    def next_payload(self):
+    def next_payload(self, nbytes=_MSG):
+        """A message's gather list: its sequence number and a stamp, then
+        zeros."""
         seq, self.seq = self.seq, self.seq + 1
-        return seq, (seq.to_bytes(8, "little")
-                     + bytes([seq % 251]) * (_MSG - 8))
+        if len(self._tail) < nbytes - 16:
+            self._tail = bytes(nbytes - 16)
+        head = seq.to_bytes(8, "little") + bytes([seq % 251]) * 8
+        return seq, [head, memoryview(self._tail)[:nbytes - 16]]
 
-    def send(self, link=None):
+    def send(self, link=None, nbytes=_MSG):
         """One message; True if it went one-sided."""
-        _, payload = self.next_payload()
-        ok = (link or self.a).send_message(1, 0, [payload], _MSG)
+        _, segs = self.next_payload(nbytes)
+        ok = (link or self.a).send_message(1, 0, segs, nbytes)
         if not ok:
-            self.deliver(1, 0, memoryview(bytearray(payload)))
+            self.deliver(1, 0, memoryview(bytearray(b"".join(segs))))
         return ok
 
     def counters(self):
@@ -427,13 +443,13 @@ def _credit_cut(rig, how):
         kw["should_stop"] = lambda: bool(stopped)
     else:
         kw["deadline"] = rig.clock.monotonic() + 0.0023
-    seq, payload = rig.next_payload()
+    seq, segs = rig.next_payload()
     if how == "closed":
         # the framed path is next, where the dead transport raises
-        assert not rig.a.send_message(1, 0, [payload], _MSG, **kw)
+        assert not rig.a.send_message(1, 0, segs, _MSG, **kw)
     else:
         with pytest.raises(rdv.SendAbandoned):
-            rig.a.send_message(1, 0, [payload], _MSG, **kw)
+            rig.a.send_message(1, 0, segs, _MSG, **kw)
     assert 2.25 <= rig.clock.ms() - t0 <= 2.45   # not the bound's 3 ms
     moved = rig.moved()
     assert moved["credit_waits"] == 1 and moved["credit_expired"] == 0
@@ -617,6 +633,368 @@ def test_fan_in_over_a_small_pool_stays_on_rendezvous(fresh_config):
         srv.stop(grace=1)
         rdv._pools.clear()
         rdv._pools.update(old_pools)
+
+
+# ---------------------------------------------------------------------------
+# one window a link: the largest class it streams, ridden by smaller messages
+# ---------------------------------------------------------------------------
+
+_BIG = 120_000                        # one 128 KiB-class message
+_BIG_REGION = 131_072 + 64 + 16 + 8
+_WINDOW_COUNTERS = ("rdv_window_received", "rdv_window_rides",
+                    "rdv_window_returns", "rdv_pool_reclaims",
+                    "rdv_claims_refused", "rdv_fallbacks")
+
+
+def _window_counts(rig):
+    m = rig._metrics
+    return {k.replace("rdv_", ""): m[k].snapshot() for k in _WINDOW_COUNTERS}
+
+
+def _window_moved(rig, base):
+    now = _window_counts(rig)
+    return {k: now[k] - base[k] for k in now}
+
+
+def _warm_window(rig, nbytes):
+    """Messages to a consumer that lets go at once until the link holds a
+    whole window of ``nbytes``'s class."""
+    for _ in range(5):
+        assert rig.send(nbytes=nbytes)
+        rig.clock.sleep(0)
+    assert len(rig.a._grants[rdv.size_class(nbytes)]) == rdv._PREGRANT_DEPTH
+
+
+def _window_ride(rig):
+    """A 64 KiB-class message rides a free 128 KiB standing region, with no
+    claim and no grant of its own class; it never takes a region another
+    send holds, nor one whose doorbell is behind."""
+    _warm_window(rig, _BIG)
+    base, offers = _window_counts(rig), rig.ops["a", rdv.OP_OFFER]
+    assert rig.send(nbytes=_MSG)
+    moved = _window_moved(rig, base)
+    assert moved["window_received"] == 1 and moved["window_rides"] == 1
+    assert rig.ops["a", rdv.OP_OFFER] == offers
+    assert not rig.b._pregrants_out.get(65_536)
+    rig.clock.sleep(0)
+    rig.residence = lambda seq: None               # keeps what it gets
+    for _ in range(3):
+        assert rig.send(nbytes=_MSG)               # three regions behind
+    window = rig.a._grants[131_072]
+    free = [c for c in window if rig.a._standing_free(c)]
+    assert len(free) == 1
+    free[0].inflight = True                        # another send holds it
+    assert rig.a._take_grant(131_072, _MSG) is None
+    free[0].inflight = False
+    assert rig.a._take_grant(131_072, _MSG) is free[0]
+    free[0].inflight = False
+    # ... and a send that finds only those takes a one-shot region, no
+    # region of the window
+    base, offers = _window_counts(rig), rig.ops["a", rdv.OP_OFFER]
+    free[0].inflight = True
+    assert rig.send(nbytes=_MSG)
+    assert _window_moved(rig, base)["window_received"] == 0
+    assert rig.ops["a", rdv.OP_OFFER] == offers + 1
+    assert rig.got == list(range(rig.seq))
+
+
+def _window_grows(rig):
+    """A link's window of 64 KiB regions grows to 128 KiB at its first
+    larger message: the sender hands the four small ones back before it
+    OFFERs, the receiver grants four of the new class once it completes,
+    and smaller messages then ride them and are granted nothing, even when
+    one of them had to be claimed."""
+    _warm_window(rig, _MSG)
+    assert rig.b._pregrants_out == {65_536: rdv._PREGRANT_DEPTH}
+    base = _window_counts(rig)
+    assert rig.send(nbytes=_BIG)
+    assert rig.b._pregrants_out == {65_536: 0,
+                                    131_072: rdv._PREGRANT_DEPTH}
+    assert not rig.a._grants[65_536]
+    assert len(rig.a._grants[131_072]) == rdv._PREGRANT_DEPTH
+    assert _window_moved(rig, base)["window_returns"] == 4
+    offers = rig.ops["a", rdv.OP_OFFER]
+    for _ in range(8):
+        rig.clock.sleep(0)
+        assert rig.send(nbytes=_MSG)
+    assert rig.ops["a", rdv.OP_OFFER] == offers
+    assert _window_moved(rig, base)["window_rides"] == 8
+    rig.clock.sleep(0)
+    rig.residence = lambda seq: None
+    for _ in range(rdv._PREGRANT_DEPTH):
+        assert rig.send(nbytes=_MSG)               # the window, full
+    assert rig.send(nbytes=_MSG)                   # a one-shot claim
+    assert rig.ops["a", rdv.OP_OFFER] == offers + 1
+    assert rig.b._pregrants_out == {65_536: 0,
+                                    131_072: rdv._PREGRANT_DEPTH}
+    assert len(rig.a._grants[131_072]) == rdv._PREGRANT_DEPTH
+    moved = _window_moved(rig, base)
+    assert moved["window_rides"] == 12 and moved["claims_refused"] == 0
+    assert rig.got == list(range(rig.seq))
+
+
+def _window_hands_back_only_free(rig):
+    """Before an OFFER larger than its window the sender hands back only
+    its free standing grants: not the two the consumer still holds, not
+    the one another send owns. Those go back once a later send finds them
+    free, being smaller than the window that grew meanwhile."""
+    _warm_window(rig, _MSG)
+    rig.residence = lambda seq: None
+    assert rig.send() and rig.send()               # two regions aliased
+    small = rig.a._grants[65_536]
+    busy = [c for c in small if not rig.a._standing_free(c)]
+    owned = [c for c in small if c not in busy][0]
+    owned.inflight = True
+    base, releases = _window_counts(rig), rig.ops["a", rdv.OP_RELEASE]
+    assert rig.send(nbytes=_BIG)
+    assert rig.ops["a", rdv.OP_RELEASE] == releases + 1
+    assert _window_moved(rig, base)["window_returns"] == 1
+    assert sorted(map(id, small)) == sorted(map(id, busy + [owned]))
+    assert rig.b._pregrants_out[65_536] == 3
+    assert len(rig.a._grants[131_072]) == rdv._PREGRANT_DEPTH
+    assert all(not rig.a._standing_free(c) for c in busy)
+    owned.inflight = False
+    rig.residence = lambda seq: 0.0
+    while rig.held:
+        rig.free()
+    assert rig.send()                              # rides the new window
+    assert rig.ops["a", rdv.OP_RELEASE] == releases + 4
+    assert not rig.a._grants[65_536] and rig.b._pregrants_out[65_536] == 0
+    assert _window_moved(rig, base)["window_returns"] == 4
+    assert rig.got == list(range(rig.seq))
+
+
+@pytest.mark.parametrize("case", ["ride", "grows",
+                                  "hands_back_only_free"])
+def test_one_window_a_link(monkeypatch, case):
+    rig = _CreditRig(monkeypatch, budget=5 * _REGION + 5 * _BIG_REGION)
+    try:
+        {"ride": _window_ride, "grows": _window_grows,
+         "hands_back_only_free": _window_hands_back_only_free}[case](rig)
+    finally:
+        rig.close()
+        rdv.window_share().drain()
+
+
+def test_pool_closes_free_regions_of_other_classes_for_budget():
+    """A class with no free region is made room for by closing FREE
+    regions of other classes; a leased, a standing and an aliased region
+    are never closed, and where the free ones are not enough the lease is
+    refused with nothing closed."""
+    from tpurpc.obs import metrics as _metrics
+
+    reclaims = _metrics.registry().metrics()["rdv_pool_reclaims"]
+    pool = rdv.LandingPool("local", budget=4 * _REGION + _BIG_REGION)
+    leased = pool.lease(_MSG, 1)
+    aliased = pool.lease(_MSG, 2)
+    body = aliased.deliver(16)
+    standing = pool.lease(_BIG, 3)
+    standing.standing = True
+    spare = [pool.lease(_MSG, 4 + i) for i in range(2)]
+    for lease in spare:
+        lease.release()
+    assert pool.stats() == {"allocated_bytes": 4 * _REGION + _BIG_REGION,
+                            "free_regions": 2}
+    r0 = reclaims.snapshot()
+    big = pool.lease(_BIG, 7)                      # closes the two free
+    assert big is not None and reclaims.snapshot() == r0 + 2
+    assert pool.stats() == {"allocated_bytes": 2 * _REGION + 2 * _BIG_REGION,
+                            "free_regions": 0}
+    assert pool.lease(_BIG, 8) is None             # nothing free to close
+    assert reclaims.snapshot() == r0 + 2
+    for lease in (leased, aliased, standing, big):
+        pr = lease.pr
+        pr.region.buf[pr.offset:pr.offset + 4] = b"live"
+    assert bytes(body[:4]) == b"live"              # the alias sees its region
+    standing.deliver(8)
+    del body
+    gc.collect()
+    for lease in (leased, aliased, standing, big):
+        lease.release()
+    gc.collect()
+    assert pool.stats()["free_regions"] == 4
+    pool.trim()
+
+
+_PAGE = 64 * 576 * 2                  # one bf16 page of one MLA layer
+
+
+def _page_messages(rng, layers=61, chunk=64):
+    """One prefill worker's page messages, in pages: a warm-up of a 1- and
+    a 4-page sequence, then lognormal lengths (mean 7,590 tokens, sigma 1,
+    clipped to 256 to 32,768) up to the first of a whole chunk and two
+    more, each chunk by chunk of 64 pages and layer by layer within a
+    chunk."""
+    mu = np.log(7590) - 0.5
+    pages, more = [1, 4], 2
+    while more:
+        pages.append(-(-int(np.clip(rng.lognormal(mu, 1.0), 256, 32768))
+                       // 64))
+        more -= max(pages[2:]) >= chunk
+    out = []
+    for total in pages:
+        for first in range(0, total, chunk):
+            out += [min(chunk, total - first)] * layers
+    return out
+
+
+def test_real_page_sizes_over_eight_links_stay_one_sided(monkeypatch):
+    """Eight links stream the page cell's real message sizes (1 to 64
+    pages of 73,728 B) against the default 256 MiB budget, interleaved, to
+    a consumer that keeps each message 0.5 to 3 ms. Once a link has sent
+    its first 64-page message its window is 5 MiB regions, which every
+    later message rides: no claim is refused and every message over the
+    bar goes one-sided. A standing window a class, kept for good, held
+    the pool whole and refused a third of the claims."""
+    rig = _CreditRig(monkeypatch, budget=256 << 20)
+
+    def alloc(nbytes):                 # pages mapped only where written
+        handle = f"local:{uuid.uuid4().hex}"
+        buf = mmap.mmap(-1, nbytes)
+        with rdv._pair.LocalDomain._lock:
+            rdv._pair.LocalDomain._registry[handle] = buf
+        return rdv._pair.Region(
+            handle, buf,
+            lambda: rdv._pair.LocalDomain._registry.pop(handle, None))
+
+    def place_head(win, placed):       # the stamp, not the zeros after it
+        off, src = placed[0]
+        win.view[off:off + 16] = src[:16]
+
+    monkeypatch.setattr(rig.pool._domain, "alloc", alloc)
+    monkeypatch.setattr(rdv, "_place_spans", place_head)
+    rng = np.random.default_rng(2407000079)
+    draws = np.random.default_rng(42)
+    rig.residence = lambda seq: float(draws.uniform(0.5, 3.0))
+    links = [rig.a] + [rig.pair()[0] for _ in range(7)]
+    plans = [_page_messages(rng) for _ in links]
+    windowed = [False] * len(links)
+    after = {"framed": 0, "sent": 0}
+    base = None
+    try:
+        for step in range(max(map(len, plans))):
+            for i, link in enumerate(links):
+                if step >= len(plans[i]):
+                    continue
+                nbytes = plans[i][step] * _PAGE + 183
+                if nbytes < rdv.min_bytes():
+                    _, segs = rig.next_payload(nbytes)   # framed, as sent
+                    rig.deliver(1, 0, memoryview(bytearray(b"".join(segs))))
+                    continue
+                ok = rig.send(link, nbytes)
+                if windowed[i]:
+                    after["sent"] += 1
+                    after["framed"] += not ok
+                if plans[i][step] == 64 and not windowed[i]:
+                    windowed[i] = True
+                    if all(windowed):
+                        base = _window_counts(rig)
+        assert all(windowed) and after["sent"] > 1000
+        assert after["framed"] == 0
+        moved = _window_moved(rig, base)
+        assert moved["claims_refused"] == 0 and moved["fallbacks"] == 0
+        assert moved["window_rides"] > 0
+        for link in links:
+            assert {c.capacity for b in link._grants.values() for c in b
+                    if c.standing} == {5 << 20}
+        assert rig.pool.stats()["allocated_bytes"] <= 256 << 20
+        assert rig.got == list(range(rig.seq))
+    finally:
+        rig.close()
+        rdv.window_share().drain()
+
+
+def test_one_class_stream_sends_the_control_frames_it_always_did(
+        credit_rig):
+    """A link that streams one size class (every other cell) holds its
+    four regions of that class and sends, op for op, the control frames
+    it sent before windows could grow: the window rides nothing, hands
+    nothing back, and the pool closes nothing."""
+    import random
+
+    rig = credit_rig
+    base = _window_counts(rig)
+    rig.warm()
+    rig.learn(1.0)
+    rng = random.Random(42)
+    # now and then the consumer keeps a message past the bound: waits
+    # expire, claims are refused, messages go framed
+    rig.residence = lambda seq: (rng.uniform(0.5, 6.0) if rng.random() < 0.9
+                                 else rng.uniform(20.0, 60.0))
+    for _ in range(300):
+        rig.send()
+    assert rig.got == list(range(rig.seq))
+    assert dict(rig.ops) == {("a", rdv.OP_OFFER): 15,
+                             ("b", rdv.OP_CLAIM): 19,
+                             ("a", rdv.OP_COMPLETE): 296}
+    assert rig.moved() == {"fallbacks": 14, "claims_refused": 14,
+                           "credit_waits": 73, "credit_expired": 14}
+    moved = _window_moved(rig, base)
+    assert (moved["window_rides"], moved["window_returns"],
+            moved["pool_reclaims"]) == (0, 0, 0)
+
+
+def test_window_under_many_sender_threads_delivers_each_message_once():
+    """Twelve threads send 64 and 128 KiB-class messages over one link at
+    a 10 us switch interval, so windows grow, regions are handed back and
+    pool regions are closed while other sends take grants: every message
+    arrives once, and no standing region is completed while its previous
+    delivery is aliased (``RegionLease.deliver`` refuses that, and the
+    message would be missing)."""
+    import sys
+
+    pool = rdv.LandingPool("local", budget=12 * _REGION + 6 * _BIG_REGION)
+    old_pools = dict(rdv._pools)
+    rdv._pools.clear()
+    rdv._pools["local"] = pool
+    got, lock, ends = [], threading.Lock(), {}
+
+    def deliver(stream_id, flags, body):
+        with lock:
+            got.append(int.from_bytes(bytes(body[:8]), "little"))
+
+    a = rdv.RdvLink("a", lambda *op: ends["b"].on_op(*op), lambda *m: None,
+                    pool_kinds=("local",), open_kinds=("local",))
+    b = rdv.RdvLink("b", lambda *op: ends["a"].on_op(*op), deliver,
+                    pool_kinds=("local",), open_kinds=("local",))
+    ends["a"], ends["b"] = a, b
+    a.negotiated = b.negotiated = True
+    tail = bytes(_BIG)
+    errors = []
+
+    def sender(t):
+        try:
+            for i in range(60):
+                seq = t * 1000 + i
+                n = _BIG if i >= 10 and (seq * 7) % 5 < 2 else _MSG
+                segs = [seq.to_bytes(8, "little"), tail[:n - 8]]
+                if not a.send_message(1, 0, segs, n):
+                    deliver(1, 0, b"".join(segs))   # the framed stand-in
+        except Exception as exc:  # surfaced below, on the test's thread
+            errors.append(exc)
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    threads = [threading.Thread(target=sender, args=(t,))
+               for t in range(12)]
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+        assert not any(t.is_alive() for t in threads) and not errors, errors
+        assert sorted(got) == sorted(t * 1000 + i for t in range(12)
+                                     for i in range(60))
+        assert pool.stats()["allocated_bytes"] <= 12 * _REGION + \
+            6 * _BIG_REGION
+    finally:
+        sys.setswitchinterval(interval)
+        a.close()
+        b.close()
+        pool.trim()
+        rdv._pools.clear()
+        rdv._pools.update(old_pools)
+        rdv.window_share().drain()
 
 
 # ---------------------------------------------------------------------------

@@ -37,17 +37,28 @@ check_rendezvous``; mutants ``write_before_claim`` and
 * peer death with a claimed region releases it (``RdvLink.close``).
 
 Steady-state fast path: after each completed transfer the receiver
-PRE-GRANTS a fresh claim of the same size class (req id 0), so a stream of
-same-shaped tensors pays zero claim round trips — the RDMAbox
-pre-registered-buffer discipline. Pre-granted transfers emit no flight
-events (edges, not traffic); solicited offers/claims/releases do, which is
-exactly the evidence the stall watchdog's ``rendezvous`` stage reads.
+PRE-GRANTS standing regions (req id 0) up to ``_PREGRANT_DEPTH``, so a
+stream pays zero claim round trips — the RDMAbox pre-registered-buffer
+discipline. A link's standing regions are ONE window of ONE size class,
+the largest the link has streamed: a message rides any free
+standing region big enough, whatever its own class; a completion of a
+class above the window (a one-shot claim: no standing region held it)
+grows the window to that class, one below it grants nothing of its own;
+and the sender hands back (``OP_RELEASE``) each free standing region
+smaller than its window, and every free one before it offers a message
+larger than all it holds, so the regions a window grew out of return to
+the pool, which closes free regions of other classes to make room for a
+class it lacks. A stream of one size keeps its four regions of that class,
+as before. Pre-granted transfers emit no flight events (edges, not
+traffic); solicited offers/claims/releases do, which is exactly the
+evidence the stall watchdog's ``rendezvous`` stage reads.
 
 Credit flow control (ISSUE 31): a link's standing regions ARE its credit
-window. A sender that holds a whole window (``_PREGRANT_DEPTH`` regions of
-the class) and finds them all still with the consumer waits for one of its
-own doorbells, as the framed ring's writer waits for ring credit, and
-places the message one-sided when it rings; it asks the receiver for a
+window. A sender that holds a whole window (``_PREGRANT_DEPTH`` standing
+regions big enough for the message) and finds them all still with the
+consumer waits for one of its own doorbells, as the framed ring's
+writer waits for ring credit, and places the message one-sided when it
+rings; it asks the receiver for a
 one-shot region beyond its window only where it has nothing to wait for
 or the wait has run out (ISSUE 33: under a fan-in every region asked for
 beyond a window is one more message parked in the receiver's queue, and
@@ -55,16 +66,17 @@ the pool's spare regions are what a late link's window and an overdue
 wait's claim are made of). A link short of its window asks first, and
 waits when the receiver refuses ("no more memory: what you hold is your
 window").
-How long is learnt, not configured: the link measures each class's
+How long is learnt, not configured: the link measures its window class's
 RESIDENCE (a region's COMPLETE to the doorbell read that finds it free,
-taken only while the sender is watching) and waits for the oldest region
-until it has been out for the smoothed residence plus four deviations
+taken only while the sender is watching; kept by the capacity of the
+standing regions, not by the class of the message that rode one) and
+waits for the oldest region until it has been out for the smoothed residence plus four deviations
 (``_Residence``). A refusal is believed for that same bound, so a link
 short of its window asks the receiver again once a window's turn, not
 once a message.
 Degradation, never a hang: every wait is finite and ends in a path that
 existed before it. A link with no measured residence, or no standing
-region of the class, does not wait (the first estimate is seeded from
+region big enough, does not wait (the first estimate is seeded from
 below by the longest a region was seen out, ``_saw_free``); a region that
 outlives its estimate (a late consumer, one that retains more than a
 window, or a stalled one) sends this message by another path, a one-shot
@@ -170,6 +182,14 @@ _RDV_CREDIT_EXPIRED = _metrics.counter("rdv_credit_expired")
 #: link in steady state holds this flat — the ctrlring smoke and bench's
 #: ctrl_wakeups_per_msg both read it as the zero-control-frames proof)
 _RDV_CTRL_FRAMES = _metrics.counter("rdv_ctrl_frames")
+#: the per-link window, on the receiver: completions that landed
+#: in a standing region, those of them whose message was of a smaller class
+#: than its region (a ride), the standing leases senders handed back, and
+#: free regions the pool closed to make budget for another class
+_RDV_WINDOW_RECV = _metrics.counter("rdv_window_received")
+_RDV_WINDOW_RIDES = _metrics.counter("rdv_window_rides")
+_RDV_WINDOW_RETURNS = _metrics.counter("rdv_window_returns")
+_RDV_POOL_RECLAIMS = _metrics.counter("rdv_pool_reclaims")
 
 # tpurpc-pulse: framed control sends are control-plane busy time — same
 # hop as the descriptor-ring posts/drains in core/ctrlring.py, so the
@@ -380,6 +400,12 @@ def size_class(nbytes: int) -> int:
     return c - step * ((c - nbytes) // step)
 
 
+def _region_bytes(cls: int) -> int:
+    """What a region of class ``cls`` charges the pool's budget: its span,
+    the alignment pad, the nonce and the doorbell word."""
+    return cls + _ALIGN + _NONCE_BYTES + _DOORBELL.size
+
+
 # ---------------------------------------------------------------------------
 # Landing pool: registered regions the receiver advertises.
 # ---------------------------------------------------------------------------
@@ -572,13 +598,15 @@ class LandingPool:
         return True
 
     def lease(self, nbytes: int, lease_id: int) -> Optional[RegionLease]:
-        """A region of capacity ≥ ``nbytes``, or None when the budget is
-        exhausted. The claim is then refused, which tells the sender that
-        the standing regions it holds are its whole window: it waits for
-        one of their doorbells for as long as the link's measured residence
-        says one is due, and otherwise (none held, none ever seen to free,
-        or the wait outlived the estimate) falls back to the framed path —
-        degradation, never a deadlock."""
+        """A region of capacity ≥ ``nbytes``: a free one of its class, else
+        a new one, for which free regions of other classes are closed where
+        the budget is spent (``rdv_pool_reclaims``). None when even that
+        does not make room: the claim is then refused, which tells the
+        sender that the standing regions it holds are its whole window: it
+        waits for one of their doorbells for as long as the link's measured
+        residence says one is due, and otherwise (none held, none ever seen
+        to free, or the wait outlived the estimate) falls back to the framed
+        path — degradation, never a deadlock."""
         cls = size_class(nbytes)
         with self._lock:
             zombies, self._zombies = self._zombies, []
@@ -587,16 +615,35 @@ class LandingPool:
             if still:
                 with self._lock:
                     self._zombies.extend(still)
+        victims: List[_PoolRegion] = []
         with self._lock:
             bucket = self._free.get(cls)
             if bucket:
                 pr = bucket.pop()
                 pr.doorbell_store(0)  # fresh lease: no consumer history
                 return RegionLease(self, pr, lease_id, cls)
-            alloc_bytes = cls + _ALIGN + _NONCE_BYTES + _DOORBELL.size
-            if self._allocated + alloc_bytes > self._budget:
-                return None
+            alloc_bytes = _region_bytes(cls)
+            need = self._allocated + alloc_bytes - self._budget
+            if need > 0:
+                # the regions a link's window grew out of come back to the
+                # free lists (``RdvLink._hand_back``), where no other class
+                # could use them: close them, largest first. Leased,
+                # standing and aliased regions are on no free list
+                if sum(_region_bytes(c) * len(b)
+                       for c, b in self._free.items()) < need:
+                    return None
+                for c in sorted(self._free, reverse=True):
+                    b = self._free[c]
+                    while b and need > 0:
+                        victims.append(b.pop())
+                        need -= _region_bytes(c)
+                        self._allocated -= _region_bytes(c)
             self._allocated += alloc_bytes
+        for pr in victims:
+            _RDV_POOL_RECLAIMS.inc()
+            if not self._try_close(pr):
+                with self._lock:
+                    self._zombies.append(pr)
         try:
             region = self._domain.alloc(alloc_bytes)
         except Exception:
@@ -618,8 +665,7 @@ class LandingPool:
             # peer window might still write; destroy it (deferred to the
             # zombie sweep while consumer aliases pin the mapping)
             with self._lock:
-                self._allocated -= (pr.capacity + _ALIGN + _NONCE_BYTES
-                                    + _DOORBELL.size)
+                self._allocated -= _region_bytes(pr.capacity)
             if not self._try_close(pr):
                 with self._lock:
                     self._zombies.append(pr)
@@ -641,8 +687,7 @@ class LandingPool:
             buckets, self._free = self._free, {}
             for bucket in buckets.values():
                 for pr in bucket:
-                    self._allocated -= (pr.capacity + _ALIGN + _NONCE_BYTES
-                                        + _DOORBELL.size)
+                    self._allocated -= _region_bytes(pr.capacity)
         for bucket in buckets.values():
             for pr in bucket:
                 try:
@@ -1122,8 +1167,12 @@ class RdvLink:
         only the credit wait (``_await_credit``), which raises
         :class:`SendAbandoned` when either cuts it."""
         cls = size_class(total)
-        claim = self._take_grant(cls, total)
-        if claim is None and self._has_standing(cls, total):
+        # the link's window class where the window holds the message, else
+        # the class that will be the window once the message is claimed:
+        # what the residence estimate and the refusal memory are kept under
+        key = self._hand_back(total) or cls
+        claim = self._take_grant(key, total)
+        if claim is None and self._has_standing(total):
             # tpurpc-pulse: every standing region's doorbell is behind —
             # the consumer is mid-batch.  A solicited claim here costs a
             # full control round trip (~0.8 ms on this rig); a bounded
@@ -1139,26 +1188,26 @@ class RdvLink:
                     except Exception:
                         drain = None
                 time.sleep(0)
-                claim = self._take_grant(cls, total, watching=True)
+                claim = self._take_grant(key, total, watching=True)
         waited = False
-        if claim is None and self._window_full(cls, total):
+        if claim is None and self._window_full(total):
             # a full standing window IS this link's credit: it waits for
             # one of its own doorbells before it asks the receiver for
             # memory beyond it (a one-shot region more is a message more
             # parked in the receiver's queue, and the pool's spare regions
             # are what a late link's window and an overdue wait's claim
             # are made of)
-            claim = self._await_credit(cls, total, deadline, should_stop)
+            claim = self._await_credit(key, total, deadline, should_stop)
             waited = True
         if claim is None:
-            if not self._refusal_stands(cls):
+            if not self._refusal_stands(key):
                 claim = self.rdv_claim(stream_id, total, cls)
                 if claim is None:
-                    self._claim_failed(cls)
+                    self._claim_failed(key)
             if claim is None and not waited:
                 # no more memory: what this link holds is its window, and
                 # a window waits for credit (once a message)
-                claim = self._await_credit(cls, total, deadline,
+                claim = self._await_credit(key, total, deadline,
                                            should_stop)
         if claim is None:
             _RDV_FALLBACK.inc()
@@ -1180,25 +1229,27 @@ class RdvLink:
         _RDV_SENT_BYTES.inc(total)
         return True
 
-    def _take_grant(self, cls: int, total: int,
+    def _take_grant(self, key: int, total: int,
                     watching: bool = False) -> Optional[_Claim]:
-        """A usable cached grant: a one-shot claim is consumed; a STANDING
-        claim is acquired (inflight flag) and reused only when its doorbell
-        shows every previous delivery's aliases died — the zero-frame
-        steady-state path. ``watching``: this look follows, by no more than
-        a poll slice, one that found every region busy, so a region it
-        finds free has just come free and its residence is a measurement
-        (``_saw_free``)."""
+        """A usable cached grant of capacity ≥ ``total``, whatever its
+        class, the largest first (the window before regions it grew out
+        of): a one-shot claim is consumed; a STANDING claim is acquired
+        (inflight flag) and reused only when its doorbell shows every
+        previous delivery's aliases died — the zero-frame steady-state
+        path. ``watching``: this look follows, by no more than a poll
+        slice, one that found every region busy, so a region it finds free
+        has just come free and its residence is a measurement of window
+        class ``key`` (``_saw_free``)."""
         with self._lock:
             if self.closed:
                 return None
-            bucket = list(self._grants.get(cls) or ())
-        for claim in bucket:
-            if claim.capacity < total:
-                continue
+            fits = sorted((c for b in self._grants.values() for c in b
+                           if c.capacity >= total),
+                          key=lambda c: -c.capacity)
+        for claim in fits:
             if not claim.standing:
                 with self._lock:
-                    b = self._grants.get(cls)
+                    b = self._grants.get(claim.capacity)
                     if b is not None and claim in b:
                         b.remove(claim)
                         return claim
@@ -1209,50 +1260,81 @@ class RdvLink:
                 claim.inflight = True
             if self._standing_free(claim):
                 if claim.used:
-                    self._saw_free(cls, claim, watching)
+                    self._saw_free(key, claim, watching)
                 return claim
             with self._lock:
                 claim.inflight = False
                 claim.busy_ns = time.monotonic_ns()
         return None
 
-    def _refusal_stands(self, cls: int) -> bool:
-        """Was this class's last claim refused so lately that asking again
-        would get the same answer? The pool gains room when a region comes
-        back, which takes a residence, so a refusal is believed for one
-        residence bound: a full window asks the receiver once a window's
-        turn, not once a message (each refused OFFER is the receiver's
-        interpreter, the fan-in's limit, taken from the very handlers the
-        sender waits for). Only where the link can wait instead: a class
-        with a measured residence."""
+    def _hand_back(self, total: int) -> int:
+        """Give the receiver back every FREE standing grant smaller than
+        the link's window class (the regions its window grew out of), or
+        than the message where it is larger than every grant held (the
+        receiver grows the window to its class when it completes): one
+        ``OP_RELEASE`` each, the grant dropped first so that no send takes
+        it after. A grant in flight, or whose doorbell is behind, stays
+        until a later send finds it free. Returns the window class where
+        the window holds ``total``, else 0. A link whose grants are all of
+        one class that holds the message reads no doorbell here."""
         with self._lock:
-            est = self._residence.get(cls)
+            standing = self._standing_for_locked(0)
+            window = max((c.capacity for c in standing), default=0)
+            bar = window if window >= total else total
+            spare = [c for c in standing
+                     if c.capacity < bar and not c.inflight]
+            for c in spare:
+                c.inflight = True
+        for claim in spare:
+            if claim.used and not self._standing_free(claim):
+                with self._lock:
+                    claim.inflight = False
+                continue
+            with self._lock:
+                b = self._grants.get(claim.capacity)
+                if b is not None and claim in b:
+                    b.remove(claim)
+            self.rdv_release(claim)
+        return window if window >= total else 0
+
+    def _refusal_stands(self, key: int) -> bool:
+        """Was the last claim of a message for window class ``key`` refused
+        so lately that asking again would get the same answer? The pool
+        gains room when a region comes back, which takes a residence, so a
+        refusal is believed for one residence bound: a full window asks the
+        receiver once a window's turn, not once a message (each refused
+        OFFER is the receiver's interpreter, the fan-in's limit, taken from
+        the very handlers the sender waits for). Only where the link can
+        wait instead: a window class with a measured residence."""
+        with self._lock:
+            est = self._residence.get(key)
             return (est is not None and est.refused_ns != 0
                     and time.monotonic_ns() - est.refused_ns
                     < est.bound_ns())
 
-    def _claim_failed(self, cls: int) -> None:
+    def _claim_failed(self, key: int) -> None:
         with self._lock:
-            est = self._residence.get(cls)
+            est = self._residence.get(key)
             if est is not None:
                 est.refused_ns = time.monotonic_ns()
 
-    def _saw_free(self, cls: int, claim: _Claim, watching: bool) -> None:
+    def _saw_free(self, key: int, claim: _Claim, watching: bool) -> None:
         """A region that was out has come back: where the sender was
         watching, the time since the region's COMPLETE is one measurement
-        of the class's residence. A free found at a send's first look says
-        only that the residence was at most that long (an idle sender
-        would read its own idle time), so it is no measurement; where the
-        class has no estimate yet, the last look that found the region
-        still out seeds one, from below."""
+        of the residence of window class ``key`` (the capacity of the
+        link's standing regions, whatever the message's own class). A free
+        found at a send's first look says only that the residence was at
+        most that long (an idle sender would read its own idle time), so it
+        is no measurement; where the window class has no estimate yet, the
+        last look that found the region still out seeds one, from below."""
         with self._lock:
             done, claim.done_ns = claim.done_ns, 0
             busy, claim.busy_ns = claim.busy_ns, 0
-            est = self._residence.get(cls)
+            est = self._residence.get(key)
             if watching and done:
                 sample = time.monotonic_ns() - done
                 if est is None:
-                    self._residence[cls] = _Residence(sample)
+                    self._residence[key] = _Residence(sample)
                 else:
                     est.feed(sample)
             elif est is None and busy > done > 0:
@@ -1265,31 +1347,32 @@ class RdvLink:
                 # long framed send, sees no ring, and stays without an
                 # estimate: 150 to 280 framed messages on two to four
                 # links of eight in a fan-in (PERF.md 6, PR 33)
-                self._residence[cls] = _Residence(busy - done)
+                self._residence[key] = _Residence(busy - done)
 
-    def _await_credit(self, cls: int, total: int,
+    def _await_credit(self, key: int, total: int,
                       deadline: Optional[float],
                       should_stop: Optional[Callable[[], bool]]
                       ) -> Optional[_Claim]:
         """The window is full (or short, and the receiver has no more
         memory): wait for one of this link's own doorbells, for as long as
         the link's history says one is due. The OLDEST region out is waited
-        for until it has been out for the class's residence bound, measured
-        from its own COMPLETE or from the class's last expired wait, if
-        that is later; any region that frees meanwhile is taken.
+        for until it has been out for window class ``key``'s residence
+        bound, measured from its own COMPLETE or from the last expired wait,
+        if that is later; any region big enough that frees meanwhile is
+        taken.
 
         None (the caller falls back to the framed path, as before this
         wait existed) at once where there is nothing to expect: no
-        measured residence of the class, no stamped standing region out;
+        measured residence of the window class, no stamped standing region
+        out;
         when the link closes; and when the oldest region outlives the
         bound (``_credit_expired``). Raises
         :class:`SendAbandoned` when the caller's ``should_stop`` holds or
         its ``deadline`` passes inside the wait."""
         with self._lock:
-            est = self._residence.get(cls)
-            out = [c.done_ns for c in self._grants.get(cls) or ()
-                   if c.standing and c.capacity >= total and c.done_ns
-                   and not c.inflight]
+            est = self._residence.get(key)
+            out = [c.done_ns for c in self._standing_for_locked(total)
+                   if c.done_ns and not c.inflight]
             if est is None or not out or self.closed:
                 return None
             # after an expired wait, a bound from THAT: a window sends one
@@ -1313,7 +1396,7 @@ class RdvLink:
                         drain()
                     except Exception:
                         drain = None
-                claim = self._take_grant(cls, total, watching=True)
+                claim = self._take_grant(key, total, watching=True)
                 if claim is not None or self.closed:
                     return claim
                 if should_stop is not None and should_stop():
@@ -1326,13 +1409,13 @@ class RdvLink:
                                             "credit wait")
                     wake_ns = min(wake_ns, deadline_ns)
                 if now_ns >= limit_ns:
-                    self._credit_expired(cls, now_ns - t0_ns)
+                    self._credit_expired(key, now_ns - t0_ns)
                     return None
                 self._idle(over, wake_ns / 1e9)
         finally:
             _RDV_CREDIT_WAIT_NS.inc(st.end())
 
-    def _credit_expired(self, cls: int, waited_ns: int) -> None:
+    def _credit_expired(self, key: int, waited_ns: int) -> None:
         """The oldest region outlived its estimate: the consumer is late
         (a tail of its residence), retains more than a window, or has
         stalled. THIS message goes by another path, which is what a
@@ -1348,10 +1431,10 @@ class RdvLink:
         raise the next bound by it, batch after batch."""
         _RDV_CREDIT_EXPIRED.inc()
         with self._lock:
-            est = self._residence.get(cls)
+            est = self._residence.get(key)
             if est is not None:
                 est.expired_ns = time.monotonic_ns()
-            for c in self._grants.get(cls) or ():
+            for c in self._standing_for_locked(0):
                 if c.done_ns:
                     c.done_ns += waited_ns
 
@@ -1370,25 +1453,28 @@ class RdvLink:
                     break
                 self._cond.wait(remain)
 
-    def _window_full(self, cls: int, total: int) -> bool:
-        """Does this link hold all the STANDING regions of the class that a
-        receiver grants one link (``_PREGRANT_DEPTH``)? Then they are its
-        credit window, and a send that finds them busy waits for one
-        (``_await_credit``) before it asks for a one-shot region beyond
-        them; a link still short of its window asks at once, which is how
-        it is topped up."""
-        with self._lock:
-            bucket = self._grants.get(cls) or ()
-            return sum(1 for c in bucket if c.standing
-                       and c.capacity >= total) >= _PREGRANT_DEPTH
+    def _standing_for_locked(self, total: int) -> List[_Claim]:
+        """The link's STANDING grants of capacity ≥ ``total``, of every
+        class (the caller holds ``_lock``)."""
+        return [c for b in self._grants.values() for c in b
+                if c.standing and c.capacity >= total]
 
-    def _has_standing(self, cls: int, total: int) -> bool:
+    def _window_full(self, total: int) -> bool:
+        """Does this link hold, among the STANDING regions big enough for
+        the message, as many as a receiver grants one link
+        (``_PREGRANT_DEPTH``)? Then they are its credit window, and a send
+        that finds them busy waits for one (``_await_credit``) before it
+        asks for a one-shot region beyond them; a link still short of its
+        window asks at once, which is how it is topped up."""
+        with self._lock:
+            return len(self._standing_for_locked(total)) >= _PREGRANT_DEPTH
+
+    def _has_standing(self, total: int) -> bool:
         """Any STANDING cached grant big enough (busy or not) — the signal
         that a freed doorbell, not a new claim, is what's worth waiting
         a moment for."""
         with self._lock:
-            bucket = self._grants.get(cls) or ()
-            return any(c.standing and c.capacity >= total for c in bucket)
+            return bool(self._standing_for_locked(total))
 
     def _standing_free(self, claim: _Claim) -> bool:
         """Has the receiver's consumer freed every previous use? Reads the
@@ -1414,7 +1500,7 @@ class RdvLink:
         released): it must not be reused."""
         with self._lock:
             claim.inflight = False
-            b = self._grants.get(size_class(claim.capacity))
+            b = self._grants.get(claim.capacity)
             if b is not None and claim in b:
                 b.remove(claim)
 
@@ -1681,17 +1767,31 @@ class RdvLink:
             return
         _RDV_RECV.inc()
         _RDV_RECV_BYTES.inc(nbytes)
+        if lease.standing:
+            _RDV_WINDOW_RECV.inc()
+            if size_class(nbytes) < lease.cls:
+                _RDV_WINDOW_RIDES.inc()
         cls, kind = lease.cls, lease.kind
         self._deliver(stream_id, flags, wrapper)
         self._maybe_pregrant(cls, kind)
 
     def _maybe_pregrant(self, cls: int, kind: str) -> None:
-        """RDMAbox discipline: keep STANDING regions granted for the
-        classes the peer is actively streaming, topped up to
-        ``_PREGRANT_DEPTH``. A standing grant costs one claim frame EVER:
-        after each use the consumer-done signal rides the region's own
-        doorbell word, so steady-state transfers carry exactly one control
-        frame (the COMPLETE) and zero claim round trips."""
+        """RDMAbox discipline: keep the link's window of STANDING regions
+        granted, topped up to ``_PREGRANT_DEPTH``, after a completion of
+        class ``cls`` (its region's). The window is of ONE class, the
+        largest standing class of the link, ``W``: a completion of a class
+        below it tops up ``W`` and grants nothing of its own (the sender
+        rides ``W``'s regions with smaller messages), and one above it (a
+        one-shot claim: no standing region held it) grants
+        ``_PREGRANT_DEPTH`` of its class, the new ``W``; the sender hands
+        back the regions it grew out of (``_hand_back``). A standing grant
+        costs one claim frame EVER: after each use the consumer-done signal
+        rides the region's own doorbell word, so steady-state transfers
+        carry exactly one control frame (the COMPLETE) and zero claim round
+        trips."""
+        with self._lock:
+            cls = max([cls] + [c for c, n in self._pregrants_out.items()
+                               if n > 0])
         while True:
             with self._lock:
                 if (self.closed or self._pregrants_out.get(
@@ -1732,6 +1832,8 @@ class RdvLink:
                 self._pregrants_out[lease.cls] = max(
                     0, self._pregrants_out.get(lease.cls, 1) - 1)
         if lease is not None:
+            if lease.standing:
+                _RDV_WINDOW_RETURNS.inc()
             _flight.emit(_flight.RDV_RELEASE, self._ftag, lease_id, req)
             lease.release()
 
