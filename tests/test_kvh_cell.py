@@ -303,14 +303,14 @@ def test_the_manifest_gained_the_cell_and_lost_nothing():
     start = MANIFEST["per_layer"].index(KVH[0])
     assert MANIFEST["per_layer"][start:start + len(EXPECT)] == KVH
     assert all(e["moves"] == "hbm_gbytes_s" for e in KVH)
-    assert [w["name"] for w in MANIFEST["workloads"]] == [
+    assert [w["name"] for w in MANIFEST["workloads"]][:6] == [
         "stream4m_c1", "stream4m_c8", "pingpong4m_c1", "fanin4m_c8",
         "fanex4m_c8", "kvh_mla_c8"]
-    cell = MANIFEST["workloads"][-1]
+    cell = MANIFEST["workloads"][5]
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         "kv_handoff_mla_paged", "kv_handoff_c8", 1)
-    assert MANIFEST["end_to_end"][0]["workloads"][-1] == "kvh_mla_c8"
-    entry = MANIFEST["configs"][-1]
+    assert MANIFEST["end_to_end"][0]["workloads"][5] == "kvh_mla_c8"
+    entry = MANIFEST["configs"][4]
     assert entry["name"] == "kv_handoff_mla_paged"
     assert FULL["source"] == entry["source"] and FULL["reduced"] == []
     assert FULL["cache"]["bytes"] == 61 * 1432 * 73728 == 6440288256

@@ -824,10 +824,11 @@ def test_every_hop_has_an_ops_counter_and_the_list_only_grows():
         assert f"lens_{hop}_ops" in snap
         assert f"lens_{hop}_cpu_ns" in snap
     # hops are appended and none moves: batch_ready and place_return
-    # where they were, the paged KV cache's three after them
+    # where they were, the paged KV cache's four after them
     assert lens.HOP_NAMES[25:28] == ("srv_reply_wait", "batch_ready",
                                      "place_return")
-    assert lens.HOP_NAMES[-3:] == ("kv_admit", "kv_write", "kv_ack")
+    assert lens.HOP_NAMES[-4:] == ("kv_admit", "kv_write", "kv_ack",
+                                   "kv_state_write")
     assert lens.HOP_NAMES[:12] == (
         "device", "send_ring", "wire", "rendezvous", "ctrl", "native_send",
         "native_recv", "native_rdv", "peer_ring", "decode", "hbm",

@@ -16,8 +16,8 @@ per-message hops ``srv_*``, ``hbm_credit`` and ``hbm_view`` of ISSUE 26 are
 listed with their sites in :data:`HOPS`, where ``d2h`` of ISSUE 28, the
 fan-in batcher's four ``batch_*`` of ISSUE 33, ``srv_reply_wait`` of
 ISSUE 36 and ``batch_ready`` and ``place_return`` of ISSUE 39 are
-appended, and the paged KV cache's ``kv_admit``, ``kv_write`` and ``kv_ack``
-after them: the registry is append-only)::
+appended, and the paged KV cache's ``kv_admit``, ``kv_write``, ``kv_ack`` and
+``kv_state_write`` after them: the registry is append-only)::
 
     d2h        a reply's device leaves read back into host landing buffers
                (tpu/serialize.py: start every transfer, await each)
@@ -242,6 +242,11 @@ HOPS: Tuple[Tuple[str, str], ...] = (
                "acknowledgement is resolved: the device finishing it and "
                "the acknowledging thread's turn (counters only: one op a "
                "sequence)"),
+    ("kv_state_write", "one state message's write dispatched: the program "
+                       "jit_tpurpc_kv_state, on the cache's writing "
+                       "thread, its span carrying the handler's call and "
+                       "message, the state layer and the slot "
+                       "(asynchronous, so not device time)"),
 )
 
 HOP_NAMES: Tuple[str, ...] = tuple(name for name, _ in HOPS)
