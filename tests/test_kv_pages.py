@@ -369,8 +369,8 @@ def test_counters_count_pages_and_sequences():
     c = cache(pages=12)
     try:
         names = ("kv_pages_written", "kv_seqs_admitted", "kv_seqs_acked",
-                 "kv_seqs_evicted", "kv_pages_evicted", "lens_kv_write_ops",
-                 "lens_kv_ack_ops")
+                 "kv_seqs_evicted", "kv_pages_evicted", "kv_messages_written",
+                 "lens_kv_ack_ops", "lens_kv_write_ops")
         before = counters(*names)
         # 5 pages and 3 pages: one message a layer each
         acks = list(c.receiver(0)(stream([sequence(1, 5), sequence(2, 3)])))
@@ -379,7 +379,9 @@ def test_counters_count_pages_and_sequences():
         [f.result(timeout=30) for f in acks]
         got = [a - b for a, b in zip(counters(*names), before)]
         # 9 pages: 8 + 1; it evicts the first sequence (5 pages)
-        assert got == [L * 17, 3, 3, 1, 5, L * 4, 3]
+        assert got[:-1] == [L * 17, 3, 3, 1, 5, L * 4, 3]
+        # an op a dispatch: a run of full chunks is one
+        assert 1 <= got[-1] <= L * 4
         assert c.snapshot()["written"] == {0: L * 8, 1: L * 9}
         total, live = metrics.registry().metrics()["kv_pages_free"].collect()
         assert live >= 1 and total >= c.free_pages() == 12 - 3 - 9
@@ -418,8 +420,9 @@ def test_two_prefill_workers_over_rpc(tpu_platform):
                           kind="stream_stream", device=True)
     srv.start()
     port = srv.add_insecure_port("127.0.0.1:0")
-    names = ("lens_kv_write_ops", "lens_kv_ack_ops", "kv_seqs_acked",
-             "kv_pages_written", "tensor_device_degraded", "hbm_place_msgs")
+    names = ("kv_messages_written", "lens_kv_ack_ops", "kv_seqs_acked",
+             "kv_pages_written", "tensor_device_degraded", "hbm_place_msgs",
+             "lens_kv_write_ops")
     before = counters(*names)
     plans = {k: [sequence(10 * k + s, n, 32)
                  for s, n in enumerate([3, 9 + 8 * k, 20])] for k in range(2)}
@@ -473,3 +476,148 @@ def test_two_prefill_workers_over_rpc(tpu_platform):
     assert got[0] == messages_ and got[1] == 6 and got[2] == 6
     assert got[3] == pages * L and got[4] == 0
     assert got[5] >= messages_ + 6   # every message landed: pages and offers
+    assert 1 <= got[6] <= messages_  # an op a dispatch, of one or a run
+
+
+# -- runs: a writer behind dispatches a sequence's queued messages at once ----------
+
+DEEP = 6                   # layers of a cache deeper than the longest run
+
+
+def deep_words(seed, n_pages, width=8):
+    """``uint16[DEEP, n_pages, T * W]`` of seeded random words."""
+    rng = np.random.default_rng(seed)
+    return rng.integers(0, 1 << 16, size=(DEEP, n_pages, T * width),
+                        dtype=np.uint16)
+
+
+def hold(c):
+    """Holds the writing thread until the returned Event is set, so that it
+    then finds everything handed over meanwhile queued."""
+    go = threading.Event()
+
+    class Held(threading.Event):
+        def set(self):
+            go.wait(30)
+            super().set()
+
+    c._writes.put(Held())
+    return go
+
+
+def deep(words, held=True):
+    """A cache of ``DEEP`` layers given the sequence ``words`` whole, with
+    the writer held until all is handed over, or (``held`` false) one
+    message at a time, each dispatched before the next goes: its store, the
+    sequence's table, and the messages and dispatches counted."""
+    c = KvPageCache(DEEP, 64, T, words.shape[2] // T, chunk_pages=C)
+    try:
+        names = ("kv_messages_written", "lens_kv_write_ops")
+        before = counters(*names)
+        go = hold(c) if held else None
+        seq = c.admit(0, 0, words.shape[1] * T)
+        for x in messages(words):
+            c.submit(seq, x)
+            if go is None:
+                c.sync()
+        if go is not None:
+            go.set()
+        seq.future.result(timeout=30)
+        c.sync()
+        got = [a - b for a, b in zip(counters(*names), before)]
+        return np.asarray(c.store).copy(), list(seq.pages), got
+    finally:
+        c.close()
+
+
+@pytest.mark.parametrize("width", [8, 32])
+@pytest.mark.parametrize("n_pages, dispatches", [
+    (8, 2),            # one chunk: a run cut at four layers, one to its end
+    (16, 4),           # two chunks: the same in each, never across
+    (19, 4 + DEEP),    # and a last chunk of 3 pages: a message a dispatch
+])
+def test_a_run_stores_what_one_message_at_a_time_stores(n_pages,
+                                                        dispatches, width):
+    """A writer that finds a sequence's messages queued writes full chunks
+    in runs of up to four layers within a chunk, and the last chunk's a
+    message at a time: the store ends bit for bit as one written a message
+    a dispatch, and holds the words sent."""
+    words = deep_words(n_pages + width, n_pages, width)
+    ran, table, (msgs, ops) = deep(words)
+    alone, table1, (msgs1, ops1) = deep(words, held=False)
+    n = -(-n_pages // C) * DEEP
+    assert (msgs, ops) == (n, dispatches) and (msgs1, ops1) == (n, n)
+    assert table == table1 and np.array_equal(ran, alone)
+    assert np.array_equal(ran[:, table].reshape(DEEP, n_pages, -1), words)
+
+
+def test_a_run_keeps_to_its_sequence_chunk_and_barriers():
+    """Two sequences' messages interleaved behind a held writer, a
+    ``sync``'s barrier and a give-up among them: a run pulls only its own
+    sequence's next messages forward and ends at a chunk's end and at a
+    barrier, the barrier is reached once everything before it is
+    dispatched and before anything after it, and the given-up sequence's
+    pages go back only behind its queued writes; ``sync`` returns with
+    every write dispatched."""
+    c = KvPageCache(DEEP, 64, T, 8, chunk_pages=C)
+    log = []
+    flush, drop = c._flush, c.drop
+
+    def spy_flush(seq):
+        log.append((seq.ordinal, c._run[0][2]["layer"], len(c._run)))
+        flush(seq)
+
+    def spy_drop(seq):
+        log.append(("drop", seq.ordinal))
+        drop(seq)
+
+    c._flush, c.drop = spy_flush, spy_drop
+    try:
+        a = c.admit(0, 0, 2 * C * T)
+        b = c.admit(1, 1, C * T)
+        xa = messages(deep_words(1, 2 * C))
+        xb = messages(deep_words(2, C))
+        go = hold(c)
+        for seq, x in ((a, xa[0]), (a, xa[1]), (b, xb[0]), (a, xa[2]),
+                       (b, xb[1])):
+            c.submit(seq, x)
+        reached = []
+
+        class Barrier(threading.Event):   # what sync() hands over
+            def set(self):
+                reached.append(list(log))
+                super().set()
+
+        c._writes.put(Barrier())
+        for x in xa[3:]:
+            c.submit(a, x)
+        for x in xb[2:4]:
+            c.submit(b, x)
+        c.give_up(b)
+        go.set()
+        a.future.result(timeout=30)
+        c.sync()
+        assert log == [(0, 0, 3), (1, 0, 2),         # the barrier
+                       (0, 3, 3),                    # the chunk's end
+                       (0, 0, 4), (0, 4, 2),         # chunk 1: cut at four
+                       (1, 2, 2), ("drop", 1)]       # then the give-up
+        assert reached == [log[:2]]
+        assert c.free_pages() == 64 - 2 * C
+    finally:
+        c.close()
+
+
+def test_a_writer_that_keeps_up_dispatches_each_message_alone():
+    """Fed a message at a time, the writer makes a program call a message,
+    each page count's own program; the run programs, one a length up to
+    four, carry the scatter's name, as the single ones do."""
+    c = KvPageCache(DEEP, 64, T, 8, chunk_pages=C)
+    try:
+        assert sorted(c._programs) == list(range(1, C + 1))
+        assert sorted(c._page_runs) == [2, 3, 4]
+        assert all("tpurpc_kv_pages" in p.as_text() for p in
+                   list(c._programs.values()) + list(c._page_runs.values()))
+    finally:
+        c.close()
+    _, _, (msgs, ops) = deep(deep_words(5, 2 * C + 3), held=False)
+    assert msgs == ops == 3 * DEEP

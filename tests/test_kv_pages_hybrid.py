@@ -431,9 +431,10 @@ def test_state_writes_are_dispatched_by_the_cache_s_own_thread():
 
     c._dispatch_state = spy
     try:
-        ops0, bytes0, n0 = counters("lens_kv_state_write_ops",
-                                    "lens_kv_state_write_bytes",
-                                    "kv_state_bytes_written")
+        msgs0, bytes0, n0, ops0 = counters("kv_messages_written",
+                                           "lens_kv_state_write_bytes",
+                                           "kv_state_bytes_written",
+                                           "lens_kv_state_write_ops")
         words = Seq(5, 2)
         seq = c.admit(0, 0, 2 * T)
         for tree in words.messages():
@@ -445,10 +446,12 @@ def test_state_writes_are_dispatched_by_the_cache_s_own_thread():
                           for s in range(S)]
         assert all(e[1] == "tpurpc-kv-write" for e in log)
         assert [e[0] for e in log].count("release") == L + S
-        got = counters("lens_kv_state_write_ops", "lens_kv_state_write_bytes",
-                       "kv_state_bytes_written")
-        assert got == [ops0 + S, bytes0 + S * c.state_bytes,
-                       n0 + S * c.state_bytes]
+        got = counters("kv_messages_written", "lens_kv_state_write_bytes",
+                       "kv_state_bytes_written", "lens_kv_state_write_ops")
+        assert got[:3] == [msgs0 + L + S, bytes0 + S * c.state_bytes,
+                           n0 + S * c.state_bytes]
+        # an op a dispatch: a run of states is one
+        assert ops0 + 1 <= got[3] <= ops0 + S
         assert c.state_bytes == 2 * 4 * 8 * 4 + 3 * 8 * 2
     finally:
         c.close()
@@ -602,8 +605,9 @@ def test_two_prefill_workers_over_rpc(tpu_platform):
                           kind="stream_stream", device=True)
     srv.start()
     port = srv.add_insecure_port("127.0.0.1:0")
-    names = ("lens_kv_state_write_ops", "kv_seqs_acked",
-             "tensor_device_degraded")
+    names = ("kv_states_written", "kv_seqs_acked",
+             "tensor_device_degraded", "kv_messages_written",
+             "lens_kv_state_write_ops")
     before = counters(*names)
     plans = {k: [Seq(10 * k + s, n) for s, n in enumerate([3, 9 + 4 * k, 5])]
              for k in range(2)}
@@ -641,4 +645,125 @@ def test_two_prefill_workers_over_rpc(tpu_platform):
     for k in range(2):
         assert seen[k] == [(s, True) for s in range(3)]
     got = [a - b for a, b in zip(counters(*names), before)]
-    assert got == [6 * S, 6, 0]
+    messages = sum(-(-w.n // C) * L + S for p in plans.values() for w in p)
+    assert got[:4] == [6 * S, 6, 0, messages]
+    assert 1 <= got[4] <= 6 * S     # an op a dispatch, of one or a run
+
+
+
+# -- runs: a writer behind dispatches a sequence's queued messages at once ----------
+
+def hold(c):
+    """Holds the writing thread until the returned Event is set, so that it
+    then finds everything handed over meanwhile queued."""
+    go = threading.Event()
+
+    class Held(threading.Event):
+        def set(self):
+            go.wait(30)
+            super().set()
+
+    c._writes.put(Held())
+    return go
+
+
+def written(words, state_layers, behind=True):
+    """A cache given the sequence ``words`` whole, with the writer held
+    until all is handed over (``behind``), or one message at a time, each
+    dispatched before the next goes: its stores, and the messages and the
+    page and state dispatches counted."""
+    c = cache(state_layers=state_layers)
+    try:
+        names = ("kv_messages_written", "lens_kv_write_ops",
+                 "lens_kv_state_write_ops")
+        before = counters(*names)
+        go = hold(c) if behind else None
+        seq = c.admit(0, 0, words.n * T)
+        for tree in words.messages(state_layers):
+            c.submit(seq, tree.get("pages", tree))
+            if go is None:
+                c.sync()
+        if go is not None:
+            go.set()
+        seq.future.result(timeout=30)
+        assert_holds(c, seq, words)
+        got = [a - b for a, b in zip(counters(*names), before)]
+        return [np.asarray(a).copy() for a in (c.store,) + c.states], got
+    finally:
+        c.close()
+
+
+@pytest.mark.parametrize("layers, n_pages, page_ops, state_ops", [
+    # states 0-1 a run ended by page layer 2; full chunks a run of 2
+    (STATE_LAYERS, 4, 2, 2),
+    (STATE_LAYERS, 9, 4, 2),
+    # states 0-2 a run; page layers 3-4 of a full last chunk a run too
+    ((0, 1, 2), 8, 2, 1),
+    ((0, 1, 2), 5, 3, 1),
+])
+def test_a_run_stores_what_one_message_at_a_time_stores(layers, n_pages,
+                                                        page_ops, state_ops):
+    """A writer that finds a sequence's messages queued writes consecutive
+    state layers in one dispatch, ended by a page layer, and full-chunk
+    page layers of one chunk in one: the stores end bit for bit as those
+    written a message a dispatch, and hold the words sent."""
+    words = Seq(n_pages + 40, n_pages)
+    ran, (msgs, pages, states) = written(words, layers)
+    alone, got1 = written(words, layers, behind=False)
+    n = -(-n_pages // C) * L + S
+    assert (msgs, pages, states) == (n, page_ops, state_ops)
+    assert got1[0] == got1[1] + got1[2] == n
+    assert all(np.array_equal(a, b) for a, b in zip(ran, alone))
+
+
+def test_a_run_of_states_keeps_to_its_sequence_and_kind():
+    """Two sequences' last chunks interleaved behind a held writer: each
+    state run pulls only its own sequence's next states forward, and ends
+    where a page layer comes; each sequence is acknowledged."""
+    c = cache()
+    log = []
+    flush = c._flush
+
+    def spy(seq):
+        x, _, marks, _ = c._run[0]
+        kind = "state" if isinstance(x, tuple) else "page"
+        log.append((seq.ordinal, kind, marks["layer"], len(c._run)))
+        flush(seq)
+
+    c._flush = spy
+    try:
+        a, b = c.admit(0, 0, 2 * T), c.admit(1, 1, 2 * T)
+        ta = [t.get("pages", t) for t in Seq(1, 2).messages()]
+        tb = [t.get("pages", t) for t in Seq(2, 2).messages()]
+        go = hold(c)
+        for x, y in zip(ta, tb):
+            c.submit(a, x)
+            c.submit(b, y)
+        go.set()
+        assert [f.result(timeout=30) for f in (a.future, b.future)] == [
+            {"seq": 0, "pages": 2}, {"seq": 1, "pages": 2}]
+        assert log == [(0, "state", 0, 2), (1, "state", 0, 2),
+                       (0, "page", 0, 1), (1, "page", 0, 1),
+                       (0, "state", 2, 1), (1, "state", 2, 1),
+                       (0, "page", 1, 1), (1, "page", 1, 1)]
+    finally:
+        c.close()
+
+
+@pytest.mark.parametrize("layers, runs", [(STATE_LAYERS, [2]),
+                                          ((0, 1, 2), [2, 3]),
+                                          ((0, 2, 4), [])])
+def test_a_writer_that_keeps_up_dispatches_each_state_alone(layers, runs):
+    """Fed a message at a time, the writer makes a program call a message;
+    the state run programs, one a length up to the longest stretch of
+    state layers, carry the state program's name."""
+    c = cache(state_layers=layers)
+    try:
+        assert sorted(c._programs) == list(range(1, C + 1))
+        assert sorted(c._state_runs) == runs
+        assert all("tpurpc_kv_state" in p.as_text() for p in
+                   [c._state_write] + list(c._state_runs.values()))
+    finally:
+        c.close()
+    _, (msgs, pages, states) = written(Seq(3, 6), layers, behind=False)
+    assert msgs == pages + states == 2 * L + S and states == S

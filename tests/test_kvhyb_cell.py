@@ -325,6 +325,39 @@ def test_a_reader_reads_the_change_and_nothing_of_a_bare_tree(name):
         assert 0 < EXPECT[name] < 100
 
 
+#: the writing thread's counters in a window where runs formed: 9,000
+#: messages in 2,000 page dispatches and 1,600 state dispatches
+RUNS = {"kv_messages_written": 9_000, "lens_kv_write_ops": 2_000,
+        "lens_kv_write_busy_ns": 2_000 * 1_000_000,
+        "lens_kv_state_write_ops": 1_600,
+        "lens_kv_state_write_busy_ns": 1_600 * 1_500_000}
+EXPECT_RUNS = {"kv_msgs_per_dispatch.kvh": 9_000 / 3_600,
+               "kv_write_msg_us.kvh": (2e9 + 2.4e9) / 9_000 / 1e3}
+
+
+@pytest.mark.parametrize("name", sorted(EXPECT_RUNS))
+def test_a_run_reader_reads_the_change_and_nothing_of_a_bare_tree(name):
+    """Both cells' readers of the writing thread's runs: the change's
+    counters read, a bare tree and the parent's program (the same stages,
+    no count of messages) read nothing, and the page cache alone reads its
+    page dispatches."""
+    read = reader(name)
+    run = copy.deepcopy(RUN)
+    run["counters"].update(RUNS)
+    assert read(run) == pytest.approx(EXPECT_RUNS[name])
+    assert read(copy.deepcopy(BARE)) is None
+    assert read(copy.deepcopy(RUN)) is None
+    run["counters"] = {k: v for k, v in RUNS.items() if "state" not in k}
+    run["counters"]["kv_messages_written"] = 2_500
+    assert read(run) == pytest.approx(
+        {"kv_msgs_per_dispatch.kvh": 1.25, "kv_write_msg_us.kvh": 800.0}[name])
+    entry = MANIFEST["per_layer"][-2 + sorted(EXPECT_RUNS).index(name)]
+    assert (entry["name"], entry["layer"], entry["source"], entry["moves"],
+            entry["workloads"]) == (name, "paged KV cache", "program_counter",
+                                    "hbm_gbytes_s", ["kvh_mla_c8",
+                                                     "kvh_hyb_c8"])
+
+
 def test_slots_never_short_read_zero_and_a_page_cache_reads_nothing():
     run = copy.deepcopy(RUN)
     del run["counters"]["kv_admit_waits_slots"]   # a delta drops a 0
@@ -339,7 +372,8 @@ def test_slots_never_short_read_zero_and_a_page_cache_reads_nothing():
 def test_the_manifest_gained_the_cell_and_lost_nothing():
     assert manifest_check.problems(MANIFEST, ROOT) == []
     assert [e["name"] for e in HYB] == list(EXPECT)
-    assert MANIFEST["per_layer"][-len(EXPECT):] == HYB
+    start = MANIFEST["per_layer"].index(HYB[0])
+    assert MANIFEST["per_layer"][start:start + len(EXPECT)] == HYB
     assert all(e["moves"] == "hbm_gbytes_s" for e in HYB)
     assert [w["name"] for w in MANIFEST["workloads"]][-2:] == [
         "kvh_mla_c8", "kvh_hyb_c8"]

@@ -234,19 +234,22 @@ HOPS: Tuple[Tuple[str, str], ...] = (
                  "sequences offered before it and for acknowledgements that "
                  "make room (one op an admission that waited; handler "
                  "thread)"),
-    ("kv_write", "one page message's write dispatched: the scatter program "
-                 "jit_tpurpc_kv_pages, on the cache's writing thread, its "
-                 "span carrying the handler's call and message, the layer "
-                 "and the page count (asynchronous, so not device time)"),
+    ("kv_write", "one dispatch of page messages: the scatter program "
+                 "jit_tpurpc_kv_pages of one message or of a run of a "
+                 "sequence's next ones, on the cache's writing thread, its "
+                 "span carrying the first's call and message and layer, a "
+                 "message's page count and the messages, msgs "
+                 "(asynchronous, so not device time)"),
     ("kv_ack", "a sequence from its last write's dispatch until its "
                "acknowledgement is resolved: the device finishing it and "
                "the acknowledging thread's turn (counters only: one op a "
                "sequence)"),
-    ("kv_state_write", "one state message's write dispatched: the program "
-                       "jit_tpurpc_kv_state, on the cache's writing "
-                       "thread, its span carrying the handler's call and "
-                       "message, the state layer and the slot "
-                       "(asynchronous, so not device time)"),
+    ("kv_state_write", "one dispatch of state messages: the program "
+                       "jit_tpurpc_kv_state of one message or of a run at "
+                       "consecutive state layers, on the cache's writing "
+                       "thread, its span carrying the first's call, "
+                       "message and state layer, the slot and the "
+                       "messages, msgs (asynchronous, so not device time)"),
 )
 
 HOP_NAMES: Tuple[str, ...] = tuple(name for name, _ in HOPS)

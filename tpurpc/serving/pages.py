@@ -56,14 +56,21 @@ ends as soon as those ahead of it are acknowledged: it cannot deadlock. A
 sequence with more pages than the cache fails its own stream, with an
 error.
 
-**The write** is ONE jitted, donated scatter a message,
+**The write** is ONE jitted, donated scatter a dispatch,
 ``jit_tpurpc_kv_pages``, one compiled shape a page count 1 ... C. A message
 lands as the ``[n, T, W]`` array it was sent as, and any program that reads
 it is compiled for that ``n``: so the scatter is, all C shapes built side by
 side in :meth:`_warm`, and no page is padded on the wire or on the device.
-Where the pages go comes from the sequence's state on the device (its block
-table and a cursor of the messages written, put there once a sequence):
-nothing crosses from the host a message beyond the message itself. The
+A dispatch may carry a *run*: a message and the same sequence's next ones,
+already queued behind it, of one kind, up to a kind's longest program. A
+run of pages is full chunks (``n = C``, the count every chunk but a
+sequence's last has) in consecutive layers of one chunk, a run of states
+consecutive state layers; the run programs, one a length, are compiled
+beside the others and keep their names, and a run of one is the message's
+own program. Where the pages go comes from the sequence's state on the
+device (its block table and a cursor of the messages written, put there
+once a sequence): nothing crosses from the host a message beyond the
+message itself. The
 pages travel, land and are stored as their bits, unsigned integers, and no
 program ever holds them as floats: on a TPU v5e even
 ``lax.bitcast_convert_type`` of a landed ``bfloat16`` array to ``uint16``
@@ -80,8 +87,14 @@ is a ``queue.SimpleQueue``, whose ``put`` never blocks: no handler holds a
 lock while a dispatch gives the interpreter up, which a lock around the
 store made every handler queue behind (PERF.md 6). The credit comes back
 once the write is dispatched, so what a connection can have waiting is its
-ring. A stream that ends inside a sequence hands over the sequence's
-release too, so its pages go back only behind its own writes.
+ring. The thread takes what is queued when it looks, and a run pulls its
+sequence's next messages forward past other sequences' (whose order
+stands), never past a ``sync``, a give-up or the stop; it never waits for a
+run to grow, so a writer that keeps up dispatches each message alone. Each
+run costs one dispatch, and one give-up of the interpreter, where each of
+its messages cost one (PERF.md 6). A stream that ends inside a sequence
+hands over the sequence's release too, so its pages go back only behind
+its own writes.
 
 **The acknowledgement** is resolved by the cache's own thread
 (``tpurpc-kv-ack``), never a handler's: it waits for the sequence's last
@@ -89,12 +102,15 @@ write to be ready on the device, marks the sequence acknowledged (evictable)
 and resolves the Future, whose thread then writes the reply.
 
 Stages (``obs/lens.py``): ``kv_admit`` (a wait for pages, one op an
-admission that waited), ``kv_write`` (a message's dispatch on the writing
-thread, its span carrying the handler's call and message, the layer and the
-page count), ``kv_state_write`` (a state message's dispatch on the same
-thread, the same ids, the state layer and the slot), ``kv_ack`` (counters
-only: last write dispatched to acknowledgement). Counters
-``kv_pages_written``, ``kv_seqs_admitted``, ``kv_seqs_acked``,
+admission that waited), ``kv_write`` (a dispatch of page messages on the
+writing thread, its span carrying the handler's call and message of the
+first, its layer, a message's page count and the messages, ``msgs``),
+``kv_state_write`` (a dispatch of state messages on the same thread, the
+same ids, the first's state layer, the slot and ``msgs``), ``kv_ack``
+(counters only: last write dispatched to acknowledgement). Counters
+``kv_messages_written`` (messages dispatched, of either kind: over the two
+stages' ops, the messages a dispatch), ``kv_pages_written``,
+``kv_seqs_admitted``, ``kv_seqs_acked``,
 ``kv_seqs_evicted``, ``kv_pages_evicted``; gauge ``kv_pages_free``. A cache
 with state adds ``kv_states_written``, ``kv_state_bytes_written``,
 ``kv_slots_evicted``, ``kv_admit_waits_slots`` / ``kv_admit_waits_pages``
@@ -107,6 +123,7 @@ from __future__ import annotations
 
 import collections
 import functools
+import itertools
 import queue
 import threading
 import time
@@ -134,8 +151,14 @@ _LOG_EVENTS = 1 << 16
 #: the write programs' shapes compiled side by side: one compile holds a
 #: host core for about 0.3 s, and the cache has one a page count
 _COMPILERS = 8
+#: the longest run of full-chunk page messages one dispatch takes: a
+#: connection's default 16 MiB of ring credit holds three of a 64-page
+#: chunk's 4.5 MiB messages, so a longer run never forms; each length is
+#: one more program compiled
+_PAGE_RUN = 4
 
 _WRITTEN = _metrics.counter("kv_pages_written")
+_MESSAGES = _metrics.counter("kv_messages_written")
 _ADMITTED = _metrics.counter("kv_seqs_admitted")
 _ACKED = _metrics.counter("kv_seqs_acked")
 _EVICTED = _metrics.counter("kv_seqs_evicted")
@@ -151,23 +174,27 @@ _SLOTS_FREE_G = _metrics.fleet("kv_slots_free", lambda c: c.free_slots())
 
 @functools.lru_cache(maxsize=None)
 def _write_program(layers: int, chunk: int, page_shape: tuple):
-    """``write(store, state, x) -> (store, state)``: message ``state[0]`` of
-    a sequence into the store, and the cursor stepped. ``state`` is
-    ``[cursor, block table...]``; ``x`` is the words of ``[n, T, W]``
-    pages. Both the store and the state are donated."""
+    """``write(store, state, *xs) -> (store, state)``: messages
+    ``state[0]``, ``state[0] + 1``, ... of a sequence into the store, one
+    ``x`` each, and the cursor stepped by as many. ``state`` is ``[cursor,
+    block table...]``; an ``x`` is the words of ``[n, T, W]`` pages. Both
+    the store and the state are donated. One ``x`` is a message's own
+    program, several a run's."""
     import jax
     from jax import lax
 
-    def write(store, state, x):
-        n = x.shape[0]
+    def write(store, state, *xs):
         cursor = state[0]
-        layer = cursor % layers
-        first = cursor // layers * chunk
-        ids = lax.dynamic_slice(state, (first + 1,), (n,))
-        # a table entry out of range is a page nobody owns: dropped
-        store = store.at[layer, ids].set(x.reshape((n,) + page_shape),
-                                         mode="drop")
-        return store, state.at[0].add(1)
+        for i, x in enumerate(xs):
+            n = x.shape[0]
+            at = cursor + i if i else cursor
+            layer = at % layers
+            first = at // layers * chunk
+            ids = lax.dynamic_slice(state, (first + 1,), (n,))
+            # a table entry out of range is a page nobody owns: dropped
+            store = store.at[layer, ids].set(x.reshape((n,) + page_shape),
+                                             mode="drop")
+        return store, state.at[0].add(len(xs))
 
     write.__name__ = write.__qualname__ = PROGRAM
     return jax.jit(write, donate_argnums=(0, 1))
@@ -175,18 +202,21 @@ def _write_program(layers: int, chunk: int, page_shape: tuple):
 
 @functools.lru_cache(maxsize=None)
 def _state_program():
-    """``write(stores, state, xs) -> (stores, state)``: a state message, one
-    array a leaf, into the sequence's slot of each leaf's store at the state
-    layer the cursor names, and the cursor stepped. ``state`` ends ``[...,
-    state cursor, slot]``. The stores and the state are donated."""
+    """``write(stores, state, *xss) -> (stores, state)``: state messages,
+    one tuple of arrays (a leaf each) an ``xs``, into the sequence's slot of
+    each leaf's store at consecutive state layers from the one the cursor
+    names, and the cursor stepped by as many. ``state`` ends ``[..., state
+    cursor, slot]``. The stores and the state are donated."""
     import jax
 
-    def write(stores, state, xs):
+    def write(stores, state, *xss):
         layer, slot = state[-2], state[-1]
-        # a slot out of range is one nobody owns: dropped
-        stores = tuple(s.at[layer, slot].set(x, mode="drop")
-                       for s, x in zip(stores, xs))
-        return stores, state.at[-2].add(1)
+        for i, xs in enumerate(xss):
+            at = layer + i if i else layer
+            # a slot out of range is one nobody owns: dropped
+            stores = tuple(s.at[at, slot].set(x, mode="drop")
+                           for s, x in zip(stores, xs))
+        return stores, state.at[-2].add(len(xss))
 
     write.__name__ = write.__qualname__ = STATE_PROGRAM
     return jax.jit(write, donate_argnums=(0, 1))
@@ -296,7 +326,11 @@ class KvPageCache:
         self._writes: queue.SimpleQueue = queue.SimpleQueue()
         #: to the acknowledging thread: sequences, None to stop
         self._acks: queue.SimpleQueue = queue.SimpleQueue()
-        self._programs, self._state_write = self._warm()
+        #: what the writing thread gathers for its next dispatch: ``(x,
+        #: ids, marks, bytes)`` a message of one sequence and kind
+        self._run: List[tuple] = []
+        (self._programs, self._page_runs, self._state_write,
+         self._state_runs) = self._warm()
         self._writer = threading.Thread(target=self._write_loop,
                                         name="tpurpc-kv-write", daemon=True)
         self._acker = threading.Thread(target=self._ack_loop,
@@ -341,12 +375,20 @@ class KvPageCache:
         self._last_kinds = tuple(i in layers for i in range(model))
         #: the device's state of a sequence ends [state cursor, slot]
         self._tail = 2 if self.slots else 0
+        #: the longest stretch of consecutive state layers: the longest run
+        #: of state messages
+        self._state_run = max((len(list(g)) for kind, g
+                               in itertools.groupby(self._last_kinds) if kind),
+                              default=0)
 
     def _warm(self):
-        """The write program of every page count, compiled side by side and
-        run once writing nothing (a table of out-of-range pages drops every
-        page): ``{n: compiled}``; and the state program, likewise (a slot
-        out of range), or None."""
+        """The write programs, compiled side by side and each run once
+        writing nothing (a table of out-of-range pages drops every page, a
+        slot out of range every state): ``{n: compiled}`` of every page
+        count; ``{k: compiled}`` of every run of k full-chunk page messages,
+        k = 2 ... min(``_PAGE_RUN``, layers); the state program, or None;
+        and ``{k: compiled}`` of every run of k states, k = 2 ... the
+        longest stretch of state layers."""
         import jax
         from jax.sharding import SingleDeviceSharding
 
@@ -359,25 +401,30 @@ class KvPageCache:
         store = spec(self._store.shape, self._store.dtype)
         state = spec((1 + self._table_len + self._tail,), np.int32)
         shape = (self.page_tokens, self.width)
+        leaves = tuple(spec(shape, dtype) for shape, dtype
+                       in self.state_leaves.values())
 
-        def build(n):
+        def build(n, k=1):
             return write.lower(store, state,
-                               spec((n,) + shape, np.uint16)).compile()
+                               *[spec((n,) + shape, np.uint16)] * k).compile()
 
-        def build_state():
+        def build_state(k):
             return _state_program().lower(
                 tuple(spec(s.shape, s.dtype) for s in self._states), state,
-                tuple(spec(shape, dtype) for shape, dtype
-                      in self.state_leaves.values())).compile()
+                *[leaves] * k).compile()
 
         counts = range(1, self.chunk + 1)
+        runs = range(2, min(_PAGE_RUN, self.layers) + 1)
+        state_runs = range(1, self._state_run + 1)
         pool = ThreadPoolExecutor(min(_COMPILERS, self.chunk),
                                   thread_name_prefix="tpurpc-kv-compile")
         with pool:
-            state_write = pool.submit(build_state) if self.slots else None
+            state_writes = {k: pool.submit(build_state, k)
+                            for k in state_runs}
+            page_runs = {k: pool.submit(build, self.chunk, k) for k in runs}
             programs = dict(zip(counts, pool.map(build, counts)))
-            if state_write is not None:
-                state_write = state_write.result()
+            page_runs = {k: f.result() for k, f in page_runs.items()}
+            state_writes = {k: f.result() for k, f in state_writes.items()}
         empty = np.full(1 + self._table_len + self._tail, self.pages,
                         np.int32)
         empty[0] = 0
@@ -385,15 +432,23 @@ class KvPageCache:
             x = jax.device_put(np.zeros((n,) + shape, np.uint16), self.device)
             self._store, _ = program(self._store,
                                      jax.device_put(empty, self.device), x)
+        for k, program in page_runs.items():
+            # x: the full chunk's, from the loop above
+            self._store, _ = program(self._store,
+                                     jax.device_put(empty, self.device),
+                                     *[x] * k)
         self._store.block_until_ready()
-        if state_write is not None:
+        if state_writes:
             empty[-2:] = 0, self.slots
             xs = tuple(jax.device_put(np.zeros(shape, dtype), self.device)
                        for shape, dtype in self.state_leaves.values())
-            self._states, _ = state_write(
-                self._states, jax.device_put(empty, self.device), xs)
+            for k, program in state_writes.items():
+                self._states, _ = program(
+                    self._states, jax.device_put(empty, self.device),
+                    *[xs] * k)
             jax.block_until_ready(self._states)
-        return programs, state_write
+        return (programs, page_runs, state_writes.pop(1, None),
+                state_writes)
 
     # -- admission -----------------------------------------------------------
 
@@ -553,8 +608,7 @@ class KvPageCache:
         if self.is_state(seq, k):
             x = self._state_message(seq, k, x)
         else:
-            chunks = -(-len(seq.pages) // self.chunk)
-            first = min(k // self.layers, chunks - 1) * self.chunk
+            first = self._chunk_of(seq, k) * self.chunk
             n = min(self.chunk, len(seq.pages) - first)
             want = (n, self.page_tokens, self.width)
             if (tuple(getattr(x, "shape", ())) != want
@@ -611,61 +665,135 @@ class KvPageCache:
 
     def _write_loop(self) -> None:
         """The writing thread: every message in the order it was handed
-        over, which is each sequence's own order."""
+        over, which is each sequence's own order, a run a dispatch
+        (:meth:`_run_of`); it waits only for the queue to be empty."""
+        pending: collections.deque = collections.deque()
         while True:
-            item = self._writes.get()
+            if not pending:
+                pending.append(self._writes.get())
+            try:
+                while True:
+                    pending.append(self._writes.get_nowait())
+            except queue.Empty:
+                pass
+            item = pending.popleft()
             if item is None:
                 return
             if isinstance(item, threading.Event):
                 item.set()
                 continue
-            seq, x, leases, ids = item
-            if x is None:
-                self.drop(seq)
+            if item[1] is None:
+                self.drop(item[0])
                 continue
-            try:
-                if seq.error is None:
+            self._write(item[0], self._run_of(item, pending))
+
+    def _run_of(self, head, pending: collections.deque) -> list:
+        """``head`` and the messages of its sequence that one dispatch takes
+        with it, taken out of ``pending``: the sequence's next messages in
+        its order, found before any barrier (an Event, a give-up, the stop),
+        while each is of the head's kind and, for pages, of the head's chunk,
+        up to the kind's longest program. Only full-chunk page messages and
+        state messages make runs."""
+        seq, x = head[0], head[1]
+        state = isinstance(x, tuple)
+        if state:
+            limit = self._state_run
+        elif x.shape[0] == self.chunk:
+            limit = max(self._page_runs, default=1)
+        else:
+            limit = 1
+        k = seq.done
+        chunk = self._chunk_of(seq, k)
+        run, i = [head], 0
+        while len(run) < limit and i < len(pending):
+            item = pending[i]
+            if not isinstance(item, tuple) or item[1] is None:
+                break
+            if item[0] is not seq:
+                i += 1
+                continue
+            k += 1
+            if (self.is_state(seq, k) != state
+                    or self._chunk_of(seq, k) != chunk):
+                break
+            run.append(item)
+            del pending[i]
+        return run
+
+    def _chunk_of(self, seq: KvSeq, k: int) -> int:
+        """The chunk message ``k`` of the sequence belongs to."""
+        return min(k // self.layers, -(-len(seq.pages) // self.chunk) - 1)
+
+    def _write(self, seq: KvSeq, run: list) -> None:
+        """A run of the sequence's messages: each through its hook
+        (:meth:`_dispatch`, :meth:`_dispatch_state`), then one program call
+        for all (:meth:`_flush`), then their credit back."""
+        first = seq.done
+        self._run = []
+        try:
+            if seq.error is None:
+                for _, x, _, ids in run:
                     if isinstance(x, tuple):
                         self._dispatch_state(seq, x, ids)
                     else:
                         self._dispatch(seq, x, ids)
-            except Exception as exc:  # the sequence fails at its ack
-                seq.error = exc
-            finally:
+                    seq.done += 1
+                self._flush(seq)
+        except Exception as exc:  # the sequence fails at its ack
+            seq.error = exc
+        finally:
+            for _, _, leases, _ in run:
                 for lease in leases:
                     lease.release()
-            seq.done += 1
-            if seq.done == seq.messages:
-                seq.t_last = time.monotonic_ns()
-                self._acks.put(seq)
+        seq.done = first + len(run)
+        if seq.done == seq.messages:
+            seq.t_last = time.monotonic_ns()
+            self._acks.put(seq)
 
     def _dispatch(self, seq: KvSeq, x, ids) -> None:
         """Page message ``seq.done - seq.states_done`` of the sequence into
-        the store (the writing thread's)."""
+        the run (the writing thread's)."""
         n = x.shape[0]
-        call, mseq = ids or (None, 0)
-        marks = {"layer": (seq.done - seq.states_done) % self.layers,
-                 "pages": n}
-        with _lens.stage("kv_write", n * self.page_bytes, call=call,
-                         seq=mseq, marks=marks):
-            self._store, seq.state = self._programs[n](self._store,
-                                                       seq.state, x)
+        self._run.append((x, ids, {"layer": (seq.done - seq.states_done)
+                                   % self.layers, "pages": n},
+                          n * self.page_bytes))
         self._written[seq.key] += n
         _WRITTEN.inc(n)
 
     def _dispatch_state(self, seq: KvSeq, xs: tuple, ids) -> None:
-        """State message ``seq.states_done`` of the sequence into its slot
+        """State message ``seq.states_done`` of the sequence into the run
         (the writing thread's)."""
-        call, mseq = ids or (None, 0)
-        marks = {"layer": seq.states_done, "slot": seq.slot}
-        with _lens.stage("kv_state_write", self.state_bytes, call=call,
-                         seq=mseq, marks=marks):
-            self._states, seq.state = self._state_write(self._states,
-                                                        seq.state, xs)
+        self._run.append((xs, ids, {"layer": seq.states_done,
+                                    "slot": seq.slot}, self.state_bytes))
         seq.states_done += 1
         self._states_written[seq.key] += 1
         _STATES_WRITTEN.inc()
         _STATE_BYTES.inc(self.state_bytes)
+
+    def _flush(self, seq: KvSeq) -> None:
+        """One program call for the messages the hooks put in the run: the
+        message's own program for one, the run's program for more."""
+        if not self._run:
+            return
+        xs, k = [m[0] for m in self._run], len(self._run)
+        _, ids, marks, _ = self._run[0]
+        call, mseq = ids or (None, 0)
+        marks = dict(marks, msgs=k)
+        nbytes = sum(m[3] for m in self._run)
+        if isinstance(xs[0], tuple):
+            program = self._state_runs[k] if k > 1 else self._state_write
+            with _lens.stage("kv_state_write", nbytes, call=call,
+                             seq=mseq, marks=marks):
+                self._states, seq.state = program(self._states, seq.state,
+                                                  *xs)
+        else:
+            program = (self._page_runs[k] if k > 1
+                       else self._programs[xs[0].shape[0]])
+            with _lens.stage("kv_write", nbytes, call=call, seq=mseq,
+                             marks=marks):
+                self._store, seq.state = program(self._store, seq.state,
+                                                 *xs)
+        _MESSAGES.inc(k)
 
     # -- the acknowledgement -----------------------------------------------------
 
